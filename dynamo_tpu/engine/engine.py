@@ -22,7 +22,9 @@ every jit call and never leave the device.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
+import os
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -50,6 +52,7 @@ from ..resilience.faultpoints import FaultInjected
 from ..resilience.policy import MIGRATION_SIGNAL
 from ..runtime.engine import AsyncEngine, Context
 from .. import tracing
+from ..tracing.loop_clock import KINDS, LoopClock
 from .allocator import (
     Block,
     BlockAllocator,
@@ -61,6 +64,10 @@ from .offload import OffloadManager
 logger = logging.getLogger(__name__)
 
 PREFILL_BUCKETS = [16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192]
+#: engine.stats keys of the work counters, exported as engine_<key>_total
+WORK_COUNTERS = ("rows_dispatched", "rows_live", "prefill_tokens_dispatched",
+                 "prefill_tokens_padding", "attn_table_pages",
+                 "attn_live_pages")
 
 # the prefill-admission first-token sampler, jitted ONCE at module scope:
 # a per-call ``jax.jit(sample_first_token)`` built a fresh wrapper (and a
@@ -715,7 +722,6 @@ class JaxEngine(AsyncEngine):
         self.stats = {
             "requests_total": 0,
             "requests_active": 0,
-            "requests_waiting": 0,
             "tokens_generated": 0,
             "prompt_tokens_total": 0,
             "prefix_cache_hits_tokens": 0,
@@ -780,7 +786,17 @@ class JaxEngine(AsyncEngine):
             "autopilot_warmup_ms_total": 0.0,
             "autopilot_quarantined": 0,
             "autopilot_quarantines_total": 0,
+            # work counters, counted where the work is handed to the
+            # device (_note_decode_work / _note_prefill_work): decode
+            # rows of the batch against rows holding a live sequence,
+            # prefill bucket lengths against their real tokens, and the
+            # block-table pages the attention kernels were asked to
+            # walk against the pages that hold live tokens
+            **dict.fromkeys(WORK_COUNTERS, 0),
         }
+        # the loop's clock (tracing/loop_clock.py): seconds by phase,
+        # dispatches by kind and slow steps, kept in self.stats
+        self._clock = LoopClock(self.stats, tracing.RECORDER)
         # SLO observatory worker-side latency distributions
         # (docs/observability.md): fixed log-bucket histograms riding
         # load_metrics as serialized vectors -> WorkerLoad.hists -> the
@@ -807,6 +823,9 @@ class JaxEngine(AsyncEngine):
         #: names the program that compiled inside its window
         self.compile_ledger: list[dict] = []
         self._weight_bytes: Optional[int] = None
+        #: device_path_stats' view of how the leaves are laid out over
+        #: the mesh, read once per layout (reshard clears it)
+        self._leaf_layout: Optional[dict] = None
 
     def _use_pallas_for(self, mesh) -> bool:
         """Pallas attention path for ``mesh``: TPU backend + shapes the
@@ -917,7 +936,19 @@ class JaxEngine(AsyncEngine):
             "engine_prefix_cache_hits_tokens": self.stats[
                 "prefix_cache_hits_tokens"],
             "engine_mixed_steps": self.stats["mixed_steps"],
+            "engine_decode_steps_total": self.stats["decode_steps"],
+            "engine_slow_steps_total": self.stats["slow_steps"],
+            "engine_preemptions_total": self.stats["preemptions"],
+            "engine_kv_pages_used": self.allocator.used_count,
+            "engine_kv_pages_total": self.allocator.num_blocks - 1,
         }
+        for phase, s in self._clock.totals().items():
+            out[f'engine_loop_seconds_total{{phase="{phase}"}}'] = round(s, 6)
+        for kind in KINDS.values():
+            out[f'engine_steps_total{{kind="{kind}"}}'] = self.stats[
+                f"steps_{kind}"]
+        for name in WORK_COUNTERS:
+            out[f"engine_{name}_total"] = self.stats[name]
         for e in self.compile_ledger:
             key = ",".join(str(k) for k in e["key"]).replace('"', "'")
             out[f'engine_compiled_program_ms{{kind="{e["kind"]}",'
@@ -927,23 +958,39 @@ class JaxEngine(AsyncEngine):
             else jax.local_devices()[:1]
         )
         out["engine_mesh_devices"] = len(devices)
-        # how many devices hold a shard of each param/cache leaf (min
-        # over leaves), and the bytes that are partitioned, not copied
-        leaves = jax.tree.leaves(self.params) + [self.k_cache, self.v_cache]
-        out["engine_leaf_devices_min"] = min(
-            len({sh.device for sh in x.addressable_shards}) for x in leaves
-        )
-        out["engine_partitioned_bytes"] = sum(
-            x.nbytes for x in leaves
-            if x.addressable_shards[0].data.nbytes < x.nbytes
-        )
-        out["engine_leaf_bytes_total"] = sum(x.nbytes for x in leaves)
+        if self._leaf_layout is None:
+            try:
+                self._leaf_layout = self._read_leaf_layout()
+            except RuntimeError:
+                # a scrape can fall between a dispatch donating the KV
+                # cache and the engine taking the new one back ("Array
+                # has been deleted"): the next scrape reads the layout,
+                # this one must not lose every other series with it
+                logger.debug("leaf layout unreadable mid-dispatch",
+                             exc_info=True)
+        out.update(self._leaf_layout or {})
         for d in devices:
             ms = d.memory_stats() or {}  # CPU reports nothing
             for name in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
                 out[f'engine_device_{name}{{device="{d.id}"}}'] = int(
                     ms.get(name, 0))
         return out
+
+    def _read_leaf_layout(self) -> dict:
+        """How many devices hold a shard of each param/cache leaf (min
+        over leaves), and the bytes that are partitioned, not copied."""
+        leaves = jax.tree.leaves(self.params) + [self.k_cache, self.v_cache]
+        return {
+            "engine_leaf_devices_min": min(
+                len({sh.device for sh in x.addressable_shards})
+                for x in leaves
+            ),
+            "engine_partitioned_bytes": sum(
+                x.nbytes for x in leaves
+                if x.addressable_shards[0].data.nbytes < x.nbytes
+            ),
+            "engine_leaf_bytes_total": sum(x.nbytes for x in leaves),
+        }
 
     # ---------------- public api ----------------
 
@@ -1239,20 +1286,33 @@ class JaxEngine(AsyncEngine):
         return {"in_use": in_use, "limit": limit, "kv_pool": kv,
                 "weights": self._weight_bytes}
 
-    async def profile(self, seconds: float) -> str:
+    async def profile(self, seconds: float, out_dir: Optional[str] = None) -> str:
         """On-demand ``jax.profiler`` capture (the frontend's
-        ``POST /profile?seconds=N``): trace every device for N seconds
-        into a fresh directory and return its path (TensorBoard /
-        Perfetto-loadable). Runs in an executor thread so serving, lease
-        keepalives and scrapes continue underneath the capture."""
+        ``POST /profile?seconds=N&dir=<path>``): trace every device for N
+        seconds into ``out_dir`` (a fresh temporary directory without
+        one) and return its path (TensorBoard / Perfetto-loadable). Runs
+        in an executor thread so serving, lease keepalives and scrapes
+        continue underneath the capture. The Python tracer is OFF: it
+        slows exactly the host code whose gaps a profile is meant to
+        size, and under ``--trace`` the loop's own annotations
+        (tracing/loop_clock.py) say what the host did, on the clock of
+        the device ops."""
         import tempfile
 
-        out_dir = tempfile.mkdtemp(prefix="dynamo-profile-")
+        if out_dir is None:
+            out_dir = tempfile.mkdtemp(prefix="dynamo-profile-")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
 
         def _capture() -> str:
-            jax.profiler.start_trace(out_dir)
+            os.makedirs(out_dir, exist_ok=True)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
             try:
-                time.sleep(seconds)
+                # the trace's first event: readers place the capture on
+                # their own clock by it
+                with jax.profiler.TraceAnnotation("engine.profile"):
+                    time.sleep(seconds)
             finally:
                 jax.profiler.stop_trace()
             return out_dir
@@ -1706,6 +1766,7 @@ class JaxEngine(AsyncEngine):
         # weight-bytes attribution re-derives from the new params
         self._compiled_keys.clear()
         self._weight_bytes = None
+        self._leaf_layout = None
         # ---- committed ----
         faultpoints.hit_sync("mid_reshard", phase="committed")
         return {
@@ -1718,12 +1779,32 @@ class JaxEngine(AsyncEngine):
 
     # ---------------- scheduler loop ----------------
 
+    async def _on_device(self, fn, *args, lock: bool = True,
+                         first: str = "dispatch"):
+        """The loop task's way to the device executor, on the loop's
+        clock: the await is ``lag`` but for what the thunk itself spent
+        in ``dispatch`` and ``device`` (tracing/loop_clock.py); waiting
+        for the device lock is lag too. Comes back to the open phase."""
+        clk = self._clock
+        prev = clk.await_thunk()
+        try:
+            async with self._device_lock if lock else contextlib.nullcontext():
+                return await asyncio.get_running_loop().run_in_executor(
+                    None, clk.thunk, first, fn, *args)
+        finally:
+            clk.settle(prev)
+
     async def _loop(self) -> None:
+        clk = self._clock
+        clk.start("admit")
         try:
             while not self._closed:
+                clk.mark("admit")
                 if self._draining:
                     self._drain_tick()
                 if self._reshard_req is not None:
+                    # a morph owns the device for its hold window
+                    clk.mark("lag")
                     await self._reshard_step()
                     continue
                 admitted = await self._admit()
@@ -1748,6 +1829,7 @@ class JaxEngine(AsyncEngine):
                     self._wake.clear()
                     if self._has_pending_work():
                         continue
+                    clk.mark("idle")
                     await self._wake.wait()
                     continue
                 # a multi-prompt prefill pack with no decode batch is
@@ -1757,6 +1839,7 @@ class JaxEngine(AsyncEngine):
                 if self._n_active or self._mixed_fusable():
                     await self._decode_once()
                 # yield to the event loop so emissions flush
+                clk.mark("yield")
                 await asyncio.sleep(0)
         except asyncio.CancelledError:
             # engine close() with sequences in flight: fail them — their
@@ -1778,6 +1861,8 @@ class JaxEngine(AsyncEngine):
             # requests: fail fast with a retryable signature
             self._dead = "engine stopped: scheduler loop crashed"
             self._fail_all_owned(text=self._dead)
+        finally:
+            clk.stop()
 
     def _has_pending_work(self) -> bool:
         """Anything the idle scheduler must NOT sleep on."""
@@ -1885,9 +1970,11 @@ class JaxEngine(AsyncEngine):
                     seq.tokens[: seq.seq_len - 1], self.cfg.block_size,
                     salt=model_hash_salt(seq.model),
                 )
+                self._clock.mark("lag")
                 await self._offload_prejoin(
                     [s for _l, s in prompt_hashes]
                 )
+                self._clock.mark("admit")
             try:
                 ok = self._begin_prefill(seq, hashes=prompt_hashes)
             except Exception:  # noqa: BLE001
@@ -1937,7 +2024,6 @@ class JaxEngine(AsyncEngine):
                 break
             admitted |= await self._prefill_step()
         self.stats["requests_active"] = self._n_active
-        self.stats["requests_waiting"] = self._waiting_size()
         return admitted
 
     def _tokens_in_vocab(self, ids) -> bool:
@@ -2080,7 +2166,7 @@ class JaxEngine(AsyncEngine):
                     "engine.queue_wait", seq.trace,
                     ts=time.time() - waited_s, dur_ms=waited_s * 1e3,
                     request_id=seq.context.id,
-                    waiting=self._waiting_size(),
+                    waiting=self._waiting_size(), step=self._clock.seq,
                 )
         self._prefill_states.append(
             _PrefillState(seq=seq, pos=history, upload=upload)
@@ -2106,10 +2192,7 @@ class JaxEngine(AsyncEngine):
         # device work (jit dispatch + compile + host sync) runs in a worker
         # thread so lease keepalives / bus traffic stay live on the loop
         try:
-            async with self._device_lock:
-                first_token = await asyncio.get_running_loop().run_in_executor(
-                    None, self._prefill_chunk_device, st
-                )
+            first_token = await self._on_device(self._prefill_chunk_device, st)
         except Exception:
             # device failure: hand reserved host blocks back so the prefix
             # isn't silently lost from the offload tier (host arrays are
@@ -2118,7 +2201,9 @@ class JaxEngine(AsyncEngine):
             self._abort_prefill(st, FinishReason.ERROR)
             return False
         if first_token is None:
+            self._clock.step_done()
             return False  # more chunks to go
+        phase = self._clock.mark("emit")
         first_token, first_lp = first_token
         if seq.generated == 0:
             # first prefill only — a preemption replay's prefill is
@@ -2131,6 +2216,7 @@ class JaxEngine(AsyncEngine):
                     request_id=seq.context.id,
                     prompt_tokens=seq.prompt_len,
                     cached_prefix=seq.cached_prefix,
+                    step=self._clock.seq,
                 )
         self._drop_prefill_state(st)
         self._commit_full_blocks(seq)
@@ -2145,6 +2231,8 @@ class JaxEngine(AsyncEngine):
                 # an unconditional placement would index(None) on a full
                 # batch and crash the scheduler loop
                 self._remote_ready.append(seq)
+        self._clock.step_done()
+        self._clock.mark(phase)
         return True
 
     def _drop_prefill_state(self, st: "_PrefillState") -> None:
@@ -2382,6 +2470,7 @@ class JaxEngine(AsyncEngine):
         T = _bucket(len(chunk))
         toks = np.zeros(T, np.int32)
         toks[: len(chunk)] = chunk
+        self._note_prefill_work(T, len(chunk))
         if self.mirror is not None:
             logits, self.k_cache, self.v_cache = self._timed_dispatch(
                 lambda: self.mirror.lead_prefill(
@@ -2921,6 +3010,12 @@ class JaxEngine(AsyncEngine):
         steps), so it no longer collapses the window — though mixed
         dispatch itself never consults this (a fused step is inherently
         one decode step per chunk)."""
+        phase = self._clock.mark("admit")
+        n = self._window_steps()
+        self._clock.mark(phase)
+        return n
+
+    def _window_steps(self) -> int:
         batch_full = self._n_active >= self.cfg.max_batch_size
         actionable = (
             (bool(self._prefill_states) and not self._mixed_fusable())
@@ -2996,6 +3091,7 @@ class JaxEngine(AsyncEngine):
 
     async def _decode_once(self) -> None:
         cfg = self.cfg
+        self._clock.mark("provision")
         faultpoints.hit_sync("mid_decode")
         if self._mixed_fusable():
             # chunked prefills fuse into this iteration's decode step: a
@@ -3181,10 +3277,9 @@ class JaxEngine(AsyncEngine):
              for i in range(cfg.max_batch_size)],
             np.int32,
         )
-        async with self._device_lock:
-            toks = await asyncio.get_running_loop().run_in_executor(
-                None, self._dispatch_window, steps, n, pending, tokens_in
-            )
+        toks = await self._on_device(
+            self._dispatch_window, steps, n, pending, tokens_in
+        )
         self._inflight = {
             "toks": toks, "n": n,
             "lps": self._window_logprobs,
@@ -3195,6 +3290,7 @@ class JaxEngine(AsyncEngine):
             await self._emit_window(prev)
         if not pipe:
             await self._drain_inflight()
+        self._clock.step_done()
 
     def _propose_ngram(self) -> Optional[np.ndarray]:
         """Prompt-lookup drafts: match each sequence's trailing n-gram
@@ -3266,11 +3362,10 @@ class JaxEngine(AsyncEngine):
              for i in range(cfg.max_batch_size)],
             np.int32,
         )
-        async with self._device_lock:
-            out_toks, n_accs, lps = await asyncio.get_running_loop().run_in_executor(
-                None, self._dispatch_verify, window,
-                proposals.astype(np.int32), steps,
-            )
+        out_toks, n_accs, lps = await self._on_device(
+            self._dispatch_verify, window, proposals.astype(np.int32), steps,
+        )
+        self._clock.mark("emit")
         self.stats["decode_steps"] += 1
         for i, seq in list(enumerate(self._active)):
             if seq is None or seq.finished:
@@ -3298,6 +3393,7 @@ class JaxEngine(AsyncEngine):
             self._seq_lens[i] = seq.seq_len
             self._last_tokens[i] = seq.tokens[-1]
             self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
+        self._clock.step_done()
         return True
 
     async def _mixed_step_once(self) -> None:
@@ -3346,7 +3442,9 @@ class JaxEngine(AsyncEngine):
                     break
         if self._n_active == 0 and len(self._prefill_states) < 2:
             return  # a lone prefill alone: the alternating step is cheaper
+        self._clock.mark("admit")
         packed = self._split_mixed_budget()
+        self._clock.mark("provision")
         # dynlint: disable=async-blocking-call -- [B]-sized host int list, no device copy
         steps = np.asarray(
             [self._active[i].generated if self._active[i] else 0
@@ -3354,12 +3452,9 @@ class JaxEngine(AsyncEngine):
             np.int32,
         )
         try:
-            async with self._device_lock:
-                toks, lps, completed = await (
-                    asyncio.get_running_loop().run_in_executor(
-                        None, self._dispatch_mixed, packed, steps
-                    )
-                )
+            toks, lps, completed = await self._on_device(
+                self._dispatch_mixed, packed, steps
+            )
         except Exception:  # noqa: BLE001
             # a fused-dispatch failure (lowering/compile) is not
             # attributable to one prompt: fail every in-flight prefill,
@@ -3373,6 +3468,7 @@ class JaxEngine(AsyncEngine):
             for st in list(self._prefill_states):
                 self._abort_prefill(st, FinishReason.ERROR)
             return
+        self._clock.mark("emit")
         self.stats["decode_steps"] += 1
         self.stats["mixed_steps"] += 1
         self.stats["mixed_prefill_segments"] += len(packed)
@@ -3413,6 +3509,7 @@ class JaxEngine(AsyncEngine):
                         request_id=seq_p.context.id,
                         prompt_tokens=seq_p.prompt_len,
                         cached_prefix=seq_p.cached_prefix,
+                        step=self._clock.seq,
                     )
             self._drop_prefill_state(st)
             self._commit_full_blocks(seq_p)
@@ -3425,6 +3522,7 @@ class JaxEngine(AsyncEngine):
                     # the KV is landed, so queue for the next free slot
                     # exactly like a remotely-prefilled sequence
                     self._remote_ready.append(seq_p)
+        self._clock.step_done()
 
     def _split_mixed_budget(self) -> list[tuple["_PrefillState", int]]:
         """Pack the Sarathi token budget across the in-flight prefills:
@@ -3527,6 +3625,12 @@ class JaxEngine(AsyncEngine):
                     d_adapter_ids=jnp.asarray(self._adapter_ids),
                     p_adapter_ids=jnp.asarray(p_ids),
                 )
+            self._note_prefill_work(MP * T, int(valids_p.sum()))
+            self._note_decode_work(1, self._seq_lens, seg_pages=(
+                MP * cfg.max_blocks_per_seq,
+                int(((hists_p + valids_p + cfg.block_size - 1)
+                     // cfg.block_size).sum()),
+            ))
             out = self._timed_dispatch(lambda: llama.mixed_step(
                 self.params,
                 cfg.model,
@@ -3617,20 +3721,51 @@ class JaxEngine(AsyncEngine):
                         key[0], key[1:], wall_ms)
 
     def _timed_dispatch(self, thunk, key: Optional[tuple] = None,
-                        trace=None):
+                        trace=None, n: int = 1):
         """Run a device dispatch. ``key`` names its program bucket (kind +
         the shape coordinates the jit cache keys on): the first dispatch
         of each bucket is timed into the XLA compile ledger
         (_note_compile). Whatever the compiler raises — a Mosaic
         rejection of a Pallas kernel included — propagates: the engine
         serves the attention path it chose at construction
-        (attention_path) or fails, never a quiet substitute."""
+        (attention_path) or fails, never a quiet substitute. On the
+        loop's clock the thunk's ``dispatch`` phase ends when the call
+        returns (the program is enqueued); ``n`` is its device steps."""
         cold = key is not None and key not in self._compiled_keys
+        if key is not None:
+            self._clock.describe(key, cold, n, self._n_active,
+                                 self.cfg.max_batch_size)
+        faultpoints.hit_thread("mid_dispatch")
         t0 = time.perf_counter() if cold else 0.0
         out = thunk()
+        self._clock.enqueued()
         if cold:
             self._note_compile(key, (time.perf_counter() - t0) * 1e3, trace)
         return out
+
+    def _note_decode_work(self, n: int, seq_lens: np.ndarray,
+                          seg_pages: tuple = (0, 0)) -> None:
+        """Work counters of one decode, mixed or verify dispatch, from
+        what the engine hands the device (no device read): the batch's
+        rows x ``n`` steps against the rows holding a live sequence, and
+        the block-table pages the attention kernel is asked to walk
+        (rows x the table's width x steps, plus a mixed step's prefill
+        segments: ``seg_pages`` = (handed, live)) against the pages
+        that hold live tokens."""
+        st, bs = self.stats, self.cfg.block_size
+        rows, width = self._block_tables.shape
+        live = seq_lens[self._seq_lens > 0]
+        st["rows_dispatched"] += rows * n
+        st["rows_live"] += len(live) * n
+        st["attn_table_pages"] += rows * width * n + seg_pages[0]
+        st["attn_live_pages"] += int(((live + bs - 1) // bs).sum()) * n \
+            + seg_pages[1]
+
+    def _note_prefill_work(self, dispatched: int, real: int) -> None:
+        """Prefill tokens handed to the device (bucket length x
+        segments) against the real tokens among them."""
+        self.stats["prefill_tokens_dispatched"] += dispatched
+        self.stats["prefill_tokens_padding"] += dispatched - real
 
     def _dispatch_verify(
         self, window: np.ndarray, proposals: np.ndarray, steps: np.ndarray
@@ -3642,6 +3777,7 @@ class JaxEngine(AsyncEngine):
         positions = np.maximum(self._seq_lens - 1, 0).astype(np.int32)
         penalized = self._penalties_active()
         want_lp = self._logprobs_active()
+        self._note_decode_work(1, self._seq_lens)
         if self.mirror is not None:
             out = self._timed_dispatch(lambda: self.mirror.lead_verify(
                 self.params, window, proposals, positions,
@@ -3733,9 +3869,10 @@ class JaxEngine(AsyncEngine):
                 lp = tuple(np.asarray(a.addressable_data(0)) for a in lp)
             return toks, lp
 
-        toks_host, lps = await asyncio.get_running_loop().run_in_executor(
-            None, materialize
+        toks_host, lps = await self._on_device(
+            materialize, lock=False, first="device"
         )
+        phase = self._clock.mark("emit")
         n = window["n"]
         self.stats["decode_steps"] += n
         # emit window tokens in step order; a sequence that hits a stop
@@ -3769,6 +3906,7 @@ class JaxEngine(AsyncEngine):
             self._seq_lens[i] = seq.seq_len
             self._last_tokens[i] = seq.tokens[-1]
             self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
+        self._clock.mark(phase)
 
     def _dispatch_window(
         self, steps: np.ndarray, n: int, pending: int, tokens_in=None
@@ -3807,6 +3945,7 @@ class JaxEngine(AsyncEngine):
             np.maximum(self._seq_lens - 1, 0) + pending
         ).astype(np.int32)
         seq_lens = (self._seq_lens + pending).astype(np.int32)
+        self._note_decode_work(n, seq_lens)
         if self.mirror is not None:
             penalized = self._penalties_active()
             want_lp = self._logprobs_active()
@@ -3826,7 +3965,7 @@ class JaxEngine(AsyncEngine):
                 tokens_dev=tokens_in,
                 sync=False,  # device handle; materialized at emission so
                 # a pipelined next window dispatches without waiting
-            ), key=("decode", n, penalized, want_lp))
+            ), key=("decode", n, penalized, want_lp), n=n)
             toks, self.k_cache, self.v_cache = out[0], out[1], out[2]
             rest = list(out[3:])
             if penalized:
@@ -3873,12 +4012,12 @@ class JaxEngine(AsyncEngine):
                 rep_pens=jnp.asarray(self._rep_pens),
                 counts=self._pen_counts,
                 prompt_mask=self._pen_mask,
-            ), key=("decode", n, True, want_lp) + self._lora_key())
+            ), key=("decode", n, True, want_lp) + self._lora_key(), n=n)
             penalized = True
         else:
             out = self._timed_dispatch(
                 lambda: llama.decode_window(*args, **kw),
-                key=("decode", n, False, want_lp) + self._lora_key(),
+                key=("decode", n, False, want_lp) + self._lora_key(), n=n,
             )
             penalized = False
         toks, self.k_cache, self.v_cache = out[:3]
@@ -3917,7 +4056,7 @@ class JaxEngine(AsyncEngine):
             # pay only the seq.trace None-check above
             tracing.RECORDER.event(
                 "engine.first_token", trace=seq.trace,
-                request_id=seq.context.id,
+                request_id=seq.context.id, step=self._clock.seq,
             )
 
         finish: Optional[FinishReason] = None

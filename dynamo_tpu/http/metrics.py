@@ -133,7 +133,7 @@ class Metrics:
                     lines.append(f"{p}_{k} {v}")
             except Exception:  # noqa: BLE001 — a bad source must not
                 # break the whole exposition
-                logger.debug("metrics source failed", exc_info=True)
+                logger.warning("metrics source failed", exc_info=True)
         return "\n".join(lines) + "\n"
 
 

@@ -102,7 +102,7 @@ class HttpService(HttpServerBase):
         self.flight = None
         if flight is not None:
             self.attach_flight(flight)
-        # async callable (seconds -> trace dir) running jax.profiler on
+        # async callable (seconds[, out dir] -> trace dir) running jax.profiler on
         # the serving engine; wired by dynamo_run when the engine is
         # in-process (None = POST /profile answers 501)
         self.profiler = profiler
@@ -169,8 +169,9 @@ class HttpService(HttpServerBase):
     async def _trace_endpoint(self, writer, path: str, query: str) -> None:
         """``GET /trace/{request_id}[?format=chrome]`` — the assembled
         per-request timeline + TTFT decomposition (or Chrome trace-event
-        JSON); ``GET /trace`` lists collected trace ids + aggregate
-        percentiles."""
+        JSON); ``GET /trace/engine`` the in-process engine's
+        ``engine.step`` spans; ``GET /trace`` lists collected trace ids
+        + aggregate percentiles."""
         if self.tracing is None:
             raise HttpError(404, "tracing is not enabled", "tracing_disabled")
         if path in ("/trace", "/trace/"):
@@ -181,6 +182,14 @@ class HttpService(HttpServerBase):
             return
         trace_id = path[len("/trace/"):]
         fmt = "chrome" if "format=chrome" in query else "timeline"
+        if trace_id == "engine":
+            # the scheduler loop's steps live in the recorder's ring,
+            # not in the collector: the loop is one endless trace
+            spans = tracing.RECORDER.spans(name=tracing.STEP_SPAN)
+            await self._send_json(
+                writer, 200, tracing.chrome_trace(spans)
+                if fmt == "chrome" else {"spans": spans})
+            return
         body = self.tracing.render_trace(trace_id, fmt=fmt)
         if body is None:
             raise HttpError(404, f"no trace for {trace_id!r}", "trace_not_found")
@@ -212,18 +221,23 @@ class HttpService(HttpServerBase):
         await self._send_json(writer, 200, body)
 
     async def _profile_endpoint(self, writer, query: str) -> None:
-        """``POST /profile?seconds=N`` — run ``jax.profiler`` on the
-        in-process engine for N seconds and return the trace path."""
+        """``POST /profile?seconds=N[&dir=<path>]`` — run ``jax.profiler``
+        on the in-process engine for N seconds, into ``dir`` when given
+        (created if missing; else a fresh temporary directory), and
+        return the trace path."""
         if self.profiler is None:
             raise HttpError(
                 501, "profiler is not wired on this frontend "
                 "(in-process engine required)", "profiler_unavailable",
             )
         import math
+        from urllib.parse import unquote
 
-        seconds = 2.0
+        seconds, out_dir = 2.0, None
         for part in query.split("&"):
             k, _, v = part.partition("=")
+            if k == "dir" and v:
+                out_dir = unquote(v)
             if k == "seconds" and v:
                 try:
                     seconds = float(v)
@@ -235,7 +249,8 @@ class HttpService(HttpServerBase):
                     raise HttpError(400, f"bad seconds={v!r}")
         seconds = min(max(seconds, 0.1), 120.0)
         try:
-            trace_dir = await self.profiler(seconds)
+            trace_dir = await self.profiler(
+                *((seconds,) if out_dir is None else (seconds, out_dir)))
         except Exception as e:  # noqa: BLE001 — surface, don't 500-loop
             raise HttpError(
                 500, f"profiler failed: {type(e).__name__}: {e}",

@@ -15,7 +15,7 @@ Arming is programmatic (``faultpoints.arm(...)`` from a test) or via the
 
 Spec grammar (comma-separated): ``point:action[=delay_s][@after][xN]``
 — *action* is ``kill`` (raise :class:`FaultInjected` at the site) or
-``delay`` (async sites sleep ``delay_s``); ``@after`` fires on the
+``delay`` (async and executor-thread sites sleep ``delay_s``); ``@after`` fires on the
 Nth hit (default 1st); ``xN`` fires N times (default once, ``x-1``
 unlimited).
 
@@ -34,6 +34,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -63,6 +64,11 @@ POINTS = (
     # must leave the engine wholly on the old layout (N<=3) or wholly
     # on the new one (N=4), never half (docs/elastic_resharding.md)
     "mid_reshard",
+    # every device dispatch of the engine (_timed_dispatch), hit in the
+    # EXECUTOR thread just before the program is enqueued: a delay here
+    # is a dispatch that stalls (a retrace, a lock) with the event loop
+    # live — what the loop clock's slow-step line must name `dispatch`
+    "mid_dispatch",
 )
 
 ACTIONS = ("kill", "delay")
@@ -187,6 +193,19 @@ class FaultPoints:
             raise FaultInjected(point, arm.hits)
         logger.debug("delay fault at sync site %s ignored", point)
 
+    def hit_thread(self, point: str, **ctx) -> None:
+        """Executor-thread site (device dispatch). ``kill`` raises;
+        ``delay`` sleeps ``delay_s`` in the thread — the event loop
+        stays live, so this is a stalled dispatch, not a stalled loop."""
+        if not self._arms:
+            return
+        arm = self._fire(point)
+        if arm is None:
+            return
+        if arm.action == "kill":
+            raise FaultInjected(point, arm.hits)
+        time.sleep(arm.delay_s)
+
     async def hit(self, point: str, **ctx) -> None:
         """Async site. ``kill`` raises; ``delay`` sleeps ``delay_s``."""
         if not self._arms:
@@ -209,6 +228,7 @@ reset = FAULTS.reset
 armed = FAULTS.armed
 hit = FAULTS.hit
 hit_sync = FAULTS.hit_sync
+hit_thread = FAULTS.hit_thread
 
 _env_spec = os.environ.get(ENV_VAR, "")
 if _env_spec:

@@ -9,11 +9,12 @@ RequestEnvelope / disagg handoff / TCP prologue across processes),
 records spans in a near-zero-cost ring buffer, and assembles them into
 per-request timelines with a canonical TTFT decomposition
 (tokenize / route / queue wait / KV-transfer exposed-vs-hidden /
-prefill / first decode). See docs/tracing.md.
+prefill / first decode). The engine's scheduler loop has its own
+clock (``loop_clock``): per-step phase accounting, always on. See
+docs/tracing.md.
 """
 
 from .context import (
-    TRACE_ANNOTATION,
     TRACEPARENT_HEADER,
     TraceContext,
     current_trace,
@@ -26,44 +27,39 @@ from .context import (
 )
 from .collector import (
     TRACE_EVENTS_SUBJECT,
-    TRACE_EVENTS_WILDCARD,
     BusExporter,
     TraceCollector,
-    percentile,
+    chrome_trace,
 )
+from .loop_clock import STEP_SPAN
 from .span import (
     NULL_SPAN,
     RECORDER,
-    SpanRecorder,
     configure,
     enabled,
     event,
     span,
 )
-from .ttft import COMPONENTS, decompose, measured_ttft_ms
+from .ttft import COMPONENTS
 
 __all__ = [
     "BusExporter",
     "COMPONENTS",
     "NULL_SPAN",
     "RECORDER",
-    "SpanRecorder",
-    "TRACE_ANNOTATION",
+    "STEP_SPAN",
     "TRACEPARENT_HEADER",
     "TRACE_EVENTS_SUBJECT",
-    "TRACE_EVENTS_WILDCARD",
     "TraceCollector",
     "TraceContext",
+    "chrome_trace",
     "configure",
     "current_trace",
     "current_traceparent",
-    "decompose",
     "enabled",
     "event",
     "extract",
     "inject",
-    "measured_ttft_ms",
-    "percentile",
     "reset_trace",
     "set_trace",
     "span",

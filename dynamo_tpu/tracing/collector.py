@@ -44,6 +44,30 @@ def percentile(values: list[float], p: float) -> float:
     return xs[k]
 
 
+def chrome_trace(spans: list[dict]) -> dict:
+    """Chrome trace-event JSON: complete ("X") events per span,
+    instant ("i") events for zero-duration spans, one pid per
+    service so frontend/router/worker/prefill rows separate."""
+    events = []
+    for s in spans:
+        ev = {
+            "name": s["name"],
+            "cat": s.get("service", "proc"),
+            "ts": s["ts"] * 1e6,  # wall seconds -> microseconds
+            "pid": s.get("service", "proc"),
+            "tid": s["trace_id"][:8],
+            "args": dict(s.get("attrs") or {}),
+        }
+        if s["dur_ms"] > 0:
+            ev["ph"] = "X"
+            ev["dur"] = s["dur_ms"] * 1e3
+        else:
+            ev["ph"] = "i"
+            ev["s"] = "t"
+        events.append(ev)
+    return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
 class TraceCollector:
     """Assembles spans into per-request timelines. Works standalone
     (feed :meth:`ingest` directly, e.g. as a recorder sink) or
@@ -189,30 +213,8 @@ class TraceCollector:
 
     # ---- exports ----
     def chrome_trace(self, id_: str) -> Optional[dict]:
-        """Chrome trace-event JSON: complete ("X") events per span,
-        instant ("i") events for zero-duration spans, one pid per
-        service so frontend/router/worker/prefill rows separate."""
         spans = self.timeline(id_)
-        if spans is None:
-            return None
-        events = []
-        for s in spans:
-            ev = {
-                "name": s["name"],
-                "cat": s.get("service", "proc"),
-                "ts": s["ts"] * 1e6,  # wall seconds -> microseconds
-                "pid": s.get("service", "proc"),
-                "tid": s["trace_id"][:8],
-                "args": dict(s.get("attrs") or {}),
-            }
-            if s["dur_ms"] > 0:
-                ev["ph"] = "X"
-                ev["dur"] = s["dur_ms"] * 1e3
-            else:
-                ev["ph"] = "i"
-                ev["s"] = "t"
-            events.append(ev)
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return None if spans is None else chrome_trace(spans)
 
     def render_trace(self, id_: str, fmt: str = "timeline") -> Optional[dict]:
         """The ``/trace/{id}`` response body."""

@@ -142,15 +142,20 @@ class SpanRecorder:
         trace: TraceContext,
         ts: float,
         dur_ms: float,
+        to_sink: bool = True,
         **attrs: Any,
     ) -> None:
         """Record a span whose start/duration were measured elsewhere
-        (e.g. queue wait reconstructed at admission time)."""
+        (e.g. queue wait reconstructed at admission time).
+        ``to_sink=False`` keeps it in the ring only: a span of a trace
+        that never ends (the engine loop's) must not reach a collector,
+        which keeps every span of a trace."""
         if not self.enabled:
             return
-        self._record(name, trace.child(), ts, dur_ms, attrs)
+        self._record(name, trace.child(), ts, dur_ms, attrs, to_sink)
 
-    def _record(self, name, trace: TraceContext, ts, dur_ms, attrs) -> None:
+    def _record(self, name, trace: TraceContext, ts, dur_ms, attrs,
+                to_sink: bool = True) -> None:
         rec = {
             "name": name,
             "trace_id": trace.trace_id,
@@ -163,7 +168,7 @@ class SpanRecorder:
         }
         with self._lock:
             self._ring.append(rec)
-        sink = self._sink
+        sink = self._sink if to_sink else None
         if sink is not None:
             try:
                 sink(rec)
@@ -171,11 +176,14 @@ class SpanRecorder:
                 logger.debug("span sink failed", exc_info=True)
 
     # ---- inspection ----
-    def spans(self, trace_id: Optional[str] = None) -> list[dict]:
+    def spans(self, trace_id: Optional[str] = None,
+              name: Optional[str] = None) -> list[dict]:
         with self._lock:
             out = list(self._ring)
         if trace_id is not None:
             out = [s for s in out if s["trace_id"] == trace_id]
+        if name is not None:
+            out = [s for s in out if s["name"] == name]
         return out
 
     def drain(self) -> list[dict]:
