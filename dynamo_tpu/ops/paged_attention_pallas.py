@@ -4,17 +4,38 @@ The reference's equivalent is vLLM's paged_attention CUDA kernel plus its
 flash-attention prefill (invoked inside the engines Dynamo wraps); here
 they are native Mosaic/TPU kernels.
 
-Design (per SURVEY.md §7 "hard parts" — this is the decode make-or-break):
+The cache layout is [Hkv, N, bs, D] (head-major): a (head, page) tile is
+one contiguous ``[bs, D]`` block, and one page of ``Hh`` heads is ``Hh``
+such tiles a fixed stride apart — one strided DMA (see
+dynamo_tpu.ops.attention module docs).
 
-  * grid = (batch, kv_heads, superblocks): one superblock = ``P``
-    consecutive logical KV pages per grid step. A single page is a tiny
-    ``[block_size, head_dim]`` tile (4 KB at bs=16/D=128/bf16) — far too
-    small to amortize per-grid-step pipeline overhead or fill the MXU, and
-    measured 80x off the HBM floor on v5e. Fetching P pages per step and
-    fusing them into ONE ``[Gp, P*bs]`` dot fixes both: P parallel
-    double-buffered DMA streams (the cache is passed P times with
-    per-page ``index_map``s — the BlockSpec pipeline machinery runs one
-    stream per input) and an MXU-shaped score matrix.
+Decode (``paged_decode_attention``):
+
+  * grid = (batch, head tiles, superblocks) = ``(B, Hkv // Hh, M // P)``.
+    A grid step covers ``P`` consecutive logical pages of ``Hh`` KV heads
+    of one row: ``P`` K and ``P`` V page streams (the cache is passed
+    ``P`` times with per-page ``index_map``s — the BlockSpec pipeline
+    runs one double-buffered DMA stream per input), each a
+    ``(Hh, 1, bs, D)`` block, scored by one ``[Hh, Gp, D] x [Hh, P*bs,
+    D]`` batched dot. The number of steps, and of page ``index_map``
+    evaluations on the scalar core, does not multiply by the KV heads:
+    for 32 slots x 16 KV heads x a 256-page table at ``P`` 8 that is
+    32 x 1 x 32 = 1,024 steps a layer-call where a step per head made
+    16,384, and a live step moves 1 MiB of bf16 pages where it moved
+    64 KiB.
+  * ``Hh`` is derived from the shapes (``_pick_heads_per_step``): the
+    largest divisor of the local ``Hkv`` whose step fits
+    ``_STEP_VMEM_BUDGET``, 8 MiB, half of the 16 MiB of scoped VMEM a
+    v5e kernel gets by default (the other half is left to Mosaic's own
+    temporaries: the concatenated pages, scores and probabilities).
+    Counted per head: the ``2 * P`` page streams double-buffered
+    (``2 * 2P * bs * D * itemsize``), the f32 copies of K and V both dots
+    read (``2 * P * bs * D * 4``), the q, output and stat blocks
+    double-buffered at f32 and the m / l / acc scratch. For bf16 pages
+    of 16 x 128 at ``P`` 8 and MHA's ``Gp`` 8: 128 KiB of streams +
+    128 KiB of temporaries + 44 KiB of the rest a head, so 16 heads ride
+    in one step (2 MiB + 2 MiB + 0.7 MiB), a tp shard's 2 heads in one,
+    and 32 heads in two steps of 16 (int8 or fp8 pages: one of 32).
   * ``PrefetchScalarGridSpec`` prefetches the block table and sequence
     lengths so each ``index_map`` can turn its *logical* page number into
     the *physical* page index. No gather of the whole table, no
@@ -23,13 +44,16 @@ Design (per SURVEY.md §7 "hard parts" — this is the decode make-or-break):
     page — consecutive identical indices make the pipeline skip the
     re-fetch, so ragged sequences cost bandwidth proportional to their
     true length, and compute for them is predicated off with ``pl.when``
-    (whole superblocks) or masking (page tails).
+    (whole superblocks) or masking (page tails). A dead step still costs
+    its ``2 * P`` index maps and a turn of the pipeline.
   * flash-attention-style online softmax in fp32 VMEM scratch
-    (running max / normalizer / accumulator) across the superblock
-    dimension; the output tile is written once on the final step.
+    (running max / normalizer / accumulator, one plane a head) across
+    the superblock dimension; the output tile is written once on the
+    final step. q is scaled in f32 and K / V are widened to f32 before
+    both dots.
 
-The cache layout [Hkv, N, bs, D] (head-major) makes each (head, page)
-tile contiguous — see dynamo_tpu.ops.attention module docs.
+Prefill (``paged_prefill_attention``) keeps the grid (q tiles, kv heads,
+superblocks): one KV head and ``P`` ``[bs, D]`` pages a step.
 """
 
 from __future__ import annotations
@@ -44,6 +68,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+# what one decode grid step may hold in VMEM by the count of
+# ``_pick_heads_per_step``: half of a v5e kernel's default 16 MiB of
+# scoped VMEM
+_STEP_VMEM_BUDGET = 8 * 2**20
+
 
 def _pick_pages_per_step(M: int, cap: int = 8) -> int:
     """Largest power of two <= cap dividing the table width."""
@@ -51,6 +80,29 @@ def _pick_pages_per_step(M: int, cap: int = 8) -> int:
     while p * 2 <= cap and M % (p * 2) == 0:
         p *= 2
     return p
+
+
+def _decode_step_vmem_bytes(Hh, Gp, D, bs, P, itemsize):
+    """VMEM one decode grid step of ``Hh`` KV heads holds, as the module
+    docstring counts it (``itemsize``: the cache's)."""
+    streams = 2 * (2 * P) * bs * D * itemsize  # double-buffered pages
+    widened = 2 * P * bs * D * 4  # f32 K and V
+    # double-buffered q, out and the two stat planes, at f32
+    q_and_out = 2 * (2 * Gp * D + 2 * Gp * 128) * 4
+    scratch = (2 * 128 + D) * Gp * 4  # m, l, acc
+    return Hh * (streams + widened + q_and_out + scratch)
+
+
+def _pick_heads_per_step(Hkv, Gp, D, bs, P, itemsize) -> int:
+    """KV heads a decode grid step covers: the largest divisor of the
+    (local) ``Hkv`` whose step fits ``_STEP_VMEM_BUDGET``; one head
+    always may."""
+    for Hh in range(Hkv, 1, -1):
+        if Hkv % Hh == 0 and _decode_step_vmem_bytes(
+            Hh, Gp, D, bs, P, itemsize
+        ) <= _STEP_VMEM_BUDGET:
+            return Hh
+    return 1
 
 
 def _decode_page(bt, sl, b, i, j, pages_per_step, block_size):
@@ -81,20 +133,21 @@ def _decode_kernel(
     has_scales: bool = False,  # int8-with-scales device cache: the
     # per-page scale planes ride in SMEM as two more scalar-prefetch refs
     # and the per-page dequant fuses into the page loads (same scheme as
-    # ragged_paged_attention_pallas)
+    # ragged_paged_attention_pallas); one scalar a page, shared by the
+    # step's heads
 ):
     P = pages_per_step
     if has_scales:
         ks_ref, vs_ref, *refs = refs  # [N] f32 each (SMEM)
-    q_ref = refs[0]  # [1, 1, Gp, D]
-    k_refs = refs[1 : 1 + P]  # each [1, 1, bs, D]
+    q_ref = refs[0]  # [1, Hh, Gp, D]
+    k_refs = refs[1 : 1 + P]  # each [Hh, 1, bs, D]
     v_refs = refs[1 + P : 1 + 2 * P]
     n_in = 1 + 2 * P
     if return_stats:
         o_ref, mo_ref, lo_ref = refs[n_in : n_in + 3]
         m_scr, l_scr, acc_scr = refs[n_in + 3 :]
     else:
-        o_ref = refs[n_in]  # [1, 1, Gp, D]
+        o_ref = refs[n_in]  # [1, Hh, Gp, D]
         m_scr, l_scr, acc_scr = refs[n_in + 1 :]
 
     b = pl.program_id(0)
@@ -121,7 +174,7 @@ def _decode_kernel(
 
     @pl.when(in_range)
     def _superblock():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [Gp, D]
+        q = q_ref[0].astype(jnp.float32) * scale  # [Hh, Gp, D]
         if has_scales:
             # fused per-page dequant: quantized tile * its page scale
             pages = [
@@ -131,58 +184,60 @@ def _decode_kernel(
             ]
             k = jnp.concatenate(
                 [
-                    r[0, 0].astype(jnp.float32) * ks_ref[pages[p]]
+                    r[:, 0].astype(jnp.float32) * ks_ref[pages[p]]
                     for p, r in enumerate(k_refs)
                 ],
-                axis=0,
-            )  # [P*bs, D]
+                axis=1,
+            )  # [Hh, P*bs, D]
             v = jnp.concatenate(
                 [
-                    r[0, 0].astype(jnp.float32) * vs_ref[pages[p]]
+                    r[:, 0].astype(jnp.float32) * vs_ref[pages[p]]
                     for p, r in enumerate(v_refs)
                 ],
-                axis=0,
+                axis=1,
             )
         else:
             k = jnp.concatenate(
-                [r[0, 0] for r in k_refs], axis=0
-            ).astype(jnp.float32)  # [P*bs, D]
-            v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0).astype(
+                [r[:, 0] for r in k_refs], axis=1
+            ).astype(jnp.float32)  # [Hh, P*bs, D]
+            v = jnp.concatenate([r[:, 0] for r in v_refs], axis=1).astype(
                 jnp.float32
             )
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [Gp, P*bs]
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [Hh, Gp, P*bs]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         keep = pos < seq_len
         if window > 0:
             row_lo = lo
             if group > 0:  # per-row floor: row r is token t = r // group
                 row_lo = lo + (
-                    jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+                    jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // group
                 )
             keep &= pos >= row_lo
         s = jnp.where(keep, s, _NEG_INF)
 
-        m_prev = m_scr[:, 0:1]  # [Gp, 1]
-        l_prev = l_scr[:, 0:1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_scr[:, :, 0:1]  # [Hh, Gp, 1]
+        l_prev = l_scr[:, :, 0:1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)  # [Gp, P*bs]
-        l_cur = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        p = jnp.exp(s - m_cur)  # [Hh, Gp, P*bs]
+        l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [Hh, Gp, D]
         m_scr[...] = jnp.broadcast_to(m_cur, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_cur, l_scr.shape)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _emit():
-        l = jnp.maximum(l_scr[:, 0:1], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[:, :, 0:1], 1e-20)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
         if return_stats:
-            mo_ref[0, 0] = m_scr[...]
-            lo_ref[0, 0] = l_scr[...]
+            mo_ref[0] = m_scr[...]
+            lo_ref[0] = l_scr[...]
 
 
 @functools.partial(
@@ -220,6 +275,9 @@ def paged_decode_attention(
         )
     # pad the query-group dim to the fp32 sublane quantum
     Gp = max(8, -(-G // 8) * 8)
+    Hh = _pick_heads_per_step(
+        Hkv, Gp, D, bs, P, k_cache_layer.dtype.itemsize
+    )
     qg = q.reshape(B, Hkv, G, D).astype(jnp.float32)
     if Gp != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
@@ -233,7 +291,7 @@ def paged_decode_attention(
         return index
 
     page_spec = [
-        pl.BlockSpec((1, 1, bs, D), page_index(j)) for j in range(P)
+        pl.BlockSpec((Hh, 1, bs, D), page_index(j)) for j in range(P)
     ]
     # per-page scales are scalars the kernel looks up by physical page:
     # SMEM (scalar prefetch), not a VMEM stream — a (1, 128) block of an
@@ -247,8 +305,8 @@ def paged_decode_attention(
     def row_index(b, h, i, *_):
         return (b, h, 0, 0)
 
-    o_spec = pl.BlockSpec((1, 1, Gp, D), row_index)
-    stat_spec = pl.BlockSpec((1, 1, Gp, 128), row_index)
+    o_spec = pl.BlockSpec((1, Hh, Gp, D), row_index)
+    stat_spec = pl.BlockSpec((1, Hh, Gp, 128), row_index)
     out_specs = [o_spec, stat_spec, stat_spec] if return_stats else o_spec
     out_shape = jax.ShapeDtypeStruct((B, Hkv, Gp, D), q.dtype)
     if return_stats:
@@ -256,17 +314,17 @@ def paged_decode_attention(
         out_shape = [out_shape, stat_shape, stat_shape]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2 + len(scale_inputs),
-        grid=(B, Hkv, M // P),
+        grid=(B, Hkv // Hh, M // P),
         in_specs=[
-            pl.BlockSpec((1, 1, Gp, D), row_index),
+            pl.BlockSpec((1, Hh, Gp, D), row_index),
             *page_spec,
             *page_spec,
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((Gp, 128), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
-            pltpu.VMEM((Gp, D), jnp.float32),
+            pltpu.VMEM((Hh, Gp, 128), jnp.float32),
+            pltpu.VMEM((Hh, Gp, 128), jnp.float32),
+            pltpu.VMEM((Hh, Gp, D), jnp.float32),
         ],
     )
     kernel = functools.partial(

@@ -9,7 +9,10 @@ reference it replaces, at the serving widths of the smoke model
 
   1. cache appends: one row (bf16), int8 + scale planes, T-token verify
   2. decode attention: paged kernel, merged out-of-cache token, int8
-     pages with per-page scales
+     pages with per-page scales; and the kernel at the benchmark cell's
+     shape (olmo2-1b.chat: 32 slots, MHA 16 x 128, a 256-page table over
+     2,560 pages) with lengths up to the full 4,096 and stats out, so
+     the accumulation across superblocks is checked where it is long
   3. chunked-prefill attention over the paged cache
   4. the ragged mixed (decode + prefill) kernel, bf16 and int8 + scales
   5. model level, 2 layers at full width: merged decode, Pallas prefill
@@ -259,6 +262,46 @@ def check_kernels(check: Checker) -> None:
           ref_chunk[:VALID])
 
 
+def check_cell_decode(check: Checker) -> None:
+    """``paged_decode_attention`` as ``olmo2-1b.chat`` calls it (the
+    merged path: stats out) at real lengths: the benchmark's reference
+    check reads two positions of 48-token prompts, which never leave the
+    first superblock of 128 tokens."""
+    Bc, Hc, Mc, Nc = 32, 16, 256, 2560
+    rng = np.random.default_rng(27)
+    ks = jax.random.split(jax.random.key(27), 3)
+    q = jax.random.normal(ks[0], (Bc, Hc, D), jnp.bfloat16)
+    kc = jax.random.normal(ks[1], (Hc, Nc, BS, D), jnp.bfloat16)
+    vc = jax.random.normal(ks[2], (Hc, Nc, BS, D), jnp.bfloat16)
+    # a row's pages are distinct; rows share the pool, as a prefix does
+    tables = jnp.asarray(np.stack([
+        rng.permutation(np.arange(1, Nc))[:Mc] for _ in range(Bc)
+    ]).astype(np.int32))
+    edges = [0, 1, BS, BS + 1, 8 * BS - 1, 8 * BS, 8 * BS + 1, 4000,
+             Mc * BS - 1, Mc * BS]
+    lens = np.concatenate([
+        edges, rng.integers(2, 4000, Bc - len(edges) - 2), [0, 0]
+    ]).astype(np.int32)
+    live = lens > 0
+    seq_lens = jnp.asarray(lens)
+    ro, rm, rl = att._history_attention_xla(
+        q[:, None], kc, vc, tables, seq_lens, SCALE
+    )  # [B, Hkv, 1, G(, D)]
+    o, m, l = paged_decode_attention(
+        *chip(q, kc, vc, tables, seq_lens), SCALE, return_stats=True
+    )
+    name = ("paged_decode_attention cell shape, lengths "
+            f"{lens.min()}-{lens.max()}")
+    check(f"{name}: all rows finite", np.isfinite(np.asarray(
+        o, np.float32)).all(), True)
+    check(f"{name}: out", np.asarray(o, np.float32)[live],
+          np.asarray(ro, np.float32).reshape(Bc, Hc, D)[live])
+    check(f"{name}: m", np.asarray(m)[live], np.asarray(rm)[live, :, 0])
+    # l sums up to 4,096 terms: relative only
+    check(f"{name}: l", np.asarray(l)[live] / np.asarray(rl)[live, :, 0],
+          np.ones_like(np.asarray(l)[live]))
+
+
 def check_model(check: Checker) -> None:
     """The smoke model cut to 2 layers, every width as published: the
     Pallas flavor of each serving program against its XLA twin."""
@@ -418,6 +461,7 @@ def main() -> int:
     check = Checker()
     with jax.default_device(jax.devices("cpu")[0]):
         check_kernels(check)
+        check_cell_decode(check)
         check_model(check)
         check_other_families(check)
     if check.failed:

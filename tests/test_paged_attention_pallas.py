@@ -312,3 +312,143 @@ def test_merged_decode_sliding_window_matches_xla():
         interpret=True,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+# ---------------- the decode kernel's head tiles ----------------
+# A grid step covers ``Hh`` KV heads of its page group (derived from the
+# shapes, ``_pick_heads_per_step``): every case runs the kernel against
+# the XLA path at a shape that puts a different ``Hh`` and head-tile
+# count in the grid.
+
+_HEAD_TILE_CASES = {
+    # the chat cell's head shape (MHA 16 x 128, pages of 16) cut in batch
+    # and table: empty, one token, a page's edge either side, a
+    # superblock's edge either side, the full table
+    "cell-mha16": dict(H=16, Hkv=16, M=32,
+                       lens=[0, 1, 16, 17, 128, 129, 300, 512]),
+    "gqa-24-8": dict(H=24, Hkv=8, M=16, lens=[0, 5, 128, 256]),
+    "tp-shard-hkv2": dict(H=4, Hkv=2, M=16, lens=[1, 129, 0, 255]),
+    # f32 pages of 32 heads overflow the step's budget: two head tiles
+    "head-tiles-hkv32": dict(H=32, Hkv=32, M=16, lens=[130, 256], tiles=2),
+    "stats": dict(H=16, Hkv=16, M=16, lens=[1, 17, 129, 256], stats=True),
+    "stats-gqa": dict(H=24, Hkv=8, M=16, lens=[3, 128, 200, 256],
+                      stats=True),
+    # the verify path: T in-flight tokens x G heads in the row dim, each
+    # row with its own window floor
+    "window-group": dict(H=16, Hkv=8, M=16, lens=[1, 40, 130, 256], T=3,
+                         window=40),
+    "int8-scales": dict(H=16, Hkv=16, M=16, lens=[0, 16, 129, 256],
+                        int8=True),
+    "int8-scales-stats": dict(H=24, Hkv=8, M=16, lens=[7, 128, 129, 256],
+                              int8=True, stats=True),
+}
+
+
+def _quantize_pages(x):
+    """f32 pages -> (int8 pages, per-page f32 scales [N])."""
+    s = jnp.maximum(jnp.max(jnp.abs(x), axis=(0, 2, 3)) / 127.0, 1e-12)
+    q = jnp.clip(jnp.round(x / s[None, :, None, None]), -127, 127)
+    return q.astype(jnp.int8), s
+
+
+@pytest.mark.parametrize("case", list(_HEAD_TILE_CASES))
+def test_decode_kernel_head_tiles_match_xla(case):
+    from dynamo_tpu.ops.attention import _history_attention_xla
+    from dynamo_tpu.ops.paged_attention_pallas import (
+        _pick_heads_per_step,
+        _pick_pages_per_step,
+    )
+
+    c = _HEAD_TILE_CASES[case]
+    H, Hkv, M, lens = c["H"], c["Hkv"], c["M"], c["lens"]
+    T, window = c.get("T", 0), c.get("window", 0)
+    B, D, bs = len(lens), 128, 16
+    q, kc, vc, tables = _mk(B, H, Hkv, D, B * M + 1, bs, M, seed=11)
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    scale = D**-0.5
+    scales = {}
+    if c.get("int8"):
+        kc, ks = _quantize_pages(kc)
+        vc, vs = _quantize_pages(vc)
+        scales = dict(k_scales=ks, v_scales=vs)
+    Hh = _pick_heads_per_step(
+        Hkv, 8, D, bs, _pick_pages_per_step(M), kc.dtype.itemsize
+    )
+    assert Hkv // Hh == c.get("tiles", 1)
+    live = np.asarray(lens) > 0
+
+    if T:  # packed as verify_attention packs: rows (hkv, t, g)
+        G = H // Hkv
+        q4 = jax.random.normal(jax.random.key(5), (B, T, H, D), jnp.float32)
+        qp = q4.reshape(B, T, Hkv, G, D).transpose(0, 2, 1, 3, 4)
+        o, m, l = paged_decode_attention(
+            qp.reshape(B, Hkv * T * G, D), kc, vc, tables, seq_lens, scale,
+            return_stats=True, window=window, q_pos_offset=1, group=G,
+            interpret=True, **scales,
+        )
+        ro, rm, rl = _history_attention_xla(
+            q4, kc, vc, tables, seq_lens, scale, window=window, **scales
+        )
+        got = (o.reshape(B, Hkv, T, G, D), m.reshape(B, Hkv, T, G),
+               l.reshape(B, Hkv, T, G))
+        for g, r in zip(got, (ro, rm, rl)):
+            np.testing.assert_allclose(
+                np.asarray(g)[live], np.asarray(r)[live], rtol=2e-5,
+                atol=2e-5,
+            )
+        return
+
+    out = paged_decode_attention(
+        q, kc, vc, tables, seq_lens, scale,
+        return_stats=bool(c.get("stats")), interpret=True, **scales,
+    )
+    o = out[0] if c.get("stats") else out
+    assert not np.isnan(np.asarray(o)).any()  # empty slots included
+    ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale, **scales)
+    np.testing.assert_allclose(
+        np.asarray(o)[live], np.asarray(ref)[live], rtol=2e-5, atol=2e-5
+    )
+    if c.get("stats"):  # the m / l planes the merged path folds
+        _, rm, rl = _history_attention_xla(
+            q[:, None], kc, vc, tables, seq_lens, scale, **scales
+        )
+        np.testing.assert_allclose(
+            np.asarray(out[1])[live], np.asarray(rm)[live, :, 0],
+            rtol=2e-5, atol=2e-5,
+        )
+        np.testing.assert_allclose(
+            np.asarray(out[2])[live], np.asarray(rl)[live, :, 0],
+            rtol=2e-5, atol=2e-5,
+        )
+
+
+def test_heads_per_step_rule():
+    """``Hh`` divides Hkv, fits the stated budget, is the largest that
+    does, and is every head for the chat cell's shape."""
+    from dynamo_tpu.ops.paged_attention_pallas import (
+        _STEP_VMEM_BUDGET,
+        _decode_step_vmem_bytes,
+        _pick_heads_per_step,
+    )
+
+    # the cell: MHA 16 x 128, bf16 pages of 16, P 8 -> one step, and the
+    # docstring's arithmetic: 2 MiB of streams + 2 MiB of f32 K / V
+    assert _pick_heads_per_step(16, 8, 128, 16, 8, 2) == 16
+    assert 4 * 2**20 < _decode_step_vmem_bytes(16, 8, 128, 16, 8, 2) \
+        < 5 * 2**20
+    assert _pick_heads_per_step(8, 8, 128, 16, 8, 2) == 8  # phi-4-mini
+    assert _pick_heads_per_step(2, 8, 128, 16, 8, 2) == 2  # a tp=4 shard
+    assert _pick_heads_per_step(32, 8, 128, 16, 8, 2) == 16  # MHA 7B
+    for Hkv in (1, 2, 3, 8, 12, 16, 32, 40, 64, 128):
+        for Gp, D, bs, P, item in (
+            (8, 128, 16, 8, 2), (8, 128, 16, 8, 1), (8, 64, 16, 8, 2),
+            (16, 128, 32, 8, 4), (40, 256, 16, 4, 2), (8, 128, 16, 1, 2),
+        ):
+            Hh = _pick_heads_per_step(Hkv, Gp, D, bs, P, item)
+            assert Hkv % Hh == 0
+            fits = [
+                h for h in range(1, Hkv + 1)
+                if Hkv % h == 0 and _decode_step_vmem_bytes(
+                    h, Gp, D, bs, P, item) <= _STEP_VMEM_BUDGET
+            ]
+            assert Hh == (max(fits) if fits else 1)

@@ -139,19 +139,35 @@ def _layer(chip, dtype, d=D):
     return chip((HKV, N, BS, d), dtype)
 
 
+# decode slots, query / kv heads, table width of the benchmark's models
+# (each with bf16 pages, stats on: the merged path a decode step runs)
+CELL_OLMO2_1B = (32, 16, 16, 256)  # olmo2-1b.chat: MHA, every head a step
+CELL_PHI4_MINI = (32, 24, 8, 256)  # GQA 24 / 8
+
+
 @pytest.mark.parametrize(
-    "d,dtype,scales,stats",
+    "d,dtype,scales,stats,shape",
     [
-        (128, jnp.bfloat16, False, False),
-        (128, jnp.bfloat16, False, True),  # the merged decode path
-        (64, jnp.bfloat16, False, True),  # gpt-oss head_dim
-        (128, jnp.int8, True, True),  # --kv-cache-dtype int8
-        (128, jnp.float8_e4m3fn, False, True),  # --kv-cache-dtype float8_e4m3
+        (128, jnp.bfloat16, False, False, None),
+        (128, jnp.bfloat16, False, True, None),  # the merged decode path
+        (64, jnp.bfloat16, False, True, None),  # gpt-oss head_dim
+        (128, jnp.int8, True, True, None),  # --kv-cache-dtype int8
+        # --kv-cache-dtype float8_e4m3
+        (128, jnp.float8_e4m3fn, False, True, None),
+        (128, jnp.bfloat16, False, True, CELL_OLMO2_1B),
+        (128, jnp.bfloat16, False, True, CELL_PHI4_MINI),
+        # 32 KV heads: bf16 pages take two head tiles of 16, int8 pages
+        # one of 32 (the largest step the budget admits)
+        (128, jnp.bfloat16, False, True, (B, 32, 32, M)),
+        (128, jnp.int8, True, True, (B, 32, 32, M)),
     ],
-    ids=["D128", "D128-stats", "D64-stats", "int8-scales", "fp8"],
+    ids=["D128", "D128-stats", "D64-stats", "int8-scales", "fp8",
+         "olmo2-1b-cell", "phi-4-mini", "mha32-two-tiles", "mha32-int8"],
 )
-def test_paged_decode_attention(chip, d, dtype, scales, stats):
+def test_paged_decode_attention(chip, d, dtype, scales, stats, shape):
     from dynamo_tpu.ops.paged_attention_pallas import paged_decode_attention
+
+    b, h, hkv, m = shape or (B, H, HKV, M)
 
     def fn(q, kc, vc, bt, sl, ks=None, vs=None):
         return paged_decode_attention(
@@ -159,9 +175,10 @@ def test_paged_decode_attention(chip, d, dtype, scales, stats):
             k_scales=ks, v_scales=vs,
         )
 
-    _compile(fn, chip((B, H, d), jnp.bfloat16), _layer(chip, dtype, d),
-             _layer(chip, dtype, d), chip((B, M), jnp.int32),
-             chip((B,), jnp.int32), *_scale_planes(chip, scales))
+    layer = chip((hkv, N, BS, d), dtype)
+    _compile(fn, chip((b, h, d), jnp.bfloat16), layer, layer,
+             chip((b, m), jnp.int32), chip((b,), jnp.int32),
+             *_scale_planes(chip, scales))
 
 
 @pytest.mark.parametrize("dtype,scales", [(jnp.bfloat16, False),
