@@ -68,6 +68,11 @@ PREFILL_BUCKETS = [16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192]
 WORK_COUNTERS = ("rows_dispatched", "rows_live", "prefill_tokens_dispatched",
                  "prefill_tokens_padding", "attn_table_pages",
                  "attn_live_pages")
+#: the same for a model with expert layers (absent for a dense one):
+#: expert slots handed over and assignments of live rows, counted on the
+#: host, and what the step programs' MoeTally brings back
+MOE_COUNTERS = ("moe_expert_slots", "moe_assignments", "moe_experts_touched",
+                "moe_experts_touched_live", "moe_max_group_rows")
 
 # the prefill-admission first-token sampler, jitted ONCE at module scope:
 # a per-call ``jax.jit(sample_first_token)`` built a fresh wrapper (and a
@@ -794,6 +799,18 @@ class JaxEngine(AsyncEngine):
             # walk against the pages that hold live tokens
             **dict.fromkeys(WORK_COUNTERS, 0),
         }
+        # expert layers whose routing the step programs count (models/
+        # llama.MoeTally; the multi-host mirror runs programs of its own
+        # and counts nothing). The counters come back as device arrays:
+        # they wait here, with what the host counted at the dispatch,
+        # until the step that finds them ready folds them into stats
+        self._moe_layers = (
+            mcfg.num_layers - mcfg.first_dense_layers
+            if mcfg.is_moe and mirror is None else 0
+        )
+        self._moe_pending: deque = deque()
+        if self._moe_layers:
+            self.stats.update(dict.fromkeys(MOE_COUNTERS, 0))
         # the loop's clock (tracing/loop_clock.py): seconds by phase,
         # dispatches by kind and slow steps, kept in self.stats
         self._clock = LoopClock(self.stats, tracing.RECORDER)
@@ -947,7 +964,7 @@ class JaxEngine(AsyncEngine):
         for kind in KINDS.values():
             out[f'engine_steps_total{{kind="{kind}"}}'] = self.stats[
                 f"steps_{kind}"]
-        for name in WORK_COUNTERS:
+        for name in WORK_COUNTERS + (MOE_COUNTERS if self._moe_layers else ()):
             out[f"engine_{name}_total"] = self.stats[name]
         for e in self.compile_ledger:
             key = ",".join(str(k) for k in e["key"]).replace('"', "'")
@@ -2201,7 +2218,7 @@ class JaxEngine(AsyncEngine):
             self._abort_prefill(st, FinishReason.ERROR)
             return False
         if first_token is None:
-            self._clock.step_done()
+            self._step_done()
             return False  # more chunks to go
         phase = self._clock.mark("emit")
         first_token, first_lp = first_token
@@ -2231,7 +2248,7 @@ class JaxEngine(AsyncEngine):
                 # an unconditional placement would index(None) on a full
                 # batch and crash the scheduler loop
                 self._remote_ready.append(seq)
-        self._clock.step_done()
+        self._step_done()
         self._clock.mark(phase)
         return True
 
@@ -2500,15 +2517,18 @@ class JaxEngine(AsyncEngine):
                     k_scales=self.k_scales,
                     v_scales=self.v_scales,
                     **self._lora_prefill_kw(seq.adapter_id),
+                    **self._moe_kw(),
                 ),
                 key=("prefill", T, ring) + self._lora_key(),
                 trace=seq.trace,
             )
             (logits, self.k_cache, self.v_cache,
-             self.k_scales, self.v_scales) = out
+             self.k_scales, self.v_scales) = out[:5]
             self._note_quant_step(0, len(chunk))
+            if self._moe_layers:
+                self._note_moe(out[5], 1, len(chunk))
             return logits, pos + len(chunk)
-        logits, self.k_cache, self.v_cache = self._timed_dispatch(
+        out = self._timed_dispatch(
             lambda: llama.prefill(
                 self.params,
                 cfg.model,
@@ -2522,10 +2542,14 @@ class JaxEngine(AsyncEngine):
                 mesh=self.mesh,
                 use_ring=ring,
                 **self._lora_prefill_kw(seq.adapter_id),
+                **self._moe_kw(),
             ),
             key=("prefill", T, ring) + self._lora_key(),
             trace=seq.trace,
         )
+        logits, self.k_cache, self.v_cache = out[:3]
+        if self._moe_layers:
+            self._note_moe(out[3], 1, len(chunk))
         return logits, pos + len(chunk)
 
     def _prefill_device(
@@ -3290,7 +3314,7 @@ class JaxEngine(AsyncEngine):
             await self._emit_window(prev)
         if not pipe:
             await self._drain_inflight()
-        self._clock.step_done()
+        self._step_done()
 
     def _propose_ngram(self) -> Optional[np.ndarray]:
         """Prompt-lookup drafts: match each sequence's trailing n-gram
@@ -3393,7 +3417,7 @@ class JaxEngine(AsyncEngine):
             self._seq_lens[i] = seq.seq_len
             self._last_tokens[i] = seq.tokens[-1]
             self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
-        self._clock.step_done()
+        self._step_done()
         return True
 
     async def _mixed_step_once(self) -> None:
@@ -3522,7 +3546,7 @@ class JaxEngine(AsyncEngine):
                     # the KV is landed, so queue for the next free slot
                     # exactly like a remotely-prefilled sequence
                     self._remote_ready.append(seq_p)
-        self._clock.step_done()
+        self._step_done()
 
     def _split_mixed_budget(self) -> list[tuple["_PrefillState", int]]:
         """Pack the Sarathi token budget across the in-flight prefills:
@@ -3658,6 +3682,7 @@ class JaxEngine(AsyncEngine):
                 merged=cfg.decode_merged,
                 with_logprobs=want_lp,
                 **kwargs,
+                **self._moe_kw(),
             ), key=("mixed", MP, T, penalized, want_lp)
                 + self._lora_key())
             toks, p_logits, self.k_cache, self.v_cache = out[:4]
@@ -3672,6 +3697,9 @@ class JaxEngine(AsyncEngine):
             if penalized:
                 self._pen_counts = rest.pop(0)
             lps_dev = rest.pop(0) if want_lp else None
+            if self._moe_layers:
+                self._note_moe(rest.pop(0), 1, int(
+                    (self._seq_lens > 0).sum() + valids_p.sum()))
             completed = []
             for i, (st, take) in enumerate(packed):
                 st.pos += take
@@ -3742,6 +3770,41 @@ class JaxEngine(AsyncEngine):
         if cold:
             self._note_compile(key, (time.perf_counter() - t0) * 1e3, trace)
         return out
+
+    def _moe_kw(self) -> dict:
+        """The step programs' keyword that makes an expert model return
+        its routing counters (nothing for a dense model: its programs
+        stay as they were)."""
+        return {"moe_counters": True} if self._moe_layers else {}
+
+    def _note_moe(self, sums, steps: int, live_rows: int) -> None:
+        """One dispatch of an expert model: ``sums`` is the program's
+        MoeTally output (a device array, not waited for here), ``steps``
+        its device steps and ``live_rows`` the rows with a real token,
+        summed over the steps."""
+        m = self.cfg.model
+        self._moe_pending.append((
+            sums, self._moe_layers * m.num_experts * steps,
+            self._moe_layers * m.num_experts_per_tok * live_rows,
+        ))
+
+    def _step_done(self) -> None:
+        """Close the loop clock's step. An expert model's step carries,
+        as the span's ``moe``, the routing counters that had come back
+        from the device by now (a pipelined window's arrive with the
+        step that emits its tokens)."""
+        moe = dict.fromkeys(MOE_COUNTERS, 0)
+        while self._moe_pending and self._moe_pending[0][0].is_ready():
+            sums, slots, assignments = self._moe_pending.popleft()
+            for name, v in zip(MOE_COUNTERS,
+                               (slots, assignments, *np.asarray(sums))):
+                moe[name] += int(v)
+        if not moe["moe_expert_slots"]:  # a dense model; nothing back yet
+            self._clock.step_done()
+            return
+        for name, v in moe.items():
+            self.stats[name] += v
+        self._clock.step_done(moe={k[4:]: v for k, v in moe.items()})
 
     def _note_decode_work(self, n: int, seq_lens: np.ndarray,
                           seg_pages: tuple = (0, 0)) -> None:
@@ -4000,6 +4063,7 @@ class JaxEngine(AsyncEngine):
             with_logprobs=want_lp,
         )
         kw.update(self._lora_decode_kw())
+        kw.update(self._moe_kw())
         quantized = self.k_scales is not None
         if quantized:
             self._flush_scale_resets()
@@ -4032,6 +4096,9 @@ class JaxEngine(AsyncEngine):
         if penalized:
             self._pen_counts = rest.pop(0)
         lps = rest.pop(0) if want_lp else None
+        if self._moe_layers:
+            self._note_moe(rest.pop(0), n,
+                           int((self._seq_lens > 0).sum()) * n)
         # device handles; materialized at emission (fetching here would
         # block the pipelined dispatch on the window's full execution)
         self._window_logprobs = lps

@@ -32,6 +32,41 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0
 
 
+#: expert families the parser knows, by ``model_type`` and by the prefix
+#: of an ``architectures`` entry (docs/models.md lists them)
+_EXPERT_MODEL_TYPES = frozenset((
+    "mixtral", "qwen2_moe", "qwen3_moe", "deepseek", "deepseek_v2",
+    "deepseek_v3", "gpt_oss", "olmoe",
+))
+_EXPERT_ARCH_PREFIXES = (
+    "Mixtral", "Qwen2Moe", "Qwen3Moe", "Deepseek", "GptOss", "Olmoe",
+)
+_EXPERT_COUNT_KEYS = ("num_local_experts", "n_routed_experts", "num_experts")
+
+
+def _reject_unknown_expert_family(cfg: dict, archs: list) -> None:
+    """A config that counts experts under a model_type / architecture
+    this parser does not know would parse as a Llama with experts —
+    whatever its norms, router and expert widths really are — and serve
+    wrong logits in silence (what ``olmoe`` did before it was added).
+    Refuse it by name. A bare dict that names no family is let through."""
+    if not any(cfg.get(k) for k in _EXPERT_COUNT_KEYS):
+        return
+    named = list(archs) + ([cfg["model_type"]] if cfg.get("model_type") else [])
+    if not named:
+        return
+    known = cfg.get("model_type") in _EXPERT_MODEL_TYPES or any(
+        a.startswith(_EXPERT_ARCH_PREFIXES) for a in archs
+    )
+    if not known:
+        raise ValueError(
+            f"unsupported expert model {named}: its config.json counts "
+            "experts, and this parser knows the expert layers of "
+            f"{sorted(_EXPERT_MODEL_TYPES)} only — refusing to serve it "
+            "as a Llama with experts"
+        )
+
+
 @dataclass(eq=False)  # identity hash/eq: used as a jit static arg
 class ModelConfig:
     vocab_size: int = 32000
@@ -49,7 +84,8 @@ class ModelConfig:
     attention_bias: bool = False
     # qwen3: per-head RMS norm on q and k after projection, before rope
     qk_norm: bool = False
-    # olmo-2: q/k RMS norm over the FULL projection width (pre-reshape)
+    # olmo-2, olmoe: q/k RMS norm over the FULL projection width
+    # (pre-reshape)
     qk_norm_full: bool = False
     # olmo-2: NO input/pre-FFN norms — normalization applies to the
     # sublayer OUTPUT (post_norms) only
@@ -220,6 +256,19 @@ class ModelConfig:
         is_glm4 = "Glm4ForCausalLM" in glm_archs or (
             cfg.get("model_type") == "glm4"
         )
+        # olmoe: a PRE-norm llama layer with olmo-2's full-width q/k
+        # norms; intermediate_size is ONE expert's width; the router's
+        # softmax weights are used as they are (norm_topk_prob false)
+        is_olmoe = any(a.startswith("Olmoe") for a in archs) or (
+            cfg.get("model_type") == "olmoe"
+        )
+        if is_olmoe and cfg.get("clip_qkv") is not None:
+            raise ValueError(
+                f"olmoe with clip_qkv={cfg['clip_qkv']} is not supported "
+                "(the q/k/v clamp is not implemented; the published "
+                "OLMoE-1B-7B configs carry clip_qkv: null)"
+            )
+        _reject_unknown_expert_family(cfg, archs)
         # qwen2moe: gated shared expert; interleaved dense layers are
         # not implemented — reject rather than serve wrong logits
         is_qwen2moe = any(a.startswith("Qwen2Moe") for a in archs)
@@ -305,24 +354,27 @@ class ModelConfig:
             attention_bias=qkv_bias,
             # qwen3 (dense and MoE): per-head q/k RMS norm, no qkv bias
             qk_norm=any(a.startswith("Qwen3") for a in archs) or is_gemma3
-            or is_olmo2,
+            or is_olmo2 or is_olmoe,
             layer_windows=layer_windows,
             attn_sinks=is_gptoss,
             moe_act="gptoss_clamp" if is_gptoss else "swiglu",
             o_bias=is_gptoss and bool(cfg.get("attention_bias")),
             # mixtral: num_local_experts; deepseek: n_routed_experts;
-            # qwen2moe/qwen3moe: the bare num_experts key
+            # qwen2moe/qwen3moe/olmoe: the bare num_experts key
             num_experts=cfg.get(
                 "num_local_experts",
                 cfg.get(
                     "n_routed_experts",
                     cfg.get("num_experts", 0)
-                    if any(a.startswith(("Qwen3", "Qwen2Moe"))
-                           for a in archs) else 0,
+                    if is_olmoe or any(a.startswith(("Qwen3", "Qwen2Moe"))
+                                       for a in archs) else 0,
                 ),
             ) or 0,
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
-            moe_intermediate_size=cfg.get("moe_intermediate_size", 0) or 0,
+            moe_intermediate_size=(
+                cfg.get("intermediate_size", 0) if is_olmoe
+                else cfg.get("moe_intermediate_size", 0)
+            ) or 0,
             # qwen2moe: ONE gated shared expert of its own width
             num_shared_experts=cfg.get("n_shared_experts", 0) or (
                 1 if is_qwen2moe else 0
@@ -332,7 +384,7 @@ class ModelConfig:
             ) if is_qwen2moe else 0,
             shared_expert_gate=is_qwen2moe,
             first_dense_layers=cfg.get("first_k_dense_replace", 0) or 0,
-            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            norm_topk_prob=cfg.get("norm_topk_prob", not is_olmoe),
             # deepseek_v2/v3 (R1 = V3): sigmoid scoring + gate bias and
             # group-limited top-k arrive with topk_method "noaux_tc"
             moe_scoring=cfg.get("scoring_func", "softmax"),
@@ -370,7 +422,7 @@ class ModelConfig:
             if is_gemma2 else 0.0,
             post_norms=is_gemma2 or is_gemma3 or is_glm4 or is_olmo2,
             norm_after=is_olmo2,
-            qk_norm_full=is_olmo2,
+            qk_norm_full=is_olmo2 or is_olmoe,
             attn_scale_base=(cfg.get("query_pre_attn_scalar") or 0)
             if (is_gemma2 or is_gemma3) else 0,
             rope_local_theta=(cfg.get("rope_local_base_freq") or 0.0)

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -146,14 +146,48 @@ def layer_groups(params: dict, cfg: ModelConfig):
     return out
 
 
-def _scan_groups(body, x, params, cfg: ModelConfig, k_cache, v_cache):
+class _LayerOf(NamedTuple):
+    """Layer ``index`` of a stacked ``[L, X, in, out]`` expert leaf, left
+    where it is: see ``_layer``."""
+
+    stack: jnp.ndarray
+    index: int
+
+
+_EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+
+
+def _layer(lps: dict, li: int, mesh=None) -> dict:
+    """Layer ``li``'s leaves out of a stacked group, for an UNROLLED
+    layer loop. A slice that feeds an XLA dot fuses into the dot's
+    operand read; one that feeds a custom call (the TPU's grouped-matmul
+    kernel behind ``lax.ragged_dot``) is first COPIED out of the stack:
+    0.75 GiB a layer at OLMoE-1B-7B's widths, more than the kernel then
+    streams, and held as a program temporary (an AOT compile for the
+    v5e put 6 GiB of them into an 8-layer decode window). So a plain
+    single-device expert stack is not sliced: it rides as ``_LayerOf``
+    and ``_ragged_mm`` hands the kernel the whole stack, with every
+    other layer's groups empty."""
+    whole = {
+        k: _LayerOf(lps[k], li) for k in _EXPERT_STACKS
+        if mesh is None and k in lps and not isinstance(lps[k], dict)
+    }
+    lp = jax.tree.map(
+        lambda a: a[li], {k: v for k, v in lps.items() if k not in whole})
+    return {**lp, **whole}
+
+
+def _scan_groups(body, x, params, cfg: ModelConfig, k_cache, v_cache,
+                 tally=None):
     """lax.scan the layer body over every layer group, threading the
     cache slices; returns (x, k_cache, v_cache) with per-group ys
     re-concatenated on the layer axis. ONE implementation for prefill
-    and both scan decode variants."""
+    and both scan decode variants. A body that adds to a MoeTally scans
+    through it (``tally``)."""
     kcs, vcs = [], []
+    scan = lax.scan if tally is None else tally.scan
     for lps, n, off in layer_groups(params, cfg):
-        x, (kc_g, vc_g) = lax.scan(
+        x, (kc_g, vc_g) = scan(
             body, x, (lps, k_cache[off : off + n], v_cache[off : off + n])
         )
         kcs.append(kc_g)
@@ -456,6 +490,46 @@ def swiglu(x, w_gate, w_up, w_down, act: str = "silu"):
     return _mm(gate * _mm(x, w_up), w_down)
 
 
+class MoeTally:
+    """Routing counters of ONE step program, gathered while it is traced
+    (the engine's ``engine_moe_*`` series; docs/observability.md).
+
+    ``live`` [rows] marks the rows of the step that carry a real token:
+    a decode slot with a sequence in it, a prefill row below its chunk's
+    valid length. Every other row is padding, routed all the same.
+    ``sums`` (int32 [3]) adds up, over the expert layers traced so far:
+    the experts that received at least one row, the experts that
+    received at least one LIVE row, and the rows of the largest group.
+    The program that made the tally returns ``sums`` beside its tokens.
+    """
+
+    def __init__(self, live: jnp.ndarray, sums=None):
+        self.live = live
+        self.sums = jnp.zeros((3,), jnp.int32) if sums is None else sums
+
+    def add(self, e: jnp.ndarray, t: jnp.ndarray, group_sizes: jnp.ndarray):
+        """One expert layer: assignment r goes to expert ``e[r]`` from
+        row ``t[r]``; ``group_sizes`` [X] counts them by expert."""
+        live = jnp.zeros_like(group_sizes).at[e].add(
+            self.live[t].astype(group_sizes.dtype))
+        self.sums = self.sums + jnp.stack([
+            jnp.sum(group_sizes > 0), jnp.sum(live > 0),
+            jnp.max(group_sizes),
+        ]).astype(jnp.int32)
+
+    def scan(self, body, x, xs):
+        """``lax.scan(body, x, xs)`` for a layer body that adds to this
+        tally: the body is traced at the scan's own level, so inside it
+        the sums ride the carry."""
+        def counted(carry, layer_in):
+            x, self.sums = carry
+            x, ys = body(x, layer_in)
+            return (x, self.sums), ys
+
+        (x, self.sums), ys = lax.scan(counted, (x, self.sums), xs)
+        return x, ys
+
+
 def _moe_route(lp: dict, cfg: ModelConfig, x: jnp.ndarray):
     """Top-k routing + expert-sorted dispatch order (shared by the single-
     device and ep-sharded ragged paths). Returns (t_sorted, w_sorted,
@@ -550,6 +624,15 @@ def _ragged_mm(xs, w, group_sizes, use_pallas: bool, interpret: bool):
         else:
             out = ragged_int8_xla(xs, w["q"], w["s"], group_sizes)
         return out.astype(xs.dtype)
+    if isinstance(w, _LayerOf):
+        # the layer's X groups among the stack's L * X: the kernel
+        # visits no tile of an empty group, so it streams this layer's
+        # touched experts and nothing is copied (_layer)
+        L, X = w.stack.shape[:2]
+        return lax.ragged_dot(
+            xs, w.stack.reshape((L * X,) + w.stack.shape[2:]),
+            jnp.pad(group_sizes, (w.index * X, (L - 1 - w.index) * X)),
+        )
     return lax.ragged_dot(xs, w, group_sizes)
 
 
@@ -574,6 +657,7 @@ def _moe_combine(o, t_sorted, w_sorted, T: int, dtype):
 def moe_ffn(
     lp: dict, cfg: ModelConfig, x: jnp.ndarray, mesh=None,
     use_pallas: bool = False, interpret: bool = False,
+    tally: Optional[MoeTally] = None,
 ) -> jnp.ndarray:
     """Mixtral/DeepSeek-style sparse MoE FFN with RAGGED dispatch (ref
     serves these via vLLM's fused_moe grouped-GEMM CUDA kernels; the TPU
@@ -600,11 +684,21 @@ def moe_ffn(
     every expert onto every device — the dense einsum's contraction over
     experts IS GSPMD's expert-parallel reduce, making it the safe (if
     FLOP-heavier) fallback for odd shapes.
+
+    ``tally`` (a step program's MoeTally) counts this layer's routing;
+    on a mesh it routes once more outside the shard_map for that.
     """
     T = x.shape[0]
     out_dt = x.dtype
+    if tally is not None and mesh is not None:
+        _, idx = _route_topk(lp, cfg, x)
+        e = idx.reshape(-1)
+        tally.add(e, jnp.arange(e.shape[0]) // cfg.num_experts_per_tok,
+                  jnp.bincount(e, length=cfg.num_experts))
     if mesh is None:
         t_sorted, w_sorted, e_sorted, group_sizes = _moe_route(lp, cfg, x)
+        if tally is not None:
+            tally.add(e_sorted, t_sorted, group_sizes)
         xs = x[t_sorted]
         g = _ragged_mm(xs, lp["we_gate"], group_sizes, use_pallas, interpret)
         u = _ragged_mm(xs, lp["we_up"], group_sizes, use_pallas, interpret)
@@ -789,12 +883,13 @@ def _moe_ragged_sharded(lp: dict, cfg: ModelConfig, x: jnp.ndarray, mesh,
 
 
 def _ffn(lp: dict, cfg: ModelConfig, h: jnp.ndarray, mesh=None,
-         use_pallas: bool = False, interpret: bool = False) -> jnp.ndarray:
+         use_pallas: bool = False, interpret: bool = False,
+         tally: Optional[MoeTally] = None) -> jnp.ndarray:
     # branch on the GROUP's own leaves, not cfg.is_moe: DeepSeek's
     # first_k_dense_replace layers are dense inside an MoE model
     if "moe_gate" in lp:
         return moe_ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas,
-                       interpret=interpret)
+                       interpret=interpret, tally=tally)
     return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.hidden_act)
 
 
@@ -857,7 +952,7 @@ def _wo_proj(lp: dict, o_flat: jnp.ndarray, lora_l=None, lora_ids=None,
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "use_pallas", "mesh", "use_ring"),
+    static_argnames=("cfg", "use_pallas", "mesh", "use_ring", "moe_counters"),
     donate_argnames=("k_cache", "v_cache"),
 )
 def prefill(
@@ -884,6 +979,10 @@ def prefill(
     # is unchanged; lora forces the unrolled layer loop.
     lora=None,
     adapter_id: Optional[jnp.ndarray] = None,
+    # expert models: also return the chunk's routing counters (MoeTally
+    # sums, int32 [3]; rows below valid_len are the live ones) as the
+    # LAST output
+    moe_counters: bool = False,
 ):
     """Process one (chunk of a) prompt; returns (last_hidden_logits, caches).
 
@@ -935,6 +1034,7 @@ def prefill(
     T = tokens.shape[0]
     x = _embed(params, cfg, tokens)  # [T, E]
     positions = history_len + jnp.arange(T)
+    tally = MoeTally(jnp.arange(T) < valid_len) if moe_counters else None
     if cfg.is_mla:
         from . import mla
 
@@ -1036,7 +1136,8 @@ def prefill(
         h = pre_norm(lp, "mlp_norm", x, cfg)
         x = x + post_norm(
             lp, "mlp_post_norm",
-            _ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas), cfg,
+            _ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas, tally=tally),
+            cfg,
         )
         if scales is not None:
             return x, (kc, vc, ks_l, vs_l)
@@ -1055,7 +1156,7 @@ def prefill(
         for lps, n, off in layer_groups(params, cfg):
             for li in range(n):
                 l = off + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 x, (kc_l, vc_l, ks_l, vs_l) = body(
                     x, (lp, k_cache[l], v_cache[l]),
                     window=window_for_layer(cfg, l),
@@ -1076,7 +1177,7 @@ def prefill(
         for lps, n, off in layer_groups(params, cfg):
             for li in range(n):
                 l = off + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 x, (kc_l, vc_l) = body(
                     x, (lp, k_cache[l], v_cache[l]),
                     window=window_for_layer(cfg, l),
@@ -1087,15 +1188,16 @@ def prefill(
                 v_cache = v_cache.at[l].set(vc_l)
     else:
         x, k_cache, v_cache = _scan_groups(
-            body, x, params, cfg, k_cache, v_cache
+            body, x, params, cfg, k_cache, v_cache, tally=tally
         )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     # logits for the last *real* token of the chunk
     last = jnp.clip(valid_len - 1, 0, T - 1)
     logits = _logits(params, cfg, x[last])
+    out = (logits, k_cache, v_cache)
     if quantized:
-        return logits, k_cache, v_cache, k_scales, v_scales
-    return logits, k_cache, v_cache
+        out += (k_scales, v_scales)
+    return out + (tally.sums,) if moe_counters else out
 
 
 # ---------------- batched decode step ----------------
@@ -1105,6 +1207,7 @@ def _decode_body(
     params, cfg, tokens, positions, block_tables, seq_lens,
     k_cache, v_cache, use_pallas, mesh=None, unroll=True, interpret=False,
     merged=True, k_scales=None, v_scales=None, lora=None, adapter_ids=None,
+    moe_tally=None,
 ):
     """Shared un-jitted decode forward (one token per sequence).
 
@@ -1121,7 +1224,9 @@ def _decode_body(
     ``k_scales``/``v_scales`` ([L, N] f32, int8-with-scales device cache)
     thread through every write (scale growth + page requant) and attention
     read (fused dequant); when present the return grows to
-    (logits, k_cache, v_cache, k_scales, v_scales, n_requants)."""
+    (logits, k_cache, v_cache, k_scales, v_scales, n_requants).
+    ``moe_tally`` (the caller's MoeTally) counts the expert layers'
+    routing."""
     quantized = k_scales is not None
     if quantized:
         if cfg.is_mla:
@@ -1162,7 +1267,7 @@ def _decode_body(
         return x + post_norm(
             lp, "mlp_post_norm",
             _ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas,
-                 interpret=interpret), cfg,
+                 interpret=interpret, tally=moe_tally), cfg,
         )
 
     inv_local_dec = _rope_freqs_local(cfg)
@@ -1243,7 +1348,7 @@ def _decode_body(
         for lps, n, goff in layer_groups(params, cfg):
             for li in range(n):
                 l = goff + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 h = pre_norm(lp, "attn_norm", x, cfg)
                 q_eff, q_pe, c_kv, k_pe = _mla.mla_q_and_latent(
                     lp, cfg, h, positions, inv_freq, msc
@@ -1279,7 +1384,7 @@ def _decode_body(
         for lps, n, goff in layer_groups(params, cfg):
             for li in range(n):
                 l = goff + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 x, kc_l, vc_l = mla_layer(x, lp, k_cache[l], v_cache[l])
                 k_cache = k_cache.at[l].set(kc_l)
                 v_cache = v_cache.at[l].set(vc_l)
@@ -1291,7 +1396,7 @@ def _decode_body(
             return x, (kc, vc)
 
         x, k_cache, v_cache = _scan_groups(
-            mla_body, x, params, cfg, k_cache, v_cache
+            mla_body, x, params, cfg, k_cache, v_cache, tally=moe_tally
         )
     elif merged:
         # MERGED one-write path (TPU): attention handles the current token
@@ -1315,7 +1420,7 @@ def _decode_body(
         for lps, n, goff in layer_groups(params, cfg):
             for li in range(n):
                 l = goff + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 lora_l = lora_for_layer(l)
                 q, k, v = layer_qkv(
                     x, lp,
@@ -1375,7 +1480,7 @@ def _decode_body(
         for lps, n, goff in layer_groups(params, cfg):
             for li in range(n):
                 l = goff + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 lora_l = lora_for_layer(l)
                 q, k, v = layer_qkv(
                     x, lp,
@@ -1437,7 +1542,7 @@ def _decode_body(
             return x, (kc, vc)
 
         x, k_cache, v_cache = _scan_groups(
-            body, x, params, cfg, k_cache, v_cache
+            body, x, params, cfg, k_cache, v_cache, tally=moe_tally
         )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x)  # [B, V]
@@ -1493,7 +1598,7 @@ def decode_step(
 @partial(
     jax.jit,
     static_argnames=("cfg", "n_steps", "use_pallas", "mesh", "unroll",
-                     "interpret", "merged", "with_logprobs"),
+                     "interpret", "merged", "with_logprobs", "moe_counters"),
     donate_argnames=("k_cache", "v_cache", "counts"),
 )
 def decode_window(
@@ -1532,6 +1637,10 @@ def decode_window(
     # multi-LoRA: step-invariant (closure constants, not scan carry)
     lora=None,
     adapter_ids: Optional[jnp.ndarray] = None,  # [B] int32; -1 = base
+    # expert models: the window's routing counters (MoeTally sums over
+    # its steps and expert layers, int32 [3]; the rows with seq_lens > 0
+    # are the live ones) as the LAST output
+    moe_counters: bool = False,
 ):
     """``n_steps`` fused decode+sample steps in ONE dispatch (lax.scan):
     the sampled token of step i feeds step i+1 entirely on device, so the
@@ -1551,6 +1660,8 @@ def decode_window(
 
     penalized = counts is not None
     quantized = k_scales is not None
+    # a dead slot enters with length 0 (the scan then counts it up)
+    live = seq_lens > 0
 
     def body(carry, _):
         tokens, positions, seq_lens, steps, k_cache, v_cache = carry[:6]
@@ -1558,13 +1669,14 @@ def decode_window(
         if quantized:
             ks, vs, nreq = rest[:3]
             del rest[:3]
+        tally = MoeTally(live, rest.pop(0)) if moe_counters else None
         cnt = rest[0] if penalized else None
         if quantized:
             logits, k_cache, v_cache, ks, vs, nr = _decode_body(
                 params, cfg, tokens, positions, block_tables, seq_lens,
                 k_cache, v_cache, use_pallas, mesh, unroll, interpret,
                 merged, k_scales=ks, v_scales=vs, lora=lora,
-                adapter_ids=adapter_ids,
+                adapter_ids=adapter_ids, moe_tally=tally,
             )
             nreq = nreq + nr
         else:
@@ -1572,6 +1684,7 @@ def decode_window(
                 params, cfg, tokens, positions, block_tables, seq_lens,
                 k_cache, v_cache, use_pallas, mesh, unroll, interpret,
                 merged, lora=lora, adapter_ids=adapter_ids,
+                moe_tally=tally,
             )
         raw_logits = logits  # reported logprobs are the model's own dist
         if penalized:
@@ -1582,6 +1695,8 @@ def decode_window(
         nxt = sample_tokens.__wrapped__(logits, keys, temps, top_ks, top_ps)
         ys = (nxt, *token_logprobs(raw_logits, nxt)) if with_logprobs else nxt
         tail = (ks, vs, nreq) if quantized else ()
+        if moe_counters:
+            tail = tail + (tally.sums,)
         if penalized:
             tail = tail + (bump_counts(cnt, nxt),)
         return (nxt, positions + 1, seq_lens + 1, steps + 1,
@@ -1590,6 +1705,8 @@ def decode_window(
     carry = (tokens, positions, seq_lens, steps, k_cache, v_cache)
     if quantized:
         carry = carry + (k_scales, v_scales, jnp.zeros((), jnp.int32))
+    if moe_counters:
+        carry = carry + (jnp.zeros((3,), jnp.int32),)
     if penalized:
         carry = carry + (counts,)
     fin, ys = lax.scan(body, carry, None, length=n_steps)
@@ -1601,9 +1718,12 @@ def decode_window(
     if quantized:
         out = out + tuple(rest[:3])  # (k_scales, v_scales, n_requants)
         del rest[:3]
+    moe = (rest.pop(0),) if moe_counters else ()
     if penalized:
         out = out + (rest[0],)
-    return out + (lps,) if with_logprobs else out
+    if with_logprobs:
+        out = out + (lps,)
+    return out + moe
 
 
 # ---------------- fused mixed prefill+decode step ----------------
@@ -1613,15 +1733,16 @@ def _mixed_fused_forward(
     params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
     p_tokens, p_tables, p_hists, p_valids, k_cache, v_cache,
     mesh=None, interpret=False, k_scales=None, v_scales=None,
-    lora=None, d_adapter_ids=None, p_adapter_ids=None,
+    lora=None, d_adapter_ids=None, p_adapter_ids=None, moe_tally=None,
 ):
     """The FULLY-fused mixed forward (TPU/Pallas path): embeddings and
     every projection/FFN/logits GEMM run over the combined [B + MP*T]
     row axis — the weight stream amortizes across the decode rows and
     every prefill segment (the mixed-batch MFU win) — and attention is
-    ONE ragged paged-attention kernel invocation per layer covering all
-    parts (ops/ragged_paged_attention_pallas). Write-before-attend
-    throughout.
+    one call per layer covering all parts
+    (ops/ragged_paged_attention_pallas: the decode rows through the
+    decode kernel, the segments through the ragged grid).
+    Write-before-attend throughout.
 
     Combined-row GEMMs reassociate reductions vs the unfused [B]- and
     [T]-row programs, so this path matches them only to kernel-grade
@@ -1672,7 +1793,7 @@ def _mixed_fused_forward(
         return x + post_norm(
             lp, "mlp_post_norm",
             _ffn(lp, cfg, h, mesh=mesh, use_pallas=True,
-                 interpret=interpret), cfg,
+                 interpret=interpret, tally=moe_tally), cfg,
         )
 
     # UNROLLED layer loop (per-layer windows / local rope stay
@@ -1680,7 +1801,7 @@ def _mixed_fused_forward(
     for lps, n, goff in layer_groups(params, cfg):
         for li in range(n):
             l = goff + li
-            lp = jax.tree.map(lambda a: a[li], lps)
+            lp = _layer(lps, li, mesh)
             lora_l = (
                 None if lora is None
                 else jax.tree.map(lambda arr: arr[l], lora)
@@ -1770,7 +1891,7 @@ def _mixed_fused_forward(
 @partial(
     jax.jit,
     static_argnames=("cfg", "use_pallas", "mesh", "unroll", "merged",
-                     "interpret", "with_logprobs"),
+                     "interpret", "with_logprobs", "moe_counters"),
     donate_argnames=("k_cache", "v_cache", "counts"),
 )
 def mixed_step(
@@ -1819,6 +1940,10 @@ def mixed_step(
     lora=None,
     d_adapter_ids: Optional[jnp.ndarray] = None,  # [B] int32
     p_adapter_ids: Optional[jnp.ndarray] = None,  # [MP] int32
+    # expert models: the step's routing counters (MoeTally sums, int32
+    # [3]; live rows are the decode rows with d_seq_lens > 0 and each
+    # segment's rows below its valid length) as the LAST output
+    moe_counters: bool = False,
 ):
     """ONE device dispatch fusing M prefill chunks into a decode step.
 
@@ -1868,13 +1993,23 @@ def mixed_step(
         token_logprobs,
     )
 
-    MP = p_tokens.shape[0]
+    MP, T = p_tokens.shape
     quantized = k_scales is not None
     if quantized:
         # scales only grow within a step — plane entries above their
         # step-entry value count the pages requantized this dispatch
         k_scales0, v_scales0 = k_scales, v_scales
-    if use_pallas and not cfg.is_mla and not cfg.attn_softcap:
+    fused = use_pallas and not cfg.is_mla and not cfg.attn_softcap
+    tally = None
+    if moe_counters:
+        # the fused forward routes every row at once; the per-part one
+        # counts the decode rows here and each segment in its prefill
+        live = d_seq_lens > 0
+        if fused:
+            p_live = jnp.arange(T)[None, :] < p_valids[:, None]
+            live = jnp.concatenate([live, p_live.reshape(-1)])
+        tally = MoeTally(live)
+    if fused:
         if quantized:
             logits_d, p_logits, k_cache, v_cache, k_scales, v_scales = (
                 _mixed_fused_forward(
@@ -1883,7 +2018,7 @@ def mixed_step(
                     k_cache, v_cache, mesh=mesh, interpret=interpret,
                     k_scales=k_scales, v_scales=v_scales, lora=lora,
                     d_adapter_ids=d_adapter_ids,
-                    p_adapter_ids=p_adapter_ids,
+                    p_adapter_ids=p_adapter_ids, moe_tally=tally,
                 )
             )
         else:
@@ -1892,6 +2027,7 @@ def mixed_step(
                 p_tokens, p_tables, p_hists, p_valids, k_cache, v_cache,
                 mesh=mesh, interpret=interpret, lora=lora,
                 d_adapter_ids=d_adapter_ids, p_adapter_ids=p_adapter_ids,
+                moe_tally=tally,
             )
     else:
         # chunks first (admission order), then decode — order is
@@ -1900,22 +2036,19 @@ def mixed_step(
         p_logit_rows = []
         for m in range(MP):
             aid = None if lora is None else p_adapter_ids[m]
+            seg = prefill.__wrapped__(
+                params, cfg, p_tokens[m], p_tables[m], p_hists[m],
+                p_valids[m], k_cache, v_cache, use_pallas=use_pallas,
+                mesh=mesh, k_scales=k_scales, v_scales=v_scales,
+                lora=lora, adapter_id=aid, moe_counters=moe_counters,
+            )
+            if moe_counters:
+                tally.sums = tally.sums + seg[-1]
+                seg = seg[:-1]
             if quantized:
-                lg, k_cache, v_cache, k_scales, v_scales = (
-                    prefill.__wrapped__(
-                        params, cfg, p_tokens[m], p_tables[m], p_hists[m],
-                        p_valids[m], k_cache, v_cache,
-                        use_pallas=use_pallas, mesh=mesh,
-                        k_scales=k_scales, v_scales=v_scales,
-                        lora=lora, adapter_id=aid,
-                    )
-                )
+                lg, k_cache, v_cache, k_scales, v_scales = seg
             else:
-                lg, k_cache, v_cache = prefill.__wrapped__(
-                    params, cfg, p_tokens[m], p_tables[m], p_hists[m],
-                    p_valids[m], k_cache, v_cache, use_pallas=use_pallas,
-                    mesh=mesh, lora=lora, adapter_id=aid,
-                )
+                lg, k_cache, v_cache = seg
             p_logit_rows.append(lg)
         p_logits = jnp.stack(p_logit_rows)  # [MP, V]
         if quantized:
@@ -1923,13 +2056,14 @@ def mixed_step(
                 params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
                 k_cache, v_cache, use_pallas, mesh, unroll, interpret,
                 merged, k_scales=k_scales, v_scales=v_scales,
-                lora=lora, adapter_ids=d_adapter_ids,
+                lora=lora, adapter_ids=d_adapter_ids, moe_tally=tally,
             )
         else:
             logits_d, k_cache, v_cache = _decode_body(
                 params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
                 k_cache, v_cache, use_pallas, mesh, unroll, interpret,
                 merged, lora=lora, adapter_ids=d_adapter_ids,
+                moe_tally=tally,
             )
 
     raw_logits = logits_d
@@ -1950,6 +2084,8 @@ def mixed_step(
         result.append(bump_counts(counts, nxt))
     if with_logprobs:
         result.append(token_logprobs(raw_logits, nxt))
+    if moe_counters:
+        result.append(tally.sums)
     return tuple(result)
 
 
@@ -1999,7 +2135,7 @@ def _verify_forward(
         for lps, ng, goff in layer_groups(params, cfg):
             for li in range(ng):
                 l = goff + li
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li, mesh)
                 h = pre_norm(lp, "attn_norm", x, cfg)
                 q_eff, q_pe, c_kv, k_pe = _mla.mla_q_and_latent(
                     lp, cfg, h, pos_bt, inv_freq, msc
@@ -2043,7 +2179,7 @@ def _verify_forward(
     for lps, ng, goff in layer_groups(params, cfg):
         for li in range(ng):
             l = goff + li
-            lp = jax.tree.map(lambda a: a[li], lps)
+            lp = _layer(lps, li, mesh)
             h = pre_norm(lp, "attn_norm", x, cfg)
             q, k, v = _qkv(lp, cfg, h)  # [B, T, H/Hkv, D]
             fr = rope_freqs_for_layer(cfg, l, inv_freq, inv_local)
@@ -2316,7 +2452,7 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
     if cfg.layer_windows:  # per-layer static windows: unrolled
         for lps, n, off in layer_groups(params, cfg):
             for li in range(n):
-                lp = jax.tree.map(lambda a: a[li], lps)
+                lp = _layer(lps, li)
                 l = off + li
                 x, _ = body(
                     x, lp, window=window_for_layer(cfg, l),

@@ -6,7 +6,7 @@ device dispatch as a decode step for every active sequence, so decode
 streams stop stalling behind prefill chunks AND queued prompts stop
 stalling behind each other's prefills (the Sarathi token-budget packing
 + the full "Ragged Paged Attention" formulation — PAPERS.md). This
-module is that step's attention: ONE kernel invocation computes
+module is that step's attention: one call computes
 
   * B decode rows — one query token per sequence, each against its own
     block table and sequence length, and
@@ -15,12 +15,20 @@ module is that step's attention: ONE kernel invocation computes
     history plus the causal prefix of the segment itself,
 
 with per-row query positions, causal masking, per-row sliding-window
-floors, and the gpt-oss sink fold, all in a single grid.
+floors, and the gpt-oss sink fold.
 
-Design — a strict generalization of the two existing kernels
-(paged_attention_pallas._decode_kernel / _prefill_kernel) and of this
-kernel's own one-segment predecessor (PR 3), reusing their row/group
-mapping (row r of a tile is token t = r // group, head g = r % group):
+Design — the decode rows take the decode kernel
+(paged_attention_pallas.paged_decode_attention through
+ops/attention.decode_attention, sinks and page scales included), whose
+cost follows the live KV; the segments take this module's ragged grid,
+a generalization of paged_attention_pallas._prefill_kernel to M
+sequences with the same row/group mapping (row r of a tile is token
+t = r // group, head g = r % group). Until PR 28 the decode rows were
+tiles of the same grid, one ``q_tile``-token tile a row: every row,
+live or dead, then cost kv_heads * superblocks grid steps of about
+1 us, 20-27 ms a layer-call at 32 rows x 16 heads x 32 superblocks
+against 1.1 ms in the decode kernel and 2.7 for the segments alone
+(chip, PR 28; the outputs are bit-identical):
 
   * everything is write-before-attend: the caller has already scattered
     the decode tokens' K/V and every segment's K/V into the paged
@@ -29,20 +37,16 @@ mapping (row r of a tile is token t = r // group, head g = r % group):
     One mask rule covers history, chunk-causal, and the decode
     self-row, for every segment.
   * grid = (tiles, kv_heads, superblocks). The tile axis is ragged over
-    SEQUENCES: tiles 0..B-1 are the decode rows (one real token each,
-    padded to the uniform ``q_tile`` tokens; the padding rows compute
-    garbage that is sliced off — their page DMAs are shared with the
-    real row, so the waste is compute the DMA-bound step hides), tiles
-    B.. are the M prefill segments in ``q_tile``-token slices, segment-
-    major.
+    SEQUENCES: the M prefill segments in ``q_tile``-token slices,
+    segment-major.
   * scalar-prefetched per-tile metadata (`tile_seq`, `tile_q0`,
-    `tile_last_q`) and the stacked block tables ([B+M, Mb]; rows B..
-    are the prefill sequences) let each page stream's ``index_map``
-    fetch exactly the physical pages the tile's own sequence needs;
-    pages past a tile's causal horizon re-map to its last needed page
-    (consecutive identical indices skip the re-fetch, the same trick as
-    the parent kernels). ``tile_seq`` is what makes the tile axis truly
-    ragged: a tile no longer infers its table row from its position.
+    `tile_last_q`) and the segments' block tables ([M, Mb]) let each
+    page stream's ``index_map`` fetch exactly the physical pages the
+    tile's own sequence needs; pages past a tile's causal horizon
+    re-map to its last needed page (consecutive identical indices skip
+    the re-fetch, the same trick as the parent kernels). ``tile_seq``
+    is what makes the tile axis truly ragged: a tile does not infer
+    its table row from its position.
   * all segments share ONE padded length T (the caller buckets the
     largest take), so the compiled program is keyed by (M bucket, T
     bucket) — never by the segment-length mixture. Dead segments
@@ -92,7 +96,7 @@ def _mixed_page(sq, bt, lastq, s, i, p, pages_per_step, block_size):
 def _mixed_kernel(
     # scalar prefetch (order matches the pallas_call operands)
     seq_ref,  # [S] int32: tile -> its sequence's row in tables_ref
-    tables_ref,  # [B+MP, Mb] int32 (SMEM): decode + prefill tables
+    tables_ref,  # [MP, Mb] int32 (SMEM): the segments' block tables
     q0_ref,  # [S] int32: tile row 0's absolute query position
     lastq_ref,  # [S] int32: tile's last REAL query position (-1 = all pad)
     # [+ k_scales, v_scales [N] f32 (SMEM) when has_scales]
@@ -253,7 +257,8 @@ def ragged_mixed_attention(
     v_scales: jnp.ndarray | None = None,  # [N] f32 (quantized caches)
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:  # (o_dec [B,H,D], o_chunks [MP,T,H,D])
-    """One kernel invocation over B decode rows + M prefill segments.
+    """B decode rows (the decode kernel) + M prefill segments (the
+    ragged grid) against one layer of the paged cache.
 
     Every part must be write-before-attend (K/V for the decode tokens
     AND every segment scattered into the cache first); every row then
@@ -274,7 +279,7 @@ def ragged_mixed_attention(
     pass. Scale-free quantized caches (the fp8 direct-cast device
     cache) simply pass no scales.
     """
-    B, H, D = q_dec.shape
+    _, H, D = q_dec.shape
     MP, T = q_chunks.shape[0], q_chunks.shape[1]
     Hkv, N, bs, _ = k_cache_layer.shape
     M = d_tables.shape[1]
@@ -287,7 +292,7 @@ def ragged_mixed_attention(
     if T % Tq:
         raise ValueError(f"q_tile={Tq} must divide segment length T={T}")
     nT = T // Tq
-    S = B + MP * nT  # ragged tile axis: B decode + MP*nT segment tiles
+    S = MP * nT  # ragged tile axis: the segments' q_tile-token slices
     Pp = pages_per_step or _pick_pages_per_step(M)
     if M % Pp:
         raise ValueError(
@@ -295,39 +300,39 @@ def ragged_mixed_attention(
             "(a truncated grid would silently drop tail pages)"
         )
 
-    # ---- pack queries: [Hkv, S*Tq*Gp, D], rows (t, g) lexicographic ----
-    # decode tiles: real row at t=0 only; rows t>0 are padding whose
-    # output is sliced off (their page DMAs are shared with row 0)
-    qd = q_dec.reshape(B, 1, Hkv, G, D)
-    qd = jnp.pad(
-        qd, ((0, 0), (0, Tq - 1), (0, 0), (0, Gp - G), (0, 0))
-    )  # [B, Tq, Hkv, Gp, D]
+    # ---- decode rows: the decode kernel, whose cost follows the live
+    # KV (all KV heads of a page group in one grid step). As tiles of
+    # this grid each of the B rows, live or dead, cost Hkv * M / Pp
+    # steps of about 1 us: 16,384 of them, 20-27 ms a layer-call at
+    # B = 32, Hkv = 16, M = 256, nine tenths of a mixed step's
+    # attention whatever it held (PERF.md section 6, PR 28) ----
+    from .attention import decode_attention
+
+    o_dec = decode_attention(
+        q_dec, k_cache_layer, v_cache_layer, d_tables, d_seq_lens, scale,
+        use_pallas=True, window=window, sinks=sinks, interpret=interpret,
+        k_scales=k_scales, v_scales=v_scales,
+    )
+
+    # ---- pack segment queries: [Hkv, S*Tq*Gp, D], rows (t, g)
+    # lexicographic ----
     qp = q_chunks.reshape(MP * T, Hkv, G, D)
     qp = jnp.pad(qp, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    qp = qp.reshape(MP * nT, Tq, Hkv, Gp, D)
-    q_all = jnp.concatenate([qd, qp], axis=0)  # [S, Tq, Hkv, Gp, D]
+    q_all = qp.reshape(S, Tq, Hkv, Gp, D)
     q_all = q_all.transpose(2, 0, 1, 3, 4).reshape(Hkv, S * Tq * Gp, D)
 
     # ---- per-tile metadata (scalar prefetch) ----
-    tables = jnp.concatenate(
-        [d_tables.astype(jnp.int32), p_tables.astype(jnp.int32)], axis=0
-    )  # [B+MP, M]
+    tables = p_tables.astype(jnp.int32)  # [MP, M]
     hists = p_hists.astype(jnp.int32)  # [MP]
     valids = p_valids.astype(jnp.int32)
-    dec_q0 = d_seq_lens.astype(jnp.int32) - 1  # -1 for inactive slots
-    # segment-major sub-tiling: tile B + m*nT + j is segment m, slice j
-    m_idx = jnp.repeat(jnp.arange(MP, dtype=jnp.int32), nT)  # [MP*nT]
+    # segment-major sub-tiling: tile m*nT + j is segment m, slice j
+    tile_seq = jnp.repeat(jnp.arange(MP, dtype=jnp.int32), nT)  # [S]
     j_idx = jnp.tile(jnp.arange(nT, dtype=jnp.int32), MP)
-    chunk_q0 = hists[m_idx] + j_idx * Tq
+    tile_q0 = hists[tile_seq] + j_idx * Tq
     # last REAL row of each segment tile (tiles fully in the padding —
     # or of a dead segment — get -1, which skips every superblock)
-    real = jnp.clip(valids[m_idx] - j_idx * Tq, 0, Tq)
-    chunk_last = jnp.where(real > 0, chunk_q0 + real - 1, -1)
-    tile_seq = jnp.concatenate(
-        [jnp.arange(B, dtype=jnp.int32), B + m_idx]
-    )  # [S]: each tile's row in the stacked tables
-    tile_q0 = jnp.concatenate([dec_q0, chunk_q0])
-    tile_last = jnp.concatenate([dec_q0, chunk_last])
+    real = jnp.clip(valids[tile_seq] - j_idx * Tq, 0, Tq)
+    tile_last = jnp.where(real > 0, tile_q0 + real - 1, -1)
 
     # index maps see every scalar-prefetch ref; ``*_`` absorbs the scale
     # planes of the quantized lane
@@ -403,10 +408,7 @@ def ragged_mixed_attention(
         tile_seq, tables, tile_q0, tile_last, *scale_inputs, q_all,
         *([k_cache_layer] * Pp), *([v_cache_layer] * Pp), *sink_inputs,
     )
-    out = out.reshape(Hkv, S, Tq, Gp, D)
-    o_dec = out[:, :B, 0].transpose(1, 0, 2, 3)  # [B, Hkv, Gp, D]
-    o_dec = o_dec[:, :, :G, :].reshape(B, H, D)
-    o_chunks = out[:, B:].reshape(Hkv, MP, nT, Tq, Gp, D)
+    o_chunks = out.reshape(Hkv, MP, nT, Tq, Gp, D)
     o_chunks = o_chunks.transpose(1, 2, 3, 0, 4, 5)  # [MP,nT,Tq,Hkv,Gp,D]
     o_chunks = o_chunks.reshape(MP, T, Hkv, Gp, D)[:, :, :, :G, :]
     return o_dec, o_chunks.reshape(MP, T, H, D)

@@ -322,7 +322,16 @@ def _fit_layers(cfg: ModelConfig, lower_fn, correction_per_moe_layer: float,
     full-depth FLOPs (+ the CA bytes, same fit) with the ragged-dot
     correction applied per MoE layer and ``intercept_correction_fn``
     (the cumsum mispricing — depth-independent, sampling runs once per
-    step not per layer) subtracted once from the first lowering."""
+    step not per layer) subtracted once from the first lowering.
+
+    An unrolled program hands the grouped-matmul kernel the WHOLE
+    [L, X, ...] expert stack (llama._layer), and cost analysis prices a
+    ragged_dot over every group of its operand: with m MoE layers
+    lowered, each layer's dots cost m times what they cost alone, so a
+    MoE model's cost is quadratic in depth. A third lowering takes the
+    quadratic term out: a layer is priced as at depth one, where the
+    stack is its own, which is what ``correction_per_moe_layer``
+    corrects."""
     k = cfg.first_dense_layers if cfg.is_moe else 0
     l1, l2 = k + 1, k + 2
     c1 = replace(cfg, num_layers=l1, layer_windows=())
@@ -330,8 +339,18 @@ def _fit_layers(cfg: ModelConfig, lower_fn, correction_per_moe_layer: float,
     lo1 = lower_fn(c1)
     a1 = lo1.cost_analysis()
     a2 = lower_fn(c2).cost_analysis()
-    per_layer_f = a2["flops"] - a1["flops"]
-    per_layer_b = a2.get("bytes accessed", 0.0) - a1.get("bytes accessed", 0.0)
+    a3 = (lower_fn(replace(cfg, num_layers=k + 3, layer_windows=()))
+          .cost_analysis() if cfg.is_moe else None)
+
+    def per_layer(key: str) -> float:
+        d1 = a2.get(key, 0.0) - a1.get(key, 0.0)
+        if a3 is None:
+            return d1
+        q = (a3.get(key, 0.0) - a2.get(key, 0.0) - d1) / 2.0
+        return d1 - 2.0 * q  # (lin + 3q) - 2q = lin + q: depth one's price
+
+    per_layer_f = per_layer("flops")
+    per_layer_b = per_layer("bytes accessed")
     n_var = cfg.num_layers - l1  # layers beyond the first lowering
     flops = a1["flops"] + n_var * per_layer_f
     bytes_ = a1.get("bytes accessed", 0.0) + n_var * per_layer_b

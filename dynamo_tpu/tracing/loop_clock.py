@@ -175,8 +175,10 @@ class LoopClock:
             out[self.phase] += time.perf_counter() - self._t
         return out
 
-    def step_done(self) -> None:
-        """A dispatch and its emission are over: close the step."""
+    def step_done(self, **attrs: Any) -> None:
+        """A dispatch and its emission are over: close the step.
+        ``attrs`` go onto its span as they are (an expert model's
+        ``moe``: the routing counters that came back during the step)."""
         if self.phase is None:
             return
         self.mark(self.phase)
@@ -216,6 +218,7 @@ class LoopClock:
                 key=str(info.get("key")), n=n, live=info.get("live"),
                 rows=info.get("rows"),
                 phases={p: round(step[p] * 1e3, 3) for p in PHASES},
+                **attrs,
             )
         self.seq += 1
 

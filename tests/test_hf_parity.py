@@ -494,6 +494,32 @@ def test_olmo2_parity(tmp_path):
     _compare(path, TOKENS, model)
 
 
+@pytest.mark.skipif(
+    not hasattr(transformers, "OlmoeConfig"),
+    reason="transformers too old for OLMoE",
+)
+def test_olmoe_parity(tmp_path):
+    """OLMoE: PRE-norm layers with olmo-2's full-width q/k RMS norms;
+    64 experts, top-8, the router's softmax weights used without
+    renormalisation; intermediate_size is one expert's width."""
+    hf_cfg = transformers.OlmoeConfig(
+        **{**TINY, "intermediate_size": 32, "num_key_value_heads": 4},
+        num_experts=64, num_experts_per_tok=8, norm_topk_prob=False,
+        pad_token_id=0,
+    )
+    model = transformers.OlmoeForCausalLM(hf_cfg)
+    with torch.no_grad():  # non-trivial norms so ordering shows
+        for name, p in model.named_parameters():
+            if "norm" in name:
+                p.normal_(1.0, 0.3)
+    path = _save(tmp_path, model)
+    cfg = ModelConfig.from_local_path(path)
+    assert cfg.qk_norm_full and not cfg.norm_after and not cfg.post_norms
+    assert (cfg.num_experts, cfg.num_experts_per_tok) == (64, 8)
+    assert cfg.moe_intermediate_size == 32 and not cfg.norm_topk_prob
+    _compare(path, TOKENS, model)
+
+
 def test_mistral_parity(tmp_path):
     hf_cfg = transformers.MistralConfig(**TINY, sliding_window=None)
     model = transformers.MistralForCausalLM(hf_cfg)

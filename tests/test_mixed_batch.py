@@ -682,3 +682,57 @@ def test_ragged_mixed_kernel_inactive_slots_zero():
     )
     assert np.all(np.asarray(o_dec)[1] == 0.0)
     assert np.all(np.isfinite(np.asarray(o_dec)[0]))
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every pallas_call under ``jaxpr``, nested calls too."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    grids.extend(_pallas_grids(inner))
+    return grids
+
+
+@pytest.mark.parametrize("B", [2, 32])
+def test_ragged_mixed_decode_rows_take_the_decode_kernel(B):
+    """The decode rows are not tiles of the ragged grid (as tiles each
+    row, live or dead, cost kv_heads x superblocks grid steps: 20-27 ms
+    a layer-call at 32 rows on the chip, PERF.md section 6): the ragged
+    grid covers the segments' tiles whatever B is, and the decode rows
+    come out of the decode kernel, bit for bit."""
+    from dynamo_tpu.ops.paged_attention_pallas import paged_decode_attention
+    from dynamo_tpu.ops.ragged_paged_attention_pallas import (
+        ragged_mixed_attention,
+    )
+
+    rng = np.random.default_rng(11)
+    Hkv, G, D, bs, M, T = 2, 2, 16, 8, 4, 16
+    kc, vc, d_tables, d_seq_lens, p_table, q_dec, q_chunk = (
+        _random_cache_setup(rng, B=B, Hkv=Hkv, G=G, D=D, bs=bs, M=M, T=T,
+                            hist=0, valid=T)
+    )
+    d_seq_lens = d_seq_lens.at[1].set(0)  # a dead slot among the rows
+    args = (
+        q_dec, q_chunk[None], kc, vc, d_tables, d_seq_lens, p_table[None],
+        jnp.asarray([0], jnp.int32), jnp.asarray([T], jnp.int32),
+    )
+
+    def call(*a):
+        return ragged_mixed_attention(
+            *a, scale=D ** -0.5, q_tile=8, pages_per_step=2, interpret=True
+        )
+
+    grids = _pallas_grids(jax.make_jaxpr(call)(*args).jaxpr)
+    assert sorted(grids) == sorted(
+        [(T // 8, Hkv, M // 2), (B, 1, M // 4)]
+    ), grids
+    o_dec, _ = call(*args)
+    ref = paged_decode_attention(
+        q_dec, kc, vc, d_tables, d_seq_lens, D ** -0.5, interpret=True
+    )
+    assert np.array_equal(np.asarray(o_dec), np.asarray(ref))

@@ -270,3 +270,32 @@ def test_ragged_int8_gmm(chip, r, k, n, x):
     _compile(ragged_int8_gmm, chip((r, k), jnp.bfloat16),
              chip((x, k, n), jnp.int8), chip((x, n), jnp.float32),
              chip((x,), jnp.int32))
+
+
+# ---------------- bf16 expert layer (OLMoE-1B-7B's widths) ----------------
+
+
+@pytest.mark.parametrize("rows", [32, 544], ids=["decode-32", "mixed-544"])
+def test_moe_ffn_bf16_olmoe_widths(chip, rows):
+    """The single-chip expert layer of a bf16 stack at the widths of the
+    benchmark's ``olmoe-1b-7b`` (64 experts of [2048, 1024], top-8) with
+    its routing tally: routing, sort, three ``lax.ragged_dot`` (which the
+    TPU compiler turns into its own grouped-matmul kernel) and the
+    scatter-add, for a decode batch and for a 512-token mixed step."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(hidden_size=2048, num_heads=16, num_kv_heads=16,
+                      num_experts=64, num_experts_per_tok=8,
+                      moe_intermediate_size=1024, norm_topk_prob=False)
+    e, f, x = 2048, 1024, 64
+    lp = {"moe_gate": chip((e, x), jnp.bfloat16),
+          "we_gate": chip((x, e, f), jnp.bfloat16),
+          "we_up": chip((x, e, f), jnp.bfloat16),
+          "we_down": chip((x, f, e), jnp.bfloat16)}
+
+    def layer(lp, h, live):
+        tally = llama.MoeTally(live)
+        return llama.moe_ffn(lp, cfg, h, tally=tally), tally.sums
+
+    _compile(layer, lp, chip((rows, e), jnp.bfloat16), chip((rows,), jnp.bool_))
