@@ -1212,8 +1212,10 @@ def _decode_body(
     """Shared un-jitted decode forward (one token per sequence).
 
     ``unroll=True`` (default) runs an UNROLLED python loop over layers
-    with static layer indices: the caches are updated by tiny in-place
-    scatters on the donated stacked arrays and read by static slices.
+    with static layer indices: the caches are updated in place on the
+    donated stacked arrays, and the attention kernels take them WHOLE
+    with the layer's index (a ``k_cache[l]`` operand of a kernel is a
+    copy of the pool: ops/paged_attention_pallas module docs).
     The scan variant threads the caches as scan xs/ys, and XLA
     materializes the re-stacked ys — a full extra cache copy per decode
     step (measured: a 2.15GB cache pair costs ~2.5GB of temp and
@@ -1436,14 +1438,14 @@ def _decode_body(
                 vs_l = v_scales[l] if quantized else None
                 if mesh is None:
                     o = att.decode_attention_merged(
-                        q, k, v, k_cache[l], v_cache[l], block_tables,
+                        q, k, v, k_cache, v_cache, l, block_tables,
                         hist_lens, scale, window=window_for_layer(cfg, l),
                         sinks=lp.get("sinks"), interpret=interpret,
                         k_scales=ks_l, v_scales=vs_l,
                     )
                 else:
                     o = att.decode_attention_merged_sharded(
-                        q, k, v, k_cache[l], v_cache[l], block_tables,
+                        q, k, v, k_cache, v_cache, l, block_tables,
                         hist_lens, scale, mesh,
                         window=window_for_layer(cfg, l),
                         sinks=lp.get("sinks"), interpret=interpret,
@@ -1512,7 +1514,7 @@ def _decode_body(
                         v.astype(v_cache.dtype)
                     )
                 o = att.decode_attention(
-                    q, k_cache[l], v_cache[l], block_tables, seq_lens, scale,
+                    q, k_cache, v_cache, l, block_tables, seq_lens, scale,
                     use_pallas=use_pallas, mesh=mesh,
                     window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
                     cap=cfg.attn_softcap,
@@ -1533,8 +1535,10 @@ def _decode_body(
             q, k, v = layer_qkv(x, lp)
             kc = att.write_decode_token_to_cache(kc, k, block_tables, positions)
             vc = att.write_decode_token_to_cache(vc, v, block_tables, positions)
+            # the scan hands its body one layer's slab: a one-layer
+            # cache and layer 0 (a bitcast)
             o = att.decode_attention(
-                q, kc, vc, block_tables, seq_lens, scale,
+                q, kc[None], vc[None], 0, block_tables, seq_lens, scale,
                 use_pallas=use_pallas, mesh=mesh, window=cfg.sliding_window,
                 sinks=lp.get("sinks"), cap=cfg.attn_softcap,
             )
@@ -2189,7 +2193,7 @@ def _verify_forward(
             v_news.append(v)
             if use_pallas and mesh is not None:
                 o = att.verify_attention_sharded(
-                    q, k, v, k_cache[l], v_cache[l], block_tables, hist_lens,
+                    q, k, v, k_cache, v_cache, l, block_tables, hist_lens,
                     scale, mesh, use_pallas=True,
                     window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
                     interpret=interpret,
@@ -2198,7 +2202,7 @@ def _verify_forward(
                 # the layer loop is unrolled, so per-layer windows and
                 # sinks (gpt-oss) thread straight through the XLA verify
                 o = att.verify_attention(
-                    q, k, v, k_cache[l], v_cache[l], block_tables, hist_lens,
+                    q, k, v, k_cache, v_cache, l, block_tables, hist_lens,
                     scale, use_pallas=use_pallas,
                     window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
                     cap=cfg.attn_softcap, interpret=interpret,

@@ -34,27 +34,31 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _shard_tp(mesh, local_fn, *, arr_specs, arrs, k_cache_layer,
-              v_cache_layer, scalars, sinks, out_spec):
+def _shard_tp(mesh, local_fn, *, arr_specs, arrs, k_cache, v_cache,
+              scalars, sinks, out_spec):
     """One shard_map over ``tp`` shared by every paged-attention wrapper.
 
     The kv-head axis is the cache's sharded axis (ops module docs), and
     paged attention is embarrassingly parallel over kv-head groups: each
-    device runs the kernel on its local [Hkv/tp, ...] cache shard
-    against its local head-sharded query arrays (``arrs`` with
-    ``arr_specs``); ``scalars`` (block tables, lengths) replicate,
-    matching the engine's host-batch inputs; other mesh axes
-    (dp/pp/sp/ep) replicate too — no collectives needed. Per-head sinks,
-    only when present, shard with the heads and arrive as ``local_fn``'s
-    LAST argument; keeping the sinks/no-sinks cases one invocation stops
-    the spec blocks drifting apart."""
+    device runs the kernel on its local [..., Hkv/tp, N, bs, D] cache
+    shard (the decode wrappers' whole cache with its leading layer axis,
+    the prefill wrapper's one layer) against its local head-sharded
+    query arrays (``arrs`` with ``arr_specs``); ``scalars`` (the layer
+    index, block tables, lengths) replicate, matching the engine's
+    host-batch inputs; other mesh axes (dp/pp/sp/ep) replicate too — no
+    collectives needed. Per-head sinks, only when present, shard with
+    the heads and arrive as ``local_fn``'s LAST argument; keeping the
+    sinks/no-sinks cases one invocation stops the spec blocks drifting
+    apart."""
+    cache_spec = P(*([None] * (k_cache.ndim - 4)), "tp", None, None, None)
     in_specs = (
         *arr_specs,
-        P("tp", None, None, None),  # k cache layer
-        P("tp", None, None, None),  # v cache layer
+        cache_spec,  # k cache
+        cache_spec,  # v cache
         *([P()] * len(scalars)),
     )
-    operands = (*arrs, k_cache_layer, v_cache_layer, *scalars)
+    # asarray: the layer index may be a Python int
+    operands = (*arrs, k_cache, v_cache, *map(jnp.asarray, scalars))
     if sinks is not None:
         in_specs += (P("tp"),)
         operands += (sinks,)
@@ -73,8 +77,9 @@ def repeat_kv(x: jnp.ndarray, n_rep: int, axis: int) -> jnp.ndarray:
 
 def decode_attention(
     q: jnp.ndarray,
-    k_cache_layer: jnp.ndarray,
-    v_cache_layer: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D]: the whole cache
+    v_cache: jnp.ndarray,
+    layer,  # int or int32 scalar: the layer to read (a slab: [None], 0)
     block_tables: jnp.ndarray,
     seq_lens: jnp.ndarray,
     scale: float,
@@ -93,7 +98,7 @@ def decode_attention(
 
     ``use_pallas`` must be trace-static. With a ``mesh``, the kernel runs
     under shard_map: each device gets its tp shard of the kv heads (cache
-    axis 0 / q axis 1) and runs the kernel on purely local tiles — paged
+    axis 1 / q axis 1) and runs the kernel on purely local tiles — paged
     attention is head-parallel, so no collectives are needed. Callers
     guarantee num_kv_heads % tp == 0 (the engine falls back to XLA
     otherwise, where GSPMD handles uneven head splits).
@@ -101,34 +106,39 @@ def decode_attention(
     ``k_scales``/``v_scales`` (per-page f32, this layer's [N] slice of
     the engine's scale planes) ride every path: fused per-page dequant
     in the kernels, gathered-scale multiply in the XLA fallback.
+
+    The kernels take the cache whole and read ``layer``'s pages in
+    place (a ``k_cache[layer]`` operand of a kernel is a copy of the
+    pool: paged_attention_pallas module docs); the XLA path slices the
+    layer here, which fuses into its gather.
     """
     if use_pallas and mesh is not None and not cap:
         return paged_decode_attention_sharded(
-            q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+            q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
             mesh, window=window, sinks=sinks, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales,
         )
     if use_pallas and sinks is None and not cap:
         return _decode_kernel(
-            q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+            q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
             window=window, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales,
         )
     if use_pallas and not cap:
         return _decode_kernel_with_sinks(
-            q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+            q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
             sinks, window=window, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales,
         )
     return decode_attention_xla(
-        q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+        q, k_cache[layer], v_cache[layer], block_tables, seq_lens, scale,
         window=window, sinks=sinks, cap=cap,
         k_scales=k_scales, v_scales=v_scales,
     )
 
 
 def _decode_kernel(
-    q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+    q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
     window: int = 0,
     interpret: bool = False,
     k_scales=None, v_scales=None,
@@ -144,14 +154,14 @@ def _decode_kernel(
     from .paged_attention_pallas import paged_decode_attention
 
     return paged_decode_attention(
-        q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+        q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
         window=window, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales,
     )
 
 
 def _decode_kernel_with_sinks(
-    q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+    q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
     sinks, window: int = 0, interpret: bool = False,
     k_scales=None, v_scales=None,
 ):
@@ -164,10 +174,10 @@ def _decode_kernel_with_sinks(
     from .paged_attention_pallas import paged_decode_attention
 
     B, H, D = q.shape
-    Hkv = k_cache_layer.shape[0]
+    Hkv = k_cache.shape[1]
     G = H // Hkv
     o, m, l = paged_decode_attention(
-        q, k_cache_layer, v_cache_layer, block_tables, seq_lens, scale,
+        q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
         return_stats=True, window=window, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales,
     )
@@ -181,8 +191,9 @@ def _decode_kernel_with_sinks(
 
 def paged_decode_attention_sharded(
     q: jnp.ndarray,  # [B, H, D]
-    k_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D], Hkv sharded over tp
-    v_cache_layer: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D], Hkv sharded over tp
+    v_cache: jnp.ndarray,
+    layer,  # int or int32 scalar, replicated
     block_tables: jnp.ndarray,  # [B, M] replicated
     seq_lens: jnp.ndarray,  # [B] replicated
     scale: float,
@@ -198,7 +209,7 @@ def paged_decode_attention_sharded(
     Per-page scales replicate like the block tables (pages aren't the
     sharded axis; every shard reads the same plane)."""
 
-    def _local(q, kc, vc, bt, sl, *rest):
+    def _local(q, kc, vc, ly, bt, sl, *rest):
         rest = list(rest)
         ks = vs = s = None
         if k_scales is not None:
@@ -208,22 +219,22 @@ def paged_decode_attention_sharded(
             s = rest[0]
         if s is None:
             return _decode_kernel(
-                q, kc, vc, bt, sl, scale, window=window, interpret=interpret,
-                k_scales=ks, v_scales=vs,
+                q, kc, vc, ly, bt, sl, scale, window=window,
+                interpret=interpret, k_scales=ks, v_scales=vs,
             )
         return _decode_kernel_with_sinks(
-            q, kc, vc, bt, sl, scale, s, window=window, interpret=interpret,
-            k_scales=ks, v_scales=vs,
+            q, kc, vc, ly, bt, sl, scale, s, window=window,
+            interpret=interpret, k_scales=ks, v_scales=vs,
         )
 
-    scalars = (block_tables, seq_lens)
+    scalars = (layer, block_tables, seq_lens)
     if k_scales is not None:
         scalars += (k_scales, v_scales)
     return _shard_tp(
         mesh, _local,
         arr_specs=(P(None, "tp", None),),  # q: heads sharded
         arrs=(q,),
-        k_cache_layer=k_cache_layer, v_cache_layer=v_cache_layer,
+        k_cache=k_cache, v_cache=v_cache,
         scalars=scalars, sinks=sinks,
         out_spec=P(None, "tp", None),
     )
@@ -233,8 +244,9 @@ def decode_attention_merged(
     q: jnp.ndarray,  # [B, H, D] current token's queries
     k_new: jnp.ndarray,  # [B, Hkv, D] current token's key (rope'd)
     v_new: jnp.ndarray,  # [B, Hkv, D]
-    k_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D] — current token NOT written
-    v_cache_layer: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D] — current token NOT written
+    v_cache: jnp.ndarray,
+    layer,  # int or int32 scalar: the layer to read
     block_tables: jnp.ndarray,  # [B, M] int32
     hist_lens: jnp.ndarray,  # [B] int32 tokens in cache (EXCLUDES current)
     scale: float,
@@ -265,8 +277,8 @@ def decode_attention_merged(
     # stats kernel, window floor — and the sink's place in the merge
     # denominator — all coincide; one implementation)
     return verify_attention(
-        q[:, None], k_new[:, None], v_new[:, None], k_cache_layer,
-        v_cache_layer, block_tables, hist_lens, scale, use_pallas=True,
+        q[:, None], k_new[:, None], v_new[:, None], k_cache, v_cache,
+        layer, block_tables, hist_lens, scale, use_pallas=True,
         window=window, sinks=sinks, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales,
     )[:, 0]
@@ -276,8 +288,9 @@ def decode_attention_merged_sharded(
     q: jnp.ndarray,  # [B, H, D], H sharded over tp
     k_new: jnp.ndarray,  # [B, Hkv, D], Hkv sharded over tp
     v_new: jnp.ndarray,
-    k_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D], Hkv sharded over tp
-    v_cache_layer: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D], Hkv sharded over tp
+    v_cache: jnp.ndarray,
+    layer,  # int or int32 scalar, replicated
     block_tables: jnp.ndarray,  # [B, M] replicated
     hist_lens: jnp.ndarray,  # [B] replicated
     scale: float,
@@ -296,7 +309,7 @@ def decode_attention_merged_sharded(
     tiles with no collectives (same head-parallel argument as
     _shard_tp)."""
 
-    def _local(q, k_new, v_new, kc, vc, bt, hl, *rest):
+    def _local(q, k_new, v_new, kc, vc, ly, bt, hl, *rest):
         rest = list(rest)
         ks = vs = s = None
         if k_scales is not None:
@@ -305,11 +318,11 @@ def decode_attention_merged_sharded(
         if rest:
             s = rest[0]
         return decode_attention_merged(
-            q, k_new, v_new, kc, vc, bt, hl, scale, window=window,
+            q, k_new, v_new, kc, vc, ly, bt, hl, scale, window=window,
             sinks=s, interpret=interpret, k_scales=ks, v_scales=vs,
         )
 
-    scalars = (block_tables, hist_lens)
+    scalars = (layer, block_tables, hist_lens)
     if k_scales is not None:
         scalars += (k_scales, v_scales)
     return _shard_tp(
@@ -320,7 +333,7 @@ def decode_attention_merged_sharded(
             P(None, "tp", None),  # v_new
         ),
         arrs=(q, k_new, v_new),
-        k_cache_layer=k_cache_layer, v_cache_layer=v_cache_layer,
+        k_cache=k_cache, v_cache=v_cache,
         scalars=scalars, sinks=sinks,
         out_spec=P(None, "tp", None),
     )
@@ -330,8 +343,9 @@ def verify_attention(
     q: jnp.ndarray,  # [B, T, H, D] queries for T in-flight tokens per seq
     k_win: jnp.ndarray,  # [B, T, Hkv, D] their keys (rope'd, NOT in cache)
     v_win: jnp.ndarray,  # [B, T, Hkv, D]
-    k_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D] history only
-    v_cache_layer: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D] history only
+    v_cache: jnp.ndarray,
+    layer,  # int or int32 scalar: the layer to read (a slab: [None], 0)
     block_tables: jnp.ndarray,  # [B, M]
     hist_lens: jnp.ndarray,  # [B] tokens in cache (before the T in-flight)
     scale: float,
@@ -355,7 +369,7 @@ def verify_attention(
     decode_attention_merged.
     """
     B, T, H, D = q.shape
-    Hkv = k_cache_layer.shape[0]
+    Hkv = k_cache.shape[1]
     G = H // Hkv
     # a softcap routes history scoring to the XLA twin — the kernels
     # know no cap (same guard as the decode/prefill dispatchers)
@@ -372,7 +386,7 @@ def verify_attention(
         qp = q.reshape(B, T, Hkv, G, D).transpose(0, 2, 1, 3, 4)
         qp = qp.reshape(B, Hkv * T * G, D)
         o_h, m_h, l_h = paged_decode_attention(
-            qp, k_cache_layer, v_cache_layer, block_tables, hist_lens,
+            qp, k_cache, v_cache, layer, block_tables, hist_lens,
             scale, return_stats=True, window=window, q_pos_offset=1,
             group=G, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales,
@@ -382,7 +396,7 @@ def verify_attention(
         l_h = l_h.reshape(B, Hkv, T, G)
     else:
         o_h, m_h, l_h = _history_attention_xla(
-            q, k_cache_layer, v_cache_layer, block_tables, hist_lens, scale,
+            q, k_cache[layer], v_cache[layer], block_tables, hist_lens, scale,
             window=window, cap=cap, k_scales=k_scales, v_scales=v_scales,
         )
     # intra-window causal scores [B, Hkv, T, G, T']
@@ -419,8 +433,9 @@ def verify_attention_sharded(
     q: jnp.ndarray,  # [B, T, H, D], H sharded over tp
     k_win: jnp.ndarray,  # [B, T, Hkv, D], Hkv sharded over tp
     v_win: jnp.ndarray,
-    k_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D], Hkv sharded over tp
-    v_cache_layer: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D], Hkv sharded over tp
+    v_cache: jnp.ndarray,
+    layer,  # int or int32 scalar, replicated
     block_tables: jnp.ndarray,  # replicated
     hist_lens: jnp.ndarray,  # replicated
     scale: float,
@@ -438,7 +453,7 @@ def verify_attention_sharded(
     shard on local tiles, no collectives (same argument as
     decode_attention_merged)."""
 
-    def _local(q, k_win, v_win, kc, vc, bt, hl, *rest):
+    def _local(q, k_win, v_win, kc, vc, ly, bt, hl, *rest):
         rest = list(rest)
         ks = vs = s = None
         if k_scales is not None:
@@ -447,12 +462,12 @@ def verify_attention_sharded(
         if rest:
             s = rest[0]
         return verify_attention(
-            q, k_win, v_win, kc, vc, bt, hl, scale,
+            q, k_win, v_win, kc, vc, ly, bt, hl, scale,
             use_pallas=use_pallas, window=window, sinks=s,
             interpret=interpret, k_scales=ks, v_scales=vs,
         )
 
-    scalars = (block_tables, hist_lens)
+    scalars = (layer, block_tables, hist_lens)
     if k_scales is not None:
         scalars += (k_scales, v_scales)
     return _shard_tp(
@@ -463,7 +478,7 @@ def verify_attention_sharded(
             P(None, None, "tp", None),  # v_win
         ),
         arrs=(q, k_win, v_win),
-        k_cache_layer=k_cache_layer, v_cache_layer=v_cache_layer,
+        k_cache=k_cache, v_cache=v_cache,
         scalars=scalars, sinks=sinks,
         out_spec=P(None, None, "tp", None),
     )
@@ -723,7 +738,7 @@ def paged_prefill_attention_sharded(
         mesh, _local,
         arr_specs=(P(None, "tp", None),),  # q: heads sharded
         arrs=(q,),
-        k_cache_layer=k_cache_layer, v_cache_layer=v_cache_layer,
+        k_cache=k_cache_layer, v_cache=v_cache_layer,
         scalars=scalars, sinks=sinks,
         out_spec=P(None, "tp", None),
     )

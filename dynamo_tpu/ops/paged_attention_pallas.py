@@ -4,19 +4,31 @@ The reference's equivalent is vLLM's paged_attention CUDA kernel plus its
 flash-attention prefill (invoked inside the engines Dynamo wraps); here
 they are native Mosaic/TPU kernels.
 
-The cache layout is [Hkv, N, bs, D] (head-major): a (head, page) tile is
-one contiguous ``[bs, D]`` block, and one page of ``Hh`` heads is ``Hh``
-such tiles a fixed stride apart — one strided DMA (see
+A layer of the cache is [Hkv, N, bs, D] (head-major): a (head, page)
+tile is one contiguous ``[bs, D]`` block, and one page of ``Hh`` heads is
+``Hh`` such tiles a fixed stride apart — one strided DMA (see
 dynamo_tpu.ops.attention module docs).
 
 Decode (``paged_decode_attention``):
 
+  * the operand is the WHOLE cache ``[L, Hkv, N, bs, D]`` plus a layer
+    index, the form ``kv_cache_append`` writes it in. The layer rides as
+    a scalar-prefetch operand and leads every page's block index, so the
+    kernel reads its pages where they lie in the pool. A ``k_cache[l]``
+    operand is a slice that feeds a custom call, which the TPU compiler
+    materialises: one copy of the whole K and V pool a decode step, 39 %
+    and 33 % of a step of the two benchmark cells (PERF.md section 6,
+    PR 29). The index is a value, not part of the kernel: a program's
+    layer-calls share one Mosaic kernel and a ``lax.scan`` may pass a
+    traced one. A caller that holds one layer's slab passes ``slab[None]``
+    and layer 0 (a bitcast).
   * grid = (batch, head tiles, superblocks) = ``(B, Hkv // Hh, M // P)``.
     A grid step covers ``P`` consecutive logical pages of ``Hh`` KV heads
     of one row: ``P`` K and ``P`` V page streams (the cache is passed
     ``P`` times with per-page ``index_map``s — the BlockSpec pipeline
     runs one double-buffered DMA stream per input), each a
-    ``(Hh, 1, bs, D)`` block, scored by one ``[Hh, Gp, D] x [Hh, P*bs,
+    ``(Hh, 1, bs, D)`` block of layer ``l`` (the layer dimension is
+    squeezed out of the block), scored by one ``[Hh, Gp, D] x [Hh, P*bs,
     D]`` batched dot. The number of steps, and of page ``index_map``
     evaluations on the scalar core, does not multiply by the KV heads:
     for 32 slots x 16 KV heads x a 256-page table at ``P`` 8 that is
@@ -118,6 +130,7 @@ def _decode_kernel(
     # scalar prefetch [+ k_scales, v_scales [N] f32 when has_scales]
     block_tables_ref,  # [B, M] int32 (SMEM)
     seq_lens_ref,  # [B] int32 (SMEM)
+    layer_ref,  # [1] int32 (SMEM): read by the page index maps only
     # inputs: q then P k-page refs then P v-page refs
     *refs,
     scale: float,
@@ -249,8 +262,9 @@ def _decode_kernel(
 )
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, H, D]
-    k_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D]
-    v_cache_layer: jnp.ndarray,  # [Hkv, N, bs, D]
+    k_cache: jnp.ndarray,  # [L, Hkv, N, bs, D]: the whole cache
+    v_cache: jnp.ndarray,  # [L, Hkv, N, bs, D]
+    layer,  # int or int32 scalar (may be traced): the layer to read
     block_tables: jnp.ndarray,  # [B, M] int32
     seq_lens: jnp.ndarray,  # [B] int32
     scale: float,
@@ -260,11 +274,11 @@ def paged_decode_attention(
     q_pos_offset: int = 0,  # see _decode_kernel
     group: int = 0,  # see _decode_kernel (verify path: heads per token)
     interpret: bool = False,
-    k_scales: jnp.ndarray | None = None,  # [N] f32 per-page (int8 cache)
-    v_scales: jnp.ndarray | None = None,
+    k_scales: jnp.ndarray | None = None,  # [N] f32 per-page (int8 cache):
+    v_scales: jnp.ndarray | None = None,  # this layer's planes
 ):  # [B, H, D] or (out, m [B, Hkv, G], l [B, Hkv, G]) when return_stats
     B, H, D = q.shape
-    Hkv, N, bs, _ = k_cache_layer.shape
+    _, Hkv, N, bs, _ = k_cache.shape
     M = block_tables.shape[1]
     G = H // Hkv
     P = pages_per_step or _pick_pages_per_step(M)
@@ -275,23 +289,23 @@ def paged_decode_attention(
         )
     # pad the query-group dim to the fp32 sublane quantum
     Gp = max(8, -(-G // 8) * 8)
-    Hh = _pick_heads_per_step(
-        Hkv, Gp, D, bs, P, k_cache_layer.dtype.itemsize
-    )
+    Hh = _pick_heads_per_step(Hkv, Gp, D, bs, P, k_cache.dtype.itemsize)
     qg = q.reshape(B, Hkv, G, D).astype(jnp.float32)
     if Gp != G:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
 
     # index maps see every scalar-prefetch ref; ``*_`` absorbs the scale
-    # planes of the int8 lane
+    # planes of the int8 lane. The layer leads the block index and its
+    # dimension is squeezed out of the block: the body sees the
+    # (Hh, 1, bs, D) page of that layer
     def page_index(j):
-        def index(b, h, i, bt, sl, *_):
-            return (h, _decode_page(bt, sl, b, i, j, P, bs), 0, 0)
+        def index(b, h, i, bt, sl, ly, *_):
+            return (ly[0], h, _decode_page(bt, sl, b, i, j, P, bs), 0, 0)
 
         return index
 
     page_spec = [
-        pl.BlockSpec((Hh, 1, bs, D), page_index(j)) for j in range(P)
+        pl.BlockSpec((None, Hh, 1, bs, D), page_index(j)) for j in range(P)
     ]
     # per-page scales are scalars the kernel looks up by physical page:
     # SMEM (scalar prefetch), not a VMEM stream — a (1, 128) block of an
@@ -313,7 +327,7 @@ def paged_decode_attention(
         stat_shape = jax.ShapeDtypeStruct((B, Hkv, Gp, 128), jnp.float32)
         out_shape = [out_shape, stat_shape, stat_shape]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 + len(scale_inputs),
+        num_scalar_prefetch=3 + len(scale_inputs),
         grid=(B, Hkv // Hh, M // P),
         in_specs=[
             pl.BlockSpec((1, Hh, Gp, D), row_index),
@@ -341,13 +355,13 @@ def paged_decode_attention(
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * 2 * B * H * M * bs * D,
-            bytes_accessed=2 * Hkv * M * bs * D * k_cache_layer.dtype.itemsize * B,
+            bytes_accessed=2 * Hkv * M * bs * D * k_cache.dtype.itemsize * B,
             transcendentals=B * H * M * bs,
         ),
         interpret=interpret,
     )(
-        block_tables, seq_lens, *scale_inputs, qg,
-        *([k_cache_layer] * P), *([v_cache_layer] * P),
+        block_tables, seq_lens, jnp.asarray(layer, jnp.int32).reshape(1),
+        *scale_inputs, qg, *([k_cache] * P), *([v_cache] * P),
     )
     if return_stats:
         o, m, l = out

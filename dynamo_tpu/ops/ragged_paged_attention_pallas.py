@@ -308,8 +308,13 @@ def ragged_mixed_attention(
     # attention whatever it held (PERF.md section 6, PR 28) ----
     from .attention import decode_attention
 
+    # both kernels take the slab as ONE one-layer cache: handed the slab
+    # in two ranks, the TPU compiler keeps a second copy of it for the
+    # decode kernel's (0.5 ms a layer at a 5 GiB pool, PERF.md section 6,
+    # PR 29)
+    k_cache, v_cache = k_cache_layer[None], v_cache_layer[None]
     o_dec = decode_attention(
-        q_dec, k_cache_layer, v_cache_layer, d_tables, d_seq_lens, scale,
+        q_dec, k_cache, v_cache, 0, d_tables, d_seq_lens, scale,
         use_pallas=True, window=window, sinks=sinks, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales,
     )
@@ -338,12 +343,12 @@ def ragged_mixed_attention(
     # planes of the quantized lane
     def page_index(p):
         def index(s, h, i, sq, bt, q0, lastq, *_):
-            return (h, _mixed_page(sq, bt, lastq, s, i, p, Pp, bs), 0, 0)
+            return (0, h, _mixed_page(sq, bt, lastq, s, i, p, Pp, bs), 0, 0)
 
         return index
 
     page_spec = [
-        pl.BlockSpec((1, 1, bs, D), page_index(p)) for p in range(Pp)
+        pl.BlockSpec((None, 1, 1, bs, D), page_index(p)) for p in range(Pp)
     ]
     has_scales = k_scales is not None
     # per-page scales are scalars looked up by physical page: SMEM
@@ -406,7 +411,7 @@ def ragged_mixed_attention(
         interpret=interpret,
     )(
         tile_seq, tables, tile_q0, tile_last, *scale_inputs, q_all,
-        *([k_cache_layer] * Pp), *([v_cache_layer] * Pp), *sink_inputs,
+        *([k_cache] * Pp), *([v_cache] * Pp), *sink_inputs,
     )
     o_chunks = out.reshape(Hkv, MP, nT, Tq, Gp, D)
     o_chunks = o_chunks.transpose(1, 2, 3, 0, 4, 5)  # [MP,nT,Tq,Hkv,Gp,D]

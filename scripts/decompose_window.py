@@ -66,7 +66,7 @@ def layer_body(x, lp, positions, k_cache, v_cache, l, *, write, attend):
     if attend:
         seq_lens = positions + 1
         o = att.decode_attention(
-            q, k_cache[l], v_cache[l], tables, seq_lens, scale,
+            q, k_cache, v_cache, l, tables, seq_lens, scale,
             use_pallas=not os.environ.get("DECOMPOSE_SMOKE"),
         )
     else:

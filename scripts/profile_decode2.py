@@ -95,10 +95,12 @@ q = jnp.zeros((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
 scale = cfg.head_dim ** -0.5
 
 lib_att = jax.jit(
-    lambda q, kl, vl: att._decode_kernel(q, kl, vl, tables, seq_lens_h, scale)
+    lambda q, kc, vc: att._decode_kernel(
+        q, kc, vc, 0, tables, seq_lens_h, scale
+    )
 )
-timeit("library paged_attention kernel (1 layer)", lib_att,
-       q, k_cache0[0], v_cache0[0])
+timeit("paged decode attention kernel (1 layer)", lib_att,
+       q, k_cache0, v_cache0)
 
 # full-cache scatter: what _decode_body does per layer per step
 kv_new = jnp.zeros((B, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)

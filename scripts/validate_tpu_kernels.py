@@ -9,10 +9,13 @@ reference it replaces, at the serving widths of the smoke model
 
   1. cache appends: one row (bf16), int8 + scale planes, T-token verify
   2. decode attention: paged kernel, merged out-of-cache token, int8
-     pages with per-page scales; and the kernel at the benchmark cell's
-     shape (olmo2-1b.chat: 32 slots, MHA 16 x 128, a 256-page table over
-     2,560 pages) with lengths up to the full 4,096 and stats out, so
-     the accumulation across superblocks is checked where it is long
+     pages with per-page scales, each reading the LAST layer of the
+     whole stacked cache (the kernel's operand since PR 29); and the
+     kernel at the benchmark cell's shape (olmo2-1b.chat: 32 slots, MHA
+     16 x 128, a 256-page table over 2,560 pages, layer 11 of the
+     16-layer 5 GiB pool) with lengths up to the full 4,096 and stats
+     out, so the accumulation across superblocks is checked where it is
+     long, and against the same layer as a one-layer cache bit for bit
   3. chunked-prefill attention over the paged cache
   4. the ragged mixed (decode + prefill) kernel, bf16 and int8 + scales
   5. model level, 2 layers at full width: merged decode, Pallas prefill
@@ -179,32 +182,34 @@ def check_kernels(check: Checker) -> None:
     check("kv_cache_append_tokens v", got_v[:, :, 1:], ref_v[:, :, 1:],
           rtol=0, atol=0)
 
-    # ---- 2. decode attention ----
-    ref = att.decode_attention_xla(q, kc[0], vc[0], tables, seq_lens, SCALE)
+    # ---- 2. decode attention: the whole L-layer cache as the operand,
+    # read at its LAST layer (a kernel that ignored the index would read
+    # layer 0's pages) ----
+    l = L - 1
+    ref = att.decode_attention_xla(q, kc[l], vc[l], tables, seq_lens, SCALE)
     got = paged_decode_attention(
-        *chip(q, kc[0], vc[0], tables, seq_lens), SCALE
+        *chip(q, kc, vc), l, *chip(tables, seq_lens), SCALE
     )
-    check("paged_decode_attention", got, ref)
+    check(f"paged_decode_attention layer {l} of {L}", got, ref)
 
-    kc1 = kc.at[0, :, blk, off].set(k_new[0])
-    vc1 = vc.at[0, :, blk, off].set(v_new[0])
-    ref = att.decode_attention_xla(q, kc1[0], vc1[0], tables, hist + 1, SCALE)
+    kc1 = kc.at[l, :, blk, off].set(k_new[l])
+    vc1 = vc.at[l, :, blk, off].set(v_new[l])
+    ref = att.decode_attention_xla(q, kc1[l], vc1[l], tables, hist + 1, SCALE)
     got = att.decode_attention_merged(
-        *chip(q, k_new[0], v_new[0], kc[0], vc[0], tables, hist), SCALE
+        *chip(q, k_new[l], v_new[l], kc, vc), l, *chip(tables, hist), SCALE
     )
-    check("decode_attention_merged", got, ref)
+    check(f"decode_attention_merged layer {l} of {L}", got, ref)
 
-    kq0, ksc = _quantize_pages(kc[0])
-    vq0, vsc = _quantize_pages(vc[0])
+    (kq_l, ksc), (vq_l, vsc) = _quantize_pages(kc[l]), _quantize_pages(vc[l])
     ref = att.decode_attention_xla(
-        q, kq0, vq0, tables, seq_lens, SCALE, k_scales=ksc, v_scales=vsc
+        q, kq_l, vq_l, tables, seq_lens, SCALE, k_scales=ksc, v_scales=vsc
     )
     ksc_c, vsc_c = chip(ksc, vsc)
     got = paged_decode_attention(
-        *chip(q, kq0, vq0, tables, seq_lens), SCALE,
+        *chip(q, kq, vq), l, *chip(tables, seq_lens), SCALE,
         k_scales=ksc_c, v_scales=vsc_c,
     )
-    check("paged_decode_attention int8+scales", got, ref)
+    check(f"paged_decode_attention int8+scales layer {l} of {L}", got, ref)
 
     # ---- 3./4. prefill + ragged mixed attention (write-before-attend) ----
     q_chunk = jax.random.normal(ks[7], (T, H, D), jnp.bfloat16)
@@ -267,12 +272,17 @@ def check_cell_decode(check: Checker) -> None:
     merged path: stats out) at real lengths: the benchmark's reference
     check reads two positions of 48-token prompts, which never leave the
     first superblock of 128 tokens."""
-    Bc, Hc, Mc, Nc = 32, 16, 256, 2560
+    Bc, Hc, Mc, Nc, Lc, lc = 32, 16, 256, 2560, 16, 11
     rng = np.random.default_rng(27)
     ks = jax.random.split(jax.random.key(27), 3)
     q = jax.random.normal(ks[0], (Bc, Hc, D), jnp.bfloat16)
-    kc = jax.random.normal(ks[1], (Hc, Nc, BS, D), jnp.bfloat16)
-    vc = jax.random.normal(ks[2], (Hc, Nc, BS, D), jnp.bfloat16)
+    # the cell's whole pool, 2 x 2.5 GiB, made on the chip (too much to
+    # draw on the host and send) and read at layer 11 where it lies; the
+    # host's reference gets a copy of that layer
+    with jax.default_device(jax.devices()[0]):
+        kc = jax.random.normal(ks[1], (Lc, Hc, Nc, BS, D), jnp.bfloat16)
+        vc = jax.random.normal(ks[2], (Lc, Hc, Nc, BS, D), jnp.bfloat16)
+    kc_l, vc_l = (jnp.asarray(np.asarray(c[lc])) for c in (kc, vc))
     # a row's pages are distinct; rows share the pool, as a prefix does
     tables = jnp.asarray(np.stack([
         rng.permutation(np.arange(1, Nc))[:Mc] for _ in range(Bc)
@@ -285,13 +295,23 @@ def check_cell_decode(check: Checker) -> None:
     live = lens > 0
     seq_lens = jnp.asarray(lens)
     ro, rm, rl = att._history_attention_xla(
-        q[:, None], kc, vc, tables, seq_lens, SCALE
+        q[:, None], kc_l, vc_l, tables, seq_lens, SCALE
     )  # [B, Hkv, 1, G(, D)]
+    rows = chip(tables, seq_lens)
     o, m, l = paged_decode_attention(
-        *chip(q, kc, vc, tables, seq_lens), SCALE, return_stats=True
+        chip(q), kc, vc, lc, *rows, SCALE, return_stats=True
     )
-    name = ("paged_decode_attention cell shape, lengths "
-            f"{lens.min()}-{lens.max()}")
+    # the same layer handed over as a one-layer cache: the same kernel on
+    # the same bytes, so bit for bit
+    slab = paged_decode_attention(
+        chip(q), *chip(kc_l[None], vc_l[None]), 0, *rows, SCALE,
+        return_stats=True,
+    )
+    name = (f"paged_decode_attention cell shape, layer {lc} of {Lc}, "
+            f"lengths {lens.min()}-{lens.max()}")
+    for part, whole, one in zip(("out", "m", "l"), (o, m, l), slab):
+        check(f"{name}: {part} == the slab's as [None], layer 0", whole,
+              one, rtol=0, atol=0)
     check(f"{name}: all rows finite", np.isfinite(np.asarray(
         o, np.float32)).all(), True)
     check(f"{name}: out", np.asarray(o, np.float32)[live],
@@ -384,21 +404,21 @@ def check_other_families(check: Checker) -> None:
     D64 = 64
     ks = jax.random.split(jax.random.key(7), 4)
     q64 = jax.random.normal(ks[0], (B, H, D64), jnp.bfloat16)
-    kc64 = jax.random.normal(ks[1], (HKV, N, BS, D64), jnp.bfloat16)
-    vc64 = jax.random.normal(ks[2], (HKV, N, BS, D64), jnp.bfloat16)
+    kc64 = jax.random.normal(ks[1], (L, HKV, N, BS, D64), jnp.bfloat16)
+    vc64 = jax.random.normal(ks[2], (L, HKV, N, BS, D64), jnp.bfloat16)
     sinks = jax.random.normal(ks[3], (H,), jnp.float32)
     for name, window, snk in (("plain", 0, None), ("window", 10, None),
                               ("sinks+window", 10, sinks)):
         ref = att.decode_attention_xla(
-            q64, kc64, vc64, tables, seq_lens, D64**-0.5, window=window,
-            sinks=snk,
+            q64, kc64[L - 1], vc64[L - 1], tables, seq_lens, D64**-0.5,
+            window=window, sinks=snk,
         )
         got = att.decode_attention(
-            *chip(q64, kc64, vc64, tables, seq_lens), D64**-0.5,
-            use_pallas=True, window=window,
+            *chip(q64, kc64, vc64), L - 1, *chip(tables, seq_lens),
+            D64**-0.5, use_pallas=True, window=window,
             sinks=None if snk is None else chip(snk),
         )
-        check(f"decode kernel D=64 {name}", got, ref)
+        check(f"decode kernel D=64 {name}, layer {L - 1} of {L}", got, ref)
 
     # MLA latent kernels at DeepSeek widths
     C, R, Hm = 512, 64, 16

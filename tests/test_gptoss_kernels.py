@@ -53,7 +53,7 @@ def test_decode_kernel_sinks_match_xla():
             q, kc, vc, tables, seq_lens, scale, window=W, sinks=sinks
         )
         got = decode_attention(
-            q, kc, vc, tables, seq_lens, scale, use_pallas=True,
+            q, kc[None], vc[None], 0, tables, seq_lens, scale, use_pallas=True,
             window=W, sinks=sinks, interpret=True,
         )
         np.testing.assert_allclose(
@@ -80,8 +80,8 @@ def test_merged_decode_sinks_match_write_then_attend():
             q, kc1, vc1, tables, hist + 1, scale, window=W, sinks=sinks
         )
         got = decode_attention_merged(
-            q, k_new, v_new, kc, vc, tables, hist, scale, window=W,
-            sinks=sinks, interpret=True,
+            q, k_new, v_new, kc[None], vc[None], 0, tables, hist, scale,
+            window=W, sinks=sinks, interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -102,8 +102,8 @@ def test_sharded_sink_paths_match_xla():
         q, kc, vc, tables, seq_lens, scale, window=7, sinks=sinks
     )
     got = decode_attention(
-        q, kc, vc, tables, seq_lens, scale, use_pallas=True, mesh=mesh,
-        window=7, sinks=sinks, interpret=True,
+        q, kc[None], vc[None], 0, tables, seq_lens, scale, use_pallas=True,
+        mesh=mesh, window=7, sinks=sinks, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -121,8 +121,8 @@ def test_sharded_sink_paths_match_xla():
         q, kc1, vc1, tables, hist + 1, scale, sinks=sinks
     )
     got = decode_attention_merged_sharded(
-        q, k_new, v_new, kc, vc, tables, hist, scale, mesh, sinks=sinks,
-        interpret=True,
+        q, k_new, v_new, kc[None], vc[None], 0, tables, hist, scale, mesh,
+        sinks=sinks, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -135,11 +135,11 @@ def test_sharded_sink_paths_match_xla():
     k_win = jax.random.normal(kq[1], (B, T, Hkv, D), jnp.float32)
     v_win = jax.random.normal(kq[2], (B, T, Hkv, D), jnp.float32)
     ref = att.verify_attention(
-        qv, k_win, v_win, kc, vc, tables, hist, scale, use_pallas=False,
-        sinks=sinks,
+        qv, k_win, v_win, kc[None], vc[None], 0, tables, hist, scale,
+        use_pallas=False, sinks=sinks,
     )
     got = verify_attention_sharded(
-        qv, k_win, v_win, kc, vc, tables, hist, scale, mesh,
+        qv, k_win, v_win, kc[None], vc[None], 0, tables, hist, scale, mesh,
         use_pallas=True, sinks=sinks, interpret=True,
     )
     np.testing.assert_allclose(

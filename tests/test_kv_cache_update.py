@@ -159,7 +159,7 @@ def test_merged_attention_matches_write_then_attend(H, Hkv):
     ref = decode_attention_xla(q, kc1[0], vc1[0], tables, hist + 1, scale)
 
     got = decode_attention_merged(
-        q, k_new[0], v_new[0], kc[0], vc[0], tables, hist, scale,
+        q, k_new[0], v_new[0], kc, vc, 0, tables, hist, scale,
         interpret=True,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
@@ -261,7 +261,7 @@ def test_merged_sharded_tp2_matches_single_device():
     scale = D**-0.5
 
     ref_o = decode_attention_merged(
-        q, k_new[0], v_new[0], kc[0], vc[0], tables, hist, scale,
+        q, k_new[0], v_new[0], kc, vc, 0, tables, hist, scale,
         interpret=True,
     )
     blk, off = decode_slot_indices(tables, hist, bs)
@@ -279,7 +279,7 @@ def test_merged_sharded_tp2_matches_single_device():
     vcs = jax.device_put(vc, cache_sh)
 
     got_o = decode_attention_merged_sharded(
-        qs, kns[0], vns[0], kcs[0], vcs[0], tables, hist, scale, mesh,
+        qs, kns[0], vns[0], kcs, vcs, 0, tables, hist, scale, mesh,
         interpret=True,
     )
     np.testing.assert_allclose(
@@ -299,7 +299,7 @@ def test_merged_attention_no_nans_on_empty_batch():
     q, kc, vc, k_new, v_new, tables = _setup(B, H, Hkv, D, L, N, bs, M, seed=2)
     hist = jnp.zeros(B, jnp.int32)
     got = decode_attention_merged(
-        q, k_new[0], v_new[0], kc[0], vc[0], tables, hist, D**-0.5,
+        q, k_new[0], v_new[0], kc, vc, 0, tables, hist, D**-0.5,
         interpret=True,
     )
     assert not np.isnan(np.asarray(got)).any()

@@ -406,8 +406,8 @@ def test_single_dispatch_kernels_consume_fp8_pages():
     kc8 = jnp.asarray(kc.astype(ml_dtypes.float8_e4m3fn))
     vc8 = jnp.asarray(vc.astype(ml_dtypes.float8_e4m3fn))
     out = paged_decode_attention(
-        q_dec, kc8, vc8, jnp.asarray(d_tables), jnp.asarray(d_seq_lens),
-        g["scale"], interpret=True,
+        q_dec, kc8[None], vc8[None], 0, jnp.asarray(d_tables),
+        jnp.asarray(d_seq_lens), g["scale"], interpret=True,
     )
     ref = att.decode_attention_xla(
         q_dec, kc8, vc8, jnp.asarray(d_tables), jnp.asarray(d_seq_lens),

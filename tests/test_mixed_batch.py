@@ -733,6 +733,7 @@ def test_ragged_mixed_decode_rows_take_the_decode_kernel(B):
     ), grids
     o_dec, _ = call(*args)
     ref = paged_decode_attention(
-        q_dec, kc, vc, d_tables, d_seq_lens, D ** -0.5, interpret=True
+        q_dec, kc[None], vc[None], 0, d_tables, d_seq_lens, D ** -0.5,
+        interpret=True,
     )
     assert np.array_equal(np.asarray(o_dec), np.asarray(ref))

@@ -36,7 +36,9 @@ def test_kernel_matches_xla(H, Hkv):
     seq_lens = jnp.asarray([1, bs, 2 * bs + 3, M * bs], jnp.int32)
     scale = D**-0.5
     ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale)
-    got = paged_decode_attention(q, kc, vc, tables, seq_lens, scale, interpret=True)
+    got = paged_decode_attention(
+        q, kc[None], vc[None], 0, tables, seq_lens, scale, interpret=True
+    )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
@@ -46,7 +48,9 @@ def test_kernel_ragged_and_empty_slots():
     q, kc, vc, tables = _mk(B, H, Hkv, D, N, bs, M, seed=1)
     seq_lens = jnp.asarray([0, 5, 0, 17], jnp.int32)
     scale = D**-0.5
-    got = paged_decode_attention(q, kc, vc, tables, seq_lens, scale, interpret=True)
+    got = paged_decode_attention(
+        q, kc[None], vc[None], 0, tables, seq_lens, scale, interpret=True
+    )
     ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale)
     got, ref = np.asarray(got), np.asarray(ref)
     assert not np.isnan(got).any()
@@ -70,11 +74,12 @@ def test_kernel_sharded_tp2_matches_xla():
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 1, 1, 1, 2),
                 ("dp", "pp", "sp", "ep", "tp"))
     qs = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
-    kcs = jax.device_put(kc, NamedSharding(mesh, P("tp", None, None, None)))
-    vcs = jax.device_put(vc, NamedSharding(mesh, P("tp", None, None, None)))
+    cache_sh = NamedSharding(mesh, P(None, "tp", None, None, None))
+    kcs = jax.device_put(kc[None], cache_sh)
+    vcs = jax.device_put(vc[None], cache_sh)
     ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale)
     got = paged_decode_attention_sharded(
-        qs, kcs, vcs, tables, seq_lens, scale, mesh, interpret=True
+        qs, kcs, vcs, 0, tables, seq_lens, scale, mesh, interpret=True
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -221,7 +226,9 @@ def test_kernel_bf16_cache():
     seq_lens = jnp.asarray([7, 2 * bs], jnp.int32)
     scale = D**-0.5
     ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale)
-    got = paged_decode_attention(q, kc, vc, tables, seq_lens, scale, interpret=True)
+    got = paged_decode_attention(
+        q, kc[None], vc[None], 0, tables, seq_lens, scale, interpret=True
+    )
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32), rtol=5e-2, atol=5e-2
     )
@@ -239,7 +246,9 @@ def test_kernel_fp8_cache():
     seq_lens = jnp.asarray([7, 2 * bs], jnp.int32)
     scale = D**-0.5
     ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale)
-    got = paged_decode_attention(q, kc, vc, tables, seq_lens, scale, interpret=True)
+    got = paged_decode_attention(
+        q, kc[None], vc[None], 0, tables, seq_lens, scale, interpret=True
+    )
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
         rtol=5e-2, atol=5e-2,
@@ -254,7 +263,8 @@ def test_decode_kernel_sliding_window_matches_xla():
     W = 10
     ref = decode_attention_xla(q, kc, vc, tables, seq_lens, scale, window=W)
     got = paged_decode_attention(
-        q, kc, vc, tables, seq_lens, scale, window=W, interpret=True
+        q, kc[None], vc[None], 0, tables, seq_lens, scale, window=W,
+        interpret=True,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -308,8 +318,8 @@ def test_merged_decode_sliding_window_matches_xla():
     vc1 = vc.at[:, blk, off].set(v_new.swapaxes(0, 1))
     ref = decode_attention_xla(q, kc1, vc1, tables, hist + 1, scale, window=W)
     got = decode_attention_merged(
-        q, k_new, v_new, kc, vc, tables, hist, scale, window=W,
-        interpret=True,
+        q, k_new, v_new, kc[None], vc[None], 0, tables, hist, scale,
+        window=W, interpret=True,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -382,7 +392,8 @@ def test_decode_kernel_head_tiles_match_xla(case):
         q4 = jax.random.normal(jax.random.key(5), (B, T, H, D), jnp.float32)
         qp = q4.reshape(B, T, Hkv, G, D).transpose(0, 2, 1, 3, 4)
         o, m, l = paged_decode_attention(
-            qp.reshape(B, Hkv * T * G, D), kc, vc, tables, seq_lens, scale,
+            qp.reshape(B, Hkv * T * G, D), kc[None], vc[None], 0, tables,
+            seq_lens, scale,
             return_stats=True, window=window, q_pos_offset=1, group=G,
             interpret=True, **scales,
         )
@@ -399,7 +410,7 @@ def test_decode_kernel_head_tiles_match_xla(case):
         return
 
     out = paged_decode_attention(
-        q, kc, vc, tables, seq_lens, scale,
+        q, kc[None], vc[None], 0, tables, seq_lens, scale,
         return_stats=bool(c.get("stats")), interpret=True, **scales,
     )
     o = out[0] if c.get("stats") else out
@@ -452,3 +463,185 @@ def test_heads_per_step_rule():
                     h, Gp, D, bs, P, item) <= _STEP_VMEM_BUDGET
             ]
             assert Hh == (max(fits) if fits else 1)
+
+
+# ---------------- the whole cache as the operand ----------------
+# The kernel takes ``[L, Hkv, N, bs, D]`` and a layer index and reads
+# the layer's pages in place. Every case: for each layer of a 3-layer
+# cache, the call on the whole cache equals the call on that layer's
+# slab as a one-layer cache BIT FOR BIT (same kernel body, same bytes).
+
+_WHOLE_CACHE_CASES = {
+    "bf16-hkv2": dict(H=4, Hkv=2),
+    "bf16-hkv16-stats": dict(H=16, Hkv=16, stats=True),
+    # bf16 pages of 32 heads: two head tiles of 16
+    "bf16-hkv32-two-tiles": dict(H=32, Hkv=32, stats=True, tiles=2),
+    "int8-scales-hkv16": dict(H=16, Hkv=16, int8=True),
+    "int8-scales-hkv2-stats": dict(H=8, Hkv=2, int8=True, stats=True),
+    # the verify path's packing: T tokens x G heads a row, per-row floor
+    "window-group": dict(H=4, Hkv=2, T=3, window=40, stats=True),
+    "sinks": dict(H=8, Hkv=2, sinks=True, window=24),
+    "scan-traced-layer": dict(H=16, Hkv=16, stats=True, scan=True),
+    "scan-traced-layer-int8": dict(H=4, Hkv=2, int8=True, scan=True),
+}
+
+
+@pytest.mark.parametrize("case", list(_WHOLE_CACHE_CASES))
+def test_whole_cache_operand_equals_slab_bitwise(case):
+    from dynamo_tpu.ops import attention as att
+    from dynamo_tpu.ops.paged_attention_pallas import (
+        _pick_heads_per_step,
+        _pick_pages_per_step,
+    )
+
+    c = _WHOLE_CACHE_CASES[case]
+    H, Hkv, T = c["H"], c["Hkv"], c.get("T", 0)
+    L, B, D, bs, M = 3, 3, 128, 16, 16
+    N = B * M + 1
+    lens = jnp.asarray([0, 130, 256], jnp.int32)  # dead, ragged, full
+    ks = jax.random.split(jax.random.key(29), 4)
+    rows = Hkv * T * (H // Hkv) if T else H
+    q = jax.random.normal(ks[0], (B, rows, D), jnp.float32)
+    kc = jax.random.normal(ks[1], (L, Hkv, N, bs, D), jnp.float32)
+    vc = jax.random.normal(ks[2], (L, Hkv, N, bs, D), jnp.float32)
+    tables = jnp.asarray(
+        np.random.default_rng(29).permutation(np.arange(1, N))
+        .reshape(B, M).astype(np.int32)
+    )
+    k_planes = v_planes = None
+    if c.get("int8"):
+        # pages [L, Hkv, N, bs, D] int8 and their planes [L, N]
+        kc, k_planes = map(jnp.stack, zip(*map(_quantize_pages, kc)))
+        vc, v_planes = map(jnp.stack, zip(*map(_quantize_pages, vc)))
+    else:
+        q = q.astype(jnp.bfloat16)
+        kc, vc = kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16)
+    Hh = _pick_heads_per_step(
+        Hkv, 8, D, bs, _pick_pages_per_step(M), kc.dtype.itemsize
+    )
+    assert Hkv // Hh == c.get("tiles", 1)
+    kw = dict(interpret=True)
+    if c.get("sinks"):
+        sinks = jax.random.normal(ks[3], (H,), jnp.float32)
+
+        def call(k, v, layer, scales):
+            return att._decode_kernel_with_sinks(
+                q, k, v, layer, tables, lens, D**-0.5, sinks,
+                window=c["window"], **scales, **kw,
+            )
+    else:
+        if T:
+            kw.update(q_pos_offset=1, group=H // Hkv)
+
+        def call(k, v, layer, scales):
+            return paged_decode_attention(
+                q, k, v, layer, tables, lens, D**-0.5,
+                return_stats=bool(c.get("stats")),
+                window=c.get("window", 0), **scales, **kw,
+            )
+
+    def planes(l):
+        if k_planes is None:
+            return {}
+        return dict(k_scales=k_planes[l], v_scales=v_planes[l])
+
+    if c.get("scan"):  # one traced index for all layers, one kernel
+        _, whole = jax.lax.scan(
+            lambda _, l: (None, call(kc, vc, l, planes(l))), None,
+            jnp.arange(L),
+        )
+        whole = [jax.tree.map(lambda a: a[l], whole) for l in range(L)]
+    else:
+        whole = [call(kc, vc, l, planes(l)) for l in range(L)]
+    for l in range(L):
+        slab = call(kc[l][None], vc[l][None], 0, planes(l))
+        for w, s in zip(jax.tree.leaves(whole[l]), jax.tree.leaves(slab)):
+            assert w.dtype == s.dtype and w.shape == s.shape
+            np.testing.assert_array_equal(
+                np.asarray(w, np.float32), np.asarray(s, np.float32)
+            )
+    # and the layers differ: an index that read the wrong slab would show
+    assert not np.array_equal(
+        np.asarray(jax.tree.leaves(whole[0])[0], np.float32)[1:],
+        np.asarray(jax.tree.leaves(whole[1])[0], np.float32)[1:],
+    )
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested ones (jit, scan, while, cond,
+    shard_map) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize(
+    "program", ["decode_window-dense", "decode_window-experts",
+                "decode_step", "decode_step-unmerged", "verify_window"]
+)
+def test_step_programs_hand_the_kernel_the_whole_cache(program):
+    """The slab must not come back: in the decode programs every cache
+    operand of every ``pallas_call`` is the cache itself, 5-D, and no
+    equation cuts an ``[Hkv, N, bs, D]`` layer out of it (the TPU
+    compiler materialises a slice that feeds a custom call: a copy of
+    the whole pool a step, PERF.md section 6, PR 29)."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    moe = dict(num_experts=4, num_experts_per_tok=2,
+               moe_intermediate_size=32)
+    cfg = ModelConfig.tiny(
+        num_layers=3, head_dim=128,
+        **(moe if program.endswith("experts") else {}),
+    )
+    B, M, N, bs = 4, 8, 40, 16
+    params = llama.init_params(cfg, jax.random.key(0))
+    kc, vc = llama.init_kv_cache(cfg, N, bs)
+    cache_shape = kc.shape
+    assert cache_shape == (3, cfg.num_kv_heads, N, bs, 128)
+    ints = jnp.ones((B,), jnp.int32)
+    floats = jnp.ones((B,), jnp.float32)
+    tables = jnp.ones((B, M), jnp.int32)
+    kw = dict(use_pallas=True, interpret=True)
+    if program.startswith("decode_window"):
+        jaxpr = jax.make_jaxpr(
+            lambda p, k, v: llama.decode_window(
+                p, cfg, ints, ints, tables, ints, ints, ints, floats, ints,
+                floats, k, v, n_steps=2,
+                moe_counters=program.endswith("experts"), **kw,
+            )
+        )(params, kc, vc)
+    elif program.startswith("decode_step"):
+        jaxpr = jax.make_jaxpr(
+            lambda p, k, v: llama.decode_step(
+                p, cfg, ints, ints, tables, ints, k, v,
+                merged=not program.endswith("unmerged"), **kw,
+            )
+        )(params, kc, vc)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda p, k, v: llama.verify_window(
+                p, cfg, jnp.ones((B, 3), jnp.int32),
+                jnp.ones((B, 2), jnp.int32), ints, tables, ints, ints, ints,
+                floats, ints, floats, k, v, n_spec=2, **kw,
+            )
+        )(params, kc, vc)
+    kernels = 0
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            kernels += 1
+            for var in eqn.invars:
+                shape = var.aval.shape
+                if len(shape) >= 4 and shape[-3:] == cache_shape[-3:]:
+                    assert shape == cache_shape, (
+                        f"a pallas_call takes a {shape} cut of the "
+                        f"{cache_shape} cache"
+                    )
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            assert shape not in (cache_shape[1:], (1, *cache_shape[1:])), (
+                f"{eqn.primitive.name} produces a layer slab {shape}"
+            )
+    # attention of every layer (+ the merged paths' one append)
+    assert kernels >= cfg.num_layers

@@ -70,7 +70,7 @@ def test_verify_attention_matches_write_then_decode():
 
     for use_pallas in (False, True):
         got = verify_attention(
-            q, k_win, v_win, kc, vc, tables, hist, scale,
+            q, k_win, v_win, kc[None], vc[None], 0, tables, hist, scale,
             use_pallas=use_pallas, interpret=True,
         )
         # reference: write rows then per-position decode attention
@@ -113,7 +113,7 @@ def test_verify_attention_windowed_exact_per_row():
 
     for use_pallas in (False, True):
         got = verify_attention(
-            q, k_win, v_win, kc, vc, tables, hist, scale,
+            q, k_win, v_win, kc[None], vc[None], 0, tables, hist, scale,
             use_pallas=use_pallas, window=W, interpret=True,
         )
         kc1, vc1 = kc, vc
@@ -152,7 +152,8 @@ def test_verify_attention_sinks_match_write_then_decode():
     scale = D**-0.5
 
     got = verify_attention(
-        q, k_win, v_win, kc, vc, tables, hist, scale, sinks=sinks,
+        q, k_win, v_win, kc[None], vc[None], 0, tables, hist, scale,
+        sinks=sinks,
     )
     kc1, vc1 = kc, vc
     for b in range(B):
@@ -482,7 +483,7 @@ def test_verify_sharded_tp2_matches_single_device():
     scale = D**-0.5
 
     ref = verify_attention(
-        q, k_win, v_win, kc, vc, tables, hist, scale,
+        q, k_win, v_win, kc[None], vc[None], 0, tables, hist, scale,
         use_pallas=True, interpret=True,
     )
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 1, 1, 1, 2),
@@ -490,10 +491,11 @@ def test_verify_sharded_tp2_matches_single_device():
     qs = jax.device_put(q, NamedSharding(mesh, P(None, None, "tp", None)))
     kws = jax.device_put(k_win, NamedSharding(mesh, P(None, None, "tp", None)))
     vws = jax.device_put(v_win, NamedSharding(mesh, P(None, None, "tp", None)))
-    csh = NamedSharding(mesh, P("tp", None, None, None))
+    csh = NamedSharding(mesh, P(None, "tp", None, None, None))
     got = verify_attention_sharded(
-        qs, kws, vws, jax.device_put(kc, csh), jax.device_put(vc, csh),
-        tables, hist, scale, mesh, use_pallas=True, interpret=True,
+        qs, kws, vws, jax.device_put(kc[None], csh),
+        jax.device_put(vc[None], csh), 0, tables, hist, scale, mesh,
+        use_pallas=True, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -511,12 +513,11 @@ def test_verify_sharded_tp2_matches_single_device():
     ref_k, ref_v = kv_cache_append_tokens(
         kN, vN, jnp.copy(kcL), jnp.copy(vcL), blk, off, interpret=True
     )
-    csh5 = NamedSharding(mesh, P(None, "tp", None, None, None))
     got_k, got_v = kv_cache_append_tokens_sharded(
         jax.device_put(kN, NamedSharding(mesh, P(None, None, None, "tp", None))),
         jax.device_put(vN, NamedSharding(mesh, P(None, None, None, "tp", None))),
-        jax.device_put(jnp.copy(kcL), csh5),
-        jax.device_put(jnp.copy(vcL), csh5),
+        jax.device_put(jnp.copy(kcL), csh),
+        jax.device_put(jnp.copy(vcL), csh),
         blk, off, mesh, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(got_k), np.asarray(ref_k))

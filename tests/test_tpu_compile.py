@@ -143,6 +143,7 @@ def _layer(chip, dtype, d=D):
 # (each with bf16 pages, stats on: the merged path a decode step runs)
 CELL_OLMO2_1B = (32, 16, 16, 256)  # olmo2-1b.chat: MHA, every head a step
 CELL_PHI4_MINI = (32, 24, 8, 256)  # GQA 24 / 8
+TP4_SHARD = (B, 4, 2, M)  # Qwen3-1.7B's 16 / 8 heads over --tp 4
 
 
 @pytest.mark.parametrize(
@@ -160,25 +161,67 @@ CELL_PHI4_MINI = (32, 24, 8, 256)  # GQA 24 / 8
         # one of 32 (the largest step the budget admits)
         (128, jnp.bfloat16, False, True, (B, 32, 32, M)),
         (128, jnp.int8, True, True, (B, 32, 32, M)),
+        (128, jnp.bfloat16, False, True, TP4_SHARD),
     ],
     ids=["D128", "D128-stats", "D64-stats", "int8-scales", "fp8",
-         "olmo2-1b-cell", "phi-4-mini", "mha32-two-tiles", "mha32-int8"],
+         "olmo2-1b-cell", "phi-4-mini", "mha32-two-tiles", "mha32-int8",
+         "tp4-shard"],
 )
 def test_paged_decode_attention(chip, d, dtype, scales, stats, shape):
+    """The kernel's operand is the whole ``[L, Hkv, N, bs, D]`` cache and
+    a layer index that is a VALUE of the program (scalar prefetch), the
+    form every decode step hands it."""
     from dynamo_tpu.ops.paged_attention_pallas import paged_decode_attention
 
     b, h, hkv, m = shape or (B, H, HKV, M)
 
-    def fn(q, kc, vc, bt, sl, ks=None, vs=None):
+    def fn(q, kc, vc, layer, bt, sl, ks=None, vs=None):
         return paged_decode_attention(
-            q, kc, vc, bt, sl, d**-0.5, return_stats=stats,
+            q, kc, vc, layer, bt, sl, d**-0.5, return_stats=stats,
             k_scales=ks, v_scales=vs,
         )
 
-    layer = chip((hkv, N, BS, d), dtype)
-    _compile(fn, chip((b, h, d), jnp.bfloat16), layer, layer,
-             chip((b, m), jnp.int32), chip((b,), jnp.int32),
-             *_scale_planes(chip, scales))
+    cache = _cache(chip, dtype, d=d, hkv=hkv)
+    _compile(fn, chip((b, h, d), jnp.bfloat16), cache, cache,
+             chip((), jnp.int32), chip((b, m), jnp.int32),
+             chip((b,), jnp.int32), *_scale_planes(chip, scales))
+
+
+@pytest.mark.parametrize("experts", [0, 8], ids=["dense", "experts"])
+def test_decode_window_keeps_no_copy_of_the_pool(chip, experts):
+    """An unrolled ``decode_window`` on the Pallas path reads its KV where
+    it lies: the program's temporaries stay far under its pool. With a
+    ``k_cache[l]`` operand of the attention kernel the TPU compiler
+    copies every layer's slab out first: at the benchmark's sizes the
+    temporaries were the pool and more (PERF.md section 6, PR 29)."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(
+        vocab_size=2048, hidden_size=512, intermediate_size=1024,
+        num_layers=4, num_heads=4, num_kv_heads=4, head_dim=128,
+        num_experts=experts, num_experts_per_tok=2,
+        moe_intermediate_size=256 if experts else 0,
+    )
+    b, m, n = 8, 32, 1024
+    params = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    cache = chip((cfg.num_layers, cfg.num_kv_heads, n, BS, cfg.head_dim),
+                 jnp.bfloat16)
+    ints, floats = chip((b,), jnp.int32), chip((b,), jnp.float32)
+    compiled = llama.decode_window.lower(
+        params, cfg, ints, ints, chip((b, m), jnp.int32), ints, ints, ints,
+        floats, ints, floats, cache, cache, n_steps=2, use_pallas=True,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # under a quarter of the pool, and sharper: under half of ONE layer's
+    # K slab, since a compiler that reuses one buffer for the slabs of
+    # this small program would still pass the quarter (the sliced form
+    # measured 30.0 MB here, this form 2.5-3.0 MB, a slab is 16.8 MB)
+    slab = cfg.num_kv_heads * n * BS * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < slab // 2
 
 
 @pytest.mark.parametrize("dtype,scales", [(jnp.bfloat16, False),
