@@ -151,7 +151,7 @@ def _track_smoke(result: dict) -> None:
 
 def time_decode_windows(
     params, cfg, *, B: int, BLOCK: int, CTX: int, WINDOW: int,
-    use_pallas: bool, merged: bool, iters: int, rounds: int = 3,
+    use_pallas: bool, iters: int, rounds: int = 3,
 ) -> float:
     """Wall-time ``iters`` fused decode+sample windows; returns tokens/s.
 
@@ -163,8 +163,7 @@ def time_decode_windows(
     ``rounds`` to shed scheduling noise; state rewinds between rounds so
     the ragged lengths stay inside the block tables (the caller must
     keep seq_len0 + iters*WINDOW <= CTX). Compile/Mosaic errors
-    propagate — callers choose their fallback (bench.py retries with
-    merged=False). Shared by bench.py and scripts/bench_mla.py so the
+    propagate. Shared by bench.py and scripts/bench_mla.py so the
     two benches cannot drift in methodology.
     """
     import jax
@@ -189,7 +188,7 @@ def time_decode_windows(
         toks, k_cache, v_cache = llama.decode_window(
             params, cfg, tokens, positions, tables, seq_lens,
             seeds, steps, temps, top_ks, top_ps, k_cache, v_cache,
-            n_steps=WINDOW, use_pallas=use_pallas, merged=merged,
+            n_steps=WINDOW, use_pallas=use_pallas,
         )
         return (toks[-1], positions + WINDOW, seq_lens + WINDOW,
                 steps + WINDOW, k_cache, v_cache)
@@ -2902,7 +2901,7 @@ def main() -> None:
     # kernel the chip's compiler refuses fails the bench
     toks_per_s = time_decode_windows(
         params, cfg, B=B, BLOCK=BLOCK, CTX=CTX, WINDOW=WINDOW,
-        use_pallas=use_pallas, merged=True, iters=ITERS,
+        use_pallas=use_pallas, iters=ITERS,
     )
 
     toks_per_s /= jax.device_count()

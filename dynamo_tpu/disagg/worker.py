@@ -23,6 +23,7 @@ import numpy as np
 
 from .. import tracing
 from ..engine.engine import JaxEngine, OutOfBlocks
+from ..models.llama import KV_HEAD_LAYOUT
 from ..protocols.common import LLMEngineOutput, PreprocessedRequest
 from ..resilience import faultpoints
 from ..resilience.faultpoints import FaultInjected
@@ -69,7 +70,7 @@ class PrefillWorker:
         self.layer_chunk = layer_chunk
         # wire-declared kv-head ordering; override only when wrapping an
         # engine whose extraction really produces a non-natural order
-        self.head_layout = head_layout or engine.cfg.kv_head_layout
+        self.head_layout = head_layout or KV_HEAD_LAYOUT
         # streamed layer-wise handoff (FlowKV): open the transfer at
         # prefill start, ship each chunk's blocks as its compute lands.
         # Engages only when the decode peer advertised the capability in
@@ -614,7 +615,6 @@ class _RemoteScatterSink:
     async def begin(self, head: dict) -> bool:
         if self._closed:
             return False
-        my_layout = self._engine.cfg.kv_head_layout
         my_tp = self._engine.cfg.mesh.tp if self._engine.cfg.mesh else 1
         layout = head.get("head_layout", "blocked")
         src_tp = head.get("src_tp", 1)
@@ -622,7 +622,7 @@ class _RemoteScatterSink:
         self._ici = None
         from ..ops.kv_rearrange import layout_mismatched
 
-        if layout_mismatched(layout, src_tp, my_layout, my_tp):
+        if layout_mismatched(layout, src_tp, KV_HEAD_LAYOUT, my_tp):
             from ..ops.kv_rearrange import rearrange_for_decode
 
             # validate the permutation NOW against both declared head
@@ -635,11 +635,11 @@ class _RemoteScatterSink:
                 for hkv in {shape[1], v_shape[1]}:
                     rearrange_for_decode(
                         np.empty((1, hkv, 0, 1, 1), np.int8),
-                        src_tp, my_tp, layout, my_layout,
+                        src_tp, my_tp, layout, KV_HEAD_LAYOUT,
                     )
             except Exception:  # noqa: BLE001 — bad peer metadata
                 return False
-            self._regroup = (src_tp, my_tp, layout, my_layout)
+            self._regroup = (src_tp, my_tp, layout, KV_HEAD_LAYOUT)
         if head.get("ici") and self._regroup is None:
             # ICI fast path: the sender negotiated the same-slice
             # device→device handoff (fingerprint re-checked here —
@@ -803,7 +803,7 @@ class DisaggEngine(AsyncEngine):
 
             conn["kv_ici"] = KV_ICI_VERSION
             conn["ici_fp"] = slice_fingerprint()
-            conn["ici_layout"] = self.engine.cfg.kv_head_layout
+            conn["ici_layout"] = KV_HEAD_LAYOUT
         return conn
 
     def _expect(self, req_id: str, sink) -> asyncio.Future:
@@ -956,12 +956,11 @@ class DisaggEngine(AsyncEngine):
             # before the commit below
             await sink.aclose()
         k_data, v_data = delivery.k_data, delivery.v_data
-        my_layout = self.engine.cfg.kv_head_layout
         my_tp = self.engine.cfg.mesh.tp if self.engine.cfg.mesh else 1
         from ..ops.kv_rearrange import layout_mismatched
 
         mismatched = k_data is not None and layout_mismatched(
-            delivery.head_layout, delivery.src_tp, my_layout, my_tp
+            delivery.head_layout, delivery.src_tp, KV_HEAD_LAYOUT, my_tp
         )
         if mismatched:
             from ..ops.kv_rearrange import rearrange_for_decode
@@ -970,10 +969,12 @@ class DisaggEngine(AsyncEngine):
                 # head-axis permutation only — valid on quantized
                 # payloads as-is (the block scales are kv-head-free)
                 k_data = rearrange_for_decode(
-                    k_data, delivery.src_tp, my_tp, delivery.head_layout, my_layout
+                    k_data, delivery.src_tp, my_tp, delivery.head_layout,
+                    KV_HEAD_LAYOUT,
                 )
                 v_data = rearrange_for_decode(
-                    v_data, delivery.src_tp, my_tp, delivery.head_layout, my_layout
+                    v_data, delivery.src_tp, my_tp, delivery.head_layout,
+                    KV_HEAD_LAYOUT,
                 )
             except Exception as e:  # noqa: BLE001 — bad peer metadata must
                 # not leak the reservation (blocks) or hang the caller

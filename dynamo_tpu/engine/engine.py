@@ -215,20 +215,6 @@ class EngineConfig:
     # windows whenever admission work is pending (fairness) and clamps to
     # each sequence's stop/context headroom. Power of two.
     decode_window: int = 4
-    # kv-head ordering of this engine's cache. The native JAX engine
-    # stores heads in natural (blocked) order — only "blocked" is valid
-    # here; foreign-ordered peers declare their layout on the KV wire
-    # (PrefillWorker head_layout / KvDelivery.head_layout) and the decode
-    # side regroups on delivery (ops/kv_rearrange.py; ref kv_rearrange)
-    kv_head_layout: str = "blocked"
-    # decode layer loop: unrolled (default — in-place cache scatters, no
-    # scan-ys cache re-stack) vs lax.scan (faster compiles on very deep
-    # models, at a full extra KV-cache copy per step)
-    decode_layer_scan: bool = False
-    # merged one-write decode (flash-merged attention + single in-place
-    # Pallas cache append per step); False = per-layer write-then-attend
-    # (escape hatch for Mosaic kernel regressions)
-    decode_merged: bool = True
     # pipelined decode: dispatch window k+1 (fed window k's last sampled
     # tokens as a device array) BEFORE the host consumes window k, hiding
     # host emission + dispatch latency behind device compute (the async
@@ -317,12 +303,6 @@ class EngineConfig:
     max_live_adapters: int = 0
 
     def __post_init__(self):
-        if self.kv_head_layout != "blocked":
-            raise ValueError(
-                "JaxEngine stores kv heads in blocked (natural) order; "
-                f"kv_head_layout={self.kv_head_layout!r} would mislabel the "
-                "cache — foreign layouts belong on the transfer metadata"
-            )
         if self.spec_gamma > 0 and self.decode_window < 2:
             raise ValueError(
                 "spec_gamma requires decode_window >= 2: the speculative "
@@ -374,12 +354,6 @@ class EngineConfig:
                 raise ValueError(
                     "adapters target the separate-QKV projection path; "
                     "MLA models have no LoRA lane yet"
-                )
-            if self.decode_layer_scan:
-                raise ValueError(
-                    "adapters require the unrolled decode layer loop "
-                    "(decode_layer_scan=False): per-layer adapter stacks "
-                    "are sliced statically like the quantized-KV branch"
                 )
             if self.ring_prefill_threshold > 0:
                 raise ValueError(
@@ -2325,7 +2299,7 @@ class JaxEngine(AsyncEngine):
         recorded as this request's ``engine.kv_restore`` span."""
         if self.offload is None:
             return
-        self.offload.flush_evictions_async(self.k_cache, self.v_cache)
+        self._before_landing()
         if upload is not None:
             t0 = time.perf_counter()
             self.k_cache, self.v_cache = self.offload.finish_upload(
@@ -2354,13 +2328,25 @@ class JaxEngine(AsyncEngine):
                     hidden_ms=round(max(total_ms - exposed_ms, 0.0), 3),
                 )
 
+    def _before_landing(self) -> None:
+        """What KV that lands on freshly allocated pages (a tier restore,
+        a prefetch, a peer or disagg delivery, a prefill) needs first,
+        in this order: the d2h gathers of every pending eviction, which
+        may still reference those pages (budget=None: a landing may
+        target any of them), then the pages' queued scale resets (int8
+        cache): a reset that ran AFTER the landing would leave the
+        landed int8 payload under scale EPS."""
+        if self.offload is not None:
+            self.offload.flush_evictions_async(self.k_cache, self.v_cache)
+        self._flush_scale_resets()
+
     def _flush_scale_resets(self) -> None:
         """int8 device cache: reset the scale-plane entries of every
         page the allocator recycled since the last dispatch (queued by
         its ``on_allocated`` hook), as ONE scatter riding the next
-        write dispatch's preamble. Idx count pads to the power-of-two
-        bucket with the trash page 0 so the scatter's program count
-        stays bucket-bounded."""
+        write dispatch's preamble (or a landing's: ``_before_landing``).
+        Idx count pads to the power-of-two bucket with the trash page 0
+        so the scatter's program count stays bucket-bounded."""
         if self.k_scales is None or not self._pending_scale_resets:
             return
         idxs = np.unique(
@@ -2995,7 +2981,7 @@ class JaxEngine(AsyncEngine):
     def _prefetch_land_device(self, upload) -> None:
         """Executor thread: flush pending evictions that may reference
         the prefetch's pages, then scatter the landed upload."""
-        self.offload.flush_evictions_async(self.k_cache, self.v_cache)
+        self._before_landing()
         self.k_cache, self.v_cache = self.offload.finish_upload(
             self.k_cache, self.v_cache, upload, account=False
         )
@@ -3675,11 +3661,6 @@ class JaxEngine(AsyncEngine):
                 self.v_cache,
                 use_pallas=self.use_pallas,
                 mesh=self.mesh,
-                # the decode part must mirror this engine's own
-                # decode_window structure or the XLA branch's bit-exact
-                # contract breaks
-                unroll=not cfg.decode_layer_scan,
-                merged=cfg.decode_merged,
                 with_logprobs=want_lp,
                 **kwargs,
                 **self._moe_kw(),
@@ -4018,8 +3999,6 @@ class JaxEngine(AsyncEngine):
                 self._temps, self._top_ks, self._top_ps,
                 self.k_cache, self.v_cache,
                 n_steps=n, use_pallas=self.use_pallas,
-                unroll=not cfg.decode_layer_scan,
-                merged=cfg.decode_merged,
                 penalties=(self._freq_pens, self._pres_pens, self._rep_pens)
                 if penalized else None,
                 pen_state=(self._pen_counts, self._pen_mask)
@@ -4058,8 +4037,6 @@ class JaxEngine(AsyncEngine):
             n_steps=n,
             use_pallas=self.use_pallas,
             mesh=self.mesh,
-            unroll=not cfg.decode_layer_scan,
-            merged=cfg.decode_merged,
             with_logprobs=want_lp,
         )
         kw.update(self._lora_decode_kw())
@@ -4629,11 +4606,7 @@ class JaxEngine(AsyncEngine):
             _scatter_blocks_requant,
         )
 
-        if self.offload is not None:
-            # pending evictions may reference the very pages we're about to
-            # overwrite — dispatch their gathers first (budget=None: the
-            # landing KV may target any freshly allocated page)
-            self.offload.flush_evictions_async(self.k_cache, self.v_cache)
+        self._before_landing()
         padded = _pad_idxs(idxs)
         if self.mirror is not None:
             # mirrored landing: broadcast the UNPADDED host blocks (the

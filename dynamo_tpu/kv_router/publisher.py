@@ -346,19 +346,19 @@ class KvPrefetchListener:
         area. A quantized delivery regroups as-is (the codec's scales
         are kv-head-free) and lands with its scale arrays — the
         landing normalizes it to THIS worker's codec mode."""
+        from ..models.llama import KV_HEAD_LAYOUT
         from ..ops.kv_rearrange import layout_mismatched, rearrange_for_decode
 
         k, v = delivery.k_data, delivery.v_data
-        my_layout = self.engine.cfg.kv_head_layout
         my_tp = self.engine.cfg.mesh.tp if self.engine.cfg.mesh else 1
         if layout_mismatched(
-            delivery.head_layout, delivery.src_tp, my_layout, my_tp
+            delivery.head_layout, delivery.src_tp, KV_HEAD_LAYOUT, my_tp
         ):
             k = rearrange_for_decode(
-                k, delivery.src_tp, my_tp, delivery.head_layout, my_layout
+                k, delivery.src_tp, my_tp, delivery.head_layout, KV_HEAD_LAYOUT
             )
             v = rearrange_for_decode(
-                v, delivery.src_tp, my_tp, delivery.head_layout, my_layout
+                v, delivery.src_tp, my_tp, delivery.head_layout, KV_HEAD_LAYOUT
             )
         return self.engine.offload.land_peer_chain(
             served, k, v,
@@ -460,6 +460,7 @@ class KvPeerServer:
 
     async def _serve(self, req: KvPeerFetchRequest) -> None:
         from ..disagg.transfer import send_kv_blocks
+        from ..models.llama import KV_HEAD_LAYOUT
         from ..resilience import faultpoints
 
         try:
@@ -574,7 +575,7 @@ class KvPeerServer:
             await send_kv_blocks(
                 req.connection, req.request_id, -1, k, v,
                 layer_chunk=self.layer_chunk,
-                head_layout=self.engine.cfg.kv_head_layout,
+                head_layout=KV_HEAD_LAYOUT,
                 src_tp=self.engine.cfg.mesh.tp if self.engine.cfg.mesh else 1,
                 hashes=hashes,
                 kv_quant=serve_q if ks is not None else "none",

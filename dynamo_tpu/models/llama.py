@@ -181,9 +181,9 @@ def _scan_groups(body, x, params, cfg: ModelConfig, k_cache, v_cache,
                  tally=None):
     """lax.scan the layer body over every layer group, threading the
     cache slices; returns (x, k_cache, v_cache) with per-group ys
-    re-concatenated on the layer axis. ONE implementation for prefill
-    and both scan decode variants. A body that adds to a MoeTally scans
-    through it (``tally``)."""
+    re-concatenated on the layer axis. ``prefill``'s homogeneous layer
+    loop: no decode program scans its layers. A body that adds to a
+    MoeTally scans through it (``tally``)."""
     kcs, vcs = [], []
     scan = lax.scan if tally is None else tally.scan
     for lps, n, off in layer_groups(params, cfg):
@@ -217,6 +217,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm_init(keys[2], (E, V), 0.02)
     return params
+
+
+# kv-head ordering of the cache below: natural (blocked) order, the only
+# one this engine stores. Foreign-ordered peers declare theirs on the KV
+# wire (PrefillWorker head_layout / KvDelivery.head_layout) and the
+# decode side regroups on delivery (ops/kv_rearrange.py)
+KV_HEAD_LAYOUT = "blocked"
 
 
 def kv_cache_shapes(
@@ -947,6 +954,26 @@ def _wo_proj(lp: dict, o_flat: jnp.ndarray, lora_l=None, lora_ids=None,
     return p
 
 
+def _layer_tail(x, lp: dict, cfg: ModelConfig, o_flat, lora_l=None,
+                lora_ids=None, lora_grouped: bool = False, mesh=None,
+                use_pallas: bool = False, interpret: bool = False,
+                tally: Optional[MoeTally] = None) -> jnp.ndarray:
+    """A layer's second half, the same in every forward: the attention
+    rows ``o_flat`` [R, H*D] through the output projection (+ LoRA
+    delta) onto the residual ``x`` [R, E], then the FFN sublayer, each
+    inside the family's pre/post norms."""
+    x = x + post_norm(
+        lp, "attn_post_norm",
+        _wo_proj(lp, o_flat, lora_l, lora_ids, lora_grouped), cfg,
+    )
+    h = pre_norm(lp, "mlp_norm", x, cfg)
+    return x + post_norm(
+        lp, "mlp_post_norm",
+        _ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas,
+             interpret=interpret, tally=tally), cfg,
+    )
+
+
 # ---------------- prefill (one sequence, chunked) ----------------
 
 
@@ -1094,7 +1121,6 @@ def prefill(
                     valid_len, scale,
                 )
             o = mla._o_proj(lp, cfg, out_lat).astype(x.dtype)
-            x = x + _mm(o, lp["wo"])
         else:
             q, k, v = _qkv(lp, cfg, h, lora_l, lora_ids)
             fr = inv_freq if freqs is None else freqs
@@ -1129,15 +1155,9 @@ def prefill(
                     cap=cfg.attn_softcap,
                     k_scales=ks_l, v_scales=vs_l,
                 )
-            x = x + post_norm(
-                lp, "attn_post_norm",
-                _wo_proj(lp, o.reshape(T, -1), lora_l, lora_ids), cfg,
-            )
-        h = pre_norm(lp, "mlp_norm", x, cfg)
-        x = x + post_norm(
-            lp, "mlp_post_norm",
-            _ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas, tally=tally),
-            cfg,
+        x = _layer_tail(
+            x, lp, cfg, o.reshape(T, -1), lora_l, lora_ids, mesh=mesh,
+            use_pallas=use_pallas, tally=tally,
         )
         if scales is not None:
             return x, (kc, vc, ks_l, vs_l)
@@ -1205,23 +1225,29 @@ def prefill(
 
 def _decode_body(
     params, cfg, tokens, positions, block_tables, seq_lens,
-    k_cache, v_cache, use_pallas, mesh=None, unroll=True, interpret=False,
-    merged=True, k_scales=None, v_scales=None, lora=None, adapter_ids=None,
+    k_cache, v_cache, use_pallas, mesh=None, interpret=False,
+    k_scales=None, v_scales=None, lora=None, adapter_ids=None,
     moe_tally=None,
 ):
     """Shared un-jitted decode forward (one token per sequence).
 
-    ``unroll=True`` (default) runs an UNROLLED python loop over layers
-    with static layer indices: the caches are updated in place on the
-    donated stacked arrays, and the attention kernels take them WHOLE
-    with the layer's index (a ``k_cache[l]`` operand of a kernel is a
-    copy of the pool: ops/paged_attention_pallas module docs).
-    The scan variant threads the caches as scan xs/ys, and XLA
-    materializes the re-stacked ys — a full extra cache copy per decode
-    step (measured: a 2.15GB cache pair costs ~2.5GB of temp and
-    dominates step time; decode is supposed to stream WEIGHTS, not
-    copy the KV pool). Scan remains for compile-time-sensitive very
-    deep models (EngineConfig.decode_layer_scan).
+    ONE rule picks the layer loop, from what the program can observe:
+    kernels on (``use_pallas``) and no attention softcap -> the MERGED
+    one-write loop of the cache kind (attention takes the current token
+    out of the cache, all layers' writes batch into one in-place Pallas
+    append); otherwise WRITE-THEN-ATTEND (XLA scatter, then attention
+    over the cache: what CPU tier-1 runs and every parity test uses as
+    its reference; gemma-2's caps live in the XLA attention). Two loops
+    a cache kind (GQA, MLA latents), each reached by exactly one
+    condition; nothing else selects a loop.
+
+    Every loop is an UNROLLED python loop over layers with static layer
+    indices: the caches are updated in place on the donated stacked
+    arrays, and the attention kernels take them WHOLE with the layer's
+    index. A ``k_cache[l]`` operand of a kernel is a copy of the pool
+    (ops/paged_attention_pallas module docs), and so are the re-stacked
+    outputs of a scan over layers: decode is supposed to stream WEIGHTS,
+    not copy the KV pool (PERF.md section 6, PR 29).
 
     ``k_scales``/``v_scales`` ([L, N] f32, int8-with-scales device cache)
     thread through every write (scale growth + page requant) and attention
@@ -1234,19 +1260,11 @@ def _decode_body(
         if cfg.is_mla:
             raise ValueError("int8 device KV scales: MLA is gated at "
                              "engine init (absorbed-matmul latents)")
-        if not unroll:
-            raise ValueError("int8 device KV scales need the unrolled "
-                             "decode (decode_layer_scan cannot carry "
-                             "per-layer plane scatters in place)")
         k_scales0, v_scales0 = k_scales, v_scales
-    if lora is not None:
-        if cfg.is_mla:
-            raise ValueError("LoRA adapters: MLA is gated at engine init "
-                             "(deltas attach to the GQA projections)")
-        if not unroll:
-            raise ValueError("LoRA adapters need the unrolled decode "
-                             "(decode_layer_scan cannot slice per-layer "
-                             "adapter stacks)")
+    if lora is not None and cfg.is_mla:
+        raise ValueError("LoRA adapters: MLA is gated at engine init "
+                         "(deltas attach to the GQA projections)")
+    merged = use_pallas and not cfg.attn_softcap
     B = tokens.shape[0]
     x = _embed(params, cfg, tokens)  # [B, E]
     if cfg.is_mla:
@@ -1261,27 +1279,25 @@ def _decode_body(
         scale = attn_query_scale(cfg)
 
     def layer_tail(x, lp, o, lora_l=None):
-        x = x + post_norm(
-            lp, "attn_post_norm",
-            _wo_proj(lp, o.reshape(B, -1), lora_l, adapter_ids), cfg,
-        )
-        h = pre_norm(lp, "mlp_norm", x, cfg)
-        return x + post_norm(
-            lp, "mlp_post_norm",
-            _ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas,
-                 interpret=interpret, tally=moe_tally), cfg,
+        return _layer_tail(
+            x, lp, cfg, o.reshape(B, -1), lora_l, adapter_ids, mesh=mesh,
+            use_pallas=use_pallas, interpret=interpret, tally=moe_tally,
         )
 
     inv_local_dec = _rope_freqs_local(cfg)
 
-    def layer_qkv(x, lp, freqs=None, lora_l=None):
+    def layer_qkv(x, lp, l, lora_l=None):
         h = pre_norm(lp, "attn_norm", x, cfg)
         # q: [B, H, D], k/v: [B, Hkv, D]
         q, k, v = _qkv(lp, cfg, h, lora_l, adapter_ids)
-        fr = inv_freq if freqs is None else freqs
+        fr = rope_freqs_for_layer(cfg, l, inv_freq, inv_local_dec)
         q = apply_rope(q, positions, fr, rope_msc)
         k = apply_rope(k, positions, fr, rope_msc)
         return q, k, v
+
+    def mla_q_and_latent(x, lp):
+        h = pre_norm(lp, "attn_norm", x, cfg)
+        return _mla.mla_q_and_latent(lp, cfg, h, positions, inv_freq, msc)
 
     def lora_for_layer(l):
         return (
@@ -1289,54 +1305,21 @@ def _decode_body(
             else jax.tree.map(lambda arr: arr[l], lora)
         )
 
-    def mla_layer(x, lp, kc_l, vc_l):
-        """One MLA decode layer against full cache layers kc_l/vc_l:
-        write the token's latent, absorbed attention (latent kernel when
-        use_pallas, XLA gather otherwise), output fold."""
-        h = pre_norm(lp, "attn_norm", x, cfg)
-        q_eff, q_pe, c_kv, k_pe = _mla.mla_q_and_latent(
-            lp, cfg, h, positions, inv_freq, msc
-        )
-        # ADJACENT advanced indices (blk, off) stay in place (unlike the
-        # non-MLA [l, :, blk, off] form where the scalar l separates
-        # them): the slice is [1, B, D], so the update is value[None]
-        kc_l = kc_l.at[:, blk, off].set(c_kv[None].astype(kc_l.dtype))
-        vc_l = vc_l.at[:, blk, off].set(k_pe[None].astype(vc_l.dtype))
-        if use_pallas and mesh is not None:
-            o = _mla_ops.mla_paged_decode_attention_sharded(
-                q_eff, q_pe, kc_l, vc_l, block_tables, seq_lens, scale,
-                mesh, interpret=interpret,
-            )
-        elif use_pallas:
-            o = _mla_ops.mla_paged_decode_attention(
-                q_eff, q_pe, kc_l, vc_l, block_tables, seq_lens, scale,
-                interpret=interpret,
-            )
-        else:
-            o = _mla.mla_decode_attention_xla(
-                q_eff, q_pe, kc_l, vc_l, block_tables, seq_lens, scale
-            )
-        o = _mla._o_proj(lp, cfg, o).astype(x.dtype)
-        return layer_tail(x, lp, o), kc_l, vc_l
+    def layers():
+        for lps, n, goff in layer_groups(params, cfg):
+            for li in range(n):
+                yield goff + li, _layer(lps, li, mesh)
 
-    # slot indices are used by the unrolled paths AND the MLA scan body
     blk, off = att.decode_slot_indices(
         block_tables, positions, k_cache.shape[3]
     )
-    mla_merged = merged and unroll and use_pallas and cfg.is_mla
-    # sinks join the flash-merge denominator and per-layer windows are
-    # static per (unrolled) layer call, so gpt-oss runs the merged
-    # one-write path like every other GQA family
-    merged = (
-        merged and unroll and use_pallas and not cfg.is_mla
-        and not cfg.attn_softcap  # gemma-2 caps live in the XLA paths
-    )
-    if mla_merged:
-        # MERGED one-write path, MLA flavor: the latent kernel scores
+    hist_lens = seq_lens - 1  # cache contents EXCLUDE the new token
+    if merged and cfg.is_mla:
+        # MERGED one-write loop, MLA flavor: the latent kernel scores
         # history with stats, the current token's (c_kv, k_pe) folds in
         # via the flash merge, and ALL layers' latent writes batch into
         # one in-place Pallas append — same 2L-scatters-to-1-append trick
-        # as the GQA merged branch below. On a mesh the query heads are
+        # as the GQA merged loop below. On a mesh the query heads are
         # the parallel axis and the latent cache replicates (MQA shape —
         # see parallel/mesh.cache_sharding), so attention shard_maps over
         # tp and every device RMWs its cache replica.
@@ -1345,31 +1328,23 @@ def _decode_body(
             kv_cache_append_replicated,
         )
 
-        hist_lens = seq_lens - 1  # cache contents EXCLUDE the new token
         c_news, pe_news = [], []
-        for lps, n, goff in layer_groups(params, cfg):
-            for li in range(n):
-                l = goff + li
-                lp = _layer(lps, li, mesh)
-                h = pre_norm(lp, "attn_norm", x, cfg)
-                q_eff, q_pe, c_kv, k_pe = _mla.mla_q_and_latent(
-                    lp, cfg, h, positions, inv_freq, msc
+        for l, lp in layers():
+            q_eff, q_pe, c_kv, k_pe = mla_q_and_latent(x, lp)
+            c_news.append(c_kv)
+            pe_news.append(k_pe)
+            if mesh is None:
+                o_lat = _mla_ops.mla_decode_attention_merged(
+                    q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
+                    block_tables, hist_lens, scale, interpret=interpret,
                 )
-                c_news.append(c_kv)
-                pe_news.append(k_pe)
-                if mesh is None:
-                    o_lat = _mla_ops.mla_decode_attention_merged(
-                        q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
-                        block_tables, hist_lens, scale, interpret=interpret,
-                    )
-                else:
-                    o_lat = _mla_ops.mla_decode_attention_merged_sharded(
-                        q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
-                        block_tables, hist_lens, scale, mesh,
-                        interpret=interpret,
-                    )
-                o = _mla._o_proj(lp, cfg, o_lat).astype(x.dtype)
-                x = layer_tail(x, lp, o)
+            else:
+                o_lat = _mla_ops.mla_decode_attention_merged_sharded(
+                    q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
+                    block_tables, hist_lens, scale, mesh,
+                    interpret=interpret,
+                )
+            x = layer_tail(x, lp, _mla._o_proj(lp, cfg, o_lat).astype(x.dtype))
         c_stack = jnp.stack(c_news)[:, :, None, :]  # [L, B, 1, C]
         pe_stack = jnp.stack(pe_news)[:, :, None, :]  # [L, B, 1, R]
         if mesh is None:
@@ -1382,34 +1357,36 @@ def _decode_body(
                 c_stack, pe_stack, k_cache, v_cache, blk, off, mesh,
                 interpret=interpret,
             )
-    elif cfg.is_mla and unroll:
-        for lps, n, goff in layer_groups(params, cfg):
-            for li in range(n):
-                l = goff + li
-                lp = _layer(lps, li, mesh)
-                x, kc_l, vc_l = mla_layer(x, lp, k_cache[l], v_cache[l])
-                k_cache = k_cache.at[l].set(kc_l)
-                v_cache = v_cache.at[l].set(vc_l)
     elif cfg.is_mla:
-        def mla_body(carry, layer_in):
-            x = carry
-            lp, kc, vc = layer_in
-            x, kc, vc = mla_layer(x, lp, kc, vc)
-            return x, (kc, vc)
-
-        x, k_cache, v_cache = _scan_groups(
-            mla_body, x, params, cfg, k_cache, v_cache, tally=moe_tally
-        )
+        # WRITE-THEN-ATTEND, MLA flavor: the token's latent lands by XLA
+        # scatter, then absorbed attention gathers the layer's pages
+        for l, lp in layers():
+            q_eff, q_pe, c_kv, k_pe = mla_q_and_latent(x, lp)
+            # advanced indices (blk, off) behind the scalar l and the
+            # full slice come to the front: the value is [B, 1, D]
+            k_cache = k_cache.at[l, :, blk, off].set(
+                c_kv[:, None].astype(k_cache.dtype)
+            )
+            v_cache = v_cache.at[l, :, blk, off].set(
+                k_pe[:, None].astype(v_cache.dtype)
+            )
+            o_lat = _mla.mla_decode_attention_xla(
+                q_eff, q_pe, k_cache[l], v_cache[l], block_tables,
+                seq_lens, scale,
+            )
+            x = layer_tail(x, lp, _mla._o_proj(lp, cfg, o_lat).astype(x.dtype))
     elif merged:
-        # MERGED one-write path (TPU): attention handles the current token
+        # MERGED one-write loop (TPU): attention handles the current token
         # out-of-cache (flash merge over the stats-emitting paged kernel),
         # so the cache sees ONE in-place Pallas append per step instead of
         # 2L XLA scatters — XLA will not update scatters of this shape in
-        # place; each one copied the full cache (measured ~0.55 GB/copy on
-        # the 1B bench config; the reference's equivalent split is vLLM's
-        # reshape_and_cache + paged attention). On a mesh, every piece is
-        # kv-head-parallel and runs under shard_map over tp (the engine
-        # only sets use_pallas when tp divides the kv heads).
+        # place; each one copies the full cache (the reference's
+        # equivalent split is vLLM's reshape_and_cache + paged attention).
+        # Sinks join the flash-merge denominator and per-layer windows
+        # are static per layer call, so gpt-oss runs it like every other
+        # GQA family. On a mesh, every piece is kv-head-parallel and runs
+        # under shard_map over tp (the engine only sets use_pallas when
+        # tp divides the kv heads).
         from ..ops.kv_cache_update_pallas import (
             kv_cache_append,
             kv_cache_append_quantized,
@@ -1417,41 +1394,33 @@ def _decode_body(
             kv_cache_append_sharded,
         )
 
-        hist_lens = seq_lens - 1  # cache contents EXCLUDE the new token
         k_news, v_news = [], []
-        for lps, n, goff in layer_groups(params, cfg):
-            for li in range(n):
-                l = goff + li
-                lp = _layer(lps, li, mesh)
-                lora_l = lora_for_layer(l)
-                q, k, v = layer_qkv(
-                    x, lp,
-                    rope_freqs_for_layer(cfg, l, inv_freq, inv_local_dec),
-                    lora_l=lora_l,
+        for l, lp in layers():
+            lora_l = lora_for_layer(l)
+            q, k, v = layer_qkv(x, lp, l, lora_l)
+            k_news.append(k)
+            v_news.append(v)
+            # history pages dequantize through the step-entry scale
+            # planes — consistent: the batched append below is what
+            # mutates pages/scales, and it runs after attention
+            ks_l = k_scales[l] if quantized else None
+            vs_l = v_scales[l] if quantized else None
+            if mesh is None:
+                o = att.decode_attention_merged(
+                    q, k, v, k_cache, v_cache, l, block_tables,
+                    hist_lens, scale, window=window_for_layer(cfg, l),
+                    sinks=lp.get("sinks"), interpret=interpret,
+                    k_scales=ks_l, v_scales=vs_l,
                 )
-                k_news.append(k)
-                v_news.append(v)
-                # history pages dequantize through the step-entry scale
-                # planes — consistent: the batched append below is what
-                # mutates pages/scales, and it runs after attention
-                ks_l = k_scales[l] if quantized else None
-                vs_l = v_scales[l] if quantized else None
-                if mesh is None:
-                    o = att.decode_attention_merged(
-                        q, k, v, k_cache, v_cache, l, block_tables,
-                        hist_lens, scale, window=window_for_layer(cfg, l),
-                        sinks=lp.get("sinks"), interpret=interpret,
-                        k_scales=ks_l, v_scales=vs_l,
-                    )
-                else:
-                    o = att.decode_attention_merged_sharded(
-                        q, k, v, k_cache, v_cache, l, block_tables,
-                        hist_lens, scale, mesh,
-                        window=window_for_layer(cfg, l),
-                        sinks=lp.get("sinks"), interpret=interpret,
-                        k_scales=ks_l, v_scales=vs_l,
-                    )
-                x = layer_tail(x, lp, o, lora_l=lora_l)
+            else:
+                o = att.decode_attention_merged_sharded(
+                    q, k, v, k_cache, v_cache, l, block_tables,
+                    hist_lens, scale, mesh,
+                    window=window_for_layer(cfg, l),
+                    sinks=lp.get("sinks"), interpret=interpret,
+                    k_scales=ks_l, v_scales=vs_l,
+                )
+            x = layer_tail(x, lp, o, lora_l)
         k_new, v_new = jnp.stack(k_news), jnp.stack(v_news)
         if quantized:
             if mesh is None:
@@ -1478,76 +1447,42 @@ def _decode_body(
                 k_new, v_new, k_cache, v_cache, blk, off, mesh,
                 interpret=interpret,
             )
-    elif unroll:
-        for lps, n, goff in layer_groups(params, cfg):
-            for li in range(n):
-                l = goff + li
-                lp = _layer(lps, li, mesh)
-                lora_l = lora_for_layer(l)
-                q, k, v = layer_qkv(
-                    x, lp,
-                    rope_freqs_for_layer(cfg, l, inv_freq, inv_local_dec),
-                    lora_l=lora_l,
-                )
-                ks_l = vs_l = None
-                if quantized:
-                    # write-before-attend: the row quantizes against the
-                    # (possibly grown) page scale, then attention
-                    # dequantizes through the SAME updated plane slice
-                    kc_l, ks_l = att.write_decode_token_to_cache_quantized(
-                        k_cache[l], k_scales[l], k, block_tables, positions
-                    )
-                    vc_l, vs_l = att.write_decode_token_to_cache_quantized(
-                        v_cache[l], v_scales[l], v, block_tables, positions
-                    )
-                    k_cache = k_cache.at[l].set(kc_l)
-                    v_cache = v_cache.at[l].set(vc_l)
-                    k_scales = k_scales.at[l].set(ks_l)
-                    v_scales = v_scales.at[l].set(vs_l)
-                else:
-                    # mixed basic+advanced indexing puts the advanced axes
-                    # (blk, off) in front: the update value is [B, Hkv, D]
-                    k_cache = k_cache.at[l, :, blk, off].set(
-                        k.astype(k_cache.dtype)
-                    )
-                    v_cache = v_cache.at[l, :, blk, off].set(
-                        v.astype(v_cache.dtype)
-                    )
-                o = att.decode_attention(
-                    q, k_cache, v_cache, l, block_tables, seq_lens, scale,
-                    use_pallas=use_pallas, mesh=mesh,
-                    window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
-                    cap=cfg.attn_softcap,
-                    k_scales=ks_l, v_scales=vs_l,
-                )
-                x = layer_tail(x, lp, o, lora_l=lora_l)
     else:
-        if cfg.layer_windows:
-            raise ValueError(
-                "decode_layer_scan cannot serve per-layer-window models "
-                "(the scan body would need a per-layer static mask "
-                "shape) — use the default unrolled decode"
+        # WRITE-THEN-ATTEND: the XLA path (CPU, kernels refused for the
+        # shape, softcap models)
+        for l, lp in layers():
+            lora_l = lora_for_layer(l)
+            q, k, v = layer_qkv(x, lp, l, lora_l)
+            ks_l = vs_l = None
+            if quantized:
+                # write-before-attend: the row quantizes against the
+                # (possibly grown) page scale, then attention
+                # dequantizes through the SAME updated plane slice
+                kc_l, ks_l = att.write_decode_token_to_cache_quantized(
+                    k_cache[l], k_scales[l], k, block_tables, positions
+                )
+                vc_l, vs_l = att.write_decode_token_to_cache_quantized(
+                    v_cache[l], v_scales[l], v, block_tables, positions
+                )
+                k_cache = k_cache.at[l].set(kc_l)
+                v_cache = v_cache.at[l].set(vc_l)
+                k_scales = k_scales.at[l].set(ks_l)
+                v_scales = v_scales.at[l].set(vs_l)
+            else:
+                # mixed basic+advanced indexing puts the advanced axes
+                # (blk, off) in front: the update value is [B, Hkv, D]
+                k_cache = k_cache.at[l, :, blk, off].set(
+                    k.astype(k_cache.dtype)
+                )
+                v_cache = v_cache.at[l, :, blk, off].set(
+                    v.astype(v_cache.dtype)
+                )
+            o = att.decode_attention_xla(
+                q, k_cache[l], v_cache[l], block_tables, seq_lens, scale,
+                window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
+                cap=cfg.attn_softcap, k_scales=ks_l, v_scales=vs_l,
             )
-
-        def body(carry, layer_in):
-            x = carry
-            lp, kc, vc = layer_in
-            q, k, v = layer_qkv(x, lp)
-            kc = att.write_decode_token_to_cache(kc, k, block_tables, positions)
-            vc = att.write_decode_token_to_cache(vc, v, block_tables, positions)
-            # the scan hands its body one layer's slab: a one-layer
-            # cache and layer 0 (a bitcast)
-            o = att.decode_attention(
-                q, kc[None], vc[None], 0, block_tables, seq_lens, scale,
-                use_pallas=use_pallas, mesh=mesh, window=cfg.sliding_window,
-                sinks=lp.get("sinks"), cap=cfg.attn_softcap,
-            )
-            x = layer_tail(x, lp, o)
-            return x, (kc, vc)
-
-        x, k_cache, v_cache = _scan_groups(
-            body, x, params, cfg, k_cache, v_cache, tally=moe_tally
-        )
+            x = layer_tail(x, lp, o, lora_l)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x)  # [B, V]
     if quantized:
@@ -1562,7 +1497,7 @@ def _decode_body(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "use_pallas", "mesh", "unroll", "interpret", "merged"),
+    static_argnames=("cfg", "use_pallas", "mesh", "interpret"),
     donate_argnames=("k_cache", "v_cache"),
 )
 def decode_step(
@@ -1576,9 +1511,7 @@ def decode_step(
     v_cache: jnp.ndarray,
     use_pallas: bool = False,
     mesh=None,
-    unroll: bool = True,
     interpret: bool = False,
-    merged: bool = True,
     k_scales: Optional[jnp.ndarray] = None,  # [L, N] f32, NOT donated
     v_scales: Optional[jnp.ndarray] = None,
     lora=None,                                # stacked adapter pytree
@@ -1586,14 +1519,12 @@ def decode_step(
 ):
     """One continuous-batching decode step for all active sequences.
 
-    ``merged=False`` opts out of the one-write merged path back to the
-    per-layer write-then-attend kernels (``EngineConfig.decode_merged``;
-    nothing selects it automatically). With scale planes the
-    return grows to (logits, k_cache, v_cache, k_scales, v_scales,
-    n_requants) — see ``_decode_body``."""
+    With scale planes the return grows to (logits, k_cache, v_cache,
+    k_scales, v_scales, n_requants) — see ``_decode_body``, which also
+    picks the layer loop."""
     return _decode_body(
         params, cfg, tokens, positions, block_tables, seq_lens,
-        k_cache, v_cache, use_pallas, mesh, unroll, interpret, merged,
+        k_cache, v_cache, use_pallas, mesh, interpret,
         k_scales=k_scales, v_scales=v_scales, lora=lora,
         adapter_ids=adapter_ids,
     )
@@ -1601,8 +1532,8 @@ def decode_step(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "n_steps", "use_pallas", "mesh", "unroll",
-                     "interpret", "merged", "with_logprobs", "moe_counters"),
+    static_argnames=("cfg", "n_steps", "use_pallas", "mesh", "interpret",
+                     "with_logprobs", "moe_counters"),
     donate_argnames=("k_cache", "v_cache", "counts"),
 )
 def decode_window(
@@ -1622,9 +1553,7 @@ def decode_window(
     n_steps: int = 1,
     use_pallas: bool = False,
     mesh=None,
-    unroll: bool = True,
     interpret: bool = False,
-    merged: bool = True,
     # sampling penalties (all-or-nothing per program: the engine compiles
     # the penalized variant only when some active request asks for one)
     freq_pens: Optional[jnp.ndarray] = None,  # [B] f32
@@ -1678,17 +1607,16 @@ def decode_window(
         if quantized:
             logits, k_cache, v_cache, ks, vs, nr = _decode_body(
                 params, cfg, tokens, positions, block_tables, seq_lens,
-                k_cache, v_cache, use_pallas, mesh, unroll, interpret,
-                merged, k_scales=ks, v_scales=vs, lora=lora,
+                k_cache, v_cache, use_pallas, mesh, interpret,
+                k_scales=ks, v_scales=vs, lora=lora,
                 adapter_ids=adapter_ids, moe_tally=tally,
             )
             nreq = nreq + nr
         else:
             logits, k_cache, v_cache = _decode_body(
                 params, cfg, tokens, positions, block_tables, seq_lens,
-                k_cache, v_cache, use_pallas, mesh, unroll, interpret,
-                merged, lora=lora, adapter_ids=adapter_ids,
-                moe_tally=tally,
+                k_cache, v_cache, use_pallas, mesh, interpret,
+                lora=lora, adapter_ids=adapter_ids, moe_tally=tally,
             )
         raw_logits = logits  # reported logprobs are the model's own dist
         if penalized:
@@ -1788,18 +1716,6 @@ def _mixed_fused_forward(
     else:
         ids_all = None
 
-    def layer_tail(x, lp, o_flat, lora_l=None):
-        x = x + post_norm(
-            lp, "attn_post_norm",
-            _wo_proj(lp, o_flat, lora_l, ids_all, lora_grouped=True), cfg,
-        )
-        h = pre_norm(lp, "mlp_norm", x, cfg)
-        return x + post_norm(
-            lp, "mlp_post_norm",
-            _ffn(lp, cfg, h, mesh=mesh, use_pallas=True,
-                 interpret=interpret, tally=moe_tally), cfg,
-        )
-
     # UNROLLED layer loop (per-layer windows / local rope stay
     # trace-static; program count bounded by the prefill buckets)
     for lps, n, goff in layer_groups(params, cfg):
@@ -1880,7 +1796,11 @@ def _mixed_fused_forward(
             o = jnp.concatenate(
                 [o_dec.reshape(B, -1), o_chunks.reshape(MP * T, -1)]
             )
-            x = layer_tail(x, lp, o, lora_l=lora_l)
+            x = _layer_tail(
+                x, lp, cfg, o, lora_l, ids_all, lora_grouped=True,
+                mesh=mesh, use_pallas=True, interpret=interpret,
+                tally=moe_tally,
+            )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits_d = _logits(params, cfg, x[:B])  # [B, V] f32
     # each segment's last REAL row only (the unfused prefill computes
@@ -1894,8 +1814,8 @@ def _mixed_fused_forward(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "use_pallas", "mesh", "unroll", "merged",
-                     "interpret", "with_logprobs", "moe_counters"),
+    static_argnames=("cfg", "use_pallas", "mesh", "interpret",
+                     "with_logprobs", "moe_counters"),
     donate_argnames=("k_cache", "v_cache", "counts"),
 )
 def mixed_step(
@@ -1922,8 +1842,6 @@ def mixed_step(
     v_cache: jnp.ndarray,
     use_pallas: bool = False,
     mesh=None,
-    unroll: bool = True,
-    merged: bool = True,
     interpret: bool = False,
     # sampling penalties (compiled in only when some request asks)
     freq_pens: Optional[jnp.ndarray] = None,  # [B] f32
@@ -1967,8 +1885,8 @@ def mixed_step(
         identity: each segment runs through EXACTLY the unfused prefill
         forward (``prefill.__wrapped__``: same scan/unrolled layer
         loop, same [T]-row GEMMs), in admission order, and the decode
-        batch through EXACTLY ``_decode_body`` with the engine's own
-        ``unroll``/``merged`` flags — so tokens AND logprobs are
+        batch through EXACTLY ``_decode_body`` (which derives the same
+        layer loop from the same inputs) — so tokens AND logprobs are
         BIT-IDENTICAL to the alternating scheduler (the
         tests/test_mixed_batch.py contract; restructured GEMMs would
         reassociate bf16 reductions and flip sampled tokens). All parts
@@ -2058,16 +1976,15 @@ def mixed_step(
         if quantized:
             logits_d, k_cache, v_cache, k_scales, v_scales, _ = _decode_body(
                 params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
-                k_cache, v_cache, use_pallas, mesh, unroll, interpret,
-                merged, k_scales=k_scales, v_scales=v_scales,
+                k_cache, v_cache, use_pallas, mesh, interpret,
+                k_scales=k_scales, v_scales=v_scales,
                 lora=lora, adapter_ids=d_adapter_ids, moe_tally=tally,
             )
         else:
             logits_d, k_cache, v_cache = _decode_body(
                 params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
-                k_cache, v_cache, use_pallas, mesh, unroll, interpret,
-                merged, lora=lora, adapter_ids=d_adapter_ids,
-                moe_tally=tally,
+                k_cache, v_cache, use_pallas, mesh, interpret,
+                lora=lora, adapter_ids=d_adapter_ids, moe_tally=tally,
             )
 
     raw_logits = logits_d
@@ -2123,6 +2040,12 @@ def _verify_forward(
     blk = jnp.take_along_axis(block_tables, pos_bt // bs, axis=1)
     off = pos_bt % bs
 
+    def layer_tail(x, lp, o):
+        return _layer_tail(
+            x.reshape(B * T, E), lp, cfg, o.reshape(B * T, -1), mesh=mesh,
+            use_pallas=use_pallas, interpret=interpret,
+        ).reshape(B, T, E)
+
     if cfg.is_mla:
         # MLA verify: absorbed multi-token attention with the in-flight
         # window OUT of the cache (ops/mla_attention_pallas
@@ -2153,12 +2076,7 @@ def _verify_forward(
                     interpret=interpret,
                 )
                 o = _mla._o_proj(lp, cfg, o).astype(x.dtype)
-                x = x + _mm(o.reshape(B * T, -1), lp["wo"]).reshape(B, T, E)
-                h = pre_norm(lp, "mlp_norm", x, cfg)
-                x = x + _ffn(
-                    lp, cfg, h.reshape(B * T, E), mesh=mesh,
-                    use_pallas=use_pallas, interpret=interpret,
-                ).reshape(B, T, E)
+                x = layer_tail(x, lp, o)
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = _logits(params, cfg, x.reshape(B * T, E)).reshape(B, T, -1)
         # the kernel serves the unsharded Pallas path; a mesh (replicated
@@ -2207,19 +2125,7 @@ def _verify_forward(
                     window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
                     cap=cfg.attn_softcap, interpret=interpret,
                 )
-            x = x + post_norm(
-                lp, "attn_post_norm",
-                _mm_b(o.reshape(B * T, -1), lp, "wo", "bo").reshape(B, T, E),
-                cfg,
-            )
-            h = pre_norm(lp, "mlp_norm", x, cfg)
-            x = x + post_norm(
-                lp, "mlp_post_norm",
-                _ffn(lp, cfg, h.reshape(B * T, E), mesh=mesh,
-                     use_pallas=use_pallas, interpret=interpret,
-                     ).reshape(B, T, E),
-                cfg,
-            )
+            x = layer_tail(x, lp, o)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x.reshape(B * T, E)).reshape(B, T, -1)
 
@@ -2435,7 +2341,6 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
             p = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum("hts,shd->thd", p, v)
             o = o.reshape(T, -1).astype(x.dtype)
-            x = x + _mm(o, lp["wo"])
         else:
             q, k, v = _qkv(lp, cfg, h)
             fr = inv_freq if freqs is None else freqs
@@ -2445,13 +2350,7 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
                 q, k, v, positions, jnp.int32(T), scale,
                 window=window, sinks=lp.get("sinks"), cap=cfg.attn_softcap,
             )
-            x = x + post_norm(
-                lp, "attn_post_norm",
-                _mm_b(o.reshape(T, -1), lp, "wo", "bo"), cfg,
-            )
-        h = pre_norm(lp, "mlp_norm", x, cfg)
-        x = x + post_norm(lp, "mlp_post_norm", _ffn(lp, cfg, h), cfg)
-        return x, None
+        return _layer_tail(x, lp, cfg, o.reshape(T, -1)), None
 
     if cfg.layer_windows:  # per-layer static windows: unrolled
         for lps, n, off in layer_groups(params, cfg):

@@ -119,7 +119,9 @@ def decode_attention(
             k_scales=k_scales, v_scales=v_scales,
         )
     if use_pallas and sinks is None and not cap:
-        return _decode_kernel(
+        from .paged_attention_pallas import paged_decode_attention
+
+        return paged_decode_attention(
             q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
             window=window, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales,
@@ -133,29 +135,6 @@ def decode_attention(
     return decode_attention_xla(
         q, k_cache[layer], v_cache[layer], block_tables, seq_lens, scale,
         window=window, sinks=sinks, cap=cap,
-        k_scales=k_scales, v_scales=v_scales,
-    )
-
-
-def _decode_kernel(
-    q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
-    window: int = 0,
-    interpret: bool = False,
-    k_scales=None, v_scales=None,
-):
-    """The decode kernel: always the in-repo one
-    (ops/paged_attention_pallas) — the kernel the CPU tests run in
-    interpret mode and tests/test_tpu_compile.py compiles for the chip,
-    with window and per-page-scale support. JAX's library
-    ``paged_attention`` used to be tried first with a quiet
-    ``except`` back to this one, so which kernel served depended on an
-    error nobody saw; whether the library kernel earns a place is a
-    measurement (ROADMAP C4), and then a shape rule, never an except."""
-    from .paged_attention_pallas import paged_decode_attention
-
-    return paged_decode_attention(
-        q, k_cache, v_cache, layer, block_tables, seq_lens, scale,
-        window=window, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales,
     )
 
@@ -210,6 +189,8 @@ def paged_decode_attention_sharded(
     sharded axis; every shard reads the same plane)."""
 
     def _local(q, kc, vc, ly, bt, sl, *rest):
+        from .paged_attention_pallas import paged_decode_attention
+
         rest = list(rest)
         ks = vs = s = None
         if k_scales is not None:
@@ -218,7 +199,7 @@ def paged_decode_attention_sharded(
         if rest:
             s = rest[0]
         if s is None:
-            return _decode_kernel(
+            return paged_decode_attention(
                 q, kc, vc, ly, bt, sl, scale, window=window,
                 interpret=interpret, k_scales=ks, v_scales=vs,
             )

@@ -16,16 +16,18 @@ Two implementations behind one call:
     (ops/moe_gmm_pallas.py / models/llama._moe_route). A batch mixing
     k adapters costs one ragged pass, not k dispatches.
   * **loop** — an unrolled per-adapter ``where`` loop. This is the
-    pinned XLA fallback: each row's delta is two plain row GEMMs
-    against its own adapter, so it is BIT-IDENTICAL to running that
-    row in a solo-adapter batch (the tests/test_multi_model.py
-    contract).
+    XLA fallback: each row's delta is two plain row GEMMs against its
+    own adapter, the sums a solo-adapter batch of that row computes.
 
 Both paths are row-local — a row's delta depends only on its own
-activations and its own adapter — so per-adapter streams in a
-mixed-adapter batch match their solo-adapter references bit-for-bit on
-whichever path serves them (same static shapes, same per-row reduction
-order; the standing mixed-batch argument from models/llama.mixed_step).
+activations and its own adapter — so the two lanes, and a row in a
+mixed-adapter batch and in a solo one, compute the same sums over the
+same operands. They agree to the order of those f32 sums, not to the
+bit: another lane or another batch shape may run another dot kernel
+(tests/test_multi_model.py states the bound, 2 gamma_{E+r} sum|x||A||B|;
+this XLA CPU build differs in the last ulp). Base rows (ids < 0) are
+exactly zero on both lanes. Greedy streams of a mixed batch match
+their solo references except at near-ties of that size.
 
 Shape/bucketing contract: ``a`` is ``[NA, E, r]``, ``b`` is
 ``[NA, r, O]``. NA is the engine's adapter-count bucket and r the rank
@@ -59,9 +61,8 @@ def lora_delta(
 
 
 def _delta_loop(x, a, b, ids):
-    """Unrolled per-adapter loop (XLA fallback, pinned bit-identical to
-    solo-adapter dispatch): adapter n's delta is computed for every row
-    and selected where ids == n. NA is small (the adapter bucket) and r
+    """Unrolled per-adapter loop (XLA fallback): adapter n's delta is
+    computed for every row and selected where ids == n. NA is small (the adapter bucket) and r
     tiny, so the redundant row work is noise next to the base GEMMs."""
     NA = a.shape[0]
     wdt = a.dtype

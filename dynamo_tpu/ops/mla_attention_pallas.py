@@ -216,41 +216,6 @@ def mla_paged_decode_attention(
     return out[:, :H, :]
 
 
-def mla_paged_decode_attention_sharded(
-    q_eff: jnp.ndarray,  # [B, H, C], H sharded over tp
-    q_pe: jnp.ndarray,  # [B, H, R], H sharded over tp
-    c_cache_layer: jnp.ndarray,  # [1, N, bs, C] replicated
-    pe_cache_layer: jnp.ndarray,  # [1, N, bs, R] replicated
-    block_tables: jnp.ndarray,  # [B, M] replicated
-    seq_lens: jnp.ndarray,  # [B] replicated
-    scale: float,
-    mesh,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """The latent kernel under shard_map over ``tp``: query heads are
-    the parallel axis (see mla_decode_attention_merged_sharded's note on
-    why the cache replicates), no collectives."""
-    from functools import partial
-
-    from jax.sharding import PartitionSpec as P
-
-    return jax.shard_map(
-        partial(mla_paged_decode_attention, scale=scale,
-                interpret=interpret),
-        mesh=mesh,
-        in_specs=(
-            P(None, "tp", None),  # q_eff
-            P(None, "tp", None),  # q_pe
-            P(),  # c cache
-            P(),  # pe cache
-            P(),  # tables
-            P(),  # seq_lens
-        ),
-        out_specs=P(None, "tp", None),
-        check_vma=False,
-    )(q_eff, q_pe, c_cache_layer, pe_cache_layer, block_tables, seq_lens)
-
-
 def mla_decode_attention_merged(
     q_eff: jnp.ndarray,  # [B, H, C]
     q_pe: jnp.ndarray,  # [B, H, R]

@@ -231,10 +231,8 @@ class StepMirror:
     # ---- fused step programs (shared leader/follower) ----
 
     def _decode_fn(self, n_steps: int = 1, use_pallas: bool = False,
-                   unroll: bool = True, merged: bool = True,
                    penalized: bool = False, with_logprobs: bool = False):
-        key = ("decode", n_steps, use_pallas, unroll, merged, penalized,
-               with_logprobs)
+        key = ("decode", n_steps, use_pallas, penalized, with_logprobs)
         if key not in self._fns:
             import jax
 
@@ -262,8 +260,7 @@ class StepMirror:
                         params, cfg, tokens, positions, tables, seq_lens,
                         seeds, steps, temps, top_ks, top_ps, k_cache,
                         v_cache, n_steps=n_steps, use_pallas=use_pallas,
-                        mesh=mesh, unroll=unroll, merged=merged,
-                        with_logprobs=with_logprobs, freq_pens=freq,
+                        mesh=mesh, with_logprobs=with_logprobs, freq_pens=freq,
                         pres_pens=pres, rep_pens=rep, counts=counts,
                         prompt_mask=prompt_mask,
                     )
@@ -279,8 +276,7 @@ class StepMirror:
                         params, cfg, tokens, positions, tables, seq_lens,
                         seeds, steps, temps, top_ks, top_ps, k_cache,
                         v_cache, n_steps=n_steps, use_pallas=use_pallas,
-                        mesh=mesh, unroll=unroll, merged=merged,
-                        with_logprobs=with_logprobs,
+                        mesh=mesh, with_logprobs=with_logprobs,
                     )
 
                 self._fns[key] = jax.jit(  # dynlint: disable=jit-in-function -- memoized: compiled once per static key
@@ -711,7 +707,6 @@ class StepMirror:
     def lead_decode(self, params, last_tokens, positions, tables, seq_lens,
                     seeds, steps, temps, top_ks, top_ps, k_cache, v_cache,
                     n_steps: int = 1, use_pallas: bool = False,
-                    unroll: bool = True, merged: bool = True,
                     penalties=None, pen_state=None,
                     with_logprobs: bool = False,
                     tokens_dev=None, sync: bool = True):
@@ -736,12 +731,9 @@ class StepMirror:
         if penalized:
             head_arrays += [np.asarray(a, np.float32) for a in penalties]
         self._lead("decode", tuple(head_arrays),
-                   n=n_steps, pallas=use_pallas, unroll=unroll,
-                   merged=merged, penalized=penalized, lp=with_logprobs,
-                   chain=chain)
-        fn = self._decode_fn(
-            n_steps, use_pallas, unroll, merged, penalized, with_logprobs
-        )
+                   n=n_steps, pallas=use_pallas, penalized=penalized,
+                   lp=with_logprobs, chain=chain)
+        fn = self._decode_fn(n_steps, use_pallas, penalized, with_logprobs)
         placed = self.place_inputs(
             "decode", head_arrays, skip=(0,) if chain else ()
         )
@@ -861,8 +853,6 @@ def run_follower(engine_cfg, params: Optional[dict] = None, seed: int = 0) -> No
         elif op == "decode":
             penalized = head.get("penalized", False)
             fn = mirror._decode_fn(head.get("n", 1), head.get("pallas", False),
-                                   head.get("unroll", True),
-                                   head.get("merged", True),
                                    penalized, head.get("lp", False))
             chain = head.get("chain", False)
             placed = mirror.place_inputs(

@@ -260,7 +260,7 @@ def _decode_lower(cfg: ModelConfig, batch: int, ctx: int, block: int = 16):
         jax.ShapeDtypeStruct((batch, M), jnp.int32), i32(batch),
         i32(batch), i32(batch), f32(batch), i32(batch), f32(batch),
         jax.ShapeDtypeStruct(ks, dt), jax.ShapeDtypeStruct(vs, dt),
-        n_steps=1, use_pallas=False, merged=True,
+        n_steps=1, use_pallas=False,
     )
 
 

@@ -2,12 +2,11 @@
 
 Measures fused decode windows (llama.decode_window) on a 1B-class
 dense-MLA config (DeepSeek head geometry: kv_lora 512, rope 64, 16
-heads) for the three paths the engine can take:
+heads) for the two paths the engine can take:
 
   * xla      — absorbed XLA decode (full-table gathers + 2L scatters)
-  * pallas   — latent kernel write-then-attend (per-layer writes)
   * merged   — latent kernel + flash merge + ONE batched append
-               (the engine default on TPU when kv_lora_rank % 128 == 0)
+               (kernels on: TPU and kv_lora_rank % 128 == 0)
 
 Prints one JSON line per path.
 """
@@ -45,15 +44,11 @@ def main() -> None:
     )
     roofline = 819e9 / param_bytes * B  # v5e HBM bw / weight stream
 
-    for label, (up, mg) in {
-        "xla": (False, False),
-        "pallas": (True, False),
-        "merged": (True, True),
-    }.items():
+    for label, up in {"xla": False, "merged": True}.items():
         try:
             tps = time_decode_windows(
                 params, cfg, B=B, BLOCK=BLOCK, CTX=CTX, WINDOW=WINDOW,
-                use_pallas=up, merged=mg, iters=800 // WINDOW,
+                use_pallas=up, iters=800 // WINDOW,
             ) / jax.device_count()  # per-chip, same as bench.py
             print(json.dumps({
                 "metric": f"mla1b_decode_tokens_per_sec_per_chip_{label}",

@@ -83,7 +83,7 @@ def measure(name, cfg, mesh):
         jnp.zeros(B, jnp.float32), zeros, jnp.ones(B, jnp.float32),
         kc, vc,
     )
-    kw = dict(n_steps=WINDOW, use_pallas=False, merged=False, mesh=mesh)
+    kw = dict(n_steps=WINDOW, use_pallas=False, mesh=mesh)
 
     # STRUCTURE: collective census of the compiled program
     compiled = llama.decode_window.lower(*args(k_cache, v_cache), **kw).compile()

@@ -19,6 +19,7 @@ import numpy as np
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops import attention as att
+from dynamo_tpu.ops.paged_attention_pallas import paged_decode_attention
 
 cfg = ModelConfig(
     vocab_size=32768, hidden_size=2048, intermediate_size=8192,
@@ -95,7 +96,7 @@ q = jnp.zeros((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
 scale = cfg.head_dim ** -0.5
 
 lib_att = jax.jit(
-    lambda q, kc, vc: att._decode_kernel(
+    lambda q, kc, vc: paged_decode_attention(
         q, kc, vc, 0, tables, seq_lens_h, scale
     )
 )

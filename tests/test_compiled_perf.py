@@ -54,14 +54,14 @@ def _decode_inputs(cfg):
     )
 
 
-def _export_tpu_text(cfg, inp, *, use_pallas, merged, mesh=None):
+def _export_tpu_text(cfg, inp, *, use_pallas, mesh=None):
     """TPU-platform StableHLO of the real ``llama.decode_window`` jit
     (donate_argnames and all), as text."""
     exp = jexport.export(llama.decode_window, platforms=["tpu"])(
         inp["params"], cfg, inp["tokens"], inp["positions"], inp["tables"],
         inp["seq_lens"], inp["seeds"], inp["steps"], inp["temps"],
         inp["top_ks"], inp["top_ps"], inp["k_cache"], inp["v_cache"],
-        n_steps=NSTEPS, use_pallas=use_pallas, merged=merged, mesh=mesh,
+        n_steps=NSTEPS, use_pallas=use_pallas, mesh=mesh,
     )
     return exp.mlir_module()
 
@@ -99,7 +99,7 @@ def test_merged_decode_is_scatter_free_on_tpu():
     lowering. head_dim=128 matches the engine's kernel gate."""
     cfg = ModelConfig.tiny(dtype="bfloat16", head_dim=128)
     inp = _decode_inputs(cfg)
-    text = _export_tpu_text(cfg, inp, use_pallas=True, merged=True)
+    text = _export_tpu_text(cfg, inp, use_pallas=True)
     shape_res = _cache_shape_res(inp["k_cache"], inp["v_cache"])
 
     assert text.count("tpu_custom_call") >= 2, (
@@ -123,7 +123,7 @@ def test_merged_decode_sharded_tp_is_scatter_free_on_tpu():
     cfg = ModelConfig.tiny(dtype="bfloat16", head_dim=128)
     inp = _decode_inputs(cfg)
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
-    text = _export_tpu_text(cfg, inp, use_pallas=True, merged=True, mesh=mesh)
+    text = _export_tpu_text(cfg, inp, use_pallas=True, mesh=mesh)
     shape_res = _cache_shape_res(inp["k_cache"], inp["v_cache"])
     assert text.count("tpu_custom_call") >= 2
     assert text.count("output_operand_alias") >= 2
@@ -135,7 +135,7 @@ def test_mla_merged_decode_is_scatter_free_on_tpu():
     one aliased append (kv_lora_rank=128 engages the engine gate)."""
     cfg = ModelConfig.tiny_mla(dtype="bfloat16", kv_lora_rank=128)
     inp = _decode_inputs(cfg)
-    text = _export_tpu_text(cfg, inp, use_pallas=True, merged=True)
+    text = _export_tpu_text(cfg, inp, use_pallas=True)
     shape_res = _cache_shape_res(inp["k_cache"], inp["v_cache"])
     assert text.count("tpu_custom_call") >= 2
     assert text.count("output_operand_alias") >= 2
@@ -148,7 +148,7 @@ def test_xla_fallback_trips_the_scatter_detector():
     detector, the regexes rotted and the positive tests prove nothing."""
     cfg = ModelConfig.tiny(dtype="bfloat16", head_dim=128)
     inp = _decode_inputs(cfg)
-    text = _export_tpu_text(cfg, inp, use_pallas=False, merged=False)
+    text = _export_tpu_text(cfg, inp, use_pallas=False)
     shape_res = _cache_shape_res(inp["k_cache"], inp["v_cache"])
     assert _full_cache_scatters(text, shape_res), (
         "scatter detector no longer matches the known-scatter XLA path"
@@ -167,7 +167,7 @@ def test_cpu_compiled_executable_aliases_both_caches():
         inp["params"], cfg, inp["tokens"], inp["positions"], inp["tables"],
         inp["seq_lens"], inp["seeds"], inp["steps"], inp["temps"],
         inp["top_ks"], inp["top_ps"], inp["k_cache"], inp["v_cache"],
-        n_steps=NSTEPS, use_pallas=False, merged=True,
+        n_steps=NSTEPS, use_pallas=False,
     ).compile()
     text = compiled.as_text()
     header = text.splitlines()[0]
@@ -477,7 +477,7 @@ def test_pp_decode_moves_activations_not_weights():
         params, cfg, inp["tokens"], inp["positions"], inp["tables"],
         inp["seq_lens"], inp["seeds"], inp["steps"], inp["temps"],
         inp["top_ks"], inp["top_ps"], k_cache, v_cache,
-        n_steps=NSTEPS, use_pallas=False, merged=False, mesh=mesh,
+        n_steps=NSTEPS, use_pallas=False, mesh=mesh,
     ).compile()
     text = compiled.as_text()
     assert "collective-permute" in text, (

@@ -298,26 +298,51 @@ def test_unservable_request_finishes_instead_of_hanging(run):
     run(main())
 
 
-def test_decode_unrolled_matches_scan(run, engine_cfg):
-    """The unrolled decode layer loop (in-place cache scatters) must
-    produce the exact token stream of the scan variant."""
+@pytest.mark.parametrize(
+    "gone", ["decode_layer_scan", "decode_merged", "kv_head_layout"]
+)
+def test_removed_engine_options_are_type_errors(gone):
+    """Nothing selects the decode layer loop (``_decode_body`` derives it
+    from ``use_pallas`` and the model) and the cache's kv-head order is
+    a constant (``llama.KV_HEAD_LAYOUT``; foreign layouts are declared
+    on the transfer metadata): the three options are not fields."""
+    with pytest.raises(TypeError, match=gone):
+        EngineConfig(model=ModelConfig.tiny(), **{gone: "interleaved"})
 
-    async def main():
-        from dataclasses import replace
 
-        outs = {}
-        for scan in (False, True):
-            cfg = replace(engine_cfg, decode_layer_scan=scan)
-            engine = JaxEngine(cfg, seed=0)
-            # greedy: the two variants are separate XLA compilations, so
-            # last-ulp logit differences are possible; argmax is robust
-            req = make_req(range(40, 52), max_tokens=7)
-            out = await collect(engine.generate(Context(req)))
-            outs[scan] = [t for o in out for t in o.token_ids]
-            await engine.close()
-        assert outs[False] == outs[True]
+def test_mirror_decode_header_names_no_layer_loop():
+    """The multi-host mirror's decode op: leader and followers derive
+    the layer loop from the same inputs, so the wire header carries
+    neither ``unroll`` nor ``merged`` and the leader's program runs."""
+    import numpy as np
 
-    run(main())
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+    from dynamo_tpu.parallel.multihost import StepMirror
+
+    cfg = ModelConfig.tiny()
+    mirror = StepMirror(make_mesh(MeshConfig(tp=2)), cfg)
+    heads = []
+    mirror._lead = lambda op, arrays, **extra: heads.append((op, extra))
+    B, M, bs = 2, 4, 4
+    params = mirror.shard_params(llama.init_params(cfg, jax.random.key(0)))
+    kc, vc = mirror.init_cache(B * M + 1, bs)
+    i32 = lambda *v: np.asarray(v, np.int32)  # noqa: E731
+    toks, kc, vc = mirror.lead_decode(
+        params, i32(3, 5), i32(0, 0),
+        np.arange(1, B * M + 1, dtype=np.int32).reshape(B, M), i32(1, 1),
+        i32(0, 0), i32(0, 0), np.zeros(B, np.float32), i32(0, 0),
+        np.ones(B, np.float32), kc, vc, n_steps=2,
+    )
+    assert toks.shape == (2, B)
+    (op, extra), = heads
+    assert op == "decode"
+    assert sorted(extra) == ["chain", "lp", "n", "pallas", "penalized"]
+
+    import inspect
+
+    for fn in (mirror.lead_decode, mirror._decode_fn):
+        assert not {"unroll", "merged"} & set(inspect.signature(fn).parameters)
 
 
 def test_commit_respects_written_horizon(run, engine_cfg, shared_engine):

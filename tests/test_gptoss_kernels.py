@@ -191,7 +191,7 @@ def test_gptoss_decode_window_pallas_matches_xla():
     )
     seq_len0 = 11
 
-    def run(use_pallas, merged):
+    def run(use_pallas):
         k_cache, v_cache = llama.init_kv_cache(cfg, NUM_BLOCKS, BLOCK)
         # seed some history so windows bind
         k_cache = k_cache + 0.01
@@ -206,13 +206,13 @@ def test_gptoss_decode_window_pallas_matches_xla():
             jnp.zeros(B, jnp.float32), jnp.zeros(B, jnp.int32),
             jnp.ones(B, jnp.float32),
             k_cache, v_cache,
-            n_steps=4, use_pallas=use_pallas, merged=merged,
+            n_steps=4, use_pallas=use_pallas,
             interpret=True,
         )
         return np.asarray(toks), np.asarray(k_cache), np.asarray(v_cache)
 
-    toks_ref, kc_ref, vc_ref = run(use_pallas=False, merged=False)
-    toks_got, kc_got, vc_got = run(use_pallas=True, merged=True)
+    toks_ref, kc_ref, vc_ref = run(use_pallas=False)
+    toks_got, kc_got, vc_got = run(use_pallas=True)
     np.testing.assert_array_equal(toks_got, toks_ref)
     np.testing.assert_allclose(kc_got, kc_ref, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(vc_got, vc_ref, rtol=1e-4, atol=1e-4)

@@ -739,11 +739,10 @@ def test_mla_paged_engine_matches_dense(tmp_path):
         cur.append(int(np.argmax(np.asarray(lg[-1]))))
     want = cur[len(prompt):]
 
-    async def main(layer_scan: bool):
+    async def main():
         engine = JaxEngine(
             EngineConfig(model=cfg, num_blocks=32, block_size=4,
-                         max_batch_size=2, max_context=64, prefill_chunk=8,
-                         decode_layer_scan=layer_scan),
+                         max_batch_size=2, max_context=64, prefill_chunk=8),
             params=params,
         )
         out = await collect(engine.generate(Context(PreprocessedRequest(
@@ -753,8 +752,7 @@ def test_mla_paged_engine_matches_dense(tmp_path):
             eos_token_ids=[],
         ))))
         toks = [t for o in out for t in o.token_ids]
-        assert toks == want, (layer_scan, toks, want)
+        assert toks == want, (toks, want)
         await engine.close()
 
-    asyncio.run(main(False))  # unrolled MLA decode
-    asyncio.run(main(True))  # layer-scan MLA decode
+    asyncio.run(main())

@@ -130,7 +130,7 @@ def test_disagg_delivery_applies_regroup(run, streamed):
         decode_engine = JaxEngine(
             EngineConfig(
                 model=mcfg, num_blocks=64, block_size=4, max_batch_size=2,
-                max_context=128, kv_head_layout="blocked",
+                max_context=128,
             ),
             seed=0,
         )
@@ -180,16 +180,6 @@ def test_disagg_delivery_applies_regroup(run, streamed):
         await drt.shutdown()
 
     run(main())
-
-
-def test_native_engine_rejects_foreign_layout():
-    import pytest
-
-    from dynamo_tpu.engine import EngineConfig
-    from dynamo_tpu.models.config import ModelConfig
-
-    with pytest.raises(ValueError, match="blocked"):
-        EngineConfig(model=ModelConfig.tiny(), kv_head_layout="interleaved")
 
 
 def test_interleaved_same_layout_different_tp_not_identity():

@@ -92,9 +92,7 @@ def test_mla_merged_decode_stream_matches_xla_path():
 
     streams = {}
     caches = {}
-    for label, (up, mg) in {
-        "xla": (False, False), "merged": (True, True)
-    }.items():
+    for label, up in {"xla": False, "merged": True}.items():
         kc, vc = jnp.copy(kc0), jnp.copy(vc0)
         # teacher-forced history
         for p in range(int(seq_lens0.max())):
@@ -103,7 +101,7 @@ def test_mla_merged_decode_stream_matches_xla_path():
             lens = jnp.minimum(positions + 1, seq_lens0)
             _, kc, vc = llama.decode_step(
                 params, cfg, toks, positions, tables, lens, kc, vc,
-                use_pallas=up, interpret=up, merged=mg,
+                use_pallas=up, interpret=up,
             )
         # greedy continuation
         toks = jnp.asarray(hist_tokens[np.arange(B), np.asarray(seq_lens0) - 1])
@@ -113,7 +111,7 @@ def test_mla_merged_decode_stream_matches_xla_path():
             positions = lens - 1
             logits, kc, vc = llama.decode_step(
                 params, cfg, toks, positions, tables, lens + 0, kc, vc,
-                use_pallas=up, interpret=up, merged=mg,
+                use_pallas=up, interpret=up,
             )
             toks = jnp.argmax(logits, axis=-1)
             out.append(np.asarray(toks))
@@ -168,8 +166,9 @@ def test_mla_merged_sharded_matches_single_device():
 
 
 def test_mla_pallas_decode_on_tp_mesh_matches_single_device():
-    """Model-level: MLA decode with the Pallas path on a tp=2 mesh
-    (merged AND non-merged) must match the single-device XLA stream."""
+    """Model-level: MLA decode with the Pallas path on a tp=2 mesh (the
+    merged loop: the one kernels-on MLA loop) must match the
+    single-device XLA stream."""
     from jax.sharding import Mesh
 
     cfg = ModelConfig.tiny_mla(dtype="float32")
@@ -181,10 +180,9 @@ def test_mla_pallas_decode_on_tp_mesh_matches_single_device():
     mesh = Mesh(devs, ("dp", "tp", "pp", "sp", "ep"))
 
     streams = {}
-    for label, (msh, up, mg) in {
-        "ref": (None, False, False),
-        "mesh-merged": (mesh, True, True),
-        "mesh-plain": (mesh, True, False),
+    for label, (msh, up) in {
+        "ref": (None, False),
+        "mesh-merged": (mesh, True),
     }.items():
         kc, vc = llama.init_kv_cache(cfg, N, BS)
         toks = jnp.asarray([5, 9], jnp.int32)
@@ -193,14 +191,13 @@ def test_mla_pallas_decode_on_tp_mesh_matches_single_device():
         for t in range(T):
             logits, kc, vc = llama.decode_step(
                 params, cfg, toks, lens - 1, tables, lens, kc, vc,
-                use_pallas=up, mesh=msh, interpret=up, merged=mg,
+                use_pallas=up, mesh=msh, interpret=up,
             )
             toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out.append(np.asarray(toks))
             lens = lens + 1
         streams[label] = np.stack(out, axis=1)
     np.testing.assert_array_equal(streams["ref"], streams["mesh-merged"])
-    np.testing.assert_array_equal(streams["ref"], streams["mesh-plain"])
 
 
 def test_mla_prefill_kernel_matches_xla():
@@ -308,35 +305,6 @@ def test_mla_verify_attention_matches_write_then_attend():
                 rtol=2e-5, atol=2e-5,
                 err_msg=f"use_pallas={use_pallas} t={t}",
             )
-
-
-def test_mla_pallas_decode_scan_path_matches_unrolled():
-    """decode_layer_scan (unroll=False) routes MLA attention through the
-    latent kernel inside lax.scan; its stream must match the unrolled
-    XLA path."""
-    cfg = ModelConfig.tiny_mla(dtype="float32")
-    B, M, T = 2, 4, 4
-    params = llama.init_params(cfg, jax.random.key(14))
-    N = B * M + 1
-    tables = jnp.asarray(np.arange(1, N, dtype=np.int32).reshape(B, M))
-    streams = {}
-    for label, (up, unroll) in {
-        "ref": (False, True), "scan-pallas": (True, False),
-    }.items():
-        kc, vc = llama.init_kv_cache(cfg, N, BS)
-        toks = jnp.asarray([3, 11], jnp.int32)
-        lens = jnp.asarray([1, 1], jnp.int32)
-        out = []
-        for _ in range(T):
-            logits, kc, vc = llama.decode_step(
-                params, cfg, toks, lens - 1, tables, lens, kc, vc,
-                use_pallas=up, unroll=unroll, interpret=up, merged=False,
-            )
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(np.asarray(toks))
-            lens = lens + 1
-        streams[label] = np.stack(out, axis=1)
-    np.testing.assert_array_equal(streams["ref"], streams["scan-pallas"])
 
 
 def test_mla_kernel_stats_power_the_merge():

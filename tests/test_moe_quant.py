@@ -179,7 +179,7 @@ def _export_decode_text(cfg, qparams, use_pallas):
         jax.ShapeDtypeStruct((B, M), jnp.int32), i32(B),
         i32(B), i32(B), f32(B), i32(B), f32(B),
         jax.ShapeDtypeStruct(ks, dt), jax.ShapeDtypeStruct(vs, dt),
-        n_steps=2, use_pallas=use_pallas, merged=use_pallas,
+        n_steps=2, use_pallas=use_pallas,
     )
     return exp.mlir_module()
 

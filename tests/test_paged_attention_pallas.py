@@ -578,7 +578,7 @@ def _eqns(jaxpr):
 
 @pytest.mark.parametrize(
     "program", ["decode_window-dense", "decode_window-experts",
-                "decode_step", "decode_step-unmerged", "verify_window"]
+                "decode_step", "verify_window"]
 )
 def test_step_programs_hand_the_kernel_the_whole_cache(program):
     """The slab must not come back: in the decode programs every cache
@@ -612,11 +612,10 @@ def test_step_programs_hand_the_kernel_the_whole_cache(program):
                 moe_counters=program.endswith("experts"), **kw,
             )
         )(params, kc, vc)
-    elif program.startswith("decode_step"):
+    elif program == "decode_step":
         jaxpr = jax.make_jaxpr(
             lambda p, k, v: llama.decode_step(
-                p, cfg, ints, ints, tables, ints, k, v,
-                merged=not program.endswith("unmerged"), **kw,
+                p, cfg, ints, ints, tables, ints, k, v, **kw,
             )
         )(params, kc, vc)
     else:
@@ -645,3 +644,54 @@ def test_step_programs_hand_the_kernel_the_whole_cache(program):
             )
     # attention of every layer (+ the merged paths' one append)
     assert kernels >= cfg.num_layers
+
+
+@pytest.mark.parametrize("use_pallas", [True, False], ids=["kernels", "xla"])
+@pytest.mark.parametrize("family", ["gqa", "mla", "softcap"])
+def test_decode_layer_loop_follows_what_the_program_observes(
+    family, use_pallas
+):
+    """``_decode_body``'s one rule, as a test: kernels on and no softcap
+    -> the MERGED loop of the cache kind (exactly one ``pallas_call``
+    writes the caches, all layers at once, and no XLA scatter lands in
+    them); otherwise WRITE-THEN-ATTEND (one scatter a layer into K and
+    into V, attention by XLA: no ``pallas_call`` at all). Nothing but
+    ``use_pallas`` and the model selects the loop."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = {
+        "gqa": lambda: ModelConfig.tiny(num_layers=3),
+        "mla": lambda: ModelConfig.tiny_mla(num_layers=3),
+        "softcap": lambda: ModelConfig.tiny(num_layers=3, attn_softcap=30.0),
+    }[family]()
+    B, M, N, bs = 4, 8, 40, 16
+    params = llama.init_params(cfg, jax.random.key(0))
+    kc, vc = llama.init_kv_cache(cfg, N, bs)
+    ints = jnp.ones((B,), jnp.int32)
+    floats = jnp.ones((B,), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, k, v: llama.decode_window(
+            p, cfg, ints, ints, jnp.ones((B, M), jnp.int32), ints, ints,
+            ints, floats, ints, floats, k, v, n_steps=2,
+            use_pallas=use_pallas, interpret=use_pallas,
+        )
+    )(params, kc, vc)
+    caches = {kc.shape, vc.shape}
+
+    def writes_cache(eqn):
+        return any(getattr(v.aval, "shape", None) in caches
+                   for v in eqn.outvars)
+
+    eqns = list(_eqns(jaxpr.jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    appends = [e for e in kernels if writes_cache(e)]
+    scatters = [e for e in eqns
+                if e.primitive.name.startswith("scatter") and writes_cache(e)]
+    if use_pallas and family != "softcap":
+        assert len(appends) == 1 and not scatters
+        assert len(kernels) == cfg.num_layers + 1  # + attention a layer
+    else:
+        assert not kernels
+        assert len(scatters) == 2 * cfg.num_layers
+

@@ -32,9 +32,12 @@ from dynamo_tpu.runtime import (
 
 @pytest.fixture(autouse=True)
 def _reset_recorder():
-    """Tracing state is process-global; every test starts dark."""
+    """Tracing state is process-global; every test starts dark, and
+    leaves the ring as long as it found it (a ring left at 8 spans
+    fails whichever file the worker runs next that counts spans)."""
+    maxlen = tracing.RECORDER._ring.maxlen
     yield
-    tracing.RECORDER.configure(enabled=False, sink=None)
+    tracing.RECORDER.configure(enabled=False, sink=None, maxlen=maxlen)
     tracing.RECORDER.clear()
 
 
