@@ -74,6 +74,13 @@ WORK_COUNTERS = ("rows_dispatched", "rows_live", "prefill_tokens_dispatched",
 MOE_COUNTERS = ("moe_expert_slots", "moe_assignments", "moe_experts_touched",
                 "moe_experts_touched_live", "moe_max_group_rows")
 
+#: the same for a model whose conv layers carry a per-sequence state
+#: (LFM2; absent otherwise): prompt tokens whose block hashes matched
+#: the prefix cache, blocks committed with their state's snapshot, and
+#: admissions that started from a snapshot
+STATE_COUNTERS = ("prefix_matched_tokens", "state_snapshots",
+                  "state_restores")
+
 # the prefill-admission first-token sampler, jitted ONCE at module scope:
 # a per-call ``jax.jit(sample_first_token)`` built a fresh wrapper (and a
 # fresh trace cache) on every admission, so every prefill paid a retrace
@@ -124,6 +131,17 @@ def _dequant_gathered(pages, scales, dtype):
     return (
         pages.astype(jnp.float32) * scales[:, None, :, None, None]
     ).astype(dtype)
+
+
+@_partial(jax.jit, donate_argnames=("state",))
+def _begin_state_row(state, slot, blk):
+    """A sequence takes row ``slot`` of the conv state (llama.init_state)
+    for its prefill: the snapshot of block ``blk``, the last of its
+    cached prefix, or zeros for a prompt that starts from token 0
+    (``blk`` < 0)."""
+    snap = state["snap"]
+    row = jnp.where(blk >= 0, snap[jnp.maximum(blk, 0)], 0)
+    return {"conv": state["conv"].at[slot].set(row), "snap": snap}
 
 
 @jax.jit
@@ -398,6 +416,9 @@ class _Sequence:
     # moving any block out of its model's prefix namespace.
     adapter_id: int = -1
     model: str = ""
+    # the row of the conv state (a decode slot's) this sequence holds
+    # from its first prefill chunk on; -1: none (no conv layers)
+    state_slot: int = -1
     finished: bool = False
     arrival_t: float = field(default_factory=time.monotonic)
     # request trace (tracing.TraceContext), captured at generate() entry
@@ -422,6 +443,7 @@ class JaxEngine(AsyncEngine):
     ):
         self.cfg = cfg
         mcfg = cfg.model
+        self._refuse_stateful(mirror)
         # multi-host: a StepMirror (parallel/multihost.py) makes this engine
         # the leader of a process-spanning mesh — every device dispatch is
         # broadcast to follower ranks which replay the identical jit call
@@ -459,6 +481,12 @@ class JaxEngine(AsyncEngine):
             if sh is not None:
                 k, v = jax.device_put(k, sh), jax.device_put(v, sh)
         self.k_cache, self.v_cache = k, v
+        # the per-sequence state that is not keys and values (LFM2's
+        # conv layers; None otherwise): a row a decode slot, and a
+        # snapshot a KV block (llama.init_state). A sequence holds its
+        # row from its first prefill chunk on (_Sequence.state_slot)
+        self.state = llama.init_state(
+            mcfg, cfg.max_batch_size, cfg.num_blocks)
         # int8-with-scales DEVICE cache (kv_cache_dtype="int8"): per-page
         # f32 scale planes [L, N] — one symmetric absmax scale per
         # (layer, physical page) per K/V, the tier codec's exact
@@ -778,13 +806,14 @@ class JaxEngine(AsyncEngine):
         # and counts nothing). The counters come back as device arrays:
         # they wait here, with what the host counted at the dispatch,
         # until the step that finds them ready folds them into stats
-        self._moe_layers = (
-            mcfg.num_layers - mcfg.first_dense_layers
-            if mcfg.is_moe and mirror is None else 0
-        )
+        self._moe_layers = mcfg.moe_layers if mirror is None else 0
         self._moe_pending: deque = deque()
+        self._state_rows = 0
+        self._device_awaits = 0  # waits for the device so far (_on_device)
         if self._moe_layers:
             self.stats.update(dict.fromkeys(MOE_COUNTERS, 0))
+        if self.state is not None:
+            self.stats.update(dict.fromkeys(STATE_COUNTERS, 0))
         # the loop's clock (tracing/loop_clock.py): seconds by phase,
         # dispatches by kind and slow steps, kept in self.stats
         self._clock = LoopClock(self.stats, tracing.RECORDER)
@@ -841,6 +870,43 @@ class JaxEngine(AsyncEngine):
                         path["reason"])
         self.attention_path = path
         return not why_not
+
+    def _refuse_stateful(self, mirror) -> None:
+        """A model whose layers carry a per-sequence state beside keys
+        and values (LFM2's conv layers) is served by the scheduler, the
+        allocator, the prefix cache, chunked and mixed prefill and
+        preemption. What cannot carry the state yet refuses the model
+        here, by name — none of it is bypassed in silence."""
+        cfg = self.cfg
+        if not cfg.model.conv_layers:
+            return
+        asked = [name for name, on in (
+            ("spec_gamma (the verify forward)", cfg.spec_gamma > 0),
+            ("ring_prefill_threshold (ring prefill)",
+             cfg.ring_prefill_threshold > 0),
+            ("mesh (tp / ep / pp / sp sharding)", cfg.mesh is not None),
+            ("the multi-host mirror", mirror is not None),
+            ("host_cache_blocks / disk_cache_blocks (the KV tiers)",
+             cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0),
+            ("adapters", bool(cfg.adapters)),
+            ("kv_cache_dtype=int8 (the scale planes)",
+             cfg.kv_cache_dtype == "int8"),
+        ) if on]
+        if asked:
+            raise ValueError(
+                f"{', '.join(asked)}: not supported for a model with conv "
+                f"layers ({cfg.model.conv_layers} of "
+                f"{cfg.model.num_layers} here): their per-sequence state "
+                "rides the scheduler, the allocator and the prefix cache "
+                "only")
+
+    def _no_state_transfer(self, what: str) -> None:
+        """The disaggregation and resharding hooks move keys and values
+        between engines; a conv layer's state has no lane there."""
+        if self.state is not None:
+            raise ValueError(
+                f"{what}: not supported for a model with conv layers (the "
+                "KV wire carries no conv state)")
 
     def _pallas_gate(self, mesh) -> Optional[str]:
         """None when the Pallas kernels serve ``mesh``; otherwise the
@@ -942,6 +1008,11 @@ class JaxEngine(AsyncEngine):
                 f"steps_{kind}"]
         for name in WORK_COUNTERS + (MOE_COUNTERS if self._moe_layers else ()):
             out[f"engine_{name}_total"] = self.stats[name]
+        if self.state is not None:
+            for name in STATE_COUNTERS:
+                out[f"engine_{name}_total"] = self.stats[name]
+            out["engine_state_bytes"] = sum(
+                a.size * a.dtype.itemsize for a in self.state.values())
         for e in self.compile_ledger:
             key = ",".join(str(k) for k in e["key"]).replace('"', "'")
             out[f'engine_compiled_program_ms{{kind="{e["kind"]}",'
@@ -1560,6 +1631,7 @@ class JaxEngine(AsyncEngine):
         Multi-host mirrors raise :class:`ReshardUnsupported` — their
         callers drain-with-handoff instead.  Returns the morph stats
         dict ({"changed", "kv_moved_blocks", "hold_ms", ...})."""
+        self._no_state_transfer("reshard")
         if self.mirror is not None:
             raise ReshardUnsupported(
                 "multi-host mirrored engines cannot morph live; drain "
@@ -1780,6 +1852,7 @@ class JaxEngine(AsyncEngine):
         for the device lock is lag too. Comes back to the open phase."""
         clk = self._clock
         prev = clk.await_thunk()
+        self._device_awaits += 1
         try:
             async with self._device_lock if lock else contextlib.nullcontext():
                 return await asyncio.get_running_loop().run_in_executor(
@@ -1829,9 +1902,21 @@ class JaxEngine(AsyncEngine):
                 # still a fused dispatch (_mixed_fusable covers it) —
                 # the queued prompts advance TOGETHER instead of
                 # head-of-line blocking behind states[0]
+                awaited = self._device_awaits
                 if self._n_active or self._mixed_fusable():
                     await self._decode_once()
-                # yield to the event loop so emissions flush
+                if self._device_awaits != awaited and (
+                    self._n_active or self._prefill_states
+                ):
+                    # the tokens this step emitted flush while the NEXT
+                    # dispatch runs: every wait for the device hands the
+                    # event loop to the streams, so yielding to them here
+                    # as well only keeps the device idle for as long as
+                    # they take (a millisecond a live stream and
+                    # dispatch: PERF.md section 6, PR 33)
+                    continue
+                # nothing waited for the device: yield to the event
+                # loop so emissions flush and requests are heard
                 clk.mark("yield")
                 await asyncio.sleep(0)
         except asyncio.CancelledError:
@@ -2122,6 +2207,11 @@ class JaxEngine(AsyncEngine):
             return None
         seq.blocks = matched + fresh
         seq.committed = len(matched)
+        if self.state is not None:
+            # what the hashes matched, counted where they match: what a
+            # prefill then skips of it (prefix_cache_hits_tokens) is
+            # _begin_prefill's to say
+            self.stats["prefix_matched_tokens"] += len(matched) * bs
         # no device match: the chain restarts from its model-salted root
         # (None for base traffic — byte-identical to pre-multi-model)
         seq.parent_hash = (
@@ -2146,6 +2236,15 @@ class JaxEngine(AsyncEngine):
             return False
         history, upload = reserved
         self.stats["prefix_cache_hits_tokens"] += history
+        if self.state is not None:
+            # every matched block has its snapshot (ConvTrack), so all
+            # the matched tokens are skipped; the row is one no sequence
+            # decodes in and no other prefill holds
+            self.stats["state_restores"] += history > 0
+            held = {st.seq.state_slot for st in self._prefill_states}
+            seq.state_slot = next(
+                i for i, s in enumerate(self._active)
+                if s is None and i not in held)
         if seq.generated == 0:
             # admission latency: arrival -> blocks reserved, reconstructed
             # backwards so the span's start anchors at arrival time. A
@@ -2210,6 +2309,7 @@ class JaxEngine(AsyncEngine):
                     prompt_tokens=seq.prompt_len,
                     cached_prefix=seq.cached_prefix,
                     step=self._clock.seq,
+                    **self._restored_attr(seq),
                 )
         self._drop_prefill_state(st)
         self._commit_full_blocks(seq)
@@ -2246,6 +2346,7 @@ class JaxEngine(AsyncEngine):
         self._drop_prefill_state(st)
         self.allocator.free(seq.blocks)
         seq.blocks = []
+        seq.state_slot = -1
         self._rollback_upload(st)
         seq.out_queue.put_nowait(
             LLMEngineOutput(finish_reason=reason, text=text)
@@ -2273,6 +2374,7 @@ class JaxEngine(AsyncEngine):
                 st.upload if not st.restored else None, seq=st.seq
             )
             st.restored = True
+            self._state_preamble(st)
             p0, t_c = st.pos, time.perf_counter()
             logits, st.pos = self._run_one_chunk(st.seq, st.pos)
             if self.cost is not None and st.pos > p0:
@@ -2289,6 +2391,34 @@ class JaxEngine(AsyncEngine):
             # interleave with other requests' decode steps, so the
             # traced prefill component must not absorb that wall time
             st.dev_ms += (time.perf_counter() - t0) * 1e3
+
+    def _state_preamble(self, st: _PrefillState) -> None:
+        """Before a sequence's first prefill chunk: its row of the conv
+        state starts from the snapshot of its cached prefix's last
+        block, or from zeros."""
+        if self.state is None or st.state_ready:
+            return
+        st.state_ready = True
+        seq = st.seq
+        n = seq.cached_prefix // self.cfg.block_size
+        self.state = _begin_state_row(
+            self.state, jnp.int32(seq.state_slot),
+            jnp.int32(seq.blocks[n - 1].idx if n else -1))
+
+    def _restored_attr(self, seq: _Sequence) -> dict:
+        """``engine.prefill``'s ``restored``: prompt tokens whose conv
+        state came from a snapshot (a model with conv layers only)."""
+        return {} if self.state is None else {"restored": seq.cached_prefix}
+
+    def _state_kw(self, seq: Optional[_Sequence] = None) -> dict:
+        """The step programs' keywords for a model with conv layers: the
+        state and, for ``seq``'s prefill chunk, its row."""
+        if self.state is None:
+            return {}
+        kw = {"state": self.state}
+        if seq is not None:
+            kw["slot"] = jnp.int32(seq.state_slot)
+        return kw
 
     def _offload_preamble(self, upload=None, seq: Optional[_Sequence] = None) -> None:
         """Dispatch d2h gathers for every pending eviction before this
@@ -2531,13 +2661,18 @@ class JaxEngine(AsyncEngine):
                 use_ring=ring,
                 **self._lora_prefill_kw(seq.adapter_id),
                 **self._moe_kw(),
+                **self._state_kw(seq),
             ),
             key=("prefill", T, ring) + self._lora_key(),
             trace=seq.trace,
         )
         logits, self.k_cache, self.v_cache = out[:3]
+        rest = list(out[3:])
+        if self.state is not None:
+            self.state = rest.pop(0)
+            self._state_rows += 1
         if self._moe_layers:
-            self._note_moe(out[3], 1, len(chunk))
+            self._note_moe(rest.pop(0), 1, len(chunk))
         return logits, pos + len(chunk)
 
     def _prefill_device(
@@ -2657,7 +2792,11 @@ class JaxEngine(AsyncEngine):
         return token, entry
 
     def _place_in_batch(self, seq: _Sequence) -> None:
-        slot = self._active.index(None)
+        # a sequence with conv state decodes in the row its prefill left
+        # the state in
+        slot = (seq.state_slot if seq.state_slot >= 0
+                else self._active.index(None))
+        assert self._active[slot] is None
         seq.slot = slot
         self._active[slot] = seq
         self._n_active += 1
@@ -2812,6 +2951,7 @@ class JaxEngine(AsyncEngine):
         PCIe (the scales are non-None exactly then); the serving side
         adopts them when the wire codec matches and re-encodes (counted
         in ``kv_device_export_requant_total``) when it doesn't."""
+        self._no_state_transfer("export_device_chain (fleet prefix cache)")
         if self.mirror is not None or not seq_hashes or self._closed:
             return [], None, None, None, None
         # claim refs via the allocator's own chain matcher (hashes are
@@ -3522,6 +3662,7 @@ class JaxEngine(AsyncEngine):
                         prompt_tokens=seq_p.prompt_len,
                         cached_prefix=seq_p.cached_prefix,
                         step=self._clock.seq,
+                        **self._restored_attr(seq_p),
                     )
             self._drop_prefill_state(st)
             self._commit_full_blocks(seq_p)
@@ -3637,6 +3778,15 @@ class JaxEngine(AsyncEngine):
                     d_adapter_ids=jnp.asarray(self._adapter_ids),
                     p_adapter_ids=jnp.asarray(p_ids),
                 )
+            if self.state is not None:
+                # a dead segment names a row past the state: dropped
+                slots_p = np.full(MP, cfg.max_batch_size, np.int32)
+                for i, (st, _take) in enumerate(packed):
+                    self._state_preamble(st)
+                    slots_p[i] = st.seq.state_slot
+                kwargs.update(self._state_kw(), p_slots=jnp.asarray(slots_p))
+                self._state_rows += len(packed) + int(
+                    (self._seq_lens > 0).sum())
             self._note_prefill_work(MP * T, int(valids_p.sum()))
             self._note_decode_work(1, self._seq_lens, seg_pages=(
                 MP * cfg.max_blocks_per_seq,
@@ -3670,6 +3820,8 @@ class JaxEngine(AsyncEngine):
                 + self._lora_key())
             toks, p_logits, self.k_cache, self.v_cache = out[:4]
             rest = list(out[4:])
+            if self.state is not None:
+                self.state = rest.pop(0)
             if quantized:
                 self.k_scales = rest.pop(0)
                 self.v_scales = rest.pop(0)
@@ -3776,6 +3928,10 @@ class JaxEngine(AsyncEngine):
         as the span's ``moe``, the routing counters that had come back
         from the device by now (a pipelined window's arrive with the
         step that emits its tokens)."""
+        attrs = {}
+        if self.state is not None:
+            # sequences whose conv state the step's dispatches advanced
+            attrs["state"], self._state_rows = self._state_rows, 0
         moe = dict.fromkeys(MOE_COUNTERS, 0)
         while self._moe_pending and self._moe_pending[0][0].is_ready():
             sums, slots, assignments = self._moe_pending.popleft()
@@ -3783,11 +3939,12 @@ class JaxEngine(AsyncEngine):
                                (slots, assignments, *np.asarray(sums))):
                 moe[name] += int(v)
         if not moe["moe_expert_slots"]:  # a dense model; nothing back yet
-            self._clock.step_done()
+            self._clock.step_done(**attrs)
             return
         for name, v in moe.items():
             self.stats[name] += v
-        self._clock.step_done(moe={k[4:]: v for k, v in moe.items()})
+        self._clock.step_done(moe={k[4:]: v for k, v in moe.items()},
+                              **attrs)
 
     def _note_decode_work(self, n: int, seq_lens: np.ndarray,
                           seg_pages: tuple = (0, 0)) -> None:
@@ -4043,6 +4200,7 @@ class JaxEngine(AsyncEngine):
         )
         kw.update(self._lora_decode_kw())
         kw.update(self._moe_kw())
+        kw.update(self._state_kw())
         quantized = self.k_scales is not None
         if quantized:
             self._flush_scale_resets()
@@ -4065,6 +4223,9 @@ class JaxEngine(AsyncEngine):
             penalized = False
         toks, self.k_cache, self.v_cache = out[:3]
         rest = list(out[3:])
+        if self.state is not None:
+            self.state = rest.pop(0)
+            self._state_rows += int((self._seq_lens > 0).sum())
         if quantized:
             self.k_scales = rest.pop(0)
             self.v_scales = rest.pop(0)
@@ -4163,7 +4324,7 @@ class JaxEngine(AsyncEngine):
             self._block_tables[seq.slot] = 0
             self._adapter_ids[seq.slot] = -1
             self._n_active -= 1
-            seq.slot = -1
+            seq.slot = seq.state_slot = -1
 
     def _commit_full_blocks(self, seq: _Sequence, written_len: int = -1) -> None:
         """Content-address blocks that just became full AND fully written.
@@ -4187,6 +4348,10 @@ class JaxEngine(AsyncEngine):
                 seq.blocks[i], tokens, seq.parent_hash
             )
             seq.committed += 1
+            if self.state is not None:
+                # the program that filled the block left the conv state
+                # at its last token under its id (llama.ConvTrack)
+                self.stats["state_snapshots"] += 1
 
     # ---------------- disaggregation hooks ----------------
     # (ref docs/disagg_serving.md:58-91; vllm patch remote-prefill states)
@@ -4236,6 +4401,7 @@ class JaxEngine(AsyncEngine):
         LEADER ships full host blocks over the transfer plane;
         ``keep_on_device`` is ignored there (a multi-process array cannot
         hand over in-process to a differently-meshed engine)."""
+        self._no_state_transfer("prefill_extract (disaggregation)")
         if self.mirror is not None:
             keep_on_device = False
         self._guard_remote_adapter(req)
@@ -4306,6 +4472,7 @@ class JaxEngine(AsyncEngine):
         bulk path, so the compiled-program count is bounded by segment
         GEOMETRY buckets, not per-request shapes (test_compiled_perf).
         Returns (first_token, first_lp, blocks_emitted)."""
+        self._no_state_transfer("prefill_extract_stream (disaggregation)")
         if self.mirror is not None:
             keep_on_device = False
         self._guard_remote_adapter(req)
@@ -4452,6 +4619,7 @@ class JaxEngine(AsyncEngine):
         host-side allocator work, and the eventual remote-KV landing
         (complete_remote -> _scatter_device) broadcasts the blocks so
         every process scatters its shards in lockstep."""
+        self._no_state_transfer("begin_remote (disaggregation)")
         req: PreprocessedRequest = request.data
         if isinstance(req, dict):
             req = PreprocessedRequest.from_dict(req)
@@ -4687,6 +4855,7 @@ class _PrefillState:
     # begun at reservation), or None when the host tier missed
     upload: Optional[object] = None
     restored: bool = False  # host-tier restore landed (first chunk)
+    state_ready: bool = False  # the conv state's row is set (first chunk)
     # span anchors for the traced "engine.prefill" component: wall start
     # + accumulated per-chunk DEVICE milliseconds (the span duration —
     # wall time would absorb decode steps interleaved between chunks)

@@ -36,10 +36,11 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 #: of an ``architectures`` entry (docs/models.md lists them)
 _EXPERT_MODEL_TYPES = frozenset((
     "mixtral", "qwen2_moe", "qwen3_moe", "deepseek", "deepseek_v2",
-    "deepseek_v3", "gpt_oss", "olmoe",
+    "deepseek_v3", "gpt_oss", "olmoe", "lfm2_moe",
 ))
 _EXPERT_ARCH_PREFIXES = (
     "Mixtral", "Qwen2Moe", "Qwen3Moe", "Deepseek", "GptOss", "Olmoe",
+    "Lfm2Moe",
 )
 _EXPERT_COUNT_KEYS = ("num_local_experts", "n_routed_experts", "num_experts")
 
@@ -65,6 +66,43 @@ def _reject_unknown_expert_family(cfg: dict, archs: list) -> None:
             f"{sorted(_EXPERT_MODEL_TYPES)} only — refusing to serve it "
             "as a Llama with experts"
         )
+
+
+#: what a ``layer_types`` entry may say, and the operator it names
+_LAYER_TYPE_OPS = {
+    "full_attention": "attn", "sliding_attention": "attn", "conv": "conv",
+}
+
+
+def _layer_kinds(cfg: dict, is_lfm2: bool) -> tuple[tuple, int]:
+    """(operator kind of every layer, leading dense-FFN layers): the ONE
+    place ``layer_types`` and ``num_dense_layers`` /
+    ``first_k_dense_replace`` are read into kinds. The operator tuple is
+    empty for an attention-only stack (every family but LFM2); an entry
+    no forward implements is refused by name, for every family — it
+    would otherwise run as attention."""
+    types = cfg.get("layer_types") or ()
+    unknown = sorted({t for t in types if t not in _LAYER_TYPE_OPS})
+    if unknown:
+        raise ValueError(
+            f"unsupported layer_types entries {unknown}: the forwards "
+            f"know {sorted(_LAYER_TYPE_OPS)} only")
+    ops = tuple(_LAYER_TYPE_OPS[t] for t in types)
+    if "conv" in ops and not is_lfm2:
+        raise ValueError(
+            "layer_types names 'conv' layers under a family other than "
+            "lfm2 / lfm2_moe: only LFM2's gated short convolution is "
+            "implemented")
+    n_dense = cfg.get("first_k_dense_replace", 0) or 0
+    if not is_lfm2:
+        return (), n_dense
+    # a depth cut keeps the published list whole: the first
+    # num_hidden_layers entries are the layers that are there
+    L = cfg.get("num_hidden_layers", 32)
+    if len(ops) < L:
+        raise ValueError(
+            f"layer_types has {len(ops)} entries for {L} layers")
+    return ops[:L], cfg.get("num_dense_layers", 0) or 0
 
 
 @dataclass(eq=False)  # identity hash/eq: used as a jit static arg
@@ -171,6 +209,17 @@ class ModelConfig:
     # (rope_partial_dim derives in __post_init__ once head_dim resolves)
     rope_partial_factor: float = 1.0
     rope_partial_dim: int = 0
+    # LFM2: every layer's OPERATOR is one of two kinds, "attn" or "conv"
+    # (a gated short causal convolution over the last conv_kernel
+    # tokens), chosen per layer; () = attention everywhere. A conv layer
+    # holds no keys and values: its per-sequence state is the last
+    # conv_kernel - 1 rows of B * x (llama.short_conv), and the KV cache
+    # holds the attention layers only (kv_layers, op_index). The FFN kind
+    # is chosen independently: first_dense_layers leading dense ones.
+    layer_ops: tuple = ()
+    conv_kernel: int = 0
+    # the renormalised top-k combine weights divide by (sum + this)
+    topk_norm_eps: float = 1e-20
     # runtime
     dtype: str = "bfloat16"
 
@@ -182,6 +231,12 @@ class ModelConfig:
                     f"layer_windows has {len(self.layer_windows)} entries "
                     f"for {self.num_layers} layers"
                 )
+        self.layer_ops = tuple(self.layer_ops)
+        if self.layer_ops and len(self.layer_ops) != self.num_layers:
+            raise ValueError(
+                f"layer_ops has {len(self.layer_ops)} entries for "
+                f"{self.num_layers} layers"
+            )
         if self.head_dim == 0:
             self.head_dim = self.hidden_size // self.num_heads
         if self.rope_partial_factor != 1.0 and not self.rope_partial_dim:
@@ -194,6 +249,30 @@ class ModelConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers whose FFN is the expert layer."""
+        return self.num_layers - self.first_dense_layers if self.is_moe else 0
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose operator is the short convolution: the layers
+        that carry a per-sequence state instead of keys and values."""
+        return self.layer_ops.count("conv")
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that hold keys and values: the KV cache's layer axis."""
+        return self.num_layers - self.conv_layers
+
+    def op_index(self, l: int) -> int:
+        """Layer ``l``'s ordinal among the layers of its operator kind:
+        an attention layer's index into the KV cache, a conv layer's into
+        the conv state (``l`` itself for an attention-only stack)."""
+        if not self.layer_ops:
+            return l
+        return self.layer_ops[:l].count(self.layer_ops[l])
 
     @property
     def qk_head_dim(self) -> int:
@@ -268,6 +347,23 @@ class ModelConfig:
                 "(the q/k/v clamp is not implemented; the published "
                 "OLMoE-1B-7B configs carry clip_qkv: null)"
             )
+        # lfm2 / lfm2_moe: conv and attention operators chosen per layer
+        # (layer_types), per-head q/k norms before rope, a tied head;
+        # lfm2_moe adds sigmoid-routed experts picked by a biased score
+        # behind num_dense_layers dense layers
+        is_lfm2 = any(a.startswith("Lfm2") for a in archs) or (
+            cfg.get("model_type") in ("lfm2", "lfm2_moe")
+        )
+        is_lfm2moe = any(a.startswith("Lfm2Moe") for a in archs) or (
+            cfg.get("model_type") == "lfm2_moe"
+        )
+        if is_lfm2 and cfg.get("conv_bias"):
+            raise ValueError(
+                "lfm2 with conv_bias=true is not supported (the "
+                "convolution's and its projections' biases are not "
+                "implemented; the published LFM2 configs carry false)"
+            )
+        layer_ops, n_dense = _layer_kinds(cfg, is_lfm2)
         _reject_unknown_expert_family(cfg, archs)
         # qwen2moe: gated shared expert; interleaved dense layers are
         # not implemented — reject rather than serve wrong logits
@@ -337,10 +433,30 @@ class ModelConfig:
         act = cfg.get("hidden_act") or cfg.get("hidden_activation") or "silu"
         if act in ("gelu", "gelu_pytorch_tanh", "gelu_tanh"):
             act = "gelu_tanh"
+        ffn_width = cfg.get("intermediate_size", 11008)
+        if is_lfm2:
+            # the original LFM2 uploads' key names, read as
+            # transformers' Lfm2Config reads them
+            ffn_width = cfg.get("block_ff_dim", ffn_width)
+            cfg = dict(cfg)
+            cfg["rope_theta"] = cfg.get("theta", cfg.get("rope_theta", 1e6))
+            if "tie_embedding" in cfg:
+                cfg["tie_word_embeddings"] = cfg["tie_embedding"]
+        if is_lfm2 and not is_lfm2moe and cfg.get(
+            "block_auto_adjust_ff_dim", True
+        ):
+            # dense LFM2 (Lfm2MLP): the written width is cut to 2/3,
+            # scaled, and rounded up to a multiple
+            mult = cfg.get("block_multiple_of", 256)
+            ffn_width = int(2 * ffn_width / 3)
+            if cfg.get("block_ffn_dim_multiplier", 1.0) is not None:
+                ffn_width = int(
+                    cfg.get("block_ffn_dim_multiplier", 1.0) * ffn_width)
+            ffn_width = mult * ((ffn_width + mult - 1) // mult)
         return ModelConfig(
             vocab_size=cfg.get("vocab_size", 32000),
             hidden_size=cfg.get("hidden_size", 4096),
-            intermediate_size=cfg.get("intermediate_size", 11008),
+            intermediate_size=ffn_width,
             num_layers=cfg.get("num_hidden_layers", 32),
             num_heads=cfg.get("num_attention_heads", 32),
             num_kv_heads=cfg.get("num_key_value_heads", cfg.get("num_attention_heads", 32)),
@@ -348,14 +464,18 @@ class ModelConfig:
             rope_theta=cfg.get("rope_theta", 10000.0),
             rope_partial_factor=cfg.get("partial_rotary_factor") or 1.0,
             rope_scaling=rope_scaling,
-            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            rms_norm_eps=cfg.get("norm_eps", 1e-5) if is_lfm2
+            else cfg.get("rms_norm_eps", 1e-5),
             max_position_embeddings=cfg.get("max_position_embeddings", 8192),
-            tie_word_embeddings=cfg.get("tie_word_embeddings", is_gemma),
+            tie_word_embeddings=cfg.get(
+                "tie_word_embeddings", is_gemma or is_lfm2),
             attention_bias=qkv_bias,
             # qwen3 (dense and MoE): per-head q/k RMS norm, no qkv bias
             qk_norm=any(a.startswith("Qwen3") for a in archs) or is_gemma3
-            or is_olmo2 or is_olmoe,
+            or is_olmo2 or is_olmoe or is_lfm2,
             layer_windows=layer_windows,
+            layer_ops=layer_ops,
+            conv_kernel=(cfg.get("conv_L_cache", 3) if is_lfm2 else 0),
             attn_sinks=is_gptoss,
             moe_act="gptoss_clamp" if is_gptoss else "swiglu",
             o_bias=is_gptoss and bool(cfg.get("attention_bias")),
@@ -366,8 +486,9 @@ class ModelConfig:
                 cfg.get(
                     "n_routed_experts",
                     cfg.get("num_experts", 0)
-                    if is_olmoe or any(a.startswith(("Qwen3", "Qwen2Moe"))
-                                       for a in archs) else 0,
+                    if is_olmoe or is_lfm2moe
+                    or any(a.startswith(("Qwen3", "Qwen2Moe"))
+                           for a in archs) else 0,
                 ),
             ) or 0,
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
@@ -383,12 +504,18 @@ class ModelConfig:
                 cfg.get("shared_expert_intermediate_size", 0) or 0
             ) if is_qwen2moe else 0,
             shared_expert_gate=is_qwen2moe,
-            first_dense_layers=cfg.get("first_k_dense_replace", 0) or 0,
+            first_dense_layers=n_dense,
             norm_topk_prob=cfg.get("norm_topk_prob", not is_olmoe),
             # deepseek_v2/v3 (R1 = V3): sigmoid scoring + gate bias and
             # group-limited top-k arrive with topk_method "noaux_tc"
-            moe_scoring=cfg.get("scoring_func", "softmax"),
-            moe_gate_bias=cfg.get("topk_method") == "noaux_tc",
+            # lfm2_moe: sigmoid scores, a bias that picks and does not
+            # weigh (use_expert_bias), weights renormalised over
+            # (sum + 1e-6)
+            moe_scoring="sigmoid" if is_lfm2moe
+            else cfg.get("scoring_func", "softmax"),
+            moe_gate_bias=bool(cfg.get("use_expert_bias")) if is_lfm2moe
+            else cfg.get("topk_method") == "noaux_tc",
+            topk_norm_eps=1e-6 if is_lfm2moe else 1e-20,
             moe_group_score=(
                 "top2" if cfg.get("topk_method") == "noaux_tc" else "max"
             ),

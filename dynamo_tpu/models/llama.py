@@ -177,6 +177,26 @@ def _layer(lps: dict, li: int, mesh=None) -> dict:
     return {**lp, **whole}
 
 
+def _layers(params: dict, cfg: ModelConfig, mesh=None):
+    """(l, lp) for every layer in forward order, for an UNROLLED layer
+    loop: ``lp`` holds layer ``l``'s leaves (``_layer``). An LFM2 stack
+    (``cfg.layer_ops``) keeps its leaves stacked by KIND — conv
+    operators, attention operators, dense FFNs, expert FFNs — and ``lp``
+    joins the layer's operator with its FFN; ``"conv_in" in lp`` says
+    which operator it is."""
+    if not cfg.layer_ops:
+        for lps, n, goff in layer_groups(params, cfg):
+            for li in range(n):
+                yield goff + li, _layer(lps, li, mesh)
+        return
+    kd = cfg.first_dense_layers if "dense_layers" in params else 0
+    for l, op in enumerate(cfg.layer_ops):
+        ops = params["conv_ops" if op == "conv" else "attn_ops"]
+        ffn = params["dense_layers"] if l < kd else params["layers"]
+        yield l, {**_layer(ops, cfg.op_index(l)),
+                  **_layer(ffn, l if l < kd else l - kd, mesh)}
+
+
 def _scan_groups(body, x, params, cfg: ModelConfig, k_cache, v_cache,
                  tally=None):
     """lax.scan the layer body over every layer group, threading the
@@ -197,6 +217,77 @@ def _scan_groups(body, x, params, cfg: ModelConfig, k_cache, v_cache,
     return x, k_cache, v_cache
 
 
+def _init_hybrid_layers(cfg: ModelConfig, key: jax.Array) -> dict:
+    """The layer leaves of an LFM2 stack, stacked by KIND: ``conv_ops``
+    [Lc, ...] and ``attn_ops`` [La, ...] (each operator with its norm),
+    ``dense_layers`` [first_dense_layers, ...] and ``layers`` (the other
+    layers' FFNs, experts or dense, with their norm). The taps and the
+    experts' selection bias are drawn non-zero so that a forward that
+    drops either shows. Two draws are smaller than the matrices' 0.02,
+    so that a bf16 forward stays near the float32 one (PERF.md section
+    6, PR 33): a random router's choices flip under bf16 rounding, and
+    the marginal one of 4 renormalised sigmoid experts carries a fifth
+    of the layer's output, so the routed experts' down-projections are
+    drawn at an eighth; the conv operator is a product of three
+    projections of its input, which triples what a rounding error
+    gains in it, so its in-projection is drawn at a half."""
+    dt = _dtype(cfg)
+    E, H, Hkv, D, K = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                       cfg.head_dim, cfg.conv_kernel)
+    Lc, La = cfg.conv_layers, cfg.kv_layers
+    kd = cfg.first_dense_layers if cfg.is_moe else 0
+    keys = jax.random.split(key, 16)
+
+    def draw(k, shape, scale=0.02, dtype=dt):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    def dense_ffn(k, n):
+        ks = jax.random.split(k, 3)
+        F = cfg.intermediate_size
+        return {
+            "mlp_norm": jnp.ones((n, E), dt),
+            "w_gate": draw(ks[0], (n, E, F)),
+            "w_up": draw(ks[1], (n, E, F)),
+            "w_down": draw(ks[2], (n, F, E)),
+        }
+
+    out = {
+        "conv_ops": {
+            "attn_norm": jnp.ones((Lc, E), dt),
+            "conv_in": draw(keys[0], (Lc, E, 3 * E), 0.01),
+            "conv_w": draw(keys[1], (Lc, K, E), 0.3),
+            "conv_out": draw(keys[2], (Lc, E, E)),
+        },
+        "attn_ops": {
+            "attn_norm": jnp.ones((La, E), dt),
+            "wq": draw(keys[3], (La, E, H * D)),
+            "wk": draw(keys[4], (La, E, Hkv * D)),
+            "wv": draw(keys[5], (La, E, Hkv * D)),
+            "wo": draw(keys[6], (La, H * D, E)),
+            "q_norm": jnp.ones((La, D), dt),
+            "k_norm": jnp.ones((La, D), dt),
+        },
+    }
+    if kd:
+        out["dense_layers"] = dense_ffn(keys[7], kd)
+    n = cfg.num_layers - kd
+    if not cfg.is_moe:
+        out["layers"] = dense_ffn(keys[8], n)
+        return out
+    X, Fm = cfg.num_experts, cfg.moe_intermediate_size
+    out["layers"] = {
+        "mlp_norm": jnp.ones((n, E), dt),
+        "moe_gate": draw(keys[9], (n, E, X)),
+        "we_gate": draw(keys[10], (n, X, E, Fm)),
+        "we_up": draw(keys[11], (n, X, E, Fm)),
+        "we_down": draw(keys[12], (n, X, Fm, E), 0.0025),
+    }
+    if cfg.moe_gate_bias:
+        out["layers"]["moe_gate_bias"] = draw(
+            keys[13], (n, X), 0.1, jnp.float32)
+    return out
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random-init params (tests/benches; real weights via weights.py)."""
     dt = _dtype(cfg)
@@ -210,10 +301,15 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     params = {
         "embed": norm_init(keys[0], (V, E), 0.02),
         "final_norm": jnp.ones((E,), dt),
-        "layers": _init_layer_group(cfg, keys[1], L - kd, cfg.is_moe),
     }
-    if kd:
-        params["dense_layers"] = _init_layer_group(cfg, keys[3], kd, False)
+    if cfg.layer_ops:
+        params.update(_init_hybrid_layers(cfg, keys[1]))
+    else:
+        params["layers"] = _init_layer_group(
+            cfg, keys[1], L - kd, cfg.is_moe)
+        if kd:
+            params["dense_layers"] = _init_layer_group(
+                cfg, keys[3], kd, False)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm_init(keys[2], (E, V), 0.02)
     return params
@@ -260,8 +356,10 @@ def kv_cache_shapes(
     per-head K/V: c_kv rides the k slot, the head-shared rotated k_pe the
     v slot — both single-"head" paged arrays, so every block-table /
     allocator / offload / transfer path works unchanged (models/mla.py).
-    Every other family stores a head in ``kv_lanes`` lanes."""
-    L = cfg.num_layers
+    Every other family stores a head in ``kv_lanes`` lanes. The layer
+    axis counts the layers that hold keys and values (an LFM2 stack's
+    attention layers: ``cfg.op_index`` is a layer's index here)."""
+    L = cfg.kv_layers
     if cfg.is_mla:
         return (
             (L, 1, num_blocks, block_size, cfg.kv_lora_rank),
@@ -627,7 +725,8 @@ def _route_topk(lp: dict, cfg: ModelConfig, x: jnp.ndarray):
     _, idx = lax.top_k(sel, k)  # selection by (biased, group-limited) score
     vals = jnp.take_along_axis(scores, idx, axis=1)  # combine: raw score
     if cfg.norm_topk_prob:
-        vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+        vals = vals / (
+            jnp.sum(vals, axis=-1, keepdims=True) + cfg.topk_norm_eps)
     return vals * cfg.routed_scaling_factor, idx
 
 
@@ -1000,6 +1099,13 @@ def _layer_tail(x, lp: dict, cfg: ModelConfig, o_flat, lora_l=None,
         _wo_proj(lp, _from_lanes(cfg, o_flat), lora_l, lora_ids,
                  lora_grouped), cfg,
     )
+    return _ffn_tail(x, lp, cfg, mesh, use_pallas, interpret, tally)
+
+
+def _ffn_tail(x, lp: dict, cfg: ModelConfig, mesh=None,
+              use_pallas: bool = False, interpret: bool = False,
+              tally: Optional[MoeTally] = None) -> jnp.ndarray:
+    """The FFN sublayer onto the residual, inside the family's norms."""
     h = pre_norm(lp, "mlp_norm", x, cfg)
     return x + post_norm(
         lp, "mlp_post_norm",
@@ -1008,13 +1114,155 @@ def _layer_tail(x, lp: dict, cfg: ModelConfig, o_flat, lora_l=None,
     )
 
 
+# ---------------- LFM2's short convolution and its state ----------------
+
+
+def init_state(cfg: ModelConfig, max_batch: int, num_blocks: int):
+    """The per-sequence state that is not keys and values, or None for a
+    stack without conv layers. A conv layer needs, to go on from token t,
+    the last ``conv_kernel - 1`` rows of ``B * x`` (``short_conv``):
+    ``conv`` [max_batch, Lc * (K-1) * E] holds them for the sequence in
+    each decode slot, ``snap`` [num_blocks, Lc * (K-1) * E] as they
+    stood at the END of each full block of the KV pool, by block id:
+    what a prefix hit that ends with that block restores
+    (``ConvTrack``). A row is kept flat: the TPU tiles an array's last
+    two dimensions, and rows of K-1 = 2 would be padded to a tile's 16."""
+    if not cfg.conv_layers:
+        return None
+    width = cfg.conv_layers * (cfg.conv_kernel - 1) * cfg.hidden_size
+    return {"conv": jnp.zeros((max_batch, width), _dtype(cfg)),
+            "snap": jnp.zeros((num_blocks, width), _dtype(cfg))}
+
+
+def short_conv(lp: dict, h: jnp.ndarray, parts: list):
+    """LFM2's gated short convolution over SEGMENTS that each start from
+    an incoming state — a decode row is a segment of one row, a chunk of
+    a prompt a segment that starts from its sequence's state, a fresh
+    prompt one that starts from zeros.
+
+        [B, C, u] = split3(h W_in);  v = B * u
+        c[t] = sum_k w[k] * v[t - (K-1) + k]       (depthwise, causal)
+        y = (C * c) W_out
+
+    ``h`` [R, E] holds the parts' rows in order; a part is
+    ``(S, T, state_in [S, K-1, E])``: S segments of T rows, and the K-1
+    rows of ``v`` before each. Returns (y [R, E], one ``v_ext``
+    [S, K-1 + T, E] a part: the state followed by the segment's own
+    ``v``, so the state after r rows is ``v_ext[:, r : r + K-1]``)."""
+    E = h.shape[-1]
+    b, c, u = jnp.split(_mm(h, lp["conv_in"]), 3, axis=-1)
+    v = b * u
+    w = lp["conv_w"].astype(jnp.float32)  # [K, E], w[K-1] on the row itself
+    convs, exts, r = [], [], 0
+    for S, T, state_in in parts:
+        v_ext = jnp.concatenate(
+            [state_in.astype(v.dtype), v[r : r + S * T].reshape(S, T, E)], 1)
+        acc = sum(w[k] * v_ext[:, k : k + T].astype(jnp.float32)
+                  for k in range(w.shape[0]))
+        convs.append(acc.astype(v.dtype).reshape(S * T, E))
+        exts.append(v_ext)
+        r += S * T
+    conv = convs[0] if len(convs) == 1 else jnp.concatenate(convs)
+    return _mm(c * conv, lp["conv_out"]), exts
+
+
+class Segments(NamedTuple):
+    """S segments of T rows each, as a step program hands them to the
+    conv layers: a decode batch (T = 1) or prefill chunks."""
+
+    rows: Optional[jnp.ndarray]  # [S] state row of each; None: s itself
+    n: jnp.ndarray  # [S] real rows of each (0: a dead slot or segment)
+    hist: jnp.ndarray  # [S] tokens before each segment's first row
+    tables: jnp.ndarray  # [S, M] block tables
+    T: int
+
+
+class ConvTrack:
+    """What the conv layers of ONE step program read and leave behind,
+    gathered while it is traced. Each segment starts from its row of
+    ``state["conv"]`` (``init_state``); ``finish`` writes back the state
+    after each segment's last REAL row (a dead segment's is left as it
+    was), and,
+    for every block of the KV pool whose last token is among a segment's
+    real rows, the state at that token into ``state["snap"]`` under the
+    block's id: the block is committed to the prefix cache only after
+    the program that filled it, so a committed block always has its
+    snapshot, and a prefix hit restores the state with the keys and
+    values it claims."""
+
+    def __init__(self, cfg: ModelConfig, state: dict, groups: list,
+                 block_size: int):
+        self.state, self.groups = state, groups
+        conv = state["conv"]
+        row = (cfg.conv_layers, cfg.conv_kernel - 1, cfg.hidden_size)
+        self.start = [
+            (conv if g.rows is None else conv[g.rows]).reshape((-1,) + row)
+            for g in groups]
+        # a group: where in a layer's v_ext the state after the last real
+        # row and at each block end lies, and the blocks that end there
+        self.last, self.block_end, self.blocks = [], [], []
+        self.after = [[] for _ in groups]  # a conv layer: [S, K-1, E]
+        self.ends = [[] for _ in groups]  # a conv layer: [S, nb, K-1, E]
+        tap = jnp.arange(cfg.conv_kernel - 1)
+        for g in groups:
+            S, M = g.tables.shape
+            seg = jnp.arange(S)
+            nb = g.T // block_size + 1  # block ends a segment can hold
+            j = g.hist[:, None] // block_size + jnp.arange(nb)[None]
+            e = (j + 1) * block_size - g.hist[:, None]  # rows up to the end
+            ok = (e >= 1) & (e <= g.n[:, None])
+            blk = jnp.take_along_axis(g.tables, jnp.clip(j, 0, M - 1), 1)
+            self.last.append((seg[:, None], g.n[:, None] + tap))
+            self.block_end.append(
+                (seg[:, None, None], jnp.clip(e, 0, g.T)[:, :, None] + tap))
+            # an index past the pool is dropped by the scatter
+            self.blocks.append(
+                jnp.where(ok, blk, state["snap"].shape[0]).reshape(-1))
+
+    def parts(self, ci: int) -> list:
+        return [(st.shape[0], g.T, st[:, ci])
+                for g, st in zip(self.groups, self.start)]
+
+    def add(self, exts: list) -> None:
+        for gi, v_ext in enumerate(exts):
+            self.after[gi].append(v_ext[self.last[gi]])
+            self.ends[gi].append(v_ext[self.block_end[gi]])
+
+    def finish(self) -> dict:
+        conv, snap = self.state["conv"], self.state["snap"]
+        W = conv.shape[1]
+        for gi, g in enumerate(self.groups):
+            # [S, Lc, K-1, E] and [S, nb, Lc, K-1, E], as flat rows
+            after = jnp.stack(self.after[gi], 1).reshape(-1, W)
+            ends = jnp.stack(self.ends[gi], 2).reshape(-1, W)
+            conv = after if g.rows is None else conv.at[g.rows].set(
+                after, mode="drop")
+            snap = snap.at[self.blocks[gi]].set(ends, mode="drop")
+        return {"conv": conv, "snap": snap}
+
+
+def _conv_layer(x, lp: dict, cfg: ModelConfig, l: int,
+                track: Optional[ConvTrack], **ffn_kw) -> jnp.ndarray:
+    """One conv layer of an LFM2 stack onto the residual ``x`` [R, E]:
+    the operator over ``track``'s segments (one segment from zeros
+    without a track: ``dense_forward``), then the FFN sublayer."""
+    h = pre_norm(lp, "attn_norm", x, cfg)
+    if track is None:
+        zeros = jnp.zeros((1, cfg.conv_kernel - 1, x.shape[-1]), x.dtype)
+        y, _ = short_conv(lp, h, [(1, x.shape[0], zeros)])
+    else:
+        y, exts = short_conv(lp, h, track.parts(cfg.op_index(l)))
+        track.add(exts)
+    return _ffn_tail(x + y, lp, cfg, **ffn_kw)
+
+
 # ---------------- prefill (one sequence, chunked) ----------------
 
 
 @partial(
     jax.jit,
     static_argnames=("cfg", "use_pallas", "mesh", "use_ring", "moe_counters"),
-    donate_argnames=("k_cache", "v_cache"),
+    donate_argnames=("k_cache", "v_cache", "state"),
 )
 def prefill(
     params: dict,
@@ -1044,6 +1292,11 @@ def prefill(
     # sums, int32 [3]; rows below valid_len are the live ones) as the
     # LAST output
     moe_counters: bool = False,
+    # LFM2: the conv layers' state (``init_state``, donated) and the row
+    # of it this sequence starts from and leaves its state in; the new
+    # state follows v_cache in the return
+    state: Optional[dict] = None,
+    slot: Optional[jnp.ndarray] = None,
 ):
     """Process one (chunk of a) prompt; returns (last_hidden_logits, caches).
 
@@ -1107,6 +1360,12 @@ def prefill(
         scale = attn_query_scale(cfg)
 
     inv_local = _rope_freqs_local(cfg)
+    track = None
+    if cfg.conv_layers:
+        assert state is not None and not quantized and lora is None
+        track = ConvTrack(cfg, state, [Segments(
+            slot[None], valid_len[None], history_len[None],
+            block_table[None], T)], k_cache.shape[3])
 
     def body(carry, layer_in, window=cfg.sliding_window, freqs=None,
              scales=None, lora_l=None):
@@ -1207,39 +1466,40 @@ def prefill(
         # per-layer scale-plane slices must thread through every write,
         # so the layer loop unrolls (the scan body cannot in-place
         # scatter the planes without a full re-stack copy per layer)
-        for lps, n, off in layer_groups(params, cfg):
-            for li in range(n):
-                l = off + li
-                lp = _layer(lps, li, mesh)
-                x, (kc_l, vc_l, ks_l, vs_l) = body(
-                    x, (lp, k_cache[l], v_cache[l]),
-                    window=window_for_layer(cfg, l),
-                    freqs=rope_freqs_for_layer(cfg, l, inv_freq, inv_local),
-                    scales=(k_scales[l], v_scales[l]),
-                    lora_l=lora_for_layer(l),
-                )
-                k_cache = k_cache.at[l].set(kc_l)
-                v_cache = v_cache.at[l].set(vc_l)
-                k_scales = k_scales.at[l].set(ks_l)
-                v_scales = v_scales.at[l].set(vs_l)
-    elif cfg.layer_windows or lora is not None:
+        for l, lp in _layers(params, cfg, mesh):
+            x, (kc_l, vc_l, ks_l, vs_l) = body(
+                x, (lp, k_cache[l], v_cache[l]),
+                window=window_for_layer(cfg, l),
+                freqs=rope_freqs_for_layer(cfg, l, inv_freq, inv_local),
+                scales=(k_scales[l], v_scales[l]),
+                lora_l=lora_for_layer(l),
+            )
+            k_cache = k_cache.at[l].set(kc_l)
+            v_cache = v_cache.at[l].set(vc_l)
+            k_scales = k_scales.at[l].set(ks_l)
+            v_scales = v_scales.at[l].set(vs_l)
+    elif cfg.layer_windows or lora is not None or cfg.layer_ops:
         # heterogeneous attention (gpt-oss alternating sliding/full):
         # the window width is trace-static PER LAYER, so the layer loop
         # unrolls — a lax.scan body cannot carry a per-layer mask shape.
         # LoRA rides the same unrolled loop: adapter stacks slice per
-        # layer with a static index (quantized-KV precedent).
-        for lps, n, off in layer_groups(params, cfg):
-            for li in range(n):
-                l = off + li
-                lp = _layer(lps, li, mesh)
-                x, (kc_l, vc_l) = body(
-                    x, (lp, k_cache[l], v_cache[l]),
-                    window=window_for_layer(cfg, l),
-                    freqs=rope_freqs_for_layer(cfg, l, inv_freq, inv_local),
-                    lora_l=lora_for_layer(l),
-                )
-                k_cache = k_cache.at[l].set(kc_l)
-                v_cache = v_cache.at[l].set(vc_l)
+        # layer with a static index (quantized-KV precedent). So does an
+        # LFM2 stack: its operator differs by layer, and an attention
+        # layer's cache index is its ordinal among them.
+        for l, lp in _layers(params, cfg, mesh):
+            if "conv_in" in lp:
+                x = _conv_layer(x, lp, cfg, l, track, mesh=mesh,
+                                use_pallas=use_pallas, tally=tally)
+                continue
+            a = cfg.op_index(l)
+            x, (kc_l, vc_l) = body(
+                x, (lp, k_cache[a], v_cache[a]),
+                window=window_for_layer(cfg, l),
+                freqs=rope_freqs_for_layer(cfg, l, inv_freq, inv_local),
+                lora_l=lora_for_layer(l),
+            )
+            k_cache = k_cache.at[a].set(kc_l)
+            v_cache = v_cache.at[a].set(vc_l)
     else:
         x, k_cache, v_cache = _scan_groups(
             body, x, params, cfg, k_cache, v_cache, tally=tally
@@ -1249,6 +1509,8 @@ def prefill(
     last = jnp.clip(valid_len - 1, 0, T - 1)
     logits = _logits(params, cfg, x[last])
     out = (logits, k_cache, v_cache)
+    if track is not None:
+        out += (track.finish(),)
     if quantized:
         out += (k_scales, v_scales)
     return out + (tally.sums,) if moe_counters else out
@@ -1261,7 +1523,7 @@ def _decode_body(
     params, cfg, tokens, positions, block_tables, seq_lens,
     k_cache, v_cache, use_pallas, mesh=None, interpret=False,
     k_scales=None, v_scales=None, lora=None, adapter_ids=None,
-    moe_tally=None,
+    moe_tally=None, state=None,
 ):
     """Shared un-jitted decode forward (one token per sequence).
 
@@ -1288,8 +1550,17 @@ def _decode_body(
     read (fused dequant); when present the return grows to
     (logits, k_cache, v_cache, k_scales, v_scales, n_requants).
     ``moe_tally`` (the caller's MoeTally) counts the expert layers'
-    routing."""
+    routing. ``state`` (LFM2: ``init_state``) is the conv layers' state,
+    row b the sequence of decode slot b; the new state is the LAST
+    output. A dead slot (length 0) keeps its row as it was: a sequence
+    that is still prefilling may own it already."""
     quantized = k_scales is not None
+    track = None
+    if cfg.conv_layers:
+        assert state is not None and not quantized and lora is None
+        track = ConvTrack(cfg, state, [Segments(
+            None, (seq_lens > 0).astype(jnp.int32), positions,
+            block_tables, 1)], k_cache.shape[3])
     if quantized:
         if cfg.is_mla:
             raise ValueError("int8 device KV scales: MLA is gated at "
@@ -1339,10 +1610,13 @@ def _decode_body(
             else jax.tree.map(lambda arr: arr[l], lora)
         )
 
-    def layers():
-        for lps, n, goff in layer_groups(params, cfg):
-            for li in range(n):
-                yield goff + li, _layer(lps, li, mesh)
+    def layers():  # (l, leaves) in forward order, for the unrolled loops
+        return _layers(params, cfg, mesh)
+
+    def conv_layer(x, lp, l):
+        return _conv_layer(
+            x, lp, cfg, l, track, mesh=mesh, use_pallas=use_pallas,
+            interpret=interpret, tally=moe_tally)
 
     blk, off = att.decode_slot_indices(
         block_tables, positions, k_cache.shape[3]
@@ -1430,6 +1704,10 @@ def _decode_body(
 
         k_news, v_news = [], []
         for l, lp in layers():
+            if "conv_in" in lp:
+                x = conv_layer(x, lp, l)
+                continue
+            a = cfg.op_index(l)  # the layer's index in the cache
             lora_l = lora_for_layer(l)
             q, k, v = layer_qkv(x, lp, l, lora_l)
             k_news.append(k)
@@ -1441,14 +1719,14 @@ def _decode_body(
             vs_l = v_scales[l] if quantized else None
             if mesh is None:
                 o = att.decode_attention_merged(
-                    q, k, v, k_cache, v_cache, l, block_tables,
+                    q, k, v, k_cache, v_cache, a, block_tables,
                     hist_lens, scale, window=window_for_layer(cfg, l),
                     sinks=lp.get("sinks"), interpret=interpret,
                     k_scales=ks_l, v_scales=vs_l,
                 )
             else:
                 o = att.decode_attention_merged_sharded(
-                    q, k, v, k_cache, v_cache, l, block_tables,
+                    q, k, v, k_cache, v_cache, a, block_tables,
                     hist_lens, scale, mesh,
                     window=window_for_layer(cfg, l),
                     sinks=lp.get("sinks"), interpret=interpret,
@@ -1485,6 +1763,10 @@ def _decode_body(
         # WRITE-THEN-ATTEND: the XLA path (CPU, kernels refused for the
         # shape, softcap models)
         for l, lp in layers():
+            if "conv_in" in lp:
+                x = conv_layer(x, lp, l)
+                continue
+            a = cfg.op_index(l)  # the layer's index in the cache
             lora_l = lora_for_layer(l)
             q, k, v = layer_qkv(x, lp, l, lora_l)
             ks_l = vs_l = None
@@ -1505,14 +1787,14 @@ def _decode_body(
             else:
                 # mixed basic+advanced indexing puts the advanced axes
                 # (blk, off) in front: the update value is [B, Hkv, D]
-                k_cache = k_cache.at[l, :, blk, off].set(
+                k_cache = k_cache.at[a, :, blk, off].set(
                     k.astype(k_cache.dtype)
                 )
-                v_cache = v_cache.at[l, :, blk, off].set(
+                v_cache = v_cache.at[a, :, blk, off].set(
                     v.astype(v_cache.dtype)
                 )
             o = att.decode_attention_xla(
-                q, k_cache[l], v_cache[l], block_tables, seq_lens, scale,
+                q, k_cache[a], v_cache[a], block_tables, seq_lens, scale,
                 window=window_for_layer(cfg, l), sinks=lp.get("sinks"),
                 cap=cfg.attn_softcap, k_scales=ks_l, v_scales=vs_l,
             )
@@ -1526,13 +1808,15 @@ def _decode_body(
             jnp.sum(k_scales > k_scales0) + jnp.sum(v_scales > v_scales0)
         ).astype(jnp.int32)
         return logits, k_cache, v_cache, k_scales, v_scales, n_requants
+    if track is not None:
+        return logits, k_cache, v_cache, track.finish()
     return logits, k_cache, v_cache
 
 
 @partial(
     jax.jit,
     static_argnames=("cfg", "use_pallas", "mesh", "interpret"),
-    donate_argnames=("k_cache", "v_cache"),
+    donate_argnames=("k_cache", "v_cache", "state"),
 )
 def decode_step(
     params: dict,
@@ -1550,17 +1834,19 @@ def decode_step(
     v_scales: Optional[jnp.ndarray] = None,
     lora=None,                                # stacked adapter pytree
     adapter_ids: Optional[jnp.ndarray] = None,  # [B] int32; -1 = base
+    state: Optional[dict] = None,  # LFM2: init_state, donated
 ):
     """One continuous-batching decode step for all active sequences.
 
     With scale planes the return grows to (logits, k_cache, v_cache,
-    k_scales, v_scales, n_requants) — see ``_decode_body``, which also
-    picks the layer loop."""
+    k_scales, v_scales, n_requants), with a conv state to (logits,
+    k_cache, v_cache, state) — see ``_decode_body``, which also picks
+    the layer loop."""
     return _decode_body(
         params, cfg, tokens, positions, block_tables, seq_lens,
         k_cache, v_cache, use_pallas, mesh, interpret,
         k_scales=k_scales, v_scales=v_scales, lora=lora,
-        adapter_ids=adapter_ids,
+        adapter_ids=adapter_ids, state=state,
     )
 
 
@@ -1568,7 +1854,7 @@ def decode_step(
     jax.jit,
     static_argnames=("cfg", "n_steps", "use_pallas", "mesh", "interpret",
                      "with_logprobs", "moe_counters"),
-    donate_argnames=("k_cache", "v_cache", "counts"),
+    donate_argnames=("k_cache", "v_cache", "counts", "state"),
 )
 def decode_window(
     params: dict,
@@ -1608,6 +1894,9 @@ def decode_window(
     # its steps and expert layers, int32 [3]; the rows with seq_lens > 0
     # are the live ones) as the LAST output
     moe_counters: bool = False,
+    # LFM2: the conv layers' state (init_state, donated; row b = slot
+    # b); it rides the scan carry and follows v_cache in the output
+    state: Optional[dict] = None,
 ):
     """``n_steps`` fused decode+sample steps in ONE dispatch (lax.scan):
     the sampled token of step i feeds step i+1 entirely on device, so the
@@ -1627,6 +1916,7 @@ def decode_window(
 
     penalized = counts is not None
     quantized = k_scales is not None
+    stateful = state is not None
     # a dead slot enters with length 0 (the scan then counts it up:
     # attention is handed 0 for it at every step, and walks no page)
     live = seq_lens > 0
@@ -1634,6 +1924,7 @@ def decode_window(
     def body(carry, _):
         tokens, positions, seq_lens, steps, k_cache, v_cache = carry[:6]
         rest = list(carry[6:])
+        st = rest.pop(0) if stateful else None
         if quantized:
             ks, vs, nreq = rest[:3]
             del rest[:3]
@@ -1649,10 +1940,12 @@ def decode_window(
             )
             nreq = nreq + nr
         else:
-            logits, k_cache, v_cache = _decode_body(
+            # (a stateful stack's new state follows the caches)
+            logits, k_cache, v_cache, *st = _decode_body(
                 params, cfg, tokens, positions, block_tables, attn_lens,
                 k_cache, v_cache, use_pallas, mesh, interpret,
                 lora=lora, adapter_ids=adapter_ids, moe_tally=tally,
+                state=st,
             )
         raw_logits = logits  # reported logprobs are the model's own dist
         if penalized:
@@ -1662,7 +1955,9 @@ def decode_window(
         keys = make_keys(seeds, steps)
         nxt = sample_tokens.__wrapped__(logits, keys, temps, top_ks, top_ps)
         ys = (nxt, *token_logprobs(raw_logits, nxt)) if with_logprobs else nxt
-        tail = (ks, vs, nreq) if quantized else ()
+        tail = tuple(st) if stateful else ()
+        if quantized:
+            tail = tail + (ks, vs, nreq)
         if moe_counters:
             tail = tail + (tally.sums,)
         if penalized:
@@ -1671,6 +1966,8 @@ def decode_window(
                 k_cache, v_cache) + tail, ys
 
     carry = (tokens, positions, seq_lens, steps, k_cache, v_cache)
+    if stateful:
+        carry = carry + (state,)
     if quantized:
         carry = carry + (k_scales, v_scales, jnp.zeros((), jnp.int32))
     if moe_counters:
@@ -1683,6 +1980,8 @@ def decode_window(
     toks = ys[0] if with_logprobs else ys
     lps = ys[1:] if with_logprobs else None
     out = (toks, k_cache, v_cache)
+    if stateful:
+        out = out + (rest.pop(0),)
     if quantized:
         out = out + tuple(rest[:3])  # (k_scales, v_scales, n_requants)
         del rest[:3]
@@ -1702,6 +2001,7 @@ def _mixed_fused_forward(
     p_tokens, p_tables, p_hists, p_valids, k_cache, v_cache,
     mesh=None, interpret=False, k_scales=None, v_scales=None,
     lora=None, d_adapter_ids=None, p_adapter_ids=None, moe_tally=None,
+    state=None, p_slots=None,
 ):
     """The FULLY-fused mixed forward (TPU/Pallas path): embeddings and
     every projection/FFN/logits GEMM run over the combined [B + MP*T]
@@ -1721,7 +2021,9 @@ def _mixed_fused_forward(
     per-part branch.
 
     Returns (decode_logits [B, V] f32, p_logits [MP, V] f32, k_cache,
-    v_cache).
+    v_cache), then the conv layers' new ``state`` for an LFM2 stack (the
+    decode rows are segments of one row from their slots' state, the
+    prefill segments start from the rows ``p_slots`` names).
     """
     from ..ops.ragged_paged_attention_pallas import (
         ragged_mixed_attention,
@@ -1751,92 +2053,102 @@ def _mixed_fused_forward(
         )
     else:
         ids_all = None
+    track = None
+    if cfg.conv_layers:
+        assert state is not None and k_scales is None and lora is None
+        track = ConvTrack(cfg, state, [
+            Segments(None, (d_seq_lens > 0).astype(jnp.int32), d_positions,
+                     d_tables, 1),
+            Segments(p_slots, p_valids, p_hists, p_tables, T),
+        ], k_cache.shape[3])
 
     # UNROLLED layer loop (per-layer windows / local rope stay
     # trace-static; program count bounded by the prefill buckets)
-    for lps, n, goff in layer_groups(params, cfg):
-        for li in range(n):
-            l = goff + li
-            lp = _layer(lps, li, mesh)
-            lora_l = (
-                None if lora is None
-                else jax.tree.map(lambda arr: arr[l], lora)
+    for l, lp in _layers(params, cfg, mesh):
+        if "conv_in" in lp:
+            x = _conv_layer(x, lp, cfg, l, track, mesh=mesh, use_pallas=True,
+                            interpret=interpret, tally=moe_tally)
+            continue
+        a = cfg.op_index(l)  # the layer's index in the cache
+        lora_l = (
+            None if lora is None
+            else jax.tree.map(lambda arr: arr[l], lora)
+        )
+        h = pre_norm(lp, "attn_norm", x, cfg)
+        w = window_for_layer(cfg, l)
+        # [B+MP*T, H/Hkv, D]
+        q, k, v = _qkv(lp, cfg, h, lora_l, ids_all, lora_grouped=True)
+        fr = rope_freqs_for_layer(cfg, l, inv_freq, inv_local)
+        q = apply_rope(q, positions_all, fr, rope_msc)
+        k = apply_rope(k, positions_all, fr, rope_msc)
+        Hq, Dh = q.shape[1], q.shape[2]
+        q_chunks = q[B:].reshape(MP, T, Hq, Dh)
+        kc_l, vc_l = k_cache[a], v_cache[a]
+        # write-before-attend for EVERY part (distinct pages: no
+        # prefill sequence is in the decode batch and segments are
+        # distinct sequences; padded/dead segment rows land in
+        # reserved trash page 0 through their zero table entries)
+        ks_l = vs_l = None
+        if k_scales is not None:
+            ks_l, vs_l = k_scales[l], v_scales[l]
+            kc_l, ks_l = att.write_decode_token_to_cache_quantized(
+                kc_l, ks_l, k[:B], d_tables, d_positions
             )
-            h = pre_norm(lp, "attn_norm", x, cfg)
-            w = window_for_layer(cfg, l)
-            kc_l, vc_l = k_cache[l], v_cache[l]
-            # [B+MP*T, H/Hkv, D]
-            q, k, v = _qkv(lp, cfg, h, lora_l, ids_all, lora_grouped=True)
-            fr = rope_freqs_for_layer(cfg, l, inv_freq, inv_local)
-            q = apply_rope(q, positions_all, fr, rope_msc)
-            k = apply_rope(k, positions_all, fr, rope_msc)
-            # write-before-attend for EVERY part (distinct pages: no
-            # prefill sequence is in the decode batch and segments are
-            # distinct sequences; padded/dead segment rows land in
-            # reserved trash page 0 through their zero table entries)
-            ks_l = vs_l = None
-            if k_scales is not None:
-                ks_l, vs_l = k_scales[l], v_scales[l]
-                kc_l, ks_l = att.write_decode_token_to_cache_quantized(
-                    kc_l, ks_l, k[:B], d_tables, d_positions
-                )
-                vc_l, vs_l = att.write_decode_token_to_cache_quantized(
-                    vc_l, vs_l, v[:B], d_tables, d_positions
-                )
-                for m in range(MP):
-                    sl = slice(B + m * T, B + (m + 1) * T)
-                    kc_l, ks_l = att.write_chunk_to_cache_quantized(
-                        kc_l, ks_l, k[sl], p_tables[m], p_hists[m],
-                        p_valids[m],
-                    )
-                    vc_l, vs_l = att.write_chunk_to_cache_quantized(
-                        vc_l, vs_l, v[sl], p_tables[m], p_hists[m],
-                        p_valids[m],
-                    )
-            else:
-                kc_l = att.write_decode_token_to_cache(
-                    kc_l, k[:B], d_tables, d_positions
-                )
-                vc_l = att.write_decode_token_to_cache(
-                    vc_l, v[:B], d_tables, d_positions
-                )
-                for m in range(MP):
-                    sl = slice(B + m * T, B + (m + 1) * T)
-                    kc_l = att.write_chunk_to_cache(
-                        kc_l, k[sl], p_tables[m], p_hists[m]
-                    )
-                    vc_l = att.write_chunk_to_cache(
-                        vc_l, v[sl], p_tables[m], p_hists[m]
-                    )
-            Hq, Dh = q.shape[1], q.shape[2]
-            q_chunks = q[B:].reshape(MP, T, Hq, Dh)
-            if mesh is not None:
-                o_dec, o_chunks = ragged_mixed_attention_sharded(
-                    q[:B], q_chunks, kc_l, vc_l, d_tables, d_seq_lens,
-                    p_tables, p_hists, p_valids, scale, mesh, window=w,
-                    sinks=lp.get("sinks"), interpret=interpret,
-                    k_scales=ks_l, v_scales=vs_l,
-                )
-            else:
-                o_dec, o_chunks = ragged_mixed_attention(
-                    q[:B], q_chunks, kc_l, vc_l, d_tables, d_seq_lens,
-                    p_tables, p_hists, p_valids, scale, window=w,
-                    sinks=lp.get("sinks"), interpret=interpret,
-                    k_scales=ks_l, v_scales=vs_l,
-                )
-            k_cache = k_cache.at[l].set(kc_l)
-            v_cache = v_cache.at[l].set(vc_l)
-            if k_scales is not None:
-                k_scales = k_scales.at[l].set(ks_l)
-                v_scales = v_scales.at[l].set(vs_l)
-            o = jnp.concatenate(
-                [o_dec.reshape(B, -1), o_chunks.reshape(MP * T, -1)]
+            vc_l, vs_l = att.write_decode_token_to_cache_quantized(
+                vc_l, vs_l, v[:B], d_tables, d_positions
             )
-            x = _layer_tail(
-                x, lp, cfg, o, lora_l, ids_all, lora_grouped=True,
-                mesh=mesh, use_pallas=True, interpret=interpret,
-                tally=moe_tally,
+            for m in range(MP):
+                sl = slice(B + m * T, B + (m + 1) * T)
+                kc_l, ks_l = att.write_chunk_to_cache_quantized(
+                    kc_l, ks_l, k[sl], p_tables[m], p_hists[m],
+                    p_valids[m],
+                )
+                vc_l, vs_l = att.write_chunk_to_cache_quantized(
+                    vc_l, vs_l, v[sl], p_tables[m], p_hists[m],
+                    p_valids[m],
+                )
+        else:
+            kc_l = att.write_decode_token_to_cache(
+                kc_l, k[:B], d_tables, d_positions
             )
+            vc_l = att.write_decode_token_to_cache(
+                vc_l, v[:B], d_tables, d_positions
+            )
+            for m in range(MP):
+                sl = slice(B + m * T, B + (m + 1) * T)
+                kc_l = att.write_chunk_to_cache(
+                    kc_l, k[sl], p_tables[m], p_hists[m]
+                )
+                vc_l = att.write_chunk_to_cache(
+                    vc_l, v[sl], p_tables[m], p_hists[m]
+                )
+        if mesh is not None:
+            o_dec, o_chunks = ragged_mixed_attention_sharded(
+                q[:B], q_chunks, kc_l, vc_l, d_tables, d_seq_lens,
+                p_tables, p_hists, p_valids, scale, mesh, window=w,
+                sinks=lp.get("sinks"), interpret=interpret,
+                k_scales=ks_l, v_scales=vs_l,
+            )
+        else:
+            o_dec, o_chunks = ragged_mixed_attention(
+                q[:B], q_chunks, kc_l, vc_l, d_tables, d_seq_lens,
+                p_tables, p_hists, p_valids, scale, window=w,
+                sinks=lp.get("sinks"), interpret=interpret,
+                k_scales=ks_l, v_scales=vs_l,
+            )
+        k_cache = k_cache.at[a].set(kc_l)
+        v_cache = v_cache.at[a].set(vc_l)
+        if k_scales is not None:
+            k_scales = k_scales.at[l].set(ks_l)
+            v_scales = v_scales.at[l].set(vs_l)
+        o = jnp.concatenate(
+            [o_dec.reshape(B, -1), o_chunks.reshape(MP * T, -1)]
+        )
+        x = _layer_tail(
+            x, lp, cfg, o, lora_l, ids_all, lora_grouped=True,
+            mesh=mesh, use_pallas=True, interpret=interpret,
+            tally=moe_tally,
+        )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits_d = _logits(params, cfg, x[:B])  # [B, V] f32
     # each segment's last REAL row only (the unfused prefill computes
@@ -1845,6 +2157,8 @@ def _mixed_fused_forward(
     p_logits = _logits(params, cfg, x[last])  # [MP, V] f32
     if k_scales is not None:
         return logits_d, p_logits, k_cache, v_cache, k_scales, v_scales
+    if track is not None:
+        return logits_d, p_logits, k_cache, v_cache, track.finish()
     return logits_d, p_logits, k_cache, v_cache
 
 
@@ -1852,7 +2166,7 @@ def _mixed_fused_forward(
     jax.jit,
     static_argnames=("cfg", "use_pallas", "mesh", "interpret",
                      "with_logprobs", "moe_counters"),
-    donate_argnames=("k_cache", "v_cache", "counts"),
+    donate_argnames=("k_cache", "v_cache", "counts", "state"),
 )
 def mixed_step(
     params: dict,
@@ -1902,6 +2216,12 @@ def mixed_step(
     # [3]; live rows are the decode rows with d_seq_lens > 0 and each
     # segment's rows below its valid length) as the LAST output
     moe_counters: bool = False,
+    # LFM2: the conv layers' state (init_state, donated), the decode
+    # rows' by slot, and the state row each prefill segment starts from
+    # and leaves its state in (a dead segment: max_batch, dropped); the
+    # new state follows v_cache in the output
+    state: Optional[dict] = None,
+    p_slots: Optional[jnp.ndarray] = None,  # [MP] int32
 ):
     """ONE device dispatch fusing M prefill chunks into a decode step.
 
@@ -1980,13 +2300,15 @@ def mixed_step(
                 )
             )
         else:
-            logits_d, p_logits, k_cache, v_cache = _mixed_fused_forward(
+            out = _mixed_fused_forward(
                 params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
                 p_tokens, p_tables, p_hists, p_valids, k_cache, v_cache,
                 mesh=mesh, interpret=interpret, lora=lora,
                 d_adapter_ids=d_adapter_ids, p_adapter_ids=p_adapter_ids,
-                moe_tally=tally,
+                moe_tally=tally, state=state, p_slots=p_slots,
             )
+            logits_d, p_logits, k_cache, v_cache = out[:4]
+            state = out[4] if state is not None else None
     else:
         # chunks first (admission order), then decode — order is
         # numerically irrelevant (independent parts) and matches the
@@ -1999,11 +2321,14 @@ def mixed_step(
                 p_valids[m], k_cache, v_cache, use_pallas=use_pallas,
                 mesh=mesh, k_scales=k_scales, v_scales=v_scales,
                 lora=lora, adapter_id=aid, moe_counters=moe_counters,
+                state=state, slot=None if state is None else p_slots[m],
             )
             if moe_counters:
                 tally.sums = tally.sums + seg[-1]
                 seg = seg[:-1]
-            if quantized:
+            if state is not None:
+                lg, k_cache, v_cache, state = seg
+            elif quantized:
                 lg, k_cache, v_cache, k_scales, v_scales = seg
             else:
                 lg, k_cache, v_cache = seg
@@ -2017,11 +2342,14 @@ def mixed_step(
                 lora=lora, adapter_ids=d_adapter_ids, moe_tally=tally,
             )
         else:
-            logits_d, k_cache, v_cache = _decode_body(
+            out = _decode_body(
                 params, cfg, d_tokens, d_positions, d_tables, d_seq_lens,
                 k_cache, v_cache, use_pallas, mesh, interpret,
                 lora=lora, adapter_ids=d_adapter_ids, moe_tally=tally,
+                state=state,
             )
+            logits_d, k_cache, v_cache = out[:3]
+            state = out[3] if state is not None else None
 
     raw_logits = logits_d
     penalized = counts is not None
@@ -2032,6 +2360,8 @@ def mixed_step(
     keys = make_keys(seeds, steps)
     nxt = sample_tokens.__wrapped__(logits_d, keys, temps, top_ks, top_ps)
     result = [nxt, p_logits, k_cache, v_cache]
+    if state is not None:
+        result.append(state)
     if quantized:
         n_requants = (
             jnp.sum(k_scales > k_scales0) + jnp.sum(v_scales > v_scales0)
@@ -2388,15 +2718,16 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
             )
         return _layer_tail(x, lp, cfg, o.reshape(T, -1)), None
 
-    if cfg.layer_windows:  # per-layer static windows: unrolled
-        for lps, n, off in layer_groups(params, cfg):
-            for li in range(n):
-                lp = _layer(lps, li)
-                l = off + li
-                x, _ = body(
-                    x, lp, window=window_for_layer(cfg, l),
-                    freqs=rope_freqs_for_layer(cfg, l, inv_freq, inv_local),
-                )
+    if cfg.layer_windows or cfg.layer_ops:
+        # per-layer static windows, per-layer operators: unrolled
+        for l, lp in _layers(params, cfg):
+            if "conv_in" in lp:
+                x = _conv_layer(x, lp, cfg, l, None)
+                continue
+            x, _ = body(
+                x, lp, window=window_for_layer(cfg, l),
+                freqs=rope_freqs_for_layer(cfg, l, inv_freq, inv_local),
+            )
     else:
         for lps, _n, _off in layer_groups(params, cfg):
             x, _ = lax.scan(body, x, lps)

@@ -49,6 +49,9 @@ KV_INT8_QMAX = 127.0
 # high-precision (tiny, or quality-critical)
 _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                "shared_gate", "shared_up", "shared_down",
+               # LFM2's conv operator: its two projections (the taps are
+               # no matmul)
+               "conv_in", "conv_out",
                # MLA projections (mla._wkv_b_parts dequants wkv_b for
                # the absorbed fold; the rest ride _mm's fused dequant)
                "wq_a", "wq_b", "wkv_a", "wkv_b")
@@ -102,7 +105,8 @@ def quantize_params(params: dict, cfg: ModelConfig, mode: str,
         raise ValueError(f"quantization must be one of {WEIGHT_MODES}")
     keys = _QUANT_KEYS + (_EXPERT_QUANT_KEYS if experts else ())
     out = dict(params)
-    for grp in ("layers", "dense_layers"):
+    # (an LFM2 stack keeps its operators in groups of their own)
+    for grp in ("layers", "dense_layers", "attn_ops", "conv_ops"):
         if grp not in params:
             continue
         layers = dict(params[grp])

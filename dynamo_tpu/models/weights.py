@@ -394,18 +394,90 @@ def load_llama_params(
                 )
         return out
 
+    def lfm2_layer_groups() -> dict:
+        """LFM2's names onto leaves stacked by KIND (llama._layers). The
+        dense model's (``conv.{in_proj,conv,out_proj}``,
+        ``self_attn.{q,k,v,out}_proj`` and ``{q,k}_layernorm``,
+        ``operator_norm``, ``ffn_norm``, ``feed_forward.{w1,w2,w3}``) are
+        transformers' ``Lfm2ForCausalLM``; the expert layers'
+        (``feed_forward.gate``, ``.expert_bias``, ``.experts.N.{w1,w2,w3}``)
+        are an offline reading of the published lfm2_moe modelling code."""
+        lay = "model.layers.{i}."
+        conv = [l for l, op in enumerate(cfg.layer_ops) if op == "conv"]
+        attn = [l for l, op in enumerate(cfg.layer_ops) if op == "attn"]
+        out = {
+            "conv_ops": {
+                "attn_norm": stack(lay + "operator_norm.weight", conv, False),
+                "conv_in": stack(lay + "conv.in_proj.weight", conv),
+                # Conv1d's [E, 1, K] -> taps [K, E], the last on the row
+                "conv_w": np.stack(
+                    [get(f"model.layers.{i}.conv.conv.weight")[:, 0, :].T
+                     for i in conv]),
+                "conv_out": stack(lay + "conv.out_proj.weight", conv),
+            },
+            "attn_ops": {
+                "attn_norm": stack(lay + "operator_norm.weight", attn, False),
+                "wq": stack(lay + "self_attn.q_proj.weight", attn),
+                "wk": stack(lay + "self_attn.k_proj.weight", attn),
+                "wv": stack(lay + "self_attn.v_proj.weight", attn),
+                "wo": stack(lay + "self_attn.out_proj.weight", attn),
+                "q_norm": stack(lay + "self_attn.q_layernorm.weight", attn,
+                                False),
+                "k_norm": stack(lay + "self_attn.k_layernorm.weight", attn,
+                                False),
+            },
+        }
+
+        def dense_ffn(rng) -> dict:
+            return {
+                "mlp_norm": stack(lay + "ffn_norm.weight", rng, False),
+                "w_gate": stack(lay + "feed_forward.w1.weight", rng),
+                "w_up": stack(lay + "feed_forward.w3.weight", rng),
+                "w_down": stack(lay + "feed_forward.w2.weight", rng),
+            }
+
+        kd = cfg.first_dense_layers if cfg.is_moe else 0
+        if kd:
+            out["dense_layers"] = dense_ffn(range(kd))
+        rest = range(kd, L)
+        if not cfg.is_moe:
+            out["layers"] = dense_ffn(rest)
+            return out
+
+        def experts(w: str) -> np.ndarray:
+            return np.stack([np.stack([
+                get(f"model.layers.{i}.feed_forward.experts.{x}.{w}.weight").T
+                for x in range(cfg.num_experts)]) for i in rest])
+
+        out["layers"] = {
+            "mlp_norm": stack(lay + "ffn_norm.weight", rest, False),
+            "moe_gate": stack(lay + "feed_forward.gate.weight", rest),
+            "we_gate": experts("w1"),
+            "we_up": experts("w3"),
+            "we_down": experts("w2"),
+        }
+        return out  # (+ the float32 expert_bias, after the cast below)
+
     kd = cfg.first_dense_layers if cfg.is_moe else 0
-    layers: dict = attn_leaves(range(kd, L))
-    layers.update(
-        moe_ffn_leaves(range(kd, L)) if cfg.is_moe
-        else dense_ffn_leaves(range(kd, L))
-    )
-    params: dict = {
-        "embed": get("model.embed_tokens.weight"),
-        "final_norm": get("model.norm.weight"),
-        "layers": layers,
-    }
-    if kd:
+    if cfg.layer_ops:
+        params: dict = {
+            "embed": get("model.embed_tokens.weight"),
+            "final_norm": get("model.embedding_norm.weight"),
+            **lfm2_layer_groups(),
+        }
+        layers = {}  # no group takes gemma's norm fold below
+    else:
+        layers: dict = attn_leaves(range(kd, L))
+        layers.update(
+            moe_ffn_leaves(range(kd, L)) if cfg.is_moe
+            else dense_ffn_leaves(range(kd, L))
+        )
+        params = {
+            "embed": get("model.embed_tokens.weight"),
+            "final_norm": get("model.norm.weight"),
+            "layers": layers,
+        }
+    if kd and not cfg.layer_ops:
         dense = attn_leaves(range(0, kd))
         dense.update(dense_ffn_leaves(range(0, kd)))
         params["dense_layers"] = dense
@@ -431,6 +503,11 @@ def load_llama_params(
         params = shard_params(params, mesh)
     else:
         params = jax.tree.map(lambda x: jnp.asarray(x, dt), params)
+    if cfg.layer_ops and cfg.moe_gate_bias:
+        # the selection bias stays float32, as published and as drawn
+        params["layers"]["moe_gate_bias"] = jnp.asarray(
+            stack("model.layers.{i}.feed_forward.expert_bias",
+                  range(kd, L), False), jnp.float32)
     for h in handles.values():
         del h
     return params

@@ -120,6 +120,44 @@ def test_phases_sum_to_the_loop_tasks_wall_time(run):
         assert delta[phase] > 0, (phase, delta)
 
 
+def test_loop_yields_only_when_no_dispatch_waited_for_the_device(run):
+    """Between two dispatches the loop does not hand the event loop a
+    pass of its own (``yield``): every dispatch waits for the device in
+    an executor, and the streams flush the last step's tokens during
+    that wait, so a pass of their own only keeps the device idle. The
+    streams still hear every token while the engine runs."""
+    async def main():
+        engine = _engine()
+        yields = 0
+        mark = engine._clock.mark
+
+        def counting(phase):
+            nonlocal yields
+            yields += phase == "yield"
+            return mark(phase)
+
+        try:
+            await _serve(engine, 1)  # programs compiled, loop running
+            engine._clock.mark = counting
+            steps0 = sum(engine.stats[f"steps_{k}"]
+                         for k in loop_clock.KINDS.values())
+            seen_while_running = 0
+            ctx = Context(_req(7, 24, 40))
+            async for _ in engine.generate(ctx):
+                seen_while_running += engine._n_active > 0
+            steps = sum(engine.stats[f"steps_{k}"]
+                        for k in loop_clock.KINDS.values()) - steps0
+        finally:
+            engine._clock.mark = mark
+            await engine.close()
+        return yields, steps, seen_while_running
+
+    yields, steps, seen = run(main())
+    assert steps >= 10  # a prefill and ten or more decode windows
+    assert yields <= 2, (yields, steps)  # at the edges, never a step
+    assert seen >= steps // 2  # tokens arrived as the steps went
+
+
 def test_counters_are_monotone_exported_and_bounded(run):
     async def main():
         engine = _engine()

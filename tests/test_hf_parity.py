@@ -756,3 +756,77 @@ def test_mla_paged_engine_matches_dense(tmp_path):
         await engine.close()
 
     asyncio.run(main())
+
+
+# ---------------- LFM2: conv and attention operators by layer ----------------
+
+
+def _lfm2_model(tmp_path):
+    hf_cfg = transformers.Lfm2Config(
+        vocab_size=256, hidden_size=64, intermediate_size=160,
+        block_multiple_of=16, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=2, max_position_embeddings=128,
+        layer_types=["conv", "full_attention", "conv", "conv"],
+        conv_L_cache=3, torch_dtype="float32",
+    )
+    model = transformers.Lfm2ForCausalLM(hf_cfg)
+    with torch.no_grad():  # ones-init norms would make the check vacuous
+        for name, p in model.named_parameters():
+            if "norm" in name:
+                p.normal_(1.0, 0.3)
+    return _save(tmp_path, model), model
+
+
+@pytest.mark.skipif(
+    not hasattr(transformers, "Lfm2Config"),
+    reason="transformers too old for LFM2",
+)
+def test_lfm2_parity(tmp_path):
+    """The dense LFM2 through the real safetensors loader: the gated
+    short convolution and its taps' order, per-head q/k norms before the
+    rotary embedding, the operator / ffn / embedding norms, the adjusted
+    FFN width and the tied head, against ``Lfm2ForCausalLM``."""
+    path, model = _lfm2_model(tmp_path)
+    cfg = ModelConfig.from_local_path(path)
+    assert cfg.layer_ops == ("conv", "attn", "conv", "conv")
+    assert cfg.intermediate_size == 112 and cfg.tie_word_embeddings
+    _compare(path, TOKENS, model)
+
+
+@pytest.mark.skipif(
+    not hasattr(transformers, "Lfm2Config"),
+    reason="transformers too old for LFM2",
+)
+def test_lfm2_decode_through_the_cache_matches_hf_cache(tmp_path):
+    """Prefill, then decoding token by token through the paged KV cache
+    and the conv state, against ``Lfm2ForCausalLM`` decoding with its own
+    ``Lfm2HybridConvCache``."""
+    path, model = _lfm2_model(tmp_path)
+    cfg = ModelConfig.from_local_path(path)
+    params = load_llama_params(path, cfg)
+    prompt, more = TOKENS[:5], TOKENS[5:] + [91, 12]
+    with torch.no_grad():
+        out = model(torch.tensor(prompt)[None], use_cache=True)
+        theirs = [out.logits[0, -1].numpy()]
+        for t in more:
+            out = model(torch.tensor([[t]]), use_cache=True,
+                        past_key_values=out.past_key_values)
+            theirs.append(out.logits[0, -1].numpy())
+    bs, N, M = 4, 8, 4
+    kc, vc = llama.init_kv_cache(cfg, N, bs)
+    state = llama.init_state(cfg, 1, N)
+    table = jnp.asarray([1, 2, 3, 4], jnp.int32)
+    toks = np.zeros(16, np.int32)
+    toks[:5] = prompt
+    logits, kc, vc, state = llama.prefill(
+        params, cfg, jnp.asarray(toks), table, jnp.int32(0), jnp.int32(5),
+        kc, vc, state=state, slot=jnp.int32(0))
+    ours = [np.asarray(logits)]
+    for i, t in enumerate(more):
+        logits, kc, vc, state = llama.decode_step(
+            params, cfg, jnp.asarray([t], jnp.int32),
+            jnp.asarray([5 + i], jnp.int32), table[None],
+            jnp.asarray([6 + i], jnp.int32), kc, vc, state=state)
+        ours.append(np.asarray(logits[0]))
+    np.testing.assert_allclose(np.stack(ours), np.stack(theirs),
+                               atol=2e-4, rtol=2e-3)

@@ -110,40 +110,53 @@ def test_decode_interleaves_with_async_flush(run, monkeypatch):
     keep streaming while a flush is in flight, and the flushed prefix
     restores bit-exact afterwards (the acceptance gate: the scheduler
     loop never blocks on a d2h eviction flush)."""
-    windows = []  # (start, end) of each fetch
+    windows = []  # (start, end) of each fetch, once the phase is armed
     real_fetch = offload_mod._device_fetch
 
     def slow_fetch(arr):
+        if not windows_armed:
+            return real_fetch(arr)
         t0 = time.monotonic()
         time.sleep(0.2)
         out = real_fetch(arr)
         windows.append((t0, time.monotonic()))
         return out
 
+    windows_armed = []
     monkeypatch.setattr(offload_mod, "_device_fetch", slow_fetch)
     engine = JaxEngine(_cfg(), seed=0)
 
     async def main():
+        token_times = []
+
+        async def run_b(base):
+            async for o in engine.generate(
+                Context(_req(range(base, base + 8), max_tokens=20))
+            ):
+                token_times.append(time.monotonic())
+
+        async def churn(base):
+            for i in range(4):
+                filler = list(range(base + 30 * i, base + 30 * i + 24))
+                await collect(engine.generate(Context(_req(filler, 2))))
+
+        # every program of the measured wave is compiled first (the
+        # prefill buckets and the decode-window ladder, then the same
+        # wave on other tokens for its mixed steps): a cold compile
+        # inside it stalls the stream for a second and decides by itself
+        # which tokens fall into which fetch
+        await engine.warmup()
+        await asyncio.gather(run_b(700), churn(500))
+        token_times.clear()
+        windows_armed.append(True)
+
         prompt_a = list(range(100, 124))  # 6 blocks of 4
         out1 = await collect(engine.generate(Context(_req(prompt_a, 4))))
         toks1 = [t for o in out1 for t in o.token_ids]
 
         # long decode B records per-token arrival times while churn
         # prompts force evictions (and therefore async flushes) under it
-        token_times = []
-
-        async def run_b():
-            async for o in engine.generate(
-                Context(_req(range(400, 408), max_tokens=20))
-            ):
-                token_times.append(time.monotonic())
-
-        async def churn():
-            for i in range(4):
-                filler = list(range(200 + 30 * i, 200 + 30 * i + 24))
-                await collect(engine.generate(Context(_req(filler, 2))))
-
-        await asyncio.gather(run_b(), churn())
+        await asyncio.gather(run_b(400), churn(200))
         assert engine.offload.d2h_flush_async_total > 0
 
         # decode progressed while a d2h was in flight: at least one B

@@ -238,6 +238,54 @@ def test_decode_window_keeps_no_copy_of_the_pool(chip, experts, head):
     assert compiled.memory_analysis().temp_size_in_bytes < slab // 2
 
 
+def test_lfm2_step_programs_keep_no_copy_of_the_state(chip):
+    """An LFM2 stack (conv and attention operators by layer, a head of
+    64, biased sigmoid experts behind a dense layer) compiles its three
+    step programs with the kernels on, the KV cache indexed by attention
+    ordinal, and writes the conv state and its per-block snapshots where
+    they lie: a decode window's temporaries stay far under the snapshot
+    pool (the scatter rides the scan's carry in place)."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(
+        vocab_size=2048, hidden_size=512, intermediate_size=1024,
+        num_layers=4, num_heads=8, num_kv_heads=2, head_dim=64,
+        layer_ops=("conv", "conv", "attn", "conv"), conv_kernel=3,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=256,
+        first_dense_layers=1, moe_scoring="sigmoid", moe_gate_bias=True,
+        qk_norm=True, tie_word_embeddings=True, topk_norm_eps=1e-6,
+    )
+    b, m, n, t = 8, 32, 4096, 64
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = shaped(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    state = shaped(jax.eval_shape(lambda: llama.init_state(cfg, b, n)))
+    cache = chip(llama.kv_cache_shapes(cfg, n, BS)[0], jnp.bfloat16)
+    assert cache.shape[0] == 1  # the attention layer only
+    ints, floats = chip((b,), jnp.int32), chip((b,), jnp.float32)
+    batch = (ints, ints, chip((b, m), jnp.int32), ints, ints, ints,
+             floats, ints, floats)
+    window = llama.decode_window.lower(
+        params, cfg, *batch, cache, cache, n_steps=2, use_pallas=True,
+        moe_counters=True, state=state).compile()
+    assert "tpu_custom_call" in window.as_text()
+    snap = state["snap"].size * 2
+    assert window.memory_analysis().temp_size_in_bytes < snap // 4
+    one = chip((1,), jnp.int32)
+    mixed = llama.mixed_step.lower(
+        params, cfg, *batch, chip((1, t), jnp.int32),
+        chip((1, m), jnp.int32), one, one, cache, cache, use_pallas=True,
+        moe_counters=True, state=state, p_slots=one).compile()
+    assert "tpu_custom_call" in mixed.as_text()
+    scalar = chip((), jnp.int32)
+    llama.prefill.lower(
+        params, cfg, chip((t,), jnp.int32), chip((m,), jnp.int32), scalar,
+        scalar, cache, cache, use_pallas=True, moe_counters=True,
+        state=state, slot=scalar).compile()
+
+
 @pytest.mark.parametrize("dtype,scales", [(jnp.bfloat16, False),
                                           (jnp.int8, True)],
                          ids=["bf16", "int8-scales"])
