@@ -67,7 +67,7 @@ PREFILL_BUCKETS = [16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192]
 #: engine.stats keys of the work counters, exported as engine_<key>_total
 WORK_COUNTERS = ("rows_dispatched", "rows_live", "prefill_tokens_dispatched",
                  "prefill_tokens_padding", "attn_table_pages",
-                 "attn_live_pages")
+                 "attn_live_pages", "sampler_filter_steps")
 #: the same for a model with expert layers (absent for a dense one):
 #: expert slots handed over and assignments of live rows, counted on the
 #: host, and what the step programs' MoeTally brings back
@@ -809,6 +809,7 @@ class JaxEngine(AsyncEngine):
         self._moe_layers = mcfg.moe_layers if mirror is None else 0
         self._moe_pending: deque = deque()
         self._state_rows = 0
+        self._filter_steps = 0  # of the open step, for its span
         self._device_awaits = 0  # waits for the device so far (_on_device)
         if self._moe_layers:
             self.stats.update(dict.fromkeys(MOE_COUNTERS, 0))
@@ -3929,6 +3930,8 @@ class JaxEngine(AsyncEngine):
         from the device by now (a pipelined window's arrive with the
         step that emits its tokens)."""
         attrs = {}
+        if self._filter_steps:
+            attrs["filter_steps"], self._filter_steps = self._filter_steps, 0
         if self.state is not None:
             # sequences whose conv state the step's dispatches advanced
             attrs["state"], self._state_rows = self._state_rows, 0
@@ -3963,6 +3966,12 @@ class JaxEngine(AsyncEngine):
         st["attn_table_pages"] += rows * width * n + seg_pages[0]
         st["attn_live_pages"] += int(((live + bs - 1) // bs).sum()) * n \
             + seg_pages[1]
+        # steps whose sampler searches for a cut: a live row samples
+        # under a top-k or a nucleus (ops/sampling._apply_topk_topp)
+        if ((self._seq_lens > 0) & (self._temps > 0)
+                & ((self._top_ks > 0) | (self._top_ps < 1.0))).any():
+            st["sampler_filter_steps"] += n
+            self._filter_steps += n
 
     def _note_prefill_work(self, dispatched: int, real: int) -> None:
         """Prefill tokens handed to the device (bucket length x

@@ -7,39 +7,28 @@ batch together — no per-request host round trips).
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
 
 
-@partial(jax.jit, static_argnames=("top_k_max",))
+@jax.jit
 def sample_tokens(
     logits: jnp.ndarray,  # [B, V] float32
     keys: jnp.ndarray,  # [B, 2] uint32 PRNG keys (jax.random.key data)
     temperature: jnp.ndarray,  # [B] 0 => greedy
     top_k: jnp.ndarray,  # [B] 0 => disabled
     top_p: jnp.ndarray,  # [B] 1.0 => disabled
-    top_k_max: int = 0,  # static cap for the top-k sort width (0 = full V)
 ) -> jnp.ndarray:  # [B] int32
     """The hot paths are gated with lax.cond so a batch that needs none of
     the machinery pays none of it: an all-greedy batch is one argmax, and
-    a filter-free sampled batch skips the full-vocab sort entirely (the
-    sort dominated fused decode-window time at V=32k before this —
-    tokens/s, not correctness, rides on these two conds)."""
-    B, V = logits.shape
+    a sampled batch pays the top-k search only if a row asks for top-k and
+    the nucleus search only if a row asks for one (_apply_topk_topp)."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def do_sample(scaled: jnp.ndarray) -> jnp.ndarray:
-        needs_filter = jnp.any((top_k > 0) | (top_p < 1.0))
-        scaled = jax.lax.cond(
-            needs_filter,
-            lambda s: _apply_topk_topp(s, top_k, top_p),
-            lambda s: s,
-            scaled,
-        )
+        scaled = _apply_topk_topp(scaled, top_k, top_p)
 
         def sample_one(key_data, row):
             key = jax.random.wrap_key_data(key_data)
@@ -54,26 +43,92 @@ def sample_tokens(
     return jax.lax.cond(all_greedy, lambda s: greedy, do_sample, scaled)
 
 
+def _flip(b: jnp.ndarray) -> jnp.ndarray:
+    # sign-magnitude <-> two's complement order; its own inverse
+    return b ^ ((b >> 31) & 0x7FFFFFFF)
+
+
+def _order_key(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> int32, order-preserving: a < b <=> key(a) < key(b), but
+    for -0.0, whose key is just below 0.0's."""
+    return _flip(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+
+def _key_value(k: jnp.ndarray) -> jnp.ndarray:
+    """The float32 of an order key (a key between two finite values' keys
+    is a finite value's)."""
+    return jax.lax.bitcast_convert_type(_flip(k), jnp.float32)
+
+
+def _cut_value(scaled: jnp.ndarray, weigh, bound: jnp.ndarray) -> jnp.ndarray:
+    """Per row of ``scaled`` [B, V] the smallest float32 t, [B], with
+    sum(weigh(s) over the row's s > t) < bound. The predicate is monotone
+    in t, so a search over the float32 order finds it: the int32 keys
+    between the row's smallest and largest value, probed at the three
+    quarter points a trip, 16 trips for the whole int32 range. A trip is
+    ONE pass over ``scaled`` (the three compare-select-reduces fuse, and
+    ``weigh`` is recomputed, not stored), and the pass is what a trip
+    costs. The row's values >= t are then its values >= the smallest
+    VALUE that meets the predicate. A row whose largest value fails it
+    (bound <= 0) keeps that value alone."""
+
+    def mean(a, b):  # floor((a + b) / 2) without overflow
+        return (a & b) + ((a ^ b) >> 1)
+
+    def trip(_, lo_hi):
+        lo, hi = lo_hi
+        mid = mean(lo, hi)
+        q1, q3 = mean(lo, mid), mean(mid, hi)
+        w = weigh(scaled)
+        ok1, ok2, ok3 = (
+            jnp.sum(jnp.where(scaled > _key_value(q)[:, None], w, 0), axis=-1)
+            < bound
+            for q in (q1, mid, q3)
+        )
+        lo = jnp.where(ok1, lo, jnp.where(ok2, q1, jnp.where(ok3, mid, q3)) + 1)
+        hi = jnp.where(ok1, q1, jnp.where(ok2, mid, jnp.where(ok3, q3, hi)))
+        return lo, hi
+
+    ends = (_order_key(jnp.min(scaled, axis=-1)),
+            _order_key(jnp.max(scaled, axis=-1)))
+    return _key_value(jax.lax.fori_loop(0, 16, trip, ends)[1])
+
+
 def _apply_topk_topp(
     scaled: jnp.ndarray, top_k: jnp.ndarray, top_p: jnp.ndarray
 ) -> jnp.ndarray:
-    """Mask temperature-scaled logits to the top-k / nucleus support."""
-    V = scaled.shape[-1]
-    # top-k: mask everything below the k-th largest
-    kth = jnp.where(top_k > 0, jnp.minimum(top_k, V), V)  # [B]
-    sorted_desc = -jnp.sort(-scaled, axis=-1)  # [B, V] descending
-    kth_val = jnp.take_along_axis(
-        sorted_desc, (kth - 1)[:, None], axis=1
-    )  # [B,1]
-    scaled = jnp.where(scaled < kth_val, NEG_INF, scaled)
-    # top-p (nucleus): keep smallest set with cumulative prob >= p
-    probs_sorted = jax.nn.softmax(sorted_desc, axis=-1)
-    cum = jnp.cumsum(probs_sorted, axis=-1)
-    inside = cum - probs_sorted < top_p[:, None]
-    thresh = jnp.min(
-        jnp.where(inside, sorted_desc, jnp.inf), axis=-1, keepdims=True
-    )
-    return jnp.where(scaled < thresh, NEG_INF, scaled)
+    """Mask temperature-scaled logits to the top-k / nucleus support:
+    keep s where s >= max(kth_val, thresh), NEG_INF elsewhere, ties at
+    either cut kept, with
+
+      * kth_val = the min(top_k, V)-th largest value of the row (top_k 0:
+        no cut),
+      * thresh = the smallest value v of the row with
+        mass(s > v) < top_p * Z, mass summing exp(s - max) and Z that sum
+        over the WHOLE row: the nucleus of the unfiltered distribution
+        (top_p >= 1: no cut).
+
+    Neither needs the row sorted: each is a threshold search (_cut_value)
+    and each runs only if a row of the batch asks for it."""
+    B, V = scaled.shape
+    no_cut = jnp.full((B,), -jnp.inf, jnp.float32)
+
+    def topk_cut(_):
+        k = jnp.where(top_k > 0, jnp.minimum(top_k, V), V)
+        return _cut_value(scaled, lambda s: 1, k)  # a count
+
+    def nucleus_cut(_):
+        top = jnp.max(scaled, axis=-1, keepdims=True)
+        mass = lambda s: jnp.exp(s - top)  # noqa: E731
+        cut = _cut_value(scaled, mass, top_p * jnp.sum(mass(scaled), axis=-1))
+        return jnp.where(top_p < 1.0, cut, -jnp.inf)
+
+    kth_val = jax.lax.cond(
+        jnp.any(top_k > 0), topk_cut, lambda _: no_cut, None)
+    thresh = jax.lax.cond(
+        jnp.any(top_p < 1.0), nucleus_cut, lambda _: no_cut, None)
+    cut = jnp.maximum(kth_val, thresh)[:, None]
+    return jnp.where(scaled < cut, NEG_INF, scaled)
 
 
 def filtered_dist(
@@ -120,9 +175,8 @@ def speculative_accept(
 
     def sampled_path(_):
         # per-position filtered distributions (flattened over B*T); the
-        # full-vocab sort/softmax runs ONLY for batches with sampled rows
-        # (same all-greedy gating discipline as sample_tokens — the sort
-        # dominates fused-step time at V=32k)
+        # searches and the softmax run ONLY for batches with sampled rows
+        # (same all-greedy gating discipline as sample_tokens)
         scaled = filtered_dist(
             logits.reshape(B * T, V), jnp.repeat(temperature, T),
             jnp.repeat(top_k, T), jnp.repeat(top_p, T),

@@ -278,30 +278,6 @@ def _prefill_lower(cfg: ModelConfig, seq: int, block: int = 16):
     )
 
 
-def _cumulative_overcount(lowered, batch: int, vocab: int) -> float:
-    """Second cost-model correction: ``jnp.cumsum`` over the vocab (the
-    top-p nucleus mask in ops/sampling.py) lowers to a prefix
-    ``reduce_window``, which HLO cost analysis prices at one add per
-    window element — B·V·V FLOPs for a [B, V] cumsum (verified: exactly
-    V² for B=1 on both the CPU and TPU lowerings).  The executed cost
-    is a linear scan (≈ 2·B·V).  Scan the module for reduce_windows
-    producing a [B, V] f32 result and re-price each; like the
-    ragged_dot correction, this is exact arithmetic on a known
-    mispricing, not a tuning knob."""
-    text = lowered.as_text()
-    sig = f"tensor<{batch}x{vocab}xf"
-    n = 0
-    idx = 0
-    while True:
-        i = text.find("stablehlo.reduce_window", idx)
-        if i < 0:
-            break
-        if sig in text[i : i + 3000]:
-            n += 1
-        idx = i + 1
-    return n * (float(batch) * vocab * vocab - 2.0 * batch * vocab)
-
-
 def _ragged_overcount(cfg: ModelConfig, rows: int) -> float:
     """HLO cost analysis prices each ragged_dot as a dense dot over the
     FULL expert stack; the executed group GEMM contracts each row against
@@ -316,13 +292,17 @@ def _ragged_overcount(cfg: ModelConfig, rows: int) -> float:
     return (cfg.num_experts - 1) * per_layer_true
 
 
-def _fit_layers(cfg: ModelConfig, lower_fn, correction_per_moe_layer: float,
-                intercept_correction_fn=None):
+def _fit_layers(cfg: ModelConfig, lower_fn, correction_per_moe_layer: float):
     """Lower the real program at two small depths, return the exact
     full-depth FLOPs (+ the CA bytes, same fit) with the ragged-dot
-    correction applied per MoE layer and ``intercept_correction_fn``
-    (the cumsum mispricing — depth-independent, sampling runs once per
-    step not per layer) subtracted once from the first lowering.
+    correction applied per MoE layer.
+
+    Cost analysis prices a loop body ONCE: of the sampler's threshold
+    search (ops/sampling._cut_value: 16 trips of three compare-select-
+    reduces over [B, V], and as many again where a row asks for top-k)
+    one trip is counted, so at most 2 x 15 x 3 x 3 x B x V integer and
+    float ops a step go uncounted, under a thousandth of a step's FLOPs
+    for every model of the table.
 
     An unrolled program hands the grouped-matmul kernel the WHOLE
     [L, X, ...] expert stack (llama._layer), and cost analysis prices a
@@ -336,8 +316,7 @@ def _fit_layers(cfg: ModelConfig, lower_fn, correction_per_moe_layer: float,
     l1, l2 = k + 1, k + 2
     c1 = replace(cfg, num_layers=l1, layer_windows=())
     c2 = replace(cfg, num_layers=l2, layer_windows=())
-    lo1 = lower_fn(c1)
-    a1 = lo1.cost_analysis()
+    a1 = lower_fn(c1).cost_analysis()
     a2 = lower_fn(c2).cost_analysis()
     a3 = (lower_fn(replace(cfg, num_layers=k + 3, layer_windows=()))
           .cost_analysis() if cfg.is_moe else None)
@@ -356,8 +335,6 @@ def _fit_layers(cfg: ModelConfig, lower_fn, correction_per_moe_layer: float,
     bytes_ = a1.get("bytes accessed", 0.0) + n_var * per_layer_b
     n_moe = (cfg.num_layers - k) if cfg.is_moe else 0
     flops -= n_moe * correction_per_moe_layer
-    if intercept_correction_fn is not None:
-        flops -= intercept_correction_fn(lo1)
     return flops, bytes_
 
 
@@ -367,8 +344,7 @@ def decode_flops_per_token(cfg: ModelConfig, batch: int, ctx: int) -> dict:
     rows = batch * cfg.num_experts_per_tok if cfg.is_moe else 0
     corr = _ragged_overcount(cfg, rows)
     flops, ca_bytes = _fit_layers(
-        cfg, lambda c: _decode_lower(c, batch, ctx), corr,
-        lambda lo: _cumulative_overcount(lo, batch, cfg.vocab_size))
+        cfg, lambda c: _decode_lower(c, batch, ctx), corr)
     return {"flops_step": flops, "flops_per_token": flops / batch,
             "xla_unfused_bytes": ca_bytes}
 

@@ -3,12 +3,12 @@ perf case).
 
 The modeled tokens/s/chip + MFU table (benchmarks/roofline_model.json,
 docs/performance.md) is only as trustworthy as its two mechanical
-inputs: cost_analysis() FLOPs with the two documented repricings
-(ragged_dot dense-overcount, cumsum reduce_window overcount), and the
-analytic byte stream.  These tests pin each input:
+inputs: cost_analysis() FLOPs with the documented repricing (ragged_dot
+dense-overcount), and the analytic byte stream.  These tests pin each
+input:
 
-* both repricing corrections are validated against the mispricing they
-  claim to fix (negative controls: if an XLA upgrade fixes the pricing,
+* the repricing correction is validated against the mispricing it
+  claims to fix (a negative control: if an XLA upgrade fixes the pricing,
   the control FAILS and the correction must be deleted — same honesty
   contract as test_compiled_perf.py's scatter detector);
 * the corrected full-depth FLOPs match a from-first-principles count of
@@ -36,7 +36,7 @@ ART = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
 
 
 # ---------------------------------------------------------------------------
-# the two cost-model corrections stay pinned to real mispricings
+# the cost-model correction stays pinned to a real mispricing
 # ---------------------------------------------------------------------------
 
 
@@ -55,34 +55,6 @@ def test_ragged_dot_is_priced_dense_by_cost_analysis():
     assert ca["flops"] == pytest.approx(dense, rel=0.02), (
         f"ragged_dot no longer priced dense ({ca['flops']:.3g} vs "
         f"{dense:.3g}) — delete the _ragged_overcount correction"
-    )
-
-
-def test_cumsum_is_priced_quadratic_by_cost_analysis():
-    """Negative control for the sampling correction: a [1, V] cumsum must
-    still be priced ~V² (reduce_window pricing).  If this fails, delete
-    _cumulative_overcount."""
-    V = 4096
-    ca = jax.jit(lambda x: jnp.cumsum(x, axis=-1)).lower(
-        jax.ShapeDtypeStruct((1, V), jnp.float32)).cost_analysis()
-    assert ca["flops"] >= 0.9 * V * V, (
-        f"cumsum priced at {ca['flops']:.3g} ≪ V²={V*V} — delete the "
-        "_cumulative_overcount correction"
-    )
-
-
-def test_cumulative_overcount_detects_the_window_cumsum():
-    """The detector must find exactly the top-p cumsum in the real
-    decode_window lowering (one [B, V] reduce_window)."""
-    cfg = ModelConfig.tiny()
-    lo = R._decode_lower(
-        ModelConfig.tiny(num_layers=1), batch=2, ctx=32)
-    over = R._cumulative_overcount(lo, 2, cfg.vocab_size)
-    V = cfg.vocab_size
-    expect = 2.0 * V * V - 2.0 * 2 * V
-    assert over == pytest.approx(expect), (
-        "expected exactly ONE [B,V] cumsum (the top-p nucleus mask) in "
-        f"the decode window; detector returned {over} (≈{over/expect:.2f}×)"
     )
 
 
