@@ -1007,6 +1007,14 @@ class JaxEngine(AsyncEngine):
         for kind in KINDS.values():
             out[f'engine_steps_total{{kind="{kind}"}}'] = self.stats[
                 f"steps_{kind}"]
+            # a step's seconds (idle apart), device steps and seconds
+            # with nothing outstanding on the device, by its kind
+            out[f'engine_step_seconds_total{{kind="{kind}"}}'] = round(
+                self.stats[f"step_seconds_{kind}"], 6)
+            out[f'engine_device_steps_total{{kind="{kind}"}}'] = self.stats[
+                f"device_steps_{kind}"]
+            out[f'engine_step_exposed_seconds_total{{kind="{kind}"}}'] = round(
+                self.stats[f"step_exposed_seconds_{kind}"], 6)
         for name in WORK_COUNTERS + (MOE_COUNTERS if self._moe_layers else ()):
             out[f"engine_{name}_total"] = self.stats[name]
         if self.state is not None:
@@ -1361,7 +1369,11 @@ class JaxEngine(AsyncEngine):
         slows exactly the host code whose gaps a profile is meant to
         size, and under ``--trace`` the loop's own annotations
         (tracing/loop_clock.py) say what the host did, on the clock of
-        the device ops."""
+        the device ops. The capture starts when asked and lasts
+        ``seconds``; if the loop enqueued nothing in that time (no
+        request was being served) it goes on until the first step that
+        follows is done, ``3 * seconds`` more at most, so that a reader
+        is not handed a profile without one device op."""
         import tempfile
 
         if out_dir is None:
@@ -1377,7 +1389,16 @@ class JaxEngine(AsyncEngine):
                 # the trace's first event: readers place the capture on
                 # their own clock by it
                 with jax.profiler.TraceAnnotation("engine.profile"):
+                    clk, seen = self._clock, self._clock.programs
                     time.sleep(seconds)
+                    if clk.programs == seen:  # nothing was dispatched
+                        late = time.monotonic() + 3 * seconds
+                        while (clk.programs == seen
+                               and time.monotonic() < late):
+                            time.sleep(0.005)
+                        step = clk.seq  # the step of the first dispatch
+                        while clk.seq == step and time.monotonic() < late:
+                            time.sleep(0.005)
             finally:
                 jax.profiler.stop_trace()
             return out_dir
@@ -2386,7 +2407,9 @@ class JaxEngine(AsyncEngine):
                 )
             if st.pos < len(st.seq.tokens):
                 return None
-            return self._sample_prefill(st.seq, logits)  # (token, lp_entry)
+            first = self._sample_prefill(st.seq, logits)  # (token, lp_entry)
+            self._clock.landed()
+            return first
         finally:
             # accumulate DEVICE time only: chunks of a long prompt
             # interleave with other requests' decode steps, so the
@@ -3436,6 +3459,9 @@ class JaxEngine(AsyncEngine):
         self._inflight = {
             "toks": toks, "n": n,
             "lps": self._window_logprobs,
+            # the clock's number of the window's program: its fetch
+            # settles the programs up to it (tracing/loop_clock.py)
+            "program": self._clock.programs,
             "slots": {i: s for i, s in enumerate(self._active)
                       if s is not None},
         }
@@ -3848,6 +3874,7 @@ class JaxEngine(AsyncEngine):
                 tuple(np.asarray(jax.device_get(a)) for a in lps_dev)
                 if lps_dev is not None else None
             )
+            self._clock.landed()
             return toks_host, lps, completed
         finally:
             # the fused dispatch's device time lands on the traced
@@ -4008,6 +4035,7 @@ class JaxEngine(AsyncEngine):
             if penalized:
                 self._pen_counts = rest.pop(0)
             lps = rest.pop(0) if want_lp else None
+            self._clock.landed()
             return toks, n_acc, lps
         kwargs = {}
         if penalized:
@@ -4048,11 +4076,9 @@ class JaxEngine(AsyncEngine):
             tuple(np.asarray(jax.device_get(a)) for a in lps_dev)
             if lps_dev is not None else None
         )
-        return (
-            np.asarray(jax.device_get(toks)),
-            np.asarray(jax.device_get(n_acc)),
-            lps,
-        )
+        toks, n_acc = (np.asarray(jax.device_get(a)) for a in (toks, n_acc))
+        self._clock.landed()
+        return toks, n_acc, lps
 
     async def _drain_inflight(self) -> None:
         """Sync + emit the pending pipelined window, if any."""
@@ -4079,6 +4105,7 @@ class JaxEngine(AsyncEngine):
                 # would wait on a cross-process collective the followers
                 # never join)
                 lp = tuple(np.asarray(a.addressable_data(0)) for a in lp)
+            self._clock.landed(window["program"])
             return toks, lp
 
         toks_host, lps = await self._on_device(

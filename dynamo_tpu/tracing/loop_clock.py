@@ -16,8 +16,11 @@ A *step* is everything between two dispatches' ends (:meth:`step_done`):
 its phase times tile the loop's wall time, and three readers share them:
 
 * always on — cumulative seconds per phase and dispatches per kind in
-  the engine's ``stats`` (``/metrics``), and ONE warning for a step that
-  took far longer than its kind leads one to expect;
+  the engine's ``stats`` (``/metrics``); by the step's kind, its seconds
+  (``idle`` apart), its device steps and its *exposed* seconds, those
+  with no program of the loop's outstanding on the device (below); and
+  ONE warning for a step that took far longer than its kind leads one
+  to expect;
 * under ``--trace`` — one ``engine.step`` span per step in the
   recorder's ring (never handed to the sink: the loop is one endless
   trace), and the same boundaries as ``jax.profiler.TraceAnnotation``
@@ -25,6 +28,13 @@ its phase times tile the loop's wall time, and three readers share them:
   ``provision`` / ``dispatch`` / ``device`` / ``emit`` on the clock of
   the device ops. Annotations open only around synchronous sections and
   inside executor thunks, never across an ``await``.
+
+A program is *outstanding* from :meth:`enqueued` (the jit call
+returned) until its result is on the host (:meth:`landed`, called where
+the engine fetches it). The device runs programs in order, so a result
+on the host settles every program enqueued before it too. Seconds with
+one or more outstanding are *covered*; a step's exposed seconds are its
+wall time, ``idle`` apart, less its covered ones.
 
 Tracing off costs one attribute check per mark.
 """
@@ -52,6 +62,9 @@ STEP_SPAN = "engine.step"
 _SYNC = frozenset(("admit", "provision", "emit"))
 _SECONDS = {p: f"loop_seconds_{p}" for p in PHASES}
 _STEPS = {k: f"steps_{k}" for k in KINDS.values()}
+#: booked once a step under its kind: (seconds, device steps, exposed)
+_BY_KIND = {k: (f"step_seconds_{k}", f"device_steps_{k}",
+                f"step_exposed_seconds_{k}") for k in KINDS.values()}
 
 # The slow-step rule: a step is slow when its wall time (idle apart)
 # exceeds what its kind leads one to expect — the mean wall time per
@@ -78,6 +91,8 @@ class LoopClock:
         self.stats = stats  # the engine's: totals go out through /metrics
         stats.update(dict.fromkeys(_SECONDS.values(), 0.0))
         stats.update(dict.fromkeys(_STEPS.values(), 0), slow_steps=0)
+        for seconds, steps, exposed in _BY_KIND.values():
+            stats.update({seconds: 0.0, steps: 0, exposed: 0.0})
         self.recorder = recorder
         self.trace = TraceContext.new()  # the loop's own: one per engine
         #: number of the open step; request spans name it (``step=``)
@@ -94,6 +109,13 @@ class LoopClock:
         self._th_thread: Optional[int] = None
         self._th_ann = None
         self._info: dict = {}
+        # programs enqueued by the loop's thunks, how many of them are
+        # known done, when the count outstanding rose from 0 (None at
+        # 0) and the open step's covered seconds
+        self.programs = 0
+        self._landed = 0
+        self._out_since: Optional[float] = None
+        self._covered = 0.0
         self._history = {k: deque(maxlen=SLOW_STEP_HISTORY)
                          for k in KINDS.values()}
 
@@ -124,6 +146,9 @@ class LoopClock:
         self.stats[_SECONDS[prev]] += d
         self._step[prev] += d
         self.phase, self._t = phase, now
+        if phase == "idle":
+            # nobody is left to fetch what is still outstanding
+            self._settle_programs(self.programs, now)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
@@ -188,8 +213,16 @@ class LoopClock:
         kind, n = info.get("kind", "?"), max(info.get("n", 1), 1)
         dur = sum(step.values())
         busy = dur - step["idle"]
+        if self._out_since is not None:
+            # split the open interval here, so that steps tile it
+            self._covered += self._t - self._out_since
+            self._out_since = self._t
+        exposed = max(busy - self._covered, 0.0)
+        self._covered = 0.0
         if kind in _STEPS:
             self.stats[_STEPS[kind]] += 1
+            for name, d in zip(_BY_KIND[kind], (busy, n, exposed)):
+                self.stats[name] += d
             hist = self._history[kind]
             cold = bool(info.get("cold"))
             expected = n * sum(hist) / len(hist) if hist else 0.0
@@ -218,6 +251,7 @@ class LoopClock:
                 key=str(info.get("key")), n=n, live=info.get("live"),
                 rows=info.get("rows"),
                 phases={p: round(step[p] * 1e3, 3) for p in PHASES},
+                exposed_ms=round(exposed * 1e3, 3),
                 **attrs,
             )
         self.seq += 1
@@ -258,11 +292,33 @@ class LoopClock:
                 kind=kind, key=str(key[1:]), n=n, live=live)
 
     def enqueued(self) -> None:
-        """The program is enqueued: from here the thunk waits for the
-        device. Only the first call of a thunk counts."""
-        if threading.get_ident() != self._th_thread or self._th[1] is not None:
+        """The program is enqueued: it is outstanding, and from here the
+        thunk waits for the device (only a thunk's first call moves it
+        from ``dispatch`` to ``device``)."""
+        if threading.get_ident() != self._th_thread:
             return
-        self._th[1] = time.perf_counter()
+        now = time.perf_counter()
+        self.programs += 1
+        if self._out_since is None:
+            self._out_since = now
+        if self._th[1] is not None:
+            return
+        self._th[1] = now
         if self._th_ann is not None:
             self._th_ann.__exit__(None, None, None)
             self._th_ann = _annotate("engine.device", seq=self.seq)
+
+    def landed(self, program: Optional[int] = None) -> None:
+        """A result is on the host: that of the ``program``-th enqueued
+        (:attr:`programs` as its dispatch left it), or of the newest.
+        It and every program before it are outstanding no longer."""
+        if threading.get_ident() == self._th_thread:
+            self._settle_programs(
+                self.programs if program is None else program,
+                time.perf_counter())
+
+    def _settle_programs(self, upto: int, now: float) -> None:
+        self._landed = max(self._landed, upto)
+        if self._landed >= self.programs and self._out_since is not None:
+            self._covered += now - self._out_since
+            self._out_since = None

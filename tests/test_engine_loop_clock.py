@@ -2,9 +2,10 @@
 phase accounting and work counters on the tiny engine.
 
 Always on: the eight phases sum to the loop task's wall time, the work
-counters go out through ``device_path_stats`` and a step that takes far
-longer than its kind leads one to expect logs ONE warning naming the
-phase. Under tracing: one ``engine.step`` span per dispatch in the
+counters go out through ``device_path_stats``, a step's seconds are
+booked by its kind and by whether a program was outstanding on the
+device, and a step that takes far longer than its kind leads one to
+expect logs ONE warning naming the phase. Under tracing: one ``engine.step`` span per dispatch in the
 recorder's ring (never the sink), the same boundaries as profiler
 annotations, and a profile written where the caller asks, without the
 Python tracer.
@@ -40,6 +41,15 @@ COUNTER_PAIRS = (  # (part, whole): part <= whole at every scrape
      "engine_prefill_tokens_dispatched_total"),
     ("engine_attn_live_pages_total", "engine_attn_table_pages_total"),
 )
+KINDS = tuple(loop_clock.KINDS.values())
+STEP_SECONDS = 'engine_step_seconds_total{kind="%s"}'
+STEP_EXPOSED = 'engine_step_exposed_seconds_total{kind="%s"}'
+DEVICE_STEPS = 'engine_device_steps_total{kind="%s"}'
+KIND_SERIES = [f % k for f in (STEP_SECONDS, STEP_EXPOSED, DEVICE_STEPS)
+               for k in KINDS]
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: a dense, an expert and a conv-state model (the benchmark's stand-ins)
+MODELS = {"dense": None, "expert": "tiny-olmoe", "conv_state": "tiny-lfm2"}
 NEW_SERIES = (
     [f'engine_loop_seconds_total{{phase="{p}"}}' for p in PHASES]
     + [f'engine_steps_total{{kind="{k}"}}'
@@ -59,6 +69,15 @@ def _tracing_and_faults_off():
     tracing.configure(enabled=False, sink=None)
     tracing.RECORDER.clear()
     faultpoints.reset()
+
+
+def _model(name: str) -> ModelConfig:
+    if MODELS[name] is None:
+        return ModelConfig.tiny()
+    with open(os.path.join(REPO, "chipbench", "testdata", MODELS[name],
+                           "config.json")) as f:
+        return ModelConfig.from_hf_config(
+            dict(json.load(f), torch_dtype="float32"))
 
 
 def _engine(**kw):
@@ -439,3 +458,322 @@ def test_trace_engine_endpoint_serves_the_ring(run):
         s["name"] == tracing.STEP_SPAN for s in body["spans"])
     assert len(chrome["traceEvents"]) == len(body["spans"])
     assert chrome["traceEvents"][0]["args"]["phases"]
+
+
+# ---------------- a step's seconds by its kind, covered and exposed ----------------
+
+
+def _by_kind(stats, series=STEP_SECONDS):
+    return sum(stats[series % k] for k in KINDS)
+
+
+_SERVED = {}
+
+
+def _served(run, name):
+    """Two waves on ``name``'s engine, scraped every 2 ms and once more
+    after the drain: (scrapes, the clock's phase totals at that last
+    scrape, the open step's seconds but ``idle``)."""
+    if name in _SERVED:
+        return _SERVED[name]
+
+    async def main():
+        engine = _engine(model=_model(name))
+        scrapes = [engine.device_path_stats()]
+
+        async def scrape():
+            while True:
+                await asyncio.sleep(0.002)
+                scrapes.append(engine.device_path_stats())
+
+        scraper = asyncio.create_task(scrape())
+        try:
+            await _wave(engine, 900)
+            await _wave(engine, 950)
+            await asyncio.sleep(0.05)  # drained: the loop idles
+        finally:
+            scraper.cancel()
+            scrapes.append(engine.device_path_stats())
+            clk = engine._clock
+            totals = clk.totals()
+            open_step = sum(v for p, v in clk._step.items() if p != "idle")
+            if clk.phase not in (None, "idle"):
+                open_step += time.perf_counter() - clk._t
+            await engine.close()
+        return scrapes, totals, open_step
+
+    _SERVED[name] = run(main())
+    return _SERVED[name]
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_kinds_seconds_sum_to_the_phases_but_idle(run, name):
+    scrapes, totals, open_step = _served(run, name)
+    last = scrapes[-1]
+    busy = sum(v for p, v in totals.items() if p != "idle")
+    assert _by_kind(last) > 0
+    # every second but idle's is booked under the kind of its step, but
+    # for the open step's (what followed the last dispatch)
+    assert _by_kind(last) + open_step == pytest.approx(busy, rel=1e-3)
+    assert _by_kind(last) == pytest.approx(busy, rel=0.01)
+    # and a kind's steps are counted where its seconds are
+    for k in KINDS:
+        assert (last[STEP_SECONDS % k] > 0) == (
+            last[f'engine_steps_total{{kind="{k}"}}'] > 0), k
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_exposed_is_a_part_of_a_kinds_seconds_monotone_and_exported(
+        run, name):
+    scrapes, _totals, _open = _served(run, name)
+    for series in KIND_SERIES:
+        values = [s[series] for s in scrapes]  # KeyError: not exported
+        assert all(b >= a for a, b in zip(values, values[1:])), series
+    for s in scrapes:
+        for k in KINDS:
+            assert 0 <= s[STEP_EXPOSED % k] <= s[STEP_SECONDS % k] + 1e-6, k
+    last = scrapes[-1]
+    # the device had work for a part of the time, and not for all of it
+    assert 0 < _by_kind(last, STEP_EXPOSED) < _by_kind(last)
+    assert last[STEP_SECONDS % "verify"] == 0  # no speculation here
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_device_steps_but_prefills_are_the_decode_steps_after_a_drain(
+        run, name):
+    scrapes, _totals, _open = _served(run, name)
+    last = scrapes[-1]
+    assert sum(last[DEVICE_STEPS % k] for k in KINDS if k != "prefill") \
+        == last["engine_decode_steps_total"] > 0
+    # a window of n counts n, every other dispatch 1
+    assert last[DEVICE_STEPS % "decode_window"] > last[
+        'engine_steps_total{kind="decode_window"}']
+    for k in ("mixed_step", "prefill"):
+        assert last[DEVICE_STEPS % k] == last[
+            f'engine_steps_total{{kind="{k}"}}'] > 0, k
+
+
+def _serve_measured(run, setup=None, max_tokens=24, **engine_kw):
+    """One request on a warm engine: the rise of (seconds, exposed) over
+    all kinds and of the loop's ``emit`` seconds. ``setup(engine)`` arms
+    what the measured request is to meet; without one the reading is
+    the file's baseline, taken once."""
+    if setup is None and "base" in _SERVED:
+        return _SERVED["base"]
+
+    async def main():
+        engine = _engine(**engine_kw)
+        try:
+            await _serve(engine, 40, max_tokens=max_tokens)  # compiled
+            await _serve(engine, 41, max_tokens=max_tokens)
+            if setup is not None:
+                setup(engine)
+            a, ta = engine.device_path_stats(), engine._clock.totals()
+            await _serve(engine, 42, max_tokens=max_tokens)
+            await asyncio.sleep(0.02)
+            b, tb = engine.device_path_stats(), engine._clock.totals()
+        finally:
+            await engine.close()
+        return (_by_kind(b) - _by_kind(a),
+                _by_kind(b, STEP_EXPOSED) - _by_kind(a, STEP_EXPOSED),
+                tb["emit"] - ta["emit"])
+
+    out = run(main())
+    if setup is None:
+        _SERVED["base"] = out
+    return out
+
+
+def test_a_delay_before_the_enqueue_is_exposed(run):
+    """``mid_dispatch`` sits before the jit call: nothing is outstanding
+    while a dispatch stalls there."""
+    delay = 0.5
+    base_s, base_x, _ = _serve_measured(run)
+    seconds, exposed, _ = _serve_measured(
+        run, lambda _e: faultpoints.arm(
+            "mid_dispatch", "delay", after=3, delay_s=delay))
+    assert exposed >= delay
+    assert exposed - base_x >= 0.9 * delay
+    covered, base_c = seconds - exposed, base_s - base_x
+    assert covered - base_c < 0.3 * delay
+
+
+def test_a_delay_inside_the_result_fetch_is_covered(run, monkeypatch):
+    """The program stays outstanding until its result is on the host:
+    a slow fetch is the device's time (or the link's), not the host's."""
+    import jax
+
+    delay, fetches = 0.05, []
+    real = jax.device_get
+
+    def slow(x):
+        fetches.append(1)
+        time.sleep(delay)
+        return real(x)
+
+    base_s, base_x, _ = _serve_measured(run)
+    seconds, exposed, _ = _serve_measured(
+        run, lambda _e: monkeypatch.setattr(jax, "device_get", slow))
+    monkeypatch.undo()
+    injected = delay * len(fetches)
+    assert len(fetches) >= 6  # the first token and the windows
+    covered, base_c = seconds - exposed, base_s - base_x
+    assert covered - base_c >= 0.9 * injected
+    assert exposed - base_x < 0.2 * injected
+
+
+@pytest.mark.parametrize("pipeline", [False, True])
+def test_a_pipelined_windows_emit_is_not_exposed(run, pipeline):
+    """With ``decode_pipeline`` window k+1 is enqueued before window k
+    is fetched, so the emission of k has a program outstanding beside
+    it; without, the device has nothing while the loop emits."""
+    def slow_emit(engine):
+        emit = engine._emit_token
+
+        def slow(*a, **kw):
+            time.sleep(0.005)
+            return emit(*a, **kw)
+
+        engine._emit_token = slow
+
+    _seconds, exposed, emit = _serve_measured(
+        run, slow_emit, max_tokens=48, decode_pipeline=pipeline)
+    assert emit >= 48 * 0.005
+    if pipeline:
+        # the first token's and the last window's emission are exposed
+        assert exposed < 0.5 * emit
+    else:
+        assert exposed >= 0.95 * emit
+
+
+def test_step_span_carries_exposed_ms(run):
+    tracing.configure(enabled=True, service="t")
+
+    async def main():
+        engine = _engine()
+        try:
+            await _wave(engine, 1000)
+            return engine.device_path_stats()
+        finally:
+            await engine.close()
+
+    stats = run(main())
+    steps = tracing.RECORDER.spans(name=tracing.STEP_SPAN)
+    assert steps
+    for s in steps:
+        a = s["attrs"]
+        busy = s["dur_ms"] - a["phases"]["idle"]
+        assert 0 <= a["exposed_ms"] <= busy + 0.01, a
+    # the spans and the counters are one booking
+    for k in KINDS:
+        assert sum(s["attrs"]["exposed_ms"] for s in steps
+                   if s["attrs"]["kind"] == k) == pytest.approx(
+            1e3 * stats[STEP_EXPOSED % k], abs=0.01 * len(steps) + 0.01)
+
+
+def test_outstanding_on_a_bare_clock():
+    """The definition on made-up times: covered runs from the enqueue
+    that raised the count from 0 to the fetch that brought it back; a
+    fetch settles every program enqueued before its own; ``step_done``
+    splits an open interval so that steps tile it."""
+    import threading
+
+    stats, now = {}, [100.0]
+    clk = loop_clock.LoopClock(stats, SpanRecorder())
+    real = time.perf_counter
+    time.perf_counter = lambda: now[0]
+    try:
+        clk.start("admit")
+        clk._th_thread = threading.get_ident()  # as inside a thunk
+        clk._th = [now[0], None, None]
+
+        def step(kind="decode_window", n=4):
+            clk._info = {"kind": kind, "key": (n,), "n": n, "live": 1,
+                         "rows": 4, "cold": True}
+            clk.step_done()
+            return (stats["step_seconds_" + kind],
+                    stats["step_exposed_seconds_" + kind],
+                    stats["device_steps_" + kind])
+
+        now[0] += 1.0            # 1 s of host work, nothing outstanding
+        clk.enqueued()           # window 1
+        first = clk.programs
+        now[0] += 2.0
+        clk.landed(first)        # fetched: 2 s covered
+        now[0] += 0.5            # emission, exposed
+        assert step() == (3.5, 1.5, 4)
+        clk.enqueued()           # a prefill chunk nobody fetches ...
+        now[0] += 1.0
+        clk.enqueued()           # ... then window 2, and window 3
+        second = clk.programs
+        clk.enqueued()
+        now[0] += 1.0
+        clk.landed(second)       # window 2's tokens: 3 is outstanding
+        now[0] += 1.0
+        assert step() == (6.5, 1.5, 8)   # covered all through
+        now[0] += 0.25
+        clk.landed()             # the newest: nothing is outstanding
+        now[0] += 0.75
+        assert step("mixed_step", 1) == (1.0, 0.75, 1)
+        clk.enqueued()
+        now[0] += 1.0
+        clk.mark("idle")         # nobody will fetch it: dropped
+        now[0] += 10.0
+        clk.mark("admit")
+        now[0] += 0.5
+        assert step("prefill", 1) == (1.5, 0.5, 1)
+    finally:
+        time.perf_counter = real
+
+
+# ---------------- POST /profile and an engine with nothing to do ----------------
+
+
+def _profile_event_s(out):
+    """How long the capture's ``engine.profile`` annotation lasted."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    events = [e for p in ProfileData.from_file(path).planes
+              if p.name == "/host:CPU" for line in p.lines
+              for e in line.events if e.name == "engine.profile"]
+    assert len(events) == 1
+    return events[0].duration_ns / 1e9
+
+
+@pytest.mark.parametrize("case", ["traffic", "a_request_later", "idle"])
+def test_profile_does_not_end_before_a_dispatch_if_one_comes(
+        run, tmp_path, case):
+    """A capture lasts ``seconds`` when the loop dispatched in that
+    time; on an engine with nothing to do it goes on until the first
+    step after it is done, ``3 * seconds`` more at most."""
+    seconds, out = 0.4, str(tmp_path / "profile")
+
+    async def main():
+        engine = _engine()
+        try:
+            await _serve(engine, 1100)
+            await asyncio.sleep(0.05)
+            before = engine._clock.seq
+            capture = asyncio.create_task(engine.profile(seconds, out))
+            if case == "traffic":
+                while not capture.done():
+                    await _serve(engine, 1101)
+            elif case == "a_request_later":
+                await asyncio.sleep(2 * seconds)
+                await _serve(engine, 1102, max_tokens=4)
+            await capture
+            return engine._clock.seq - before
+        finally:
+            await engine.close()
+
+    steps, lasted = run(main()), _profile_event_s(out)
+    if case == "traffic":
+        assert steps > 0 and seconds <= lasted < seconds + 0.2
+    elif case == "a_request_later":
+        # it ended with the request's first step, not at the cap (the
+        # capture's own start-up shifts the request towards its start)
+        assert steps > 0 and seconds < lasted < 3 * seconds
+    else:
+        assert steps == 0 and 4 * seconds <= lasted < 4 * seconds + 0.2
