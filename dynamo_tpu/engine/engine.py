@@ -26,7 +26,7 @@ import contextlib
 import logging
 import os
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import AsyncIterator, Optional
 
@@ -72,14 +72,21 @@ WORK_COUNTERS = ("rows_dispatched", "rows_live", "prefill_tokens_dispatched",
 #: expert slots handed over and assignments of live rows, counted on the
 #: host, and what the step programs' MoeTally brings back
 MOE_COUNTERS = ("moe_expert_slots", "moe_assignments", "moe_experts_touched",
-                "moe_experts_touched_live", "moe_max_group_rows")
+                "moe_experts_touched_live", "moe_max_group_rows",
+                "moe_held_assignments")
 
-#: the same for a model whose conv layers carry a per-sequence state
-#: (LFM2; absent otherwise): prompt tokens whose block hashes matched
-#: the prefix cache, blocks committed with their state's snapshot, and
-#: admissions that started from a snapshot
+#: the same for a model whose layers carry a per-sequence state (LFM2's
+#: conv layers, GigaChat 3.5's linear-attention layers; absent
+#: otherwise): prompt tokens whose block hashes matched the prefix
+#: cache, snapshots of the state taken (a block committed with its
+#: snapshot, or a chunk's end state kept), admissions that started from
+#: a snapshot; snapshots that lost their row to a newer one, matched
+#: tokens a hit was cut short by for want of a snapshot, and the bytes
+#: of recurrent matrices the step programs read and wrote (the last
+#: three stay 0 for a state that is snapshotted a row a block)
 STATE_COUNTERS = ("prefix_matched_tokens", "state_snapshots",
-                  "state_restores")
+                  "state_restores", "state_snapshot_evictions",
+                  "prefix_unsnapshotted_tokens", "linear_state_bytes")
 
 # the prefill-admission first-token sampler, jitted ONCE at module scope:
 # a per-call ``jax.jit(sample_first_token)`` built a fresh wrapper (and a
@@ -134,14 +141,99 @@ def _dequant_gathered(pages, scales, dtype):
 
 
 @_partial(jax.jit, donate_argnames=("state",))
-def _begin_state_row(state, slot, blk):
-    """A sequence takes row ``slot`` of the conv state (llama.init_state)
-    for its prefill: the snapshot of block ``blk``, the last of its
-    cached prefix, or zeros for a prompt that starts from token 0
-    (``blk`` < 0)."""
-    snap = state["snap"]
-    row = jnp.where(blk >= 0, snap[jnp.maximum(blk, 0)], 0)
-    return {"conv": state["conv"].at[slot].set(row), "snap": snap}
+def _begin_state_row(state, slot, snap_row):
+    """A sequence takes row ``slot`` of the state (llama.init_state) for
+    its prefill: snapshot ``snap_row`` (of the last block of its cached
+    prefix: ``SnapshotPool.row_of``), every part of it, or zeros for a
+    prompt that starts from token 0 (``snap_row`` < 0)."""
+    at = jnp.maximum(snap_row, 0)
+    out = dict(state)
+    out["conv"] = state["conv"].at[slot].set(
+        jnp.where(snap_row >= 0, state["snap"][at], 0))
+    if "rec" in state:
+        out["rec"] = state["rec"].at[:, slot].set(
+            jnp.where(snap_row >= 0, state["snap_rec"][:, at], 0))
+    return out
+
+
+class SnapshotPool:
+    """Which KV block's state snapshot lies in which row of the state's
+    snapshot pool (``llama.init_state``'s ``snap`` arrays): the host
+    side of ONE mechanism for every kind of state.
+
+    WHERE a snapshot can be taken is the kind of state's rule
+    (``llama.StateTrack``). A state the programs can write at every
+    block end (``at_block_ends``: a conv layer's window of rows) has a
+    row a block, which it is small enough for: the pool is ``dense``,
+    the row IS the block id and every committed block has its snapshot.
+    A state that exists at a chunk's end only (a recurrent matrix) is a
+    map with LRU reuse, whatever the pool's size: a row is taken
+    (``take``) for the block at which a prefill chunk ends, the program
+    writes the chunk's end state there, and a block whose row went to a
+    newer snapshot has none any more. An entry carries the block's
+    chained hash, so a block id the allocator recycled for other content
+    does not answer for the old one. A prefix hit counts up to the last
+    matched block for which ``row_of`` answers."""
+
+    def __init__(self, rows: int, num_blocks: int, at_block_ends: bool = False):
+        if at_block_ends and rows < num_blocks:
+            raise ValueError(
+                f"a state snapshotted at every block end needs a row a "
+                f"block: {rows} rows for {num_blocks} blocks")
+        self.rows = rows
+        self.dense = at_block_ends
+        self._by_block: OrderedDict = OrderedDict()  # idx -> [row, hash]
+        self._free = list(range(rows - 1, -1, -1))
+        self._pins: dict[int, int] = {}
+        self.evictions = 0
+
+    def row_of(self, block: Block) -> int:
+        """The row that holds ``block``'s snapshot, or -1."""
+        if self.dense:
+            return block.idx
+        e = self._by_block.get(block.idx)
+        if e is None or e[1] is None or e[1] != block.seq_hash:
+            return -1
+        self._by_block.move_to_end(block.idx)
+        return e[0]
+
+    def take(self, block: Block) -> int:
+        """A row for the snapshot about to be written for ``block``: the
+        one it has, a free one, or the least recently used unpinned one
+        (its block loses its snapshot); -1 if every row is pinned."""
+        e = self._by_block.pop(block.idx, None)
+        if e is not None:
+            row = e[0]
+        elif self._free:
+            row = self._free.pop()
+        else:
+            victim = next((b for b, (r, _h) in self._by_block.items()
+                           if r not in self._pins), None)
+            if victim is None:
+                return -1
+            row = self._by_block.pop(victim)[0]
+            self.evictions += 1
+        self._by_block[block.idx] = [row, block.seq_hash]
+        return row
+
+    def bind(self, block: Block) -> None:
+        """``block`` was committed: its pending snapshot (taken before
+        the block had a hash) answers for this content from now on."""
+        e = self._by_block.get(block.idx)
+        if e is not None and e[1] is None:
+            e[1] = block.seq_hash
+
+    def pin(self, row: int) -> None:
+        """An admission will restore from ``row``: it is not reused
+        until ``unpin``."""
+        self._pins[row] = self._pins.get(row, 0) + 1
+
+    def unpin(self, row: int) -> None:
+        n = self._pins.get(row, 0) - 1
+        if n > 0:
+            self._pins[row] = n
+        else:
+            self._pins.pop(row, None)
 
 
 @jax.jit
@@ -263,6 +355,11 @@ class EngineConfig:
     # ties; sampled streams match plain decode in distribution, not
     # token-for-token (the standard spec-decode contract). 0 = off.
     spec_gamma: int = 0
+    # rows of the state's snapshot pool for a model whose per-sequence
+    # state is too large for a row a KV block (llama.
+    # state_snapshot_rows; GigaChat 3.5: 16.4 MiB a row); 0 = 64. A
+    # small state (LFM2) keeps a row a block whatever this says
+    state_snapshots: int = 0
     spec_ngram: int = 3
     # weight quantization: "none" | "int8" | "fp8_e4m3" (models/quant.py —
     # per-output-channel scales; halves decode's HBM weight streaming, the
@@ -419,6 +516,12 @@ class _Sequence:
     # the row of the conv state (a decode slot's) this sequence holds
     # from its first prefill chunk on; -1: none (no conv layers)
     state_slot: int = -1
+    # a state snapshotted at a chunk's end only (SnapshotPool, not
+    # dense): the token counts at which a prefill chunk of this sequence
+    # has to end and leave a snapshot, and the row its first chunk
+    # restores (-1: starts from zeros)
+    snap_points: tuple = ()
+    restore_row: int = -1
     finished: bool = False
     arrival_t: float = field(default_factory=time.monotonic)
     # request trace (tracing.TraceContext), captured at generate() entry
@@ -486,7 +589,14 @@ class JaxEngine(AsyncEngine):
         # snapshot a KV block (llama.init_state). A sequence holds its
         # row from its first prefill chunk on (_Sequence.state_slot)
         self.state = llama.init_state(
-            mcfg, cfg.max_batch_size, cfg.num_blocks)
+            mcfg, cfg.max_batch_size, cfg.num_blocks, cfg.state_snapshots)
+        self.snapshots = None if self.state is None else SnapshotPool(
+            self.state["snap"].shape[0], cfg.num_blocks,
+            at_block_ends=mcfg.conv_layers > 0)
+        # bytes of recurrent matrices ONE sequence holds (0: conv rows only)
+        rec = (self.state or {}).get("rec")  # [Ll, max_batch, Hv, Dk, Dv]
+        self._rec_row_bytes = 0 if rec is None else (
+            rec.size // rec.shape[1] * rec.dtype.itemsize)
         # int8-with-scales DEVICE cache (kv_cache_dtype="int8"): per-page
         # f32 scale planes [L, N] — one symmetric absmax scale per
         # (layer, physical page) per K/V, the tier codec's exact
@@ -879,8 +989,9 @@ class JaxEngine(AsyncEngine):
         preemption. What cannot carry the state yet refuses the model
         here, by name — none of it is bypassed in silence."""
         cfg = self.cfg
-        if not cfg.model.conv_layers:
+        if not cfg.model.state_layers:
             return
+        kind = ("linear-attention" if cfg.model.linear_layers else "conv")
         asked = [name for name, on in (
             ("spec_gamma (the verify forward)", cfg.spec_gamma > 0),
             ("ring_prefill_threshold (ring prefill)",
@@ -895,19 +1006,21 @@ class JaxEngine(AsyncEngine):
         ) if on]
         if asked:
             raise ValueError(
-                f"{', '.join(asked)}: not supported for a model with conv "
-                f"layers ({cfg.model.conv_layers} of "
+                f"{', '.join(asked)}: not supported for a model with {kind} "
+                f"layers ({cfg.model.state_layers} of "
                 f"{cfg.model.num_layers} here): their per-sequence state "
                 "rides the scheduler, the allocator and the prefix cache "
-                "only")
+                "only (no model-native drafting either: the verify "
+                "forward cannot roll a state back)")
 
     def _no_state_transfer(self, what: str) -> None:
         """The disaggregation and resharding hooks move keys and values
         between engines; a conv layer's state has no lane there."""
         if self.state is not None:
             raise ValueError(
-                f"{what}: not supported for a model with conv layers (the "
-                "KV wire carries no conv state)")
+                f"{what}: not supported for a model with "
+                f"{'linear-attention' if self.cfg.model.linear_layers else 'conv'}"
+                " layers (the KV wire carries no per-sequence state)")
 
     def _pallas_gate(self, mesh) -> Optional[str]:
         """None when the Pallas kernels serve ``mesh``; otherwise the
@@ -2229,18 +2342,49 @@ class JaxEngine(AsyncEngine):
             return None
         seq.blocks = matched + fresh
         seq.committed = len(matched)
+        n_hit = len(matched)
         if self.state is not None:
             # what the hashes matched, counted where they match: what a
             # prefill then skips of it (prefix_cache_hits_tokens) is
             # _begin_prefill's to say
             self.stats["prefix_matched_tokens"] += len(matched) * bs
+            # a hit counts up to the last matched block that holds a
+            # snapshot of the state: the tokens behind it are computed
+            # again (into the matched blocks they already lie in, with
+            # the same values) to rebuild the state, and the chunk that
+            # reaches the end of the match leaves the snapshot the next
+            # asker restores (SnapshotPool; a dense pool cuts nothing)
+            pool = self.snapshots
+            seq.restore_row = -1
+            while n_hit and (row := pool.row_of(matched[n_hit - 1])) < 0:
+                n_hit -= 1
+            if n_hit:
+                seq.restore_row = row
+            self.stats["prefix_unsnapshotted_tokens"] += (
+                len(matched) - n_hit) * bs
+            points = set()
+            if not pool.dense:
+                if n_hit < len(matched):
+                    points.add(len(matched) * bs)
+                # the prompt's last full block: what a repeat or an
+                # extension of this prompt will match. Not for a prompt
+                # of under two blocks: the cut is one more dispatch, and
+                # what a repeat would skip is less than a dispatch costs
+                # (it also keeps a 2-block prompt in the bucket a
+                # harness warms with it)
+                if len(prompt) >= 2 * bs:
+                    points.add(len(prompt) // bs * bs)
+                if restore_hashes:  # (refused with tiers; belt and braces)
+                    points.clear()
+            seq.snap_points = tuple(sorted(
+                p for p in points if p > n_hit * bs))
         # no device match: the chain restarts from its model-salted root
         # (None for base traffic — byte-identical to pre-multi-model)
         seq.parent_hash = (
             matched[-1].seq_hash if matched
             else model_hash_salt(seq.model)
         )
-        history = (len(matched) + len(restore_hashes)) * bs
+        history = (n_hit + len(restore_hashes)) * bs
         seq.cached_prefix = history
         upload = None
         if self.offload is not None and restore_hashes:
@@ -2259,10 +2403,12 @@ class JaxEngine(AsyncEngine):
         history, upload = reserved
         self.stats["prefix_cache_hits_tokens"] += history
         if self.state is not None:
-            # every matched block has its snapshot (ConvTrack), so all
-            # the matched tokens are skipped; the row is one no sequence
-            # decodes in and no other prefill holds
+            # the matched tokens up to the last snapshot are skipped
+            # (_reserve_for_prompt); the row is one no sequence decodes
+            # in and no other prefill holds
             self.stats["state_restores"] += history > 0
+            if seq.restore_row >= 0:
+                self.snapshots.pin(seq.restore_row)
             held = {st.seq.state_slot for st in self._prefill_states}
             seq.state_slot = next(
                 i for i, s in enumerate(self._active)
@@ -2353,6 +2499,11 @@ class JaxEngine(AsyncEngine):
     def _drop_prefill_state(self, st: "_PrefillState") -> None:
         if st in self._prefill_states:
             self._prefill_states.remove(st)
+            if (self.state is not None and not st.state_ready
+                    and st.seq.restore_row >= 0):
+                # dropped before its first chunk restored the row
+                self.snapshots.unpin(st.seq.restore_row)
+                st.seq.restore_row = -1
 
     def _abort_prefill(
         self, st: "_PrefillState", reason: FinishReason,
@@ -2424,24 +2575,59 @@ class JaxEngine(AsyncEngine):
             return
         st.state_ready = True
         seq = st.seq
-        n = seq.cached_prefix // self.cfg.block_size
         self.state = _begin_state_row(
             self.state, jnp.int32(seq.state_slot),
-            jnp.int32(seq.blocks[n - 1].idx if n else -1))
+            jnp.int32(seq.restore_row))
+        if seq.restore_row >= 0:
+            self.snapshots.unpin(seq.restore_row)
+
+    def _clip_take(self, seq: _Sequence, pos: int, take: int) -> int:
+        """``take`` tokens of ``seq``'s prompt from ``pos``, cut so that
+        the chunk ends where the sequence wants a snapshot of its state
+        (``_Sequence.snap_points``: a state that exists at a chunk's end
+        only)."""
+        for p in seq.snap_points:
+            if pos < p < pos + take:
+                return p - pos
+        return take
+
+    def _snap_row(self, seq: _Sequence, end: int) -> int:
+        """The snapshot row for ``seq``'s chunk that ends after ``end``
+        tokens: a row of the pool if the sequence wants a snapshot
+        there, else an index past the pool (the program drops it)."""
+        pool = self.snapshots
+        if end in seq.snap_points:
+            row = pool.take(seq.blocks[end // self.cfg.block_size - 1])
+            if row >= 0:
+                self.stats["state_snapshots"] += 1
+                self.stats["state_snapshot_evictions"] = pool.evictions
+                return row
+        return pool.rows
+
+    def _note_state(self, segments: int, decode_steps: int = 0) -> None:
+        """Bytes of recurrent matrices a dispatch reads and writes: a
+        prefill segment its sequence's, a decode step every slot's (the
+        program carries dead slots' through unchanged)."""
+        self.stats["linear_state_bytes"] += 2 * self._rec_row_bytes * (
+            segments + decode_steps * self.cfg.max_batch_size)
 
     def _restored_attr(self, seq: _Sequence) -> dict:
         """``engine.prefill``'s ``restored``: prompt tokens whose conv
         state came from a snapshot (a model with conv layers only)."""
         return {} if self.state is None else {"restored": seq.cached_prefix}
 
-    def _state_kw(self, seq: Optional[_Sequence] = None) -> dict:
-        """The step programs' keywords for a model with conv layers: the
-        state and, for ``seq``'s prefill chunk, its row."""
+    def _state_kw(self, seq: Optional[_Sequence] = None,
+                  end: int = 0) -> dict:
+        """The step programs' keywords for a model with state layers: the
+        state and, for ``seq``'s prefill chunk that ends after ``end``
+        tokens, its row and (a sparse pool) its snapshot row."""
         if self.state is None:
             return {}
         kw = {"state": self.state}
         if seq is not None:
             kw["slot"] = jnp.int32(seq.state_slot)
+            if not self.snapshots.dense:
+                kw["snap_row"] = jnp.int32(self._snap_row(seq, end))
         return kw
 
     def _offload_preamble(self, upload=None, seq: Optional[_Sequence] = None) -> None:
@@ -2624,7 +2810,8 @@ class JaxEngine(AsyncEngine):
         ring = self._ring_chunk(seq, pos)
         # ring: the WHOLE prompt is one sequence-parallel chunk
         chunk = seq.tokens[pos:] if ring else (
-            seq.tokens[pos : pos + cfg.prefill_chunk]
+            seq.tokens[pos : pos + self._clip_take(
+                seq, pos, cfg.prefill_chunk)]
         )
         T = _bucket(len(chunk))
         toks = np.zeros(T, np.int32)
@@ -2670,6 +2857,7 @@ class JaxEngine(AsyncEngine):
             if self._moe_layers:
                 self._note_moe(out[5], 1, len(chunk))
             return logits, pos + len(chunk)
+        state_kw = self._state_kw(seq, pos + len(chunk))
         out = self._timed_dispatch(
             lambda: llama.prefill(
                 self.params,
@@ -2685,7 +2873,7 @@ class JaxEngine(AsyncEngine):
                 use_ring=ring,
                 **self._lora_prefill_kw(seq.adapter_id),
                 **self._moe_kw(),
-                **self._state_kw(seq),
+                **state_kw,
             ),
             key=("prefill", T, ring) + self._lora_key(),
             trace=seq.trace,
@@ -2695,6 +2883,7 @@ class JaxEngine(AsyncEngine):
         if self.state is not None:
             self.state = rest.pop(0)
             self._state_rows += 1
+            self._note_state(1)
         if self._moe_layers:
             self._note_moe(rest.pop(0), 1, len(chunk))
         return logits, pos + len(chunk)
@@ -3723,6 +3912,9 @@ class JaxEngine(AsyncEngine):
             extra = min(left, rem[i] - takes[i])
             takes[i] += extra
             left -= extra
+        if self.state is not None:
+            takes = [self._clip_take(st.seq, st.pos, t)
+                     for st, t in zip(sts, takes)]
         return list(zip(sts, takes))
 
     def _dispatch_mixed(
@@ -3812,8 +4004,14 @@ class JaxEngine(AsyncEngine):
                     self._state_preamble(st)
                     slots_p[i] = st.seq.state_slot
                 kwargs.update(self._state_kw(), p_slots=jnp.asarray(slots_p))
+                if not self.snapshots.dense:
+                    snaps_p = np.full(MP, self.snapshots.rows, np.int32)
+                    for i, (st, take) in enumerate(packed):
+                        snaps_p[i] = self._snap_row(st.seq, st.pos + take)
+                    kwargs.update(p_snaps=jnp.asarray(snaps_p))
                 self._state_rows += len(packed) + int(
                     (self._seq_lens > 0).sum())
+                self._note_state(len(packed), 1)
             self._note_prefill_work(MP * T, int(valids_p.sum()))
             self._note_decode_work(1, self._seq_lens, seg_pages=(
                 MP * cfg.max_blocks_per_seq,
@@ -3947,7 +4145,7 @@ class JaxEngine(AsyncEngine):
         summed over the steps."""
         m = self.cfg.model
         self._moe_pending.append((
-            sums, self._moe_layers * m.num_experts * steps,
+            sums, self._moe_layers * m.local_experts * steps,
             self._moe_layers * m.num_experts_per_tok * live_rows,
         ))
 
@@ -3965,9 +4163,11 @@ class JaxEngine(AsyncEngine):
         moe = dict.fromkeys(MOE_COUNTERS, 0)
         while self._moe_pending and self._moe_pending[0][0].is_ready():
             sums, slots, assignments = self._moe_pending.popleft()
-            for name, v in zip(MOE_COUNTERS,
-                               (slots, assignments, *np.asarray(sums))):
-                moe[name] += int(v)
+            sums = [int(v) for v in np.asarray(sums)]
+            if len(sums) == 3:  # every expert is here: all of them held
+                sums.append(assignments)
+            for name, v in zip(MOE_COUNTERS, (slots, assignments, *sums)):
+                moe[name] += v
         if not moe["moe_expert_slots"]:  # a dense model; nothing back yet
             self._clock.step_done(**attrs)
             return
@@ -4262,6 +4462,7 @@ class JaxEngine(AsyncEngine):
         if self.state is not None:
             self.state = rest.pop(0)
             self._state_rows += int((self._seq_lens > 0).sum())
+            self._note_state(0, n)
         if quantized:
             self.k_scales = rest.pop(0)
             self.v_scales = rest.pop(0)
@@ -4385,9 +4586,12 @@ class JaxEngine(AsyncEngine):
             )
             seq.committed += 1
             if self.state is not None:
-                # the program that filled the block left the conv state
-                # at its last token under its id (llama.ConvTrack)
-                self.stats["state_snapshots"] += 1
+                if self.snapshots.dense:
+                    # the program that filled the block left the state at
+                    # its last token under its id (llama.StateTrack)
+                    self.stats["state_snapshots"] += 1
+                else:  # a snapshot taken for it answers for this content
+                    self.snapshots.bind(seq.blocks[i])
 
     # ---------------- disaggregation hooks ----------------
     # (ref docs/disagg_serving.md:58-91; vllm patch remote-prefill states)

@@ -296,6 +296,7 @@ def engine_config(args, cfg: ModelConfig, served_name: str = "") -> EngineConfig
         decode_pipeline=args.decode_pipeline,
         spec_gamma=args.spec_gamma,
         spec_ngram=args.spec_ngram,
+        state_snapshots=getattr(args, "state_snapshots", 0),
         mixed_batch=not args.no_mixed_batch,
         mixed_step_budget=args.mixed_step_budget,
         mixed_max_prefills=args.mixed_max_prefills,
@@ -1147,6 +1148,11 @@ def main(argv=None) -> None:
                    help="max concurrent prompts packed into one fused "
                         "mixed step (the budget splits across them; "
                         "1 = one prefill at a time)")
+    p.add_argument("--state-snapshots", type=int, default=0,
+                   help="rows of the state's snapshot pool for a model "
+                        "whose per-sequence state is too large for a row a "
+                        "KV block (GigaChat 3.5's linear-attention layers: "
+                        "16.4 MiB a row); 0 = 64")
     p.add_argument("--spec-gamma", type=int, default=0,
                    help="speculative decoding: proposals per verify (0=off)")
     p.add_argument("--spec-ngram", type=int, default=3,

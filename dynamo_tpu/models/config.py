@@ -36,11 +36,11 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 #: of an ``architectures`` entry (docs/models.md lists them)
 _EXPERT_MODEL_TYPES = frozenset((
     "mixtral", "qwen2_moe", "qwen3_moe", "deepseek", "deepseek_v2",
-    "deepseek_v3", "gpt_oss", "olmoe", "lfm2_moe",
+    "deepseek_v3", "gpt_oss", "olmoe", "lfm2_moe", "gigachat3_5",
 ))
 _EXPERT_ARCH_PREFIXES = (
     "Mixtral", "Qwen2Moe", "Qwen3Moe", "Deepseek", "GptOss", "Olmoe",
-    "Lfm2Moe",
+    "Lfm2Moe", "GigaChat35",
 )
 _EXPERT_COUNT_KEYS = ("num_local_experts", "n_routed_experts", "num_experts")
 
@@ -74,13 +74,60 @@ _LAYER_TYPE_OPS = {
 }
 
 
-def _layer_kinds(cfg: dict, is_lfm2: bool) -> tuple[tuple, int]:
+#: the linear-attention operators the forwards implement, by the
+#: config's ``linear_attention_type``
+_LINEAR_ATTENTION_TYPES = frozenset(("GigaChat35GatedDeltaNet",))
+
+
+def _linear_layer_kinds(cfg: dict) -> tuple:
+    """GigaChat 3.5: ``full_attention_layers`` names the (latent)
+    attention layers, every other layer is the linear-attention operator
+    ``linear_attention_type`` names. A depth cut keeps the published list
+    whole: the entries under ``num_hidden_layers`` are the layers that
+    are there, and at least one entry must be (a stack of linear layers
+    alone has no KV cache to hand the engine)."""
+    kind = cfg.get("linear_attention_type")
+    if kind not in _LINEAR_ATTENTION_TYPES:
+        raise ValueError(
+            f"unsupported linear_attention_type {kind!r}: the forwards "
+            f"know {sorted(_LINEAR_ATTENTION_TYPES)} only")
+    L = cfg.get("num_hidden_layers", 32)
+    full = cfg.get("full_attention_layers")
+    if not isinstance(full, (list, tuple)) or any(
+            not isinstance(i, int) or i < 0 for i in full):
+        raise ValueError(
+            f"full_attention_layers must list layer indices, got {full!r}")
+    depth = cfg.get("num_hidden_layers_published", L)
+    past = sorted(i for i in full if i >= max(depth, L))
+    if past:
+        raise ValueError(
+            f"full_attention_layers entries {past} lie past the depth "
+            f"({max(depth, L)} layers)")
+    here = {i for i in full if i < L}
+    if not here:
+        raise ValueError(
+            f"full_attention_layers {list(full)} names no layer under "
+            f"num_hidden_layers {L}: a stack of linear-attention layers "
+            "alone is not supported")
+    return tuple("attn" if l in here else "linear" for l in range(L))
+
+
+def _layer_kinds(cfg: dict, is_lfm2: bool,
+                 is_gigachat35: bool = False) -> tuple[tuple, int]:
     """(operator kind of every layer, leading dense-FFN layers): the ONE
-    place ``layer_types`` and ``num_dense_layers`` /
-    ``first_k_dense_replace`` are read into kinds. The operator tuple is
-    empty for an attention-only stack (every family but LFM2); an entry
-    no forward implements is refused by name, for every family — it
-    would otherwise run as attention."""
+    place ``layer_types`` / ``full_attention_layers`` and
+    ``num_dense_layers`` / ``first_k_dense_replace`` are read into kinds.
+    The operator tuple is empty for an attention-only stack (every family
+    but LFM2 and GigaChat 3.5); an entry no forward implements is refused
+    by name, for every family — it would otherwise run as attention."""
+    if is_gigachat35:
+        return _linear_layer_kinds(cfg), cfg.get(
+            "first_k_dense_replace", 0) or 0
+    if cfg.get("linear_attention_type") or cfg.get("full_attention_layers"):
+        raise ValueError(
+            "linear_attention_type / full_attention_layers under a family "
+            "other than gigachat3_5: only GigaChat 3.5's gated delta rule "
+            "is implemented")
     types = cfg.get("layer_types") or ()
     unknown = sorted({t for t in types if t not in _LAYER_TYPE_OPS})
     if unknown:
@@ -218,6 +265,38 @@ class ModelConfig:
     # is chosen independently: first_dense_layers leading dense ones.
     layer_ops: tuple = ()
     conv_kernel: int = 0
+    # GigaChat 3.5: a third operator kind, "linear": the gated delta rule
+    # (llama.gated_delta) over linear_key_heads key heads (each serving
+    # linear_value_heads / linear_key_heads value heads), behind a
+    # depthwise causal convolution of linear_conv_kernel taps over q, k
+    # and v. Its per-sequence state has two parts: the convolution's last
+    # taps - 1 rows (the model's dtype) and one [key_dim, value_dim]
+    # float32 matrix a value head (llama.init_state)
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_o_norm_eps: float = 1e-6
+    linear_gate_scale: float = 1.0  # the output gate: scale * sigmoid(z)
+    # the attention output times sigmoid(h W_g) before the output
+    # projection (arXiv:2505.06708)
+    gated_attention: bool = False
+    # SwiGLU with both streams clamped: silu(min(gate, limit)) *
+    # clip(up, -limit, limit), in every FFN kind; 0 = off
+    swiglu_limit: float = 0.0
+    # ZeroCenteredGatedNorm: an RMS norm's learned scale is
+    # norm_gate_weight * sigmoid(w) (1 at w = 0 for a weight of 2);
+    # 0 = the plain scale w
+    norm_gate_weight: float = 0.0
+    # one chip's share of an expert layer (any expert family): the chip
+    # holds experts [expert_first, expert_first + experts_held) of
+    # num_experts. The router scores all num_experts and picks
+    # num_experts_per_tok of them; the chip computes what ITS experts
+    # give the rows routed to them (plus the shared expert), and the
+    # other chips' parts are not stood in for. 0 = every expert is here
+    experts_held: int = 0
+    expert_first: int = 0
     # the renormalised top-k combine weights divide by (sum + this)
     topk_norm_eps: float = 1e-20
     # runtime
@@ -262,9 +341,31 @@ class ModelConfig:
         return self.layer_ops.count("conv")
 
     @property
+    def linear_layers(self) -> int:
+        """Layers whose operator is the gated delta rule: a convolution's
+        rows AND a recurrent matrix a sequence instead of keys and values."""
+        return self.layer_ops.count("linear")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that carry a per-sequence state (either kind)."""
+        return self.conv_layers + self.linear_layers
+
+    @property
     def kv_layers(self) -> int:
         """Layers that hold keys and values: the KV cache's layer axis."""
-        return self.num_layers - self.conv_layers
+        return self.num_layers - self.state_layers
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights this chip holds (``experts_held``)."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels of a linear layer's convolution: q, k and v."""
+        return (2 * self.linear_key_heads * self.linear_key_dim
+                + self.linear_value_heads * self.linear_value_dim)
 
     def op_index(self, l: int) -> int:
         """Layer ``l``'s ordinal among the layers of its operator kind:
@@ -363,8 +464,63 @@ class ModelConfig:
                 "convolution's and its projections' biases are not "
                 "implemented; the published LFM2 configs carry false)"
             )
-        layer_ops, n_dense = _layer_kinds(cfg, is_lfm2)
+        # gigachat3_5: linear-attention (gated delta rule) and gated
+        # latent-attention layers (full_attention_layers), norms before
+        # and after every sublayer whose scale is 2 sigmoid(w), clamped
+        # SwiGLU, DeepSeek-V3's sigmoid router with a selection bias (the
+        # family's: the config carries no scoring_func / topk_method key)
+        is_gigachat35 = any(a.startswith("GigaChat35") for a in archs) or (
+            cfg.get("model_type") == "gigachat3_5"
+        )
+        if is_gigachat35:
+            refused = [k for k, bad in (
+                ("norm_type", cfg.get("norm_type", "ZeroCenteredGatedNorm")
+                 != "ZeroCenteredGatedNorm"),
+                ("layernorm_type",
+                 cfg.get("layernorm_type", "pre_post") != "pre_post"),
+                ("linear_gating_type", cfg.get(
+                    "linear_gating_type",
+                    "gated_rmsnorm_sigmoid_zero_centered")
+                 != "gated_rmsnorm_sigmoid_zero_centered"),
+                ("use_shared_expert_sigmoid",
+                 bool(cfg.get("use_shared_expert_sigmoid"))),
+                ("n_group", (cfg.get("n_group") or 1) > 1),
+                ("kv_lora_rank", not cfg.get("kv_lora_rank")),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    f"gigachat3_5 with {refused} other than the published "
+                    "GigaChat3.5 configs carry is not supported (only "
+                    "ZeroCenteredGatedNorm / pre_post / "
+                    "gated_rmsnorm_sigmoid_zero_centered, an always-on "
+                    "shared expert, one routing group and latent "
+                    "attention are implemented)")
+            if cfg.get("num_nextn_predict_layers"):
+                raise ValueError(
+                    "gigachat3_5 with num_nextn_predict_layers="
+                    f"{cfg['num_nextn_predict_layers']}: the multi-token "
+                    "prediction modules are not served (no model-native "
+                    "drafting; the verify forward cannot roll a recurrent "
+                    "state back) — serve with num_nextn_predict_layers 0")
+        layer_ops, n_dense = _layer_kinds(cfg, is_lfm2, is_gigachat35)
         _reject_unknown_expert_family(cfg, archs)
+        # one chip's share of the experts (any family): expert_share
+        # {"published": X, "first": i} beside the family's own count key,
+        # which then counts the experts held here
+        share = cfg.get("expert_share")
+        held_key = next((k for k in _EXPERT_COUNT_KEYS if cfg.get(k)), None)
+        experts_held = expert_first = 0
+        if share is not None:
+            held = cfg.get(held_key, 0) if held_key else 0
+            pub, expert_first = share.get("published", 0), share.get("first", 0)
+            if not (0 < held <= pub and 0 <= expert_first
+                    and expert_first + held <= pub):
+                raise ValueError(
+                    f"expert_share {share} beside {held_key}={held}: the "
+                    "held experts must be a range of the published ones")
+            experts_held = held if held < pub else 0
+            cfg = dict(cfg)
+            cfg[held_key] = pub
         # qwen2moe: gated shared expert; interleaved dense layers are
         # not implemented — reject rather than serve wrong logits
         is_qwen2moe = any(a.startswith("Qwen2Moe") for a in archs)
@@ -453,6 +609,8 @@ class ModelConfig:
                 ffn_width = int(
                     cfg.get("block_ffn_dim_multiplier", 1.0) * ffn_width)
             ffn_width = mult * ((ffn_width + mult - 1) // mult)
+        # the keys only GigaChat 3.5 is read for: absent for every other
+        giga = cfg if is_gigachat35 else {}
         return ModelConfig(
             vocab_size=cfg.get("vocab_size", 32000),
             hidden_size=cfg.get("hidden_size", 4096),
@@ -476,6 +634,20 @@ class ModelConfig:
             layer_windows=layer_windows,
             layer_ops=layer_ops,
             conv_kernel=(cfg.get("conv_L_cache", 3) if is_lfm2 else 0),
+            linear_key_heads=giga.get("linear_num_key_heads", 0),
+            linear_value_heads=giga.get("linear_num_value_heads", 0),
+            linear_key_dim=giga.get("linear_key_head_dim", 0),
+            linear_value_dim=giga.get("linear_value_head_dim", 0),
+            linear_conv_kernel=giga.get("linear_conv_kernel_dim", 4)
+            if is_gigachat35 else 0,
+            linear_o_norm_eps=giga.get("linear_attn_o_norm_eps", 1e-6),
+            linear_gate_scale=giga.get("linear_sigmoid_gate_scale", 1.0),
+            gated_attention=bool(giga.get("gated_attention")),
+            swiglu_limit=float(giga.get("swiglu_limit") or 0.0),
+            norm_gate_weight=float(giga.get("layernorm_gating_weight", 2))
+            if is_gigachat35 else 0.0,
+            experts_held=experts_held,
+            expert_first=expert_first,
             attn_sinks=is_gptoss,
             moe_act="gptoss_clamp" if is_gptoss else "swiglu",
             o_bias=is_gptoss and bool(cfg.get("attention_bias")),
@@ -511,10 +683,10 @@ class ModelConfig:
             # lfm2_moe: sigmoid scores, a bias that picks and does not
             # weigh (use_expert_bias), weights renormalised over
             # (sum + 1e-6)
-            moe_scoring="sigmoid" if is_lfm2moe
+            moe_scoring="sigmoid" if is_lfm2moe or is_gigachat35
             else cfg.get("scoring_func", "softmax"),
             moe_gate_bias=bool(cfg.get("use_expert_bias")) if is_lfm2moe
-            else cfg.get("topk_method") == "noaux_tc",
+            else is_gigachat35 or cfg.get("topk_method") == "noaux_tc",
             topk_norm_eps=1e-6 if is_lfm2moe else 1e-20,
             moe_group_score=(
                 "top2" if cfg.get("topk_method") == "noaux_tc" else "max"
@@ -547,7 +719,8 @@ class ModelConfig:
             if is_gemma2 else 0.0,
             final_softcap=(cfg.get("final_logit_softcapping") or 0.0)
             if is_gemma2 else 0.0,
-            post_norms=is_gemma2 or is_gemma3 or is_glm4 or is_olmo2,
+            post_norms=is_gemma2 or is_gemma3 or is_glm4 or is_olmo2
+            or is_gigachat35,
             norm_after=is_olmo2,
             qk_norm_full=is_olmo2 or is_olmoe,
             attn_scale_base=(cfg.get("query_pre_attn_scalar") or 0)

@@ -112,6 +112,9 @@ def _init_layer_group(cfg: ModelConfig, key: jax.Array, L: int,
         layers["moe_gate"] = layer_stack(mk[0], (E, X))
         if cfg.moe_gate_bias:
             layers["moe_gate_bias"] = jnp.zeros((L, X), jnp.float32)
+        # the router scores every published expert; the stacks hold the
+        # experts that are here (cfg.experts_held: one chip's share)
+        X = cfg.local_experts
         layers["we_gate"] = layer_stack(mk[1], (X, E, Fm))
         layers["we_up"] = layer_stack(mk[2], (X, E, Fm))
         layers["we_down"] = layer_stack(mk[3], (X, Fm, E))
@@ -177,6 +180,17 @@ def _layer(lps: dict, li: int, mesh=None) -> dict:
     return {**lp, **whole}
 
 
+#: where a hybrid stack keeps the leaves of each operator kind
+_OP_LEAVES = {"conv": "conv_ops", "linear": "linear_ops", "attn": "attn_ops"}
+
+
+def _is_state_layer(lp: dict) -> bool:
+    """``lp`` (``_layers``) is a layer whose operator carries a
+    per-sequence state and no keys and values: LFM2's short convolution
+    or GigaChat 3.5's gated delta rule."""
+    return "conv_in" in lp or "lin_qkvz" in lp
+
+
 def _layers(params: dict, cfg: ModelConfig, mesh=None):
     """(l, lp) for every layer in forward order, for an UNROLLED layer
     loop: ``lp`` holds layer ``l``'s leaves (``_layer``). An LFM2 stack
@@ -191,7 +205,7 @@ def _layers(params: dict, cfg: ModelConfig, mesh=None):
         return
     kd = cfg.first_dense_layers if "dense_layers" in params else 0
     for l, op in enumerate(cfg.layer_ops):
-        ops = params["conv_ops" if op == "conv" else "attn_ops"]
+        ops = params[_OP_LEAVES[op]]
         ffn = params["dense_layers"] if l < kd else params["layers"]
         yield l, {**_layer(ops, cfg.op_index(l)),
                   **_layer(ffn, l if l < kd else l - kd, mesh)}
@@ -288,6 +302,105 @@ def _init_hybrid_layers(cfg: ModelConfig, key: jax.Array) -> dict:
     return out
 
 
+@partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
+def _draw_leaf(key, shape, scale, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def _draw_stacked(key, shape, scale, dtype):
+    """A stacked ``[n, ...]`` leaf drawn a layer at a time: the float32
+    draw of a whole stack of experts (3.76 GB at GigaChat 3.5's widths)
+    does not fit beside the weights it joins (ROADMAP B1)."""
+    ks = jax.random.split(key, shape[0])
+    return jnp.stack([_draw_leaf(k, tuple(shape[1:]), scale, dtype)
+                      for k in ks])
+
+
+def _init_gigachat35_layers(cfg: ModelConfig, key: jax.Array) -> dict:
+    """The layer leaves of a GigaChat 3.5 stack, by KIND as LFM2's:
+    ``linear_ops`` [Ll, ...] (the gated delta rule), ``attn_ops``
+    [La, ...] (latent attention and its output gate), ``dense_layers``
+    and ``layers`` (the FFNs: experts HELD here, the shared expert, the
+    router over all published experts). Each operator and FFN carries a
+    norm before and one after. Norm leaves, taps, the selection bias,
+    ``A_log`` and ``dt_bias`` are drawn non-zero so that a forward that
+    drops or misreads one shows. Smaller than the matrices' 0.02, so
+    that a bf16 forward stays near the float32 one (PERF.md section 6,
+    PRs 33 and 43): the routed experts' down-projections (a flipped
+    marginal choice of 8 carries an eighth of the routed output times
+    routed_scaling_factor 2.5) and ``lin_ba``, whose ``g`` sits in an
+    exponent."""
+    dt = _dtype(cfg)
+    E, H = cfg.hidden_size, cfg.num_heads
+    Ll, La = cfg.linear_layers, cfg.kv_layers
+    Hk, Hv = cfg.linear_key_heads, cfg.linear_value_heads
+    Dk, Dv, K = cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv_kernel
+    C = cfg.linear_conv_dim
+    kd = cfg.first_dense_layers
+    keys = iter(jax.random.split(key, 48))
+
+    def draw(shape, scale=0.02, dtype=dt):
+        return _draw_stacked(next(keys), tuple(shape), scale, dtype)
+
+    def norm(n, width):
+        return draw((n, width), 0.1)
+
+    def dense_ffn(n):
+        F = cfg.intermediate_size
+        return {
+            "mlp_norm": norm(n, E), "mlp_post_norm": norm(n, E),
+            "w_gate": draw((n, E, F)), "w_up": draw((n, E, F)),
+            "w_down": draw((n, F, E)),
+        }
+
+    Cq, Ckv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dqk, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    out = {
+        "linear_ops": {
+            "attn_norm": norm(Ll, E), "attn_post_norm": norm(Ll, E),
+            "lin_qkvz": draw((Ll, E, C + Hv * Dv)),
+            "lin_ba": draw((Ll, E, 2 * Hv), 0.005),
+            "lin_conv_w": draw((Ll, K, C), 0.3),
+            # decay rates exp(A_log) in about [0.5, 8], steps
+            # softplus(dt_bias) about 0.05: g = -A * step near -0.1
+            "lin_A_log": draw((Ll, Hv), 0.7, jnp.float32) + 0.7,
+            "lin_dt_bias": draw((Ll, Hv), 0.5, jnp.float32) - 3.0,
+            "lin_o_norm": draw((Ll, Dv), 0.1),
+            "lin_out": draw((Ll, Hv * Dv, E)),
+        },
+        "attn_ops": {
+            "attn_norm": norm(La, E), "attn_post_norm": norm(La, E),
+            "wq_a": draw((La, E, Cq)), "q_norm": norm(La, Cq),
+            "wq_b": draw((La, Cq, H * (dqk + dr))),
+            "wkv_a": draw((La, E, Ckv + dr)), "kv_norm": norm(La, Ckv),
+            "wkv_b": draw((La, Ckv, H * (dqk + dv))),
+            "wo": draw((La, H * dv, E)),
+        },
+    }
+    if cfg.gated_attention:
+        out["attn_ops"]["attn_gate"] = draw((La, E, H * dv))
+    if kd:
+        out["dense_layers"] = dense_ffn(kd)
+    n = cfg.num_layers - kd
+    if not cfg.is_moe:
+        out["layers"] = dense_ffn(n)
+        return out
+    X, Xl, Fm = cfg.num_experts, cfg.local_experts, cfg.moe_intermediate_size
+    Fs = Fm * cfg.num_shared_experts
+    out["layers"] = {
+        "mlp_norm": norm(n, E), "mlp_post_norm": norm(n, E),
+        "moe_gate": draw((n, E, X)),
+        "moe_gate_bias": draw((n, X), 0.1, jnp.float32),
+        "we_gate": draw((n, Xl, E, Fm)), "we_up": draw((n, Xl, E, Fm)),
+        "we_down": draw((n, Xl, Fm, E), 0.0025),
+    }
+    if Fs:
+        out["layers"].update(
+            shared_gate=draw((n, E, Fs)), shared_up=draw((n, E, Fs)),
+            shared_down=draw((n, Fs, E)))
+    return out
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random-init params (tests/benches; real weights via weights.py)."""
     dt = _dtype(cfg)
@@ -302,7 +415,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         "embed": norm_init(keys[0], (V, E), 0.02),
         "final_norm": jnp.ones((E,), dt),
     }
-    if cfg.layer_ops:
+    if cfg.linear_layers:
+        params.update(_init_gigachat35_layers(cfg, keys[1]))
+        params["final_norm"] = norm_init(keys[3], (E,), 0.1)
+    elif cfg.layer_ops:
         params.update(_init_hybrid_layers(cfg, keys[1]))
     else:
         params["layers"] = _init_layer_group(
@@ -311,7 +427,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             params["dense_layers"] = _init_layer_group(
                 cfg, keys[3], kd, False)
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = norm_init(keys[2], (E, V), 0.02)
+        # GigaChat 3.5 (hidden 7168): the head drawn so that the logits
+        # spread as at the hidden 2048 of the configurations the reference
+        # check's tolerance was set on (0.02 sqrt(2048) = 0.9): the same
+        # relative error in the last hidden state then moves a logprob as
+        # far (PERF.md section 6, PR 43); 0.02 at a width up to 2048
+        head = min(0.02, 0.02 * (2048 / E) ** 0.5) if cfg.linear_layers else 0.02
+        params["lm_head"] = norm_init(keys[2], (E, V), head)
     return params
 
 
@@ -392,12 +514,29 @@ def attn_query_scale(cfg: ModelConfig) -> float:
     return (cfg.attn_scale_base or cfg.head_dim) ** -0.5
 
 
+def model_norm(x: jnp.ndarray, w: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The family's RMS norm over the last axis with the learned leaf
+    ``w``: the scale is ``w`` itself, or GigaChat 3.5's
+    ``norm_gate_weight * sigmoid(w)`` (ZeroCenteredGatedNorm: 1 at
+    ``w = 0``)."""
+    if cfg.norm_gate_weight:
+        # the scale is COMPUTED (2 sigmoid(w)), so it stays in float32
+        # and the product is rounded once: rounding it to the model's
+        # dtype first would add a second rounding a norm that a learned
+        # scale, stored in that dtype, does not have
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        scale = cfg.norm_gate_weight * jax.nn.sigmoid(w.astype(jnp.float32))
+        return (xf * lax.rsqrt(var + cfg.rms_norm_eps) * scale).astype(x.dtype)
+    return rms_norm(x, w, cfg.rms_norm_eps)
+
+
 def pre_norm(lp: dict, key: str, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """Pre-sublayer RMS norm — identity for norm-AFTER families (OLMo-2
     carries no input/pre-FFN norms; normalization happens on the
     sublayer output via post_norm)."""
     w = lp.get(key)
-    return x if w is None else rms_norm(x, w, cfg.rms_norm_eps)
+    return x if w is None else model_norm(x, w, cfg)
 
 
 def post_norm(lp: dict, key: str, v: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -405,7 +544,7 @@ def post_norm(lp: dict, key: str, v: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarr
     residual add (post_attention/post_feedforward_layernorm). No-op for
     every other family (no post-norm weights in lp)."""
     w = lp.get(key)
-    return v if w is None else rms_norm(v, w, cfg.rms_norm_eps)
+    return v if w is None else model_norm(v, w, cfg)
 
 
 def window_for_layer(cfg: ModelConfig, l: int) -> int:
@@ -614,13 +753,21 @@ def _mm_b(x: jnp.ndarray, lp: dict, w_key: str, b_key: str) -> jnp.ndarray:
     return out if b is None else out + b
 
 
-def swiglu(x, w_gate, w_up, w_down, act: str = "silu"):
-    gate = _mm(x, w_gate)
+def _glu(gate, up, act: str = "silu", limit: float = 0.0):
+    """act(gate) * up; with a ``limit`` (GigaChat 3.5's swiglu_limit)
+    both streams are clamped first: gate from above, up on both sides."""
+    if limit:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
     gate = (
         jax.nn.gelu(gate, approximate=True) if act == "gelu_tanh"
         else jax.nn.silu(gate)
     )
-    return _mm(gate * _mm(x, w_up), w_down)
+    return gate * up
+
+
+def swiglu(x, w_gate, w_up, w_down, act: str = "silu", limit: float = 0.0):
+    return _mm(_glu(_mm(x, w_gate), _mm(x, w_up), act, limit), w_down)
 
 
 class MoeTally:
@@ -630,7 +777,11 @@ class MoeTally:
     ``sums`` (int32 [3]) adds up, over the expert layers traced so far:
     the experts that received at least one row (what the grouped matmuls
     stream), the experts that received at least one LIVE row, and the
-    rows of the largest group. The step programs hand their ``live``
+    rows of the largest group. On a chip that holds a share of the
+    experts (``cfg.experts_held``; ``MoeTally.zeros``) a fourth entry
+    counts the assignments that fell to an expert held here; where every
+    expert is here that is every live row's, which the host counts. The
+    step programs hand their ``live``
     mask to the routing (``_moe_route``), which sends a row that carries
     no token to no group: the first two then agree, and a routing that
     let a dead row into a group would show as the first above the
@@ -640,6 +791,11 @@ class MoeTally:
 
     def __init__(self, sums=None):
         self.sums = jnp.zeros((3,), jnp.int32) if sums is None else sums
+
+    @staticmethod
+    def zeros(cfg: ModelConfig) -> jnp.ndarray:
+        """The empty sums of a program over ``cfg``."""
+        return jnp.zeros((4 if cfg.experts_held else 3,), jnp.int32)
 
     def add(self, e: jnp.ndarray, t: jnp.ndarray, group_sizes: jnp.ndarray,
             live: jnp.ndarray):
@@ -652,8 +808,8 @@ class MoeTally:
             live.astype(group_sizes.dtype))
         self.sums = self.sums + jnp.stack([
             jnp.sum(group_sizes > 0), jnp.sum(by_expert > 0),
-            jnp.max(group_sizes),
-        ]).astype(jnp.int32)
+            jnp.max(group_sizes), jnp.sum(group_sizes),
+        ][: self.sums.shape[0]]).astype(jnp.int32)
 
     def scan(self, body, x, xs):
         """``lax.scan(body, x, xs)`` for a layer body that adds to this
@@ -692,20 +848,29 @@ def _moe_route(lp: dict, cfg: ModelConfig, x: jnp.ndarray, live=None):
     with weight 0 into the sacrificial row T (``_moe_combine``), and
     ``e_sorted`` names the last expert for them (an index in bounds for
     the per-expert biases). A live row's arithmetic is the same with and
-    without the mask. None routes every row."""
+    without the mask. None routes every row. A chip that holds a share
+    of the experts (``cfg.experts_held``) drops the assignments to the
+    others the same way: the ids returned are LOCAL ones."""
     k = cfg.num_experts_per_tok
-    X = cfg.num_experts
+    X = cfg.local_experts  # the groups the matmuls are handed
     vals, idx = _route_topk(lp, cfg, x)
-    if live is not None:
-        idx = jnp.where(live[:, None], idx, X)
-        vals = jnp.where(live[:, None], vals, 0.0)
+    keep = None if live is None else jnp.broadcast_to(live[:, None], idx.shape)
+    if cfg.experts_held:
+        # one chip's share: an assignment to an expert that lies on
+        # another chip joins no group here, like a dead row's
+        idx = idx - cfg.expert_first
+        here = (idx >= 0) & (idx < X)
+        keep = here if keep is None else keep & here
+    if keep is not None:
+        idx = jnp.where(keep, idx, X)
+        vals = jnp.where(keep, vals, 0.0)
     e_flat = idx.reshape(-1)  # [T*k] row-major: assignment r -> token r//k
     order = jnp.argsort(e_flat)  # stable: deterministic within an expert
     t_sorted = order // k
     w_sorted = vals.reshape(-1)[order]
     e_sorted = e_flat[order]  # expert id per sorted row (expert biases)
     group_sizes = jnp.bincount(e_flat, length=X)  # an id of X counts nowhere
-    if live is not None:
+    if keep is not None:
         t_sorted = jnp.where(e_sorted < X, t_sorted, x.shape[0])
         e_sorted = jnp.minimum(e_sorted, X - 1)
     return t_sorted, w_sorted, e_sorted, group_sizes
@@ -763,7 +928,7 @@ def _expert_act(cfg: ModelConfig, g: jnp.ndarray, u: jnp.ndarray):
         g = jnp.clip(g, None, 7.0)
         u = jnp.clip(u, -7.0, 7.0)
         return (u + 1.0) * (g * jax.nn.sigmoid(1.702 * g))
-    return jax.nn.silu(g) * u
+    return _glu(g, u, limit=cfg.swiglu_limit)
 
 
 def _ragged_mm(xs, w, group_sizes, use_pallas: bool, interpret: bool):
@@ -805,11 +970,13 @@ def _dense_expert_mm(x, w, spec: str):
     return jnp.einsum(spec, x, w)
 
 
-def _moe_gather(x, t_sorted, live):
-    """The token row of each assignment, in expert order. With a mask the
-    assignments of dead rows name the sacrificial row T (``_moe_route``):
-    they read the last row instead, into rows no group covers."""
-    if live is not None:
+def _moe_gather(x, t_sorted, masked):
+    """The token row of each assignment, in expert order. Where the
+    routing dropped assignments (``masked``: the ``live`` mask it was
+    given, or True for a share of the experts) they name the sacrificial
+    row T (``_moe_route``): they read the last row instead, into rows no
+    group covers."""
+    if masked is not None and masked is not False:
         t_sorted = jnp.minimum(t_sorted, x.shape[0] - 1)
     return x[t_sorted]
 
@@ -865,6 +1032,11 @@ def moe_ffn(
     """
     T = x.shape[0]
     out_dt = x.dtype
+    if mesh is not None and cfg.experts_held:
+        raise ValueError(
+            "experts_held (one chip's share of the experts) under a mesh: "
+            "the share IS the expert-parallel layout; shard the whole "
+            "layer over ep instead")
     if tally is not None and mesh is not None:
         t_sorted, _, e_sorted, group_sizes = _moe_route(lp, cfg, x, live)
         tally.add(e_sorted, t_sorted, group_sizes, live)
@@ -873,7 +1045,8 @@ def moe_ffn(
             lp, cfg, x, live)
         if tally is not None:
             tally.add(e_sorted, t_sorted, group_sizes, live)
-        xs = _moe_gather(x, t_sorted, live)
+        xs = _moe_gather(x, t_sorted,
+                         live is not None or bool(cfg.experts_held))
         g = _ragged_mm(xs, lp["we_gate"], group_sizes, use_pallas, interpret)
         u = _ragged_mm(xs, lp["we_up"], group_sizes, use_pallas, interpret)
         if "be_gate" in lp:  # gpt-oss per-expert projection biases
@@ -905,14 +1078,15 @@ def moe_ffn(
     else:
         out = _moe_dense_dispatch(lp, cfg, x)
     if "shared_gate" in lp:
-        out = out + _shared_expert(lp, x)
+        out = out + _shared_expert(lp, x, cfg.swiglu_limit)
     return out
 
 
-def _shared_expert(lp: dict, x: jnp.ndarray) -> jnp.ndarray:
+def _shared_expert(lp: dict, x: jnp.ndarray, limit: float = 0.0) -> jnp.ndarray:
     """Shared-expert contribution: DeepSeek's is always-on; Qwen2-MoE
     gates it per token with sigmoid(x @ shared_expert_gate)."""
-    shared = swiglu(x, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    shared = swiglu(x, lp["shared_gate"], lp["shared_up"], lp["shared_down"],
+                    limit=limit)
     if "shared_egate" in lp:
         g = jax.nn.sigmoid(
             x.astype(jnp.float32) @ lp["shared_egate"].astype(jnp.float32)
@@ -933,6 +1107,8 @@ def _moe_dense_dispatch(lp: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarr
         * vals[..., None],
         axis=1,
     )  # [T, X] routing weights
+    if cfg.experts_held:  # the held experts' columns
+        w = w[:, cfg.expert_first : cfg.expert_first + cfg.experts_held]
     g = _dense_expert_mm(x, lp["we_gate"], "te,xef->txf")
     u = _dense_expert_mm(x, lp["we_up"], "te,xef->txf")
     if "be_gate" in lp:  # gpt-oss per-expert projection biases
@@ -948,7 +1124,7 @@ def moe_ffn_dense(lp: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     """Full dense-dispatch reference (incl. shared experts) for tests."""
     out = _moe_dense_dispatch(lp, cfg, x)
     if "shared_gate" in lp:
-        out = out + _shared_expert(lp, x)
+        out = out + _shared_expert(lp, x, cfg.swiglu_limit)
     return out
 
 
@@ -1072,7 +1248,8 @@ def _ffn(lp: dict, cfg: ModelConfig, h: jnp.ndarray, mesh=None,
     if "moe_gate" in lp:
         return moe_ffn(lp, cfg, h, mesh=mesh, use_pallas=use_pallas,
                        interpret=interpret, tally=tally, live=live)
-    return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.hidden_act)
+    return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.hidden_act,
+                  cfg.swiglu_limit)
 
 
 def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
@@ -1167,21 +1344,71 @@ def _ffn_tail(x, lp: dict, cfg: ModelConfig, mesh=None,
 # ---------------- LFM2's short convolution and its state ----------------
 
 
-def init_state(cfg: ModelConfig, max_batch: int, num_blocks: int):
+#: a state whose row is at least this large gets a snapshot pool of its
+#: own size and not a row a KV block (``state_snapshot_rows``)
+SNAPSHOT_ROW_A_BLOCK_BYTES = 1 << 20
+
+
+def state_row_bytes(cfg: ModelConfig) -> int:
+    """Bytes of ONE sequence's state over all state layers: the
+    convolution's last rows in the model's dtype and, for the gated
+    delta rule, one float32 [key_dim, value_dim] matrix a value head."""
+    item = jnp.dtype(_dtype(cfg)).itemsize
+    conv = cfg.conv_layers * (cfg.conv_kernel - 1) * cfg.hidden_size
+    lin = cfg.linear_layers * (cfg.linear_conv_kernel - 1) * cfg.linear_conv_dim
+    rec = (cfg.linear_layers * cfg.linear_value_heads * cfg.linear_key_dim
+           * cfg.linear_value_dim)
+    return (conv + lin) * item + rec * 4
+
+
+def state_snapshot_rows(cfg: ModelConfig, num_blocks: int,
+                        snapshots: int = 0) -> int:
+    """Rows of the snapshot pool: a row a KV block for a small state
+    (LFM2's 72 KiB: every block end can hold one), else ``snapshots``
+    rows (GigaChat 3.5's 16.4 MiB: 64 rows are 1 GiB), which the engine
+    maps to blocks and reuses."""
+    if state_row_bytes(cfg) < SNAPSHOT_ROW_A_BLOCK_BYTES:
+        return num_blocks
+    return max(1, min(snapshots or 64, num_blocks))
+
+
+def init_state(cfg: ModelConfig, max_batch: int, num_blocks: int,
+               snapshots: int = 0):
     """The per-sequence state that is not keys and values, or None for a
-    stack without conv layers. A conv layer needs, to go on from token t,
-    the last ``conv_kernel - 1`` rows of ``B * x`` (``short_conv``):
+    stack without state layers. A conv layer needs, to go on from token
+    t, the last ``conv_kernel - 1`` rows of ``B * x`` (``short_conv``):
     ``conv`` [max_batch, Lc * (K-1) * E] holds them for the sequence in
-    each decode slot, ``snap`` [num_blocks, Lc * (K-1) * E] as they
-    stood at the END of each full block of the KV pool, by block id:
-    what a prefix hit that ends with that block restores
-    (``ConvTrack``). A row is kept flat: the TPU tiles an array's last
-    two dimensions, and rows of K-1 = 2 would be padded to a tile's 16."""
-    if not cfg.conv_layers:
+    each decode slot, ``snap`` [snapshot rows, Lc * (K-1) * E] as they
+    stood when a snapshot was taken (``StateTrack``): what a prefix hit
+    restores. A row is kept flat: the TPU tiles an array's last two
+    dimensions, and rows of K-1 = 2 would be padded to a tile's 16.
+
+    A linear-attention layer (``gated_delta``) has the same rows for its
+    convolution over q, k and v, and beside them ``rec`` [Ll, max_batch,
+    Hv, Dk, Dv] float32, the delta rule's matrices (layer-major: a
+    layer's rows are one contiguous slab, updated in place), with
+    ``snap_rec`` [Ll, snapshot rows, Hv, Dk, Dv]."""
+    if not cfg.state_layers:
         return None
-    width = cfg.conv_layers * (cfg.conv_kernel - 1) * cfg.hidden_size
-    return {"conv": jnp.zeros((max_batch, width), _dtype(cfg)),
-            "snap": jnp.zeros((num_blocks, width), _dtype(cfg))}
+    if cfg.conv_layers and cfg.linear_layers:
+        raise ValueError("conv and linear-attention layers in one stack "
+                         "are not supported")
+    n_snap = state_snapshot_rows(cfg, num_blocks, snapshots)
+    if cfg.conv_layers:
+        width = cfg.conv_layers * (cfg.conv_kernel - 1) * cfg.hidden_size
+    else:
+        width = (cfg.linear_layers * (cfg.linear_conv_kernel - 1)
+                 * cfg.linear_conv_dim)
+    state = {"conv": jnp.zeros((max_batch, width), _dtype(cfg)),
+             "snap": jnp.zeros((n_snap, width), _dtype(cfg))}
+    if cfg.linear_layers:
+        mat = (cfg.linear_value_heads, cfg.linear_key_dim,
+               cfg.linear_value_dim)
+        state["rec"] = jnp.zeros(
+            (cfg.linear_layers, max_batch) + mat, jnp.float32)
+        state["snap_rec"] = jnp.zeros(
+            (cfg.linear_layers, n_snap) + mat, jnp.float32)
+    return state
 
 
 def short_conv(lp: dict, h: jnp.ndarray, parts: list):
@@ -1216,53 +1443,225 @@ def short_conv(lp: dict, h: jnp.ndarray, parts: list):
     return _mm(c * conv, lp["conv_out"]), exts
 
 
+# ---------------- GigaChat 3.5's gated delta rule ----------------
+
+#: rows of one block of the chunked form: inside a block the delta rule
+#: is a triangular solve and matrix products, across blocks a scan
+DELTA_BLOCK = 64
+_HI = lax.Precision.HIGHEST
+
+
+def delta_rule_step(q, k, v, g, beta, S):
+    """ONE token of the gated delta rule for every (segment, value head):
+
+        S <- exp(g) S;  d = beta (v - S^T k);  S <- S + k d^T;  o = S^T q
+
+    q, k [N, Hv, Dk], v [N, Hv, Dv], g, beta [N, Hv], S [N, Hv, Dk, Dv],
+    all float32. Returns (o [N, Hv, Dv], S). A row with g = 0 and
+    beta = 0 leaves S as it was."""
+    S = S * jnp.exp(g)[..., None, None]
+    d = beta[..., None] * (v - jnp.sum(S * k[..., None], axis=-2))
+    S = S + k[..., None] * d[..., None, :]
+    return jnp.sum(S * q[..., None], axis=-2), S
+
+
+def delta_rule_chunked(q, k, v, g, beta, S, block: int = DELTA_BLOCK):
+    """T tokens of the gated delta rule from the state ``S``, in blocks
+    of ``block`` rows (the chunked form of arXiv:2412.06464): inside a
+    block the T dependent steps become one unit-lower-triangular solve
+    and matrix products, and the state moves once a block.
+
+    q, k [N, Hv, T, Dk], v [N, Hv, T, Dv], g, beta [N, Hv, T], S
+    [N, Hv, Dk, Dv], float32, T a multiple of ``block``. Returns (o
+    [N, Hv, T, Dv], S after the last row); rows with g = 0 and beta = 0
+    (padding) leave the state as it was. Products are taken at full
+    float32 precision: the recurrence (``delta_rule_step``) is exact in
+    float32, and the two forms have to agree to its rounding."""
+    N, Hv, T, Dk = q.shape
+    nb = T // block
+    blk = lambda a: a.reshape((N, Hv, nb, block) + a.shape[3:])  # noqa: E731
+    q, k, v, g, beta = blk(q), blk(k), blk(v), blk(g), blk(beta)
+    gc = jnp.cumsum(g, axis=-1)  # decay from the block's start, log
+    i = jnp.arange(block)
+    diff = gc[..., :, None] - gc[..., None, :]
+    decay = jnp.exp(jnp.where(i[:, None] >= i[None, :], diff, -jnp.inf))
+    kb = k * beta[..., None]
+    # (I + A) u = beta v, (I + A) w = beta k exp(gc): A strictly lower
+    A = jnp.einsum("...ik,...jk->...ij", kb, k, precision=_HI) * decay
+    A = jnp.where(i[:, None] > i[None, :], A, 0.0) + jnp.eye(block)
+    rhs = jnp.concatenate(
+        [v * beta[..., None], kb * jnp.exp(gc)[..., None]], axis=-1)
+    sol = jax.scipy.linalg.solve_triangular(
+        A, rhs, lower=True, unit_diagonal=True)
+    u, w = sol[..., : v.shape[-1]], sol[..., v.shape[-1]:]
+    qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=_HI) * decay
+    q_in = q * jnp.exp(gc)[..., None]
+    k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]
+    last = jnp.exp(gc[..., -1])
+
+    def one(S, xs):
+        u_b, w_b, qk_b, q_b, k_b, last_b = xs
+        v_new = u_b - jnp.einsum("nhtk,nhkv->nhtv", w_b, S, precision=_HI)
+        o = (jnp.einsum("nhtk,nhkv->nhtv", q_b, S, precision=_HI)
+             + jnp.einsum("nhts,nhsv->nhtv", qk_b, v_new, precision=_HI))
+        S = S * last_b[..., None, None] + jnp.einsum(
+            "nhtk,nhtv->nhkv", k_b, v_new, precision=_HI)
+        return S, o
+
+    front = lambda a: jnp.moveaxis(a, 2, 0)  # noqa: E731
+    S, o = lax.scan(one, S, tuple(map(front, (u, w, qk, q_in, k_out, last))))
+    return jnp.moveaxis(o, 0, 2).reshape(N, Hv, T, -1), S
+
+
+def gated_delta(lp: dict, cfg: ModelConfig, h: jnp.ndarray, parts: list,
+                steps: Optional[list] = None):
+    """GigaChat 3.5's linear-attention operator (Gated DeltaNet) over
+    SEGMENTS that each start from an incoming state, as ``short_conv``:
+
+        [q, k, v, z] = h W_qkvz;  [b, a] = h W_ba
+        [q, k, v] = silu(causal depthwise conv_K([q, k, v]))
+        q = l2norm(q) Dk^-0.5, k = l2norm(k)    (a head; a key head
+                                                 serves Hv / Hk value heads)
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)
+        o_t = delta rule over t (``delta_rule_step``), state float32
+        y = (rms(o) (1 + w_o) * gate_scale sigmoid(z)) W_out
+
+    ``h`` [R, E] holds the parts' rows in order; a part is ``(S, T, n
+    [S], conv_in [S, K-1, C], rec_in [S, Hv, Dk, Dv])``: S segments of T
+    rows of which the first ``n`` are real, the K-1 rows of the
+    convolution's input before each and the matrices they start from. A
+    segment of one row runs the recurrence, a longer one the chunked
+    form; rows past ``n`` leave the matrices as they were. Returns (y
+    [R, E], one ``v_ext`` [S, K-1 + T, C] a part, as ``short_conv``, one
+    ``rec`` [S, Hv, Dk, Dv] a part: the matrices after row n).
+    ``steps`` may name, a part of one-row segments, another
+    implementation of ``delta_rule_step`` (the Pallas kernel over the
+    whole state, ``StateTrack.run_linear``: ``rec_in`` is then whatever
+    that one takes); None: the ``jax.numpy`` one."""
+    Hk, Hv = cfg.linear_key_heads, cfg.linear_value_heads
+    Dk, Dv, C = cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv_dim
+    f32 = jnp.float32
+    qkvz = _mm(h, lp["lin_qkvz"])
+    mix, z = qkvz[:, :C], qkvz[:, C:]
+    ba = h.astype(f32) @ lp["lin_ba"].astype(f32)
+    beta_all = jax.nn.sigmoid(ba[:, :Hv])
+    g_all = -jnp.exp(lp["lin_A_log"].astype(f32)) * jax.nn.softplus(
+        ba[:, Hv:] + lp["lin_dt_bias"].astype(f32))
+    w = lp["lin_conv_w"].astype(f32)  # [K, C], w[K-1] on the row itself
+    outs, exts, recs, r = [], [], [], 0
+    for pi, (S, T, n, conv_in, rec_in) in enumerate(parts):
+        step = (steps[pi] if steps else None) or delta_rule_step
+        rows = slice(r, r + S * T)
+        r += S * T
+        v_ext = jnp.concatenate(
+            [conv_in.astype(mix.dtype), mix[rows].reshape(S, T, C)], 1)
+        acc = sum(w[j] * v_ext[:, j : j + T].astype(f32)
+                  for j in range(w.shape[0]))
+        acc = jax.nn.silu(acc)
+        q = acc[..., : Hk * Dk].reshape(S, T, Hk, Dk)
+        k = acc[..., Hk * Dk : 2 * Hk * Dk].reshape(S, T, Hk, Dk)
+        v = acc[..., 2 * Hk * Dk :].reshape(S, T, Hv, Dv)
+        l2 = lambda a: a * lax.rsqrt(  # noqa: E731
+            jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+        q = jnp.repeat(l2(q) * Dk ** -0.5, Hv // Hk, axis=2)
+        k = jnp.repeat(l2(k), Hv // Hk, axis=2)
+        real = (jnp.arange(T)[None, :] < n[:, None])[..., None]  # [S, T, 1]
+        g = jnp.where(real, g_all[rows].reshape(S, T, Hv), 0.0)
+        beta = jnp.where(real, beta_all[rows].reshape(S, T, Hv), 0.0)
+        if T == 1:
+            with jax.named_scope("linear_attn_recurrent"):
+                o, rec = step(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rec_in)
+            o = o[:, None]
+        else:
+            block = min(DELTA_BLOCK, T)
+            pad = -T % block
+            tm = lambda a: jnp.moveaxis(  # noqa: E731
+                jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)),
+                1, 2)
+            with jax.named_scope("linear_attn_chunked"):
+                o, rec = delta_rule_chunked(
+                    tm(q), tm(k), tm(v), tm(g), tm(beta), rec_in, block)
+            o = jnp.moveaxis(o, 2, 1)[:, :T]
+        outs.append(o.reshape(S * T, Hv, Dv))
+        exts.append(v_ext)
+        recs.append(rec)
+    o = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    o = o * lax.rsqrt(
+        jnp.mean(o * o, -1, keepdims=True) + cfg.linear_o_norm_eps)
+    o = o * (1.0 + lp["lin_o_norm"].astype(f32))
+    gate = cfg.linear_gate_scale * jax.nn.sigmoid(
+        z.astype(f32)).reshape(-1, Hv, Dv)
+    y = (o * gate).astype(h.dtype).reshape(-1, Hv * Dv)
+    return _mm(y, lp["lin_out"]), exts, recs
+
+
 class Segments(NamedTuple):
     """S segments of T rows each, as a step program hands them to the
-    conv layers: a decode batch (T = 1) or prefill chunks."""
+    state layers: a decode batch (T = 1) or prefill chunks."""
 
     rows: Optional[jnp.ndarray]  # [S] state row of each; None: s itself
     n: jnp.ndarray  # [S] real rows of each (0: a dead slot or segment)
     hist: jnp.ndarray  # [S] tokens before each segment's first row
     tables: jnp.ndarray  # [S, M] block tables
     T: int
+    # [S] the snapshot row that takes each segment's state after its last
+    # real row (an index past the pool: none), for a state that is
+    # snapshotted at a segment's end only; None: no segment's is
+    snap: Optional[jnp.ndarray] = None
 
 
-class ConvTrack:
-    """What the conv layers of ONE step program read and leave behind,
-    gathered while it is traced. Each segment starts from its row of
-    ``state["conv"]`` (``init_state``); ``finish`` writes back the state
-    after each segment's last REAL row (a dead segment's is left as it
-    was), and,
-    for every block of the KV pool whose last token is among a segment's
-    real rows, the state at that token into ``state["snap"]`` under the
-    block's id: the block is committed to the prefix cache only after
-    the program that filled it, so a committed block always has its
-    snapshot, and a prefix hit restores the state with the keys and
-    values it claims."""
+class StateTrack:
+    """What the state layers of ONE step program read and leave behind,
+    gathered while it is traced. Each segment starts from its row of the
+    state (``init_state``); the state after each segment's last REAL row
+    is written back (a dead segment's is left as it was), and snapshots
+    are taken WHERE THE KIND OF STATE ALLOWS:
+
+      * a conv layer's state is a window of rows the layer has anyway
+        (``v_ext``), so for every block of the KV pool whose last token
+        is among a segment's real rows the state at that token goes into
+        ``state["snap"]`` under the block's id (a row a block: the pool
+        is as long as the KV pool). The block is committed to the prefix
+        cache only after the program that filled it, so a committed
+        block always has its snapshot;
+      * a recurrent matrix exists at a segment's END only, so a segment
+        whose ``Segments.snap`` names a row leaves its end state (both
+        parts) there; the engine ends a chunk where it wants a snapshot
+        and keeps the map from blocks to rows (engine.SnapshotPool).
+    """
 
     def __init__(self, cfg: ModelConfig, state: dict, groups: list,
                  block_size: int):
-        self.state, self.groups = state, groups
+        self.cfg, self.state, self.groups = cfg, state, groups
+        self.linear = bool(cfg.linear_layers)
         conv = state["conv"]
-        row = (cfg.conv_layers, cfg.conv_kernel - 1, cfg.hidden_size)
+        if self.linear:
+            K, W = cfg.linear_conv_kernel, cfg.linear_conv_dim
+            self.rec, self.snap_rec = state["rec"], state["snap_rec"]
+        else:
+            K, W = cfg.conv_kernel, cfg.hidden_size
+        row = (cfg.state_layers, K - 1, W)
         self.start = [
             (conv if g.rows is None else conv[g.rows]).reshape((-1,) + row)
             for g in groups]
         # a group: where in a layer's v_ext the state after the last real
         # row and at each block end lies, and the blocks that end there
         self.last, self.block_end, self.blocks = [], [], []
-        self.after = [[] for _ in groups]  # a conv layer: [S, K-1, E]
-        self.ends = [[] for _ in groups]  # a conv layer: [S, nb, K-1, E]
-        tap = jnp.arange(cfg.conv_kernel - 1)
+        self.after = [[] for _ in groups]  # a state layer: [S, K-1, W]
+        self.ends = [[] for _ in groups]  # a conv layer: [S, nb, K-1, W]
+        tap = jnp.arange(K - 1)
         for g in groups:
             S, M = g.tables.shape
             seg = jnp.arange(S)
+            self.last.append((seg[:, None], g.n[:, None] + tap))
+            if self.linear:
+                continue
             nb = g.T // block_size + 1  # block ends a segment can hold
             j = g.hist[:, None] // block_size + jnp.arange(nb)[None]
             e = (j + 1) * block_size - g.hist[:, None]  # rows up to the end
             ok = (e >= 1) & (e <= g.n[:, None])
             blk = jnp.take_along_axis(g.tables, jnp.clip(j, 0, M - 1), 1)
-            self.last.append((seg[:, None], g.n[:, None] + tap))
             self.block_end.append(
                 (seg[:, None, None], jnp.clip(e, 0, g.T)[:, :, None] + tap))
             # an index past the pool is dropped by the scatter
@@ -1276,34 +1675,98 @@ class ConvTrack:
     def add(self, exts: list) -> None:
         for gi, v_ext in enumerate(exts):
             self.after[gi].append(v_ext[self.last[gi]])
-            self.ends[gi].append(v_ext[self.block_end[gi]])
+            if not self.linear:
+                self.ends[gi].append(v_ext[self.block_end[gi]])
+
+    def run_linear(self, lp: dict, h: jnp.ndarray, ci: int,
+                   use_pallas: bool = False,
+                   interpret: bool = False) -> jnp.ndarray:
+        """Linear layer ``ci``'s operator over the groups; its matrices
+        move in place in ``rec`` (and into ``snap_rec`` where a segment
+        names a snapshot row). With kernels on, a decode batch (every
+        slot a segment of one row) takes its step in the Pallas kernel,
+        which reads and writes the layer's matrices once, where they
+        lie (ops/gated_delta_pallas)."""
+        from ..ops import gated_delta_pallas as gdp
+
+        cfg = self.cfg
+        kernel = use_pallas and (interpret or gdp.kernel_serves(
+            cfg.linear_value_heads, cfg.linear_key_dim, cfg.linear_value_dim))
+
+        def in_place(q, k, v, g, beta, _rec_in):
+            o, self.rec = gdp.linear_attn_recurrent_step(
+                q, k, v, g, beta, self.rec, jnp.int32(ci),
+                interpret=interpret)
+            return o, None
+
+        parts, steps = [], []
+        for g, st in zip(self.groups, self.start):
+            whole = kernel and g.rows is None and g.T == 1
+            rec_in = (None if whole else self.rec[ci] if g.rows is None
+                      else self.rec[ci, g.rows])
+            parts.append((st.shape[0], g.T, g.n, st[:, ci], rec_in))
+            steps.append(in_place if whole else None)
+        y, exts, recs = gated_delta(lp, cfg, h, parts, steps)
+        self.add(exts)
+        for g, rec in zip(self.groups, recs):
+            if rec is None:  # the kernel has written self.rec
+                continue
+            self.rec = (self.rec.at[ci].set(rec) if g.rows is None
+                        else self.rec.at[ci, g.rows].set(rec, mode="drop"))
+            if g.snap is not None:
+                self.snap_rec = self.snap_rec.at[ci, g.snap].set(
+                    rec, mode="drop")
+        return y
 
     def finish(self) -> dict:
         conv, snap = self.state["conv"], self.state["snap"]
         W = conv.shape[1]
         for gi, g in enumerate(self.groups):
-            # [S, Lc, K-1, E] and [S, nb, Lc, K-1, E], as flat rows
+            # [S, Ls, K-1, W] and [S, nb, Lc, K-1, W], as flat rows
             after = jnp.stack(self.after[gi], 1).reshape(-1, W)
-            ends = jnp.stack(self.ends[gi], 2).reshape(-1, W)
             conv = after if g.rows is None else conv.at[g.rows].set(
                 after, mode="drop")
-            snap = snap.at[self.blocks[gi]].set(ends, mode="drop")
-        return {"conv": conv, "snap": snap}
+            if not self.linear:
+                ends = jnp.stack(self.ends[gi], 2).reshape(-1, W)
+                snap = snap.at[self.blocks[gi]].set(ends, mode="drop")
+            elif g.snap is not None:
+                snap = snap.at[g.snap].set(after, mode="drop")
+        out = {"conv": conv, "snap": snap}
+        if self.linear:
+            out.update(rec=self.rec, snap_rec=self.snap_rec)
+        return out
 
 
 def _conv_layer(x, lp: dict, cfg: ModelConfig, l: int,
-                track: Optional[ConvTrack], **ffn_kw) -> jnp.ndarray:
-    """One conv layer of an LFM2 stack onto the residual ``x`` [R, E]:
-    the operator over ``track``'s segments (one segment from zeros
-    without a track: ``dense_forward``), then the FFN sublayer."""
+                track: Optional[StateTrack], **ffn_kw) -> jnp.ndarray:
+    """One state layer of a hybrid stack onto the residual ``x`` [R, E]:
+    the operator (LFM2's short convolution or GigaChat 3.5's gated delta
+    rule, by the layer's leaves) over ``track``'s segments (one segment
+    from zeros without a track: ``dense_forward``), then the FFN
+    sublayer."""
     h = pre_norm(lp, "attn_norm", x, cfg)
-    if track is None:
+    R = x.shape[0]
+    if "lin_qkvz" in lp:
+        if track is None:
+            zeros = jnp.zeros(
+                (1, cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), x.dtype)
+            rec = jnp.zeros((1, cfg.linear_value_heads, cfg.linear_key_dim,
+                             cfg.linear_value_dim), jnp.float32)
+            y, _, _ = gated_delta(
+                lp, cfg, h, [(1, R, jnp.full((1,), R), zeros, rec)])
+        else:
+            y = track.run_linear(
+                lp, h, cfg.op_index(l), ffn_kw.get("use_pallas", False),
+                ffn_kw.get("interpret", False))
+    elif track is None:
         zeros = jnp.zeros((1, cfg.conv_kernel - 1, x.shape[-1]), x.dtype)
-        y, _ = short_conv(lp, h, [(1, x.shape[0], zeros)])
+        y, _ = short_conv(lp, h, [(1, R, zeros)])
     else:
         y, exts = short_conv(lp, h, track.parts(cfg.op_index(l)))
         track.add(exts)
-    return _ffn_tail(x + y, lp, cfg, **ffn_kw)
+    return _ffn_tail(x + post_norm(lp, "attn_post_norm", y, cfg), lp, cfg,
+                     **ffn_kw)
+
 
 
 # ---------------- prefill (one sequence, chunked) ----------------
@@ -1347,6 +1810,10 @@ def prefill(
     # state follows v_cache in the return
     state: Optional[dict] = None,
     slot: Optional[jnp.ndarray] = None,
+    # a state that is snapshotted at a segment's end only (GigaChat
+    # 3.5's matrices): the snapshot row that takes this chunk's end
+    # state, or an index past the pool for none
+    snap_row: Optional[jnp.ndarray] = None,
 ):
     """Process one (chunk of a) prompt; returns (last_hidden_logits, caches).
 
@@ -1399,7 +1866,7 @@ def prefill(
     x = _embed(params, cfg, tokens)  # [T, E]
     positions = history_len + jnp.arange(T)
     live = jnp.arange(T) < valid_len  # the bucket's padding routes nowhere
-    tally = MoeTally() if moe_counters else None
+    tally = MoeTally(MoeTally.zeros(cfg)) if moe_counters else None
     if cfg.is_mla:
         from . import mla
 
@@ -1412,11 +1879,13 @@ def prefill(
 
     inv_local = _rope_freqs_local(cfg)
     track = None
-    if cfg.conv_layers:
+    if cfg.state_layers:
         assert state is not None and not quantized and lora is None
-        track = ConvTrack(cfg, state, [Segments(
+        track = StateTrack(cfg, state, [Segments(
             slot[None], valid_len[None], history_len[None],
-            block_table[None], T)], k_cache.shape[3])
+            block_table[None], T,
+            None if snap_row is None else snap_row[None])],
+            k_cache.shape[3])
 
     def body(carry, layer_in, window=cfg.sliding_window, freqs=None,
              scales=None, lora_l=None):
@@ -1464,7 +1933,7 @@ def prefill(
                     q_eff, q_pe, kc, vc, block_table, history_len,
                     valid_len, scale,
                 )
-            o = mla._o_proj(lp, cfg, out_lat).astype(x.dtype)
+            o = mla.o_proj_gated(lp, cfg, out_lat, h).astype(x.dtype)
         else:
             q, k, v = _qkv(lp, cfg, h, lora_l, lora_ids)
             fr = inv_freq if freqs is None else freqs
@@ -1538,7 +2007,7 @@ def prefill(
         # LFM2 stack: its operator differs by layer, and an attention
         # layer's cache index is its ordinal among them.
         for l, lp in _layers(params, cfg, mesh):
-            if "conv_in" in lp:
+            if _is_state_layer(lp):
                 x = _conv_layer(x, lp, cfg, l, track, mesh=mesh,
                                 use_pallas=use_pallas, tally=tally,
                                 live=live)
@@ -1556,7 +2025,7 @@ def prefill(
         x, k_cache, v_cache = _scan_groups(
             body, x, params, cfg, k_cache, v_cache, tally=tally
         )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = model_norm(x, params["final_norm"], cfg)
     # logits for the last *real* token of the chunk
     last = jnp.clip(valid_len - 1, 0, T - 1)
     logits = _logits(params, cfg, x[last])
@@ -1611,9 +2080,9 @@ def _decode_body(
     own it already."""
     quantized = k_scales is not None
     track = None
-    if cfg.conv_layers:
+    if cfg.state_layers:
         assert state is not None and not quantized and lora is None
-        track = ConvTrack(cfg, state, [Segments(
+        track = StateTrack(cfg, state, [Segments(
             None, (seq_lens > 0).astype(jnp.int32), positions,
             block_tables, 1)], k_cache.shape[3])
     if quantized:
@@ -1657,8 +2126,10 @@ def _decode_body(
         return q, k, v
 
     def mla_q_and_latent(x, lp):
+        """(the normed input, for the output gate; q_eff, q_pe, c_kv, k_pe)"""
         h = pre_norm(lp, "attn_norm", x, cfg)
-        return _mla.mla_q_and_latent(lp, cfg, h, positions, inv_freq, msc)
+        return (h,) + _mla.mla_q_and_latent(
+            lp, cfg, h, positions, inv_freq, msc)
 
     def lora_for_layer(l):
         return (
@@ -1694,21 +2165,26 @@ def _decode_body(
 
         c_news, pe_news = [], []
         for l, lp in layers():
-            q_eff, q_pe, c_kv, k_pe = mla_q_and_latent(x, lp)
+            if _is_state_layer(lp):
+                x = conv_layer(x, lp, l)
+                continue
+            a = cfg.op_index(l)  # the layer's index in the cache
+            h, q_eff, q_pe, c_kv, k_pe = mla_q_and_latent(x, lp)
             c_news.append(c_kv)
             pe_news.append(k_pe)
             if mesh is None:
                 o_lat = _mla_ops.mla_decode_attention_merged(
-                    q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
+                    q_eff, q_pe, c_kv, k_pe, k_cache[a], v_cache[a],
                     block_tables, hist_lens, scale, interpret=interpret,
                 )
             else:
                 o_lat = _mla_ops.mla_decode_attention_merged_sharded(
-                    q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
+                    q_eff, q_pe, c_kv, k_pe, k_cache[a], v_cache[a],
                     block_tables, hist_lens, scale, mesh,
                     interpret=interpret,
                 )
-            x = layer_tail(x, lp, _mla._o_proj(lp, cfg, o_lat).astype(x.dtype))
+            x = layer_tail(
+                x, lp, _mla.o_proj_gated(lp, cfg, o_lat, h).astype(x.dtype))
         c_stack = jnp.stack(c_news)[:, :, None, :]  # [L, B, 1, C]
         pe_stack = jnp.stack(pe_news)[:, :, None, :]  # [L, B, 1, R]
         if mesh is None:
@@ -1725,20 +2201,25 @@ def _decode_body(
         # WRITE-THEN-ATTEND, MLA flavor: the token's latent lands by XLA
         # scatter, then absorbed attention gathers the layer's pages
         for l, lp in layers():
-            q_eff, q_pe, c_kv, k_pe = mla_q_and_latent(x, lp)
-            # advanced indices (blk, off) behind the scalar l and the
+            if _is_state_layer(lp):
+                x = conv_layer(x, lp, l)
+                continue
+            a = cfg.op_index(l)  # the layer's index in the cache
+            h, q_eff, q_pe, c_kv, k_pe = mla_q_and_latent(x, lp)
+            # advanced indices (blk, off) behind the scalar a and the
             # full slice come to the front: the value is [B, 1, D]
-            k_cache = k_cache.at[l, :, blk, off].set(
+            k_cache = k_cache.at[a, :, blk, off].set(
                 c_kv[:, None].astype(k_cache.dtype)
             )
-            v_cache = v_cache.at[l, :, blk, off].set(
+            v_cache = v_cache.at[a, :, blk, off].set(
                 k_pe[:, None].astype(v_cache.dtype)
             )
             o_lat = _mla.mla_decode_attention_xla(
-                q_eff, q_pe, k_cache[l], v_cache[l], block_tables,
+                q_eff, q_pe, k_cache[a], v_cache[a], block_tables,
                 seq_lens, scale,
             )
-            x = layer_tail(x, lp, _mla._o_proj(lp, cfg, o_lat).astype(x.dtype))
+            x = layer_tail(
+                x, lp, _mla.o_proj_gated(lp, cfg, o_lat, h).astype(x.dtype))
     elif merged:
         # MERGED one-write loop (TPU): attention handles the current token
         # out-of-cache (flash merge over the stats-emitting paged kernel),
@@ -1760,7 +2241,7 @@ def _decode_body(
 
         k_news, v_news = [], []
         for l, lp in layers():
-            if "conv_in" in lp:
+            if _is_state_layer(lp):
                 x = conv_layer(x, lp, l)
                 continue
             a = cfg.op_index(l)  # the layer's index in the cache
@@ -1819,7 +2300,7 @@ def _decode_body(
         # WRITE-THEN-ATTEND: the XLA path (CPU, kernels refused for the
         # shape, softcap models)
         for l, lp in layers():
-            if "conv_in" in lp:
+            if _is_state_layer(lp):
                 x = conv_layer(x, lp, l)
                 continue
             a = cfg.op_index(l)  # the layer's index in the cache
@@ -1855,7 +2336,7 @@ def _decode_body(
                 cap=cfg.attn_softcap, k_scales=ks_l, v_scales=vs_l,
             )
             x = layer_tail(x, lp, o, lora_l)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = model_norm(x, params["final_norm"], cfg)
     logits = _logits(params, cfg, x)  # [B, V]
     if quantized:
         # scales only grow within a step, so plane entries above their
@@ -2028,7 +2509,7 @@ def decode_window(
     if quantized:
         carry = carry + (k_scales, v_scales, jnp.zeros((), jnp.int32))
     if moe_counters:
-        carry = carry + (jnp.zeros((3,), jnp.int32),)
+        carry = carry + (MoeTally.zeros(cfg),)
     if penalized:
         carry = carry + (counts,)
     fin, ys = lax.scan(body, carry, None, length=n_steps)
@@ -2119,9 +2600,9 @@ def _mixed_fused_forward(
     else:
         ids_all = None
     track = None
-    if cfg.conv_layers:
+    if cfg.state_layers:
         assert state is not None and k_scales is None and lora is None
-        track = ConvTrack(cfg, state, [
+        track = StateTrack(cfg, state, [
             Segments(None, (d_seq_lens > 0).astype(jnp.int32), d_positions,
                      d_tables, 1),
             Segments(p_slots, p_valids, p_hists, p_tables, T),
@@ -2138,7 +2619,7 @@ def _mixed_fused_forward(
     # UNROLLED layer loop (per-layer windows / local rope stay
     # trace-static; program count bounded by the prefill buckets)
     for l, lp in _layers(params, cfg, mesh):
-        if "conv_in" in lp:
+        if _is_state_layer(lp):
             x = _conv_layer(x, lp, cfg, l, track, mesh=mesh, use_pallas=True,
                             interpret=interpret, tally=moe_tally, live=live)
             continue
@@ -2215,7 +2696,7 @@ def _mixed_fused_forward(
             mesh=mesh, use_pallas=True, interpret=interpret,
             tally=moe_tally, live=live,
         )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = model_norm(x, params["final_norm"], cfg)
     logits_d = _logits(params, cfg, x[:B])  # [B, V] f32
     # each segment's last REAL row only (the unfused prefill computes
     # the same single row — full [T, V] head matmuls would be pure waste)
@@ -2289,6 +2770,9 @@ def mixed_step(
     # new state follows v_cache in the output
     state: Optional[dict] = None,
     p_slots: Optional[jnp.ndarray] = None,  # [MP] int32
+    # [MP] int32: the snapshot row that takes each segment's end state
+    # (``prefill``'s ``snap_row``; past the pool: none)
+    p_snaps: Optional[jnp.ndarray] = None,
 ):
     """ONE device dispatch fusing M prefill chunks into a decode step.
 
@@ -2352,7 +2836,7 @@ def mixed_step(
     if fused:
         p_live = jnp.arange(T)[None, :] < p_valids[:, None]
         live = jnp.concatenate([live, p_live.reshape(-1)])
-    tally = MoeTally() if moe_counters else None
+    tally = MoeTally(MoeTally.zeros(cfg)) if moe_counters else None
     if fused:
         if quantized:
             logits_d, p_logits, k_cache, v_cache, k_scales, v_scales = (
@@ -2389,6 +2873,7 @@ def mixed_step(
                 mesh=mesh, k_scales=k_scales, v_scales=v_scales,
                 lora=lora, adapter_id=aid, moe_counters=moe_counters,
                 state=state, slot=None if state is None else p_slots[m],
+                snap_row=None if p_snaps is None else p_snaps[m],
             )
             if moe_counters:
                 tally.sums = tally.sums + seg[-1]
@@ -2511,7 +2996,7 @@ def _verify_forward(
                 )
                 o = _mla._o_proj(lp, cfg, o).astype(x.dtype)
                 x = layer_tail(x, lp, o)
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = model_norm(x, params["final_norm"], cfg)
         logits = _logits(params, cfg, x.reshape(B * T, E)).reshape(B, T, -1)
         # the kernel serves the unsharded Pallas path; a mesh (replicated
         # latent cache) or a Pallas-off engine takes the XLA scatter
@@ -2560,7 +3045,7 @@ def _verify_forward(
                     cap=cfg.attn_softcap, interpret=interpret,
                 )
             x = layer_tail(x, lp, o)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = model_norm(x, params["final_norm"], cfg)
     logits = _logits(params, cfg, x.reshape(B * T, E)).reshape(B, T, -1)
 
     if use_pallas and mesh is not None:
@@ -2740,16 +3225,15 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
 
             H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
             if cfg.q_lora_rank:
-                q = _mm(rms_norm(_mm(h, lp["wq_a"]), lp["q_norm"],
-                                 cfg.rms_norm_eps), lp["wq_b"])
+                q = _mm(model_norm(_mm(h, lp["wq_a"]), lp["q_norm"], cfg),
+                        lp["wq_b"])
             else:
                 q = _mm(h, lp["wq"])
             q = q.reshape(T, H, dn + dr)
             q_nope, q_pe = q[..., :dn], q[..., dn:]
             q_pe = _mla.rope_rotate(q_pe, positions, inv_freq, msc)
             kv = _mm(h, lp["wkv_a"])
-            c_kv = rms_norm(kv[..., : cfg.kv_lora_rank], lp["kv_norm"],
-                            cfg.rms_norm_eps)
+            c_kv = model_norm(kv[..., : cfg.kv_lora_rank], lp["kv_norm"], cfg)
             k_pe = _mla.rope_rotate(
                 kv[..., cfg.kv_lora_rank:][:, None, :], positions,
                 inv_freq, msc,
@@ -2773,8 +3257,11 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
             causal = positions[:, None] >= positions[None, :]
             s = jnp.where(causal[None], s, -1e30)
             p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("hts,shd->thd", p, v)
-            o = o.reshape(T, -1).astype(x.dtype)
+            o = jnp.einsum("hts,shd->thd", p, v).reshape(T, -1)
+            if "attn_gate" in lp:
+                o = o * jax.nn.sigmoid(
+                    _mm(h, lp["attn_gate"]).astype(jnp.float32))
+            o = o.astype(x.dtype)
         else:
             q, k, v = _qkv(lp, cfg, h)
             fr = inv_freq if freqs is None else freqs
@@ -2789,7 +3276,7 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
     if cfg.layer_windows or cfg.layer_ops:
         # per-layer static windows, per-layer operators: unrolled
         for l, lp in _layers(params, cfg):
-            if "conv_in" in lp:
+            if _is_state_layer(lp):
                 x = _conv_layer(x, lp, cfg, l, None)
                 continue
             x, _ = body(
@@ -2799,5 +3286,5 @@ def dense_forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.nd
     else:
         for lps, _n, _off in layer_groups(params, cfg):
             x, _ = lax.scan(body, x, lps)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = model_norm(x, params["final_norm"], cfg)
     return _logits(params, cfg, x)

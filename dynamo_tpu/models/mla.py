@@ -116,13 +116,13 @@ def mla_q_and_latent(lp: dict, cfg: ModelConfig, x: jnp.ndarray,
     k_pe [..., R])
     with C = kv_lora_rank, R = qk_rope_head_dim. q_eff is the ABSORBED
     query (q_nope @ w_kc) scoring directly against cache latents."""
-    from .llama import _mm, rms_norm
+    from .llama import _mm, model_norm
 
     H = cfg.num_heads
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     if cfg.q_lora_rank:
-        q = _mm(rms_norm(_mm(x, lp["wq_a"]), lp["q_norm"],
-                         cfg.rms_norm_eps), lp["wq_b"])
+        q = _mm(model_norm(_mm(x, lp["wq_a"]), lp["q_norm"], cfg),
+                lp["wq_b"])
     else:
         q = _mm(x, lp["wq"])
     q = q.reshape(x.shape[:-1] + (H, dn + dr))
@@ -130,8 +130,7 @@ def mla_q_and_latent(lp: dict, cfg: ModelConfig, x: jnp.ndarray,
     q_pe = rope_rotate(q_pe, positions, inv_freq, mscale)
 
     kv = _mm(x, lp["wkv_a"])  # [T, C + R]
-    c_kv = rms_norm(kv[..., : cfg.kv_lora_rank], lp["kv_norm"],
-                    cfg.rms_norm_eps)
+    c_kv = model_norm(kv[..., : cfg.kv_lora_rank], lp["kv_norm"], cfg)
     k_pe = kv[..., cfg.kv_lora_rank:]
     k_pe = rope_rotate(k_pe[..., None, :], positions, inv_freq,
                        mscale)[..., 0, :]
@@ -151,6 +150,19 @@ def _o_proj(lp: dict, cfg: ModelConfig, out_lat: jnp.ndarray) -> jnp.ndarray:
     _, w_vc = _wkv_b_parts(lp, cfg)
     o = jnp.einsum("...hc,chd->...hd", out_lat, w_vc.astype(jnp.float32))
     return o.reshape(o.shape[:-2] + (-1,))
+
+
+def o_proj_gated(lp: dict, cfg: ModelConfig, out_lat: jnp.ndarray,
+                 h: jnp.ndarray) -> jnp.ndarray:
+    """``_o_proj``, then GigaChat 3.5's output gate where the layer has
+    one (``attn_gate``): the attention rows times sigmoid(h W_g), ``h``
+    the layer's normed input, before the output projection."""
+    from .llama import _mm
+
+    o = _o_proj(lp, cfg, out_lat)
+    if "attn_gate" in lp:
+        o = o * jax.nn.sigmoid(_mm(h, lp["attn_gate"]).astype(jnp.float32))
+    return o
 
 
 def mla_prefill_attention_xla(

@@ -127,17 +127,23 @@ def main() -> int:
 
     params = shaped(jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.key(0))))
-    pool = llama.kv_cache_shapes(cfg, n, bs)[0]
-    cache = chip(pool, jnp.bfloat16)
+    pool, pool_v = llama.kv_cache_shapes(cfg, n, bs)
+    # (a latent cache's second pool is narrower: the rotary keys)
+    cache, cache_v = chip(pool, jnp.bfloat16), chip(pool_v, jnp.bfloat16)
     slab_bytes = 2
     for d in pool[1:]:
         slab_bytes *= d
     kw = {}
     if cfg.is_moe:
         kw["moe_counters"] = True
-    state = None
-    if cfg.conv_layers:
-        state = shaped(jax.eval_shape(lambda: llama.init_state(cfg, b, n)))
+    state, snap, snaps = None, {}, {}
+    if cfg.state_layers:
+        rows = int(_flag(flags, "--state-snapshots", 0))
+        state = shaped(jax.eval_shape(
+            lambda: llama.init_state(cfg, b, n, rows)))
+        if cfg.linear_layers:  # snapshotted at a chunk's end, by row
+            snap = {"snap_row": chip((), jnp.int32)}
+            snaps = {"p_snaps": chip((1,), jnp.int32)}
     ints, floats = chip((b,), jnp.int32), chip((b,), jnp.float32)
     batch = (ints, ints, chip((b, m), jnp.int32), ints, ints, ints,
              floats, ints, floats)
@@ -146,16 +152,17 @@ def main() -> int:
     lowered = {
         "mixed": lambda: llama.mixed_step.lower(
             params, cfg, *batch, chip((1, t), jnp.int32),
-            chip((1, m), jnp.int32), one_i, one_i, cache, cache,
+            chip((1, m), jnp.int32), one_i, one_i, cache, cache_v,
             use_pallas=True, **kw,
-            **({"state": state, "p_slots": one_i} if state else {})),
+            **({"state": state, "p_slots": one_i, **snaps}
+               if state else {})),
         "decode": lambda: llama.decode_window.lower(
-            params, cfg, *batch, cache, cache, n_steps=args.window,
+            params, cfg, *batch, cache, cache_v, n_steps=args.window,
             use_pallas=True, **kw, **({"state": state} if state else {})),
         "prefill": lambda: llama.prefill.lower(
             params, cfg, chip((t,), jnp.int32), chip((m,), jnp.int32),
-            scalar, scalar, cache, cache, use_pallas=True, **kw,
-            **({"state": state, "slot": scalar} if state else {})),
+            scalar, scalar, cache, cache_v, use_pallas=True, **kw,
+            **({"state": state, "slot": scalar, **snap} if state else {})),
     }
     print(f"{args.config}: pool {list(pool)} bf16 = "
           f"{slab_bytes * pool[0] / GIB:.3f} GiB a cache, one K slab "

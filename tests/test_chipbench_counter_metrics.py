@@ -85,15 +85,24 @@ def test_their_series_are_what_the_engine_exports(name):
         assert f"'{base}{{{{kind=" in engine, series
 
 
-def test_benchmark_json_lists_the_five_for_all_three_cells():
+#: the per-layer metrics PR 43 added for ``gigachat35.reason``: what a
+#: server before it lacks (series or ops), so each answers None there
+NEW_IN_43 = ("linear_attn_op_share.serve", "latent_attn_kernel_share.serve",
+             "moe_held_assignment_share", "linear_state_mib_per_step",
+             "prefix_unsnapshotted_share")
+
+
+def test_benchmark_json_lists_the_five_for_every_cell():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
-    last = bench["per_layer"][-len(EXPECTED):]
-    assert [m["name"] for m in last] == [
-        "decode_step_ms", "mixed_step_ms", "stall_step_time_share",
-        "decode_host_gap_ms", "device_exposed_share"]
-    for m in last:
+    five = ["decode_step_ms", "mixed_step_ms", "stall_step_time_share",
+            "decode_host_gap_ms", "device_exposed_share"]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    names = [m["name"] for m in bench["per_layer"]]
+    # entries are only ever appended: the five stand where PR 40 put them
+    assert names[names.index(five[0]):][:5] == five
+    for m in (by_name[n] for n in five):
         s = spec(m["name"])
         assert m["source"] == "program_counter" and m["workloads"] == cells
         assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
@@ -115,8 +124,55 @@ def test_every_metric_file_passes_a_server_that_lacks_its_series(name, model):
            "peaks": {}}
     got = reducer.reduce(ctx, s.get("selector", {}))
     assert got is None or isinstance(got, float)
-    if name in EXPECTED:
+    if name in EXPECTED or name in NEW_IN_43:
         assert got is None
     elif s["reducer"] == "counter_ratio" and not name.startswith(
             ("moe_", "state_")):
         assert got is not None
+
+
+def test_benchmark_json_lists_pr43s_metrics_for_its_cell_only():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert [m["name"] for m in bench["per_layer"][-len(NEW_IN_43):]] == list(
+        NEW_IN_43)
+    for m in bench["per_layer"][-len(NEW_IN_43):]:
+        s = spec(m["name"])
+        assert m["workloads"] == ["gigachat35.reason"]
+        assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
+            s["unit"], s["better"], s["layer"], s["moves"])
+        assert m["source"] == {"metrics_delta": "program_counter",
+                               "device_trace": "device_trace"}[s["source"]]
+    # the cell joins every metric that was there, and nothing else moved
+    for m in bench["per_layer"][: -len(NEW_IN_43)]:
+        assert m["workloads"][-1] == "gigachat35.reason", m["name"]
+
+
+@pytest.mark.parametrize("name", NEW_IN_43)
+def test_pr43s_metrics_read_a_server_that_has_their_series(name):
+    """A window of the new cell, made up: 1,000 decode steps of 32 slots
+    (a layer's matrices 4 MiB a slot, 4 layers, read and written), 64 of
+    1,024 assignments held, 64 of 640 matched tokens without a snapshot;
+    a trace in which the two kernels took 3 and 1 of 10 busy seconds."""
+    delta = {
+        "engine_decode_steps_total": 1000.0,
+        "engine_linear_state_bytes_total": 1000.0 * 32 * 2 * (16 << 20),
+        "engine_moe_held_assignments_total": 64.0,
+        "engine_moe_assignments_total": 1024.0,
+        "engine_prefix_unsnapshotted_tokens_total": 64.0,
+        "engine_prefix_matched_tokens_total": 640.0,
+    }
+    device = {"busy_s": 10.0, "op_seconds": [
+        ["linear_attn_recurrent_step", 3.0], ["fusion", 5.0],
+        ["mla_paged_decode_attention", 0.75],
+        ["mla_paged_prefill_attention", 0.25], ["ragged-dot", 1.0]]}
+    s = spec(name)
+    reducer = importlib.import_module(f"chipbench.reducers.{s['reducer']}")
+    got = reducer.reduce({"delta": delta, "device": device}, s["selector"])
+    assert got == pytest.approx({
+        "linear_attn_op_share.serve": 30.0,
+        "latent_attn_kernel_share.serve": 10.0,
+        "moe_held_assignment_share": 6.25,
+        "linear_state_mib_per_step": 1024.0,
+        "prefix_unsnapshotted_share": 10.0,
+    }[name])

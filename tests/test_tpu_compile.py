@@ -481,3 +481,33 @@ def test_moe_ffn_bf16_olmoe_widths(chip, rows):
         return llama.moe_ffn(lp, cfg, h, tally=tally, live=live), tally.sums
 
     _compile(layer, lp, chip((rows, e), jnp.bfloat16), chip((rows,), jnp.bool_))
+
+
+# ---------------- the gated delta rule's decode step ----------------
+
+
+def test_linear_attn_recurrent_step_at_gigachat35_widths(chip):
+    """One token of the delta rule for 32 decode slots x 64 value heads
+    of [128, 128] float32, in place in layer 2 of a 4-layer state of 512
+    MiB: the whole state is aliased to the output, nothing of it is a
+    temporary (a ``rec[li]`` operand would be a copy of a layer's 128
+    MiB)."""
+    from dynamo_tpu.ops.gated_delta_pallas import (
+        kernel_serves, linear_attn_recurrent_step,
+    )
+
+    b, hv, dk, dv, ll = 32, 64, 128, 128, 4
+    assert kernel_serves(hv, dk, dv) and not kernel_serves(4, 16, 16)
+    f = jnp.float32
+
+    def step(q, k, v, g, beta, rec):
+        return linear_attn_recurrent_step(q, k, v, g, beta, rec, jnp.int32(2))
+
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        chip((b, hv, dk), f), chip((b, hv, dk), f), chip((b, hv, dv), f),
+        chip((b, hv), f), chip((b, hv), f), chip((ll, b, hv, dk, dv), f),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == ll * b * hv * dk * dv * 4
+    assert mem.temp_size_in_bytes < 16 << 20
