@@ -37,7 +37,7 @@ import numpy as np
 from ..models import llama
 from ..models.config import ModelConfig
 from ..ops.sampling import make_keys, sample_first_token, sample_tokens
-from ..parallel.mesh import LogicalLayout, MeshConfig, make_mesh
+from ..parallel.mesh import LogicalLayout, MeshConfig, make_mesh, replicated
 from ..protocols.common import (
     FinishReason,
     LLMEngineOutput,
@@ -61,6 +61,7 @@ from .allocator import (
     sequence_block_hashes,
 )
 from .offload import OffloadManager
+from .step_state import StepState
 
 logger = logging.getLogger(__name__)
 
@@ -872,32 +873,20 @@ class JaxEngine(AsyncEngine):
         self._reshard_busy = False
         self._resharding = False
         self.morpher = None
-        # host mirrors of device-side batch state
-        M = cfg.max_blocks_per_seq
-        self._block_tables = np.zeros((cfg.max_batch_size, M), np.int32)
-        # (the decode rows' tables in the window pool, where there is one)
-        self._wtables = np.zeros_like(self._block_tables)
-        self._seq_lens = np.zeros(cfg.max_batch_size, np.int32)
-        self._last_tokens = np.zeros(cfg.max_batch_size, np.int32)
-        self._seeds = np.zeros(cfg.max_batch_size, np.int32)
-        self._temps = np.zeros(cfg.max_batch_size, np.float32)
-        self._top_ks = np.zeros(cfg.max_batch_size, np.int32)
-        self._top_ps = np.ones(cfg.max_batch_size, np.float32)
+        # the decode batch's per-slot step state: the host mirror, the
+        # device's resident copy and what differs (engine/step_state.py)
+        self._rows = StepState(
+            cfg.max_batch_size, cfg.max_blocks_per_seq,
+            window=self.wpool is not None, sharding=self._rows_sharding())
         # sampling penalties (vLLM semantics — see ops/sampling):
         # device [B, V] output-token counts + prompt-membership mask,
         # allocated lazily on the first request that asks for a penalty
-        self._freq_pens = np.zeros(cfg.max_batch_size, np.float32)
-        self._pres_pens = np.zeros(cfg.max_batch_size, np.float32)
-        self._rep_pens = np.ones(cfg.max_batch_size, np.float32)
         self._pen_counts = None
         self._pen_mask = None
         # requested top-logprob count per slot (-1 = logprobs off;
         # 0 = chosen-token logprob only, no alternates)
         self._logprob_ks = np.full(cfg.max_batch_size, -1, np.int32)
         self._window_logprobs = None
-        # per-slot adapter id (-1 = base); mirrors the device dispatch's
-        # adapter_ids operand exactly like _seeds/_temps mirror theirs
-        self._adapter_ids = np.full(cfg.max_batch_size, -1, np.int32)
         # live-request refcount per adapter NAME: an adapter a running
         # sequence depends on must never be LRU-evicted mid-stream
         self._adapter_refs: dict[str, int] = {}
@@ -976,6 +965,13 @@ class JaxEngine(AsyncEngine):
             # block-table pages the attention kernels were asked to
             # walk against the pages that hold live tokens
             **dict.fromkeys(WORK_COUNTERS, 0),
+            # the dispatch thunks' hand-overs: host-to-device transfers
+            # by the step's kind, and the decode-row dispatches that
+            # sent the whole step state against those that sent a few
+            # cells or nothing (engine/step_state.py)
+            **{"step_handovers_" + k: 0 for k in KINDS.values()},
+            "step_state_resyncs": 0,
+            "step_state_resident": 0,
         }
         # expert layers whose routing the step programs count (models/
         # llama.MoeTally; the multi-host mirror runs programs of its own
@@ -1243,6 +1239,10 @@ class JaxEngine(AsyncEngine):
                 f"device_steps_{kind}"]
             out[f'engine_step_exposed_seconds_total{{kind="{kind}"}}'] = round(
                 self.stats[f"step_exposed_seconds_{kind}"], 6)
+            out[f'engine_step_handovers_total{{kind="{kind}"}}'] = self.stats[
+                f"step_handovers_{kind}"]
+        for name in ("step_state_resyncs", "step_state_resident"):
+            out[f"engine_{name}_total"] = self.stats[name]
         for name in WORK_COUNTERS + (MOE_COUNTERS if self._moe_layers else ()):
             out[f"engine_{name}_total"] = self.stats[name]
         if self.wpool is not None:
@@ -2081,6 +2081,7 @@ class JaxEngine(AsyncEngine):
         self.cfg.mesh = new_mesh_cfg
         self.use_pallas = new_use_pallas
         # dynflow: end-commit-block
+        self._rows.move(self._rows_sharding())
         moved = self.allocator.resident_count
         self.stats["resharded_total"] += 1
         self.stats["reshard_kv_moved_blocks"] += moved
@@ -2972,10 +2973,8 @@ class JaxEngine(AsyncEngine):
     def _lora_decode_kw(self) -> dict:
         if self.adapters is None:
             return {}
-        return {
-            "lora": self.adapters.device_stack(),
-            "adapter_ids": jnp.asarray(self._adapter_ids),
-        }
+        # (the rows' adapter ids ride the resident step state)
+        return {"lora": self.adapters.device_stack()}
 
     def _lora_key(self) -> tuple:
         """Compile-key suffix: the registry's static bucket pair (or
@@ -2998,6 +2997,8 @@ class JaxEngine(AsyncEngine):
         toks = np.zeros(T, np.int32)
         toks[: len(chunk)] = chunk
         self._note_prefill_work(T, len(chunk))
+        # tokens, table(s), history, valid: each its own transfer
+        self._handed("prefill", 4 + (self.wpool is not None))
         if self.mirror is not None:
             logits, self.k_cache, self.v_cache = self._timed_dispatch(
                 lambda: self.mirror.lead_prefill(
@@ -3123,9 +3124,30 @@ class JaxEngine(AsyncEngine):
 
     def _set_tables(self, seq: _Sequence) -> None:
         """``seq``'s decode slot's row of the block tables, anew."""
-        self._block_tables[seq.slot] = self._table_for(seq)
-        if self.wpool is not None:
-            self._wtables[seq.slot] = self._wtable_for(seq)
+        self._rows.set_tables(
+            seq.slot, self._table_for(seq),
+            None if self.wpool is None else self._wtable_for(seq))
+
+    def _decode_rows(self, kind: str, pending: int = 0) -> dict:
+        """A step program's decode rows, for all three thunks: the
+        resident step state and what this dispatch must add to it
+        (``StepState.hand_over``: the mirror whole, a few cells, or
+        nothing), as the program's keywords."""
+        rows, delta, sent = self._rows.hand_over(pending)
+        self._handed(kind, 1 if sent else 0)
+        self.stats["step_state_resyncs" if sent == "mirror"
+                   else "step_state_resident"] += 1
+        return {"rows": rows, "rows_delta": delta}
+
+    def _handed(self, kind: str, n: int) -> None:
+        """``n`` host-to-device transfers of a dispatch thunk of
+        ``kind`` (a program key's first element)."""
+        self.stats["step_handovers_" + KINDS[kind]] += n
+
+    def _rows_sharding(self):
+        """Where the resident step state lives: replicated over the
+        mesh, or the default device's own placement."""
+        return None if self.mesh is None else replicated(self.mesh)
 
     def _free_blocks(self, seq: _Sequence) -> None:
         """Everything ``seq`` holds in the pool(s)."""
@@ -3275,28 +3297,31 @@ class JaxEngine(AsyncEngine):
         self._n_active += 1
         so = seq.request.sampling_options
         self._set_tables(seq)
-        self._seq_lens[slot] = seq.seq_len
-        self._last_tokens[slot] = seq.tokens[-1]
-        # mask into int32 range: PRNG seeds only need entropy, not magnitude
-        self._seeds[slot] = (so.seed or 0) & 0x7FFFFFFF
-        self._temps[slot] = so.temperature if so.temperature is not None else 1.0
-        self._top_ks[slot] = so.top_k or 0
-        self._top_ps[slot] = so.top_p if so.top_p is not None else 1.0
-        self._freq_pens[slot] = so.frequency_penalty or 0.0
-        self._pres_pens[slot] = so.presence_penalty or 0.0
-        self._rep_pens[slot] = so.repetition_penalty or 1.0
+        self._rows.place(
+            slot, seq_len=seq.seq_len, token=seq.tokens[-1],
+            steps=seq.generated,
+            # mask into int32 range: PRNG seeds only need entropy, not magnitude
+            seed=(so.seed or 0) & 0x7FFFFFFF,
+            temperature=so.temperature if so.temperature is not None else 1.0,
+            top_k=so.top_k or 0,
+            top_p=so.top_p if so.top_p is not None else 1.0,
+            freq_pen=so.frequency_penalty or 0.0,
+            pres_pen=so.presence_penalty or 0.0,
+            rep_pen=so.repetition_penalty or 1.0,
+            adapter_id=seq.adapter_id,
+        )
         self._logprob_ks[slot] = (
             min(so.logprobs, 20) if so.logprobs is not None else -1
         )
-        self._adapter_ids[slot] = seq.adapter_id
         if self._slot_has_penalty(slot):
             self._reset_penalty_slot(slot, seq)
 
     def _slot_has_penalty(self, i: int) -> bool:
+        r = self._rows
         return (
-            self._freq_pens[i] != 0.0
-            or self._pres_pens[i] != 0.0
-            or self._rep_pens[i] != 1.0
+            r.freq_pens[i] != 0.0
+            or r.pres_pens[i] != 0.0
+            or r.rep_pens[i] != 1.0
         )
 
     def _penalties_active(self) -> bool:
@@ -3359,7 +3384,7 @@ class JaxEngine(AsyncEngine):
         overwritten KV."""
         if self.offload is None or not self.offload.has_pending():
             return
-        must = set(np.unique(self._block_tables).tolist())
+        must = set(np.unique(self._rows.tables).tolist())
         must.discard(0)
         self.offload.flush_evictions_async(
             self.k_cache, self.v_cache,
@@ -3882,25 +3907,15 @@ class JaxEngine(AsyncEngine):
             # never grow past what was provisioned
             n = min(n, self._pick_window())
         prev = self._inflight
-        # chain token inputs on device when a window is in flight;
-        # otherwise feed the host-mirrored last tokens. Under the mirror
-        # the previous output is a multi-process array — eager indexing
-        # is illegal, so the whole [n, B] array is handed over and
-        # lead_decode slices on device (followers slice their own copy).
-        if prev is None:
-            tokens_in = None
-        elif self.mirror is not None:
-            tokens_in = prev["toks"]
-        else:
-            tokens_in = prev["toks"][-1]
-        # dynlint: disable=async-blocking-call -- [B]-sized host int list, no device copy
-        steps = np.asarray(
-            [(self._active[i].generated if self._active[i] else 0) + pending
-             for i in range(cfg.max_batch_size)],
-            np.int32,
-        )
+        # a window in flight chains its tokens on the device: in the
+        # resident step state, or under the mirror, whose previous output
+        # is a multi-process array (eager indexing is illegal), as the
+        # whole [n, B] array that lead_decode slices on device (followers
+        # slice their own copy).
+        tokens_in = (prev["toks"] if prev is not None
+                     and self.mirror is not None else None)
         toks = await self._on_device(
-            self._dispatch_window, steps, n, pending, tokens_in
+            self._dispatch_window, n, pending, tokens_in
         )
         self._inflight = {
             "toks": toks, "n": n,
@@ -3973,22 +3988,10 @@ class JaxEngine(AsyncEngine):
                 if extra is None:
                     return False
                 seq.blocks.extend(extra)
-                self._block_tables[seq.slot] = self._table_for(seq)
+                self._set_tables(seq)
 
-        # window tokens: last accepted token + proposals (-1 -> 0 for a
-        # safe embed; acceptance on device uses the ORIGINAL -1s, which
-        # never accept)
-        window = np.zeros((cfg.max_batch_size, T), np.int32)
-        window[:, 0] = self._last_tokens
-        window[:, 1:] = np.maximum(proposals, 0)
-        # dynlint: disable=async-blocking-call -- [B]-sized host int list, no device copy
-        steps = np.asarray(
-            [self._active[i].generated if self._active[i] else 0
-             for i in range(cfg.max_batch_size)],
-            np.int32,
-        )
         out_toks, n_accs, lps = await self._on_device(
-            self._dispatch_verify, window, proposals.astype(np.int32), steps,
+            self._dispatch_verify, proposals.astype(np.int32),
         )
         self._clock.mark("emit")
         self.stats["decode_steps"] += 1
@@ -4015,8 +4018,7 @@ class JaxEngine(AsyncEngine):
                 self._emit_token(seq, int(out_toks[i, t]), entry)
             if seq.finished or self._active[i] is not seq:
                 continue
-            self._seq_lens[i] = seq.seq_len
-            self._last_tokens[i] = seq.tokens[-1]
+            self._rows.advance(i, seq.seq_len, seq.tokens[-1], seq.generated)
             self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
         self._step_done()
         return True
@@ -4073,15 +4075,9 @@ class JaxEngine(AsyncEngine):
                 packed.remove((st, take))
         if not packed:
             return
-        # dynlint: disable=async-blocking-call -- [B]-sized host int list, no device copy
-        steps = np.asarray(
-            [self._active[i].generated if self._active[i] else 0
-             for i in range(cfg.max_batch_size)],
-            np.int32,
-        )
         try:
             toks, lps, completed = await self._on_device(
-                self._dispatch_mixed, packed, steps
+                self._dispatch_mixed, packed
             )
         except Exception:  # noqa: BLE001
             # a fused-dispatch failure (lowering/compile) is not
@@ -4119,8 +4115,8 @@ class JaxEngine(AsyncEngine):
                 self._emit_token(seq, int(toks[i]), entry)
                 if seq.finished or self._active[i] is not seq:
                     continue
-                self._seq_lens[i] = seq.seq_len
-                self._last_tokens[i] = seq.tokens[-1]
+                self._rows.advance(
+                    i, seq.seq_len, seq.tokens[-1], seq.generated)
                 self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
         # prompts whose FINAL chunk just ran: first token sampled on
         # device in _dispatch_mixed — emit + join the batch, in
@@ -4180,9 +4176,7 @@ class JaxEngine(AsyncEngine):
                      for st, t in zip(sts, takes)]
         return list(zip(sts, takes))
 
-    def _dispatch_mixed(
-        self, packed: list[tuple["_PrefillState", int]], steps: np.ndarray
-    ):
+    def _dispatch_mixed(self, packed: list[tuple["_PrefillState", int]]):
         """Executor thread: the fused mixed dispatch over M prefill
         segments + the decode batch. Returns (decode_tokens [B] np,
         logprob arrays or None, completed: [(state, (first_token,
@@ -4234,15 +4228,16 @@ class JaxEngine(AsyncEngine):
                     wtables_p[i] = self._wtable_for(st.seq)
                 hists_p[i] = st.pos
                 valids_p[i] = len(chunk)
-            positions = np.maximum(self._seq_lens - 1, 0).astype(np.int32)
             penalized = self._penalties_active()
             want_lp = self._logprobs_active()
-            kwargs = {}
+            kwargs = self._decode_rows("mixed")
+            # what the segments need, in ONE transfer
+            segs = {"p_tokens": toks_p, "p_hists": hists_p,
+                    "p_valids": valids_p, "p_tables": (
+                        tables_p if wtables_p is None
+                        else (tables_p, wtables_p))}
             if penalized:
                 kwargs.update(
-                    freq_pens=jnp.asarray(self._freq_pens),
-                    pres_pens=jnp.asarray(self._pres_pens),
-                    rep_pens=jnp.asarray(self._rep_pens),
                     counts=self._pen_counts,
                     prompt_mask=self._pen_mask,
                 )
@@ -4259,52 +4254,41 @@ class JaxEngine(AsyncEngine):
                 p_ids = np.full(MP, -1, np.int32)
                 for i, (st, _take) in enumerate(packed):
                     p_ids[i] = st.seq.adapter_id
-                kwargs.update(
-                    lora=self.adapters.device_stack(),
-                    d_adapter_ids=jnp.asarray(self._adapter_ids),
-                    p_adapter_ids=jnp.asarray(p_ids),
-                )
+                kwargs.update(lora=self.adapters.device_stack())
+                segs["p_adapter_ids"] = p_ids
             if self.state is not None:
                 # a dead segment names a row past the state: dropped
                 slots_p = np.full(MP, cfg.max_batch_size, np.int32)
                 for i, (st, _take) in enumerate(packed):
                     self._state_preamble(st)
                     slots_p[i] = st.seq.state_slot
-                kwargs.update(self._state_kw(), p_slots=jnp.asarray(slots_p))
+                kwargs.update(self._state_kw())
+                segs["p_slots"] = slots_p
                 if not self.snapshots.dense:
                     snaps_p = np.full(MP, self.snapshots.rows, np.int32)
                     for i, (st, take) in enumerate(packed):
                         snaps_p[i] = self._snap_row(st.seq, st.pos + take)
-                    kwargs.update(p_snaps=jnp.asarray(snaps_p))
+                    segs["p_snaps"] = snaps_p
                 self._state_rows += len(packed) + int(
-                    (self._seq_lens > 0).sum())
+                    (self._rows.seq_lens > 0).sum())
                 self._note_state(len(packed), 1)
+            kwargs.update(jax.device_put(segs, self._rows.sharding))
+            self._handed("mixed", 1)
             self._note_prefill_work(MP * T, int(valids_p.sum()))
-            self._note_decode_work(1, self._seq_lens, seg_pages=(
+            self._note_decode_work(1, self._rows.seq_lens, seg_pages=(
                 MP * cfg.max_blocks_per_seq,
                 int(((hists_p + valids_p + cfg.block_size - 1)
                      // cfg.block_size).sum()),
             ))
-            self._note_window_work(1, self._seq_lens, [
+            self._note_window_work(1, self._rows.seq_lens, [
                 (st.pos, take) for st, take in packed])
+            live_rows = int((self._rows.seq_lens > 0).sum())
             out = self._timed_dispatch(lambda: llama.mixed_step(
                 self.params,
                 cfg.model,
-                jnp.asarray(self._last_tokens),
-                jnp.asarray(positions),
-                self._tables(self._block_tables, self._wtables),
-                jnp.asarray(self._seq_lens),
-                jnp.asarray(self._seeds),
-                jnp.asarray(steps),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._top_ks),
-                jnp.asarray(self._top_ps),
-                jnp.asarray(toks_p),
-                self._tables(tables_p, wtables_p),
-                jnp.asarray(hists_p),
-                jnp.asarray(valids_p),
-                self.k_cache,
-                self.v_cache,
+                *llama.ROWS_RESIDENT,
+                k_cache=self.k_cache,
+                v_cache=self.v_cache,
                 use_pallas=self.use_pallas,
                 mesh=self.mesh,
                 with_logprobs=want_lp,
@@ -4314,6 +4298,7 @@ class JaxEngine(AsyncEngine):
                 + self._lora_key())
             toks, p_logits, self.k_cache, self.v_cache = out[:4]
             rest = list(out[4:])
+            self._rows.took(rest.pop(), 1)
             if self.state is not None:
                 self.state = rest.pop(0)
             if quantized:
@@ -4327,8 +4312,8 @@ class JaxEngine(AsyncEngine):
                 self._pen_counts = rest.pop(0)
             lps_dev = rest.pop(0) if want_lp else None
             if self._moe_layers:
-                self._note_moe(rest.pop(0), 1, int(
-                    (self._seq_lens > 0).sum() + valids_p.sum()))
+                self._note_moe(rest.pop(0), 1,
+                               live_rows + int(valids_p.sum()))
             completed = []
             for i, (st, take) in enumerate(packed):
                 st.pos += take
@@ -4459,8 +4444,9 @@ class JaxEngine(AsyncEngine):
         segments: ``seg_pages`` = (handed, live)) against the pages
         that hold live tokens."""
         st, bs = self.stats, self.cfg.block_size
-        rows, width = self._block_tables.shape
-        live = seq_lens[self._seq_lens > 0]
+        r = self._rows
+        rows, width = r.tables.shape
+        live = seq_lens[r.seq_lens > 0]
         st["rows_dispatched"] += rows * n
         st["rows_live"] += len(live) * n
         st["attn_table_pages"] += rows * width * n + seg_pages[0]
@@ -4468,8 +4454,8 @@ class JaxEngine(AsyncEngine):
             + seg_pages[1]
         # steps whose sampler searches for a cut: a live row samples
         # under a top-k or a nucleus (ops/sampling._apply_topk_topp)
-        if ((self._seq_lens > 0) & (self._temps > 0)
-                & ((self._top_ks > 0) | (self._top_ps < 1.0))).any():
+        if ((r.seq_lens > 0) & (r.temps > 0)
+                & ((r.top_ks > 0) | (r.top_ps < 1.0))).any():
             st["sampler_filter_steps"] += n
             self._filter_steps += n
 
@@ -4487,7 +4473,7 @@ class JaxEngine(AsyncEngine):
             return
         st, bs, wp = self.stats, self.cfg.block_size, self.wpool
         for i, seq in enumerate(self._active):
-            if seq is None or self._seq_lens[i] <= 0:
+            if seq is None or self._rows.seq_lens[i] <= 0:
                 continue
             ctx = int(seq_lens[i])
             pages = -(-ctx // bs)
@@ -4507,25 +4493,29 @@ class JaxEngine(AsyncEngine):
         self.stats["prefill_tokens_dispatched"] += dispatched
         self.stats["prefill_tokens_padding"] += dispatched - real
 
-    def _dispatch_verify(
-        self, window: np.ndarray, proposals: np.ndarray, steps: np.ndarray
-    ):
+    def _dispatch_verify(self, proposals: np.ndarray):
         """Executor thread: fused verify forward + on-device acceptance.
         Returns (out_tokens [B, T], n_acc [B], lp arrays or None)."""
         cfg = self.cfg
+        r = self._rows
         self._flush_evictions_budgeted()
-        positions = np.maximum(self._seq_lens - 1, 0).astype(np.int32)
         penalized = self._penalties_active()
         want_lp = self._logprobs_active()
-        self._note_decode_work(1, self._seq_lens)
+        self._note_decode_work(1, r.seq_lens)
         if self.mirror is not None:
+            # window tokens: last accepted token + proposals (-1 -> 0 for
+            # a safe embed; acceptance on device uses the ORIGINAL -1s,
+            # which never accept)
+            window = np.concatenate(
+                [r.tokens[:, None], np.maximum(proposals, 0)], axis=1)
             out = self._timed_dispatch(lambda: self.mirror.lead_verify(
-                self.params, window, proposals, positions,
-                self._block_tables, self._seq_lens, self._seeds, steps,
-                self._temps, self._top_ks, self._top_ps,
+                self.params, window, proposals,
+                np.maximum(r.seq_lens - 1, 0).astype(np.int32),
+                r.tables, r.seq_lens, r.seeds, r.steps,
+                r.temps, r.top_ks, r.top_ps,
                 self.k_cache, self.v_cache,
                 n_spec=cfg.spec_gamma, use_pallas=self.use_pallas,
-                penalties=(self._freq_pens, self._pres_pens, self._rep_pens)
+                penalties=(r.freq_pens, r.pres_pens, r.rep_pens)
                 if penalized else None,
                 pen_state=(self._pen_counts, self._pen_mask)
                 if penalized else None,
@@ -4538,30 +4528,24 @@ class JaxEngine(AsyncEngine):
             lps = rest.pop(0) if want_lp else None
             self._clock.landed()
             return toks, n_acc, lps
-        kwargs = {}
+        # (the rows it is handed are not what it leaves: the accepted
+        # counts are the host's to apply, and the next dispatch sends
+        # the mirror whole)
+        kwargs = self._decode_rows("verify")
         if penalized:
             kwargs.update(
-                freq_pens=jnp.asarray(self._freq_pens),
-                pres_pens=jnp.asarray(self._pres_pens),
-                rep_pens=jnp.asarray(self._rep_pens),
                 counts=self._pen_counts,
                 prompt_mask=self._pen_mask,
             )
+        self._handed("verify", 1)
         out = self._timed_dispatch(lambda: llama.verify_window(
             self.params,
             cfg.model,
-            jnp.asarray(window),
-            jnp.asarray(proposals),
-            jnp.asarray(positions),
-            jnp.asarray(self._block_tables),
-            jnp.asarray(self._seq_lens),
-            jnp.asarray(self._seeds),
-            jnp.asarray(steps),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._top_ks),
-            jnp.asarray(self._top_ps),
-            self.k_cache,
-            self.v_cache,
+            None,
+            jax.device_put(proposals, r.sharding),
+            *llama.ROWS_RESIDENT[1:],
+            k_cache=self.k_cache,
+            v_cache=self.v_cache,
             n_spec=cfg.spec_gamma,
             use_pallas=self.use_pallas,
             mesh=self.mesh,
@@ -4643,26 +4627,24 @@ class JaxEngine(AsyncEngine):
         for i, seq in live:
             if seq.finished:
                 continue
-            self._seq_lens[i] = seq.seq_len
-            self._last_tokens[i] = seq.tokens[-1]
+            self._rows.advance(i, seq.seq_len, seq.tokens[-1], seq.generated)
             self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
         self._clock.mark(phase)
 
-    def _dispatch_window(
-        self, steps: np.ndarray, n: int, pending: int, tokens_in=None
-    ):
+    def _dispatch_window(self, n: int, pending: int, tokens_in=None):
         """Runs in an executor thread: dispatch one fused n-step
         decode+sample window WITHOUT syncing its result. Returns the
         sampled-token device array [n, B] (host np array on the mirror
         path, which syncs internally).
 
         ``pending`` > 0 means an undrained window is in flight: this
-        window's token inputs are that window's last sampled tokens
-        (``tokens_in``, a device array — the chain stays on device) and
-        the host-mirrored positions/lengths advance by ``pending``
-        steps."""
+        window's token inputs are that window's last sampled tokens and
+        its lengths and steps are ``pending`` ahead of the host's
+        mirror. The resident step state already holds all three (the
+        chain stays on device); on the mirror path ``tokens_in`` is the
+        in-flight window's token array and the host arrays advance."""
         cfg = self.cfg
-        if pending and tokens_in is None:
+        if pending and tokens_in is None and self.mirror is not None:
             raise RuntimeError(
                 "pending window without a chained token source"
             )
@@ -4681,22 +4663,23 @@ class JaxEngine(AsyncEngine):
                     f"(seq_len={seq.seq_len}, blocks={len(seq.blocks)})"
                 )
         self._flush_evictions_budgeted()
-        positions = (
-            np.maximum(self._seq_lens - 1, 0) + pending
-        ).astype(np.int32)
-        seq_lens = (self._seq_lens + pending).astype(np.int32)
+        r = self._rows
+        seq_lens = (r.seq_lens + pending).astype(np.int32)
         self._note_decode_work(n, seq_lens)
         self._note_window_work(n, seq_lens)
         if self.mirror is not None:
             penalized = self._penalties_active()
             want_lp = self._logprobs_active()
+            positions = (
+                np.maximum(r.seq_lens - 1, 0) + pending
+            ).astype(np.int32)
             out = self._timed_dispatch(lambda: self.mirror.lead_decode(
-                self.params, self._last_tokens, positions,
-                self._block_tables, seq_lens, self._seeds, steps,
-                self._temps, self._top_ks, self._top_ps,
+                self.params, r.tokens, positions,
+                r.tables, seq_lens, r.seeds, r.steps + pending,
+                r.temps, r.top_ks, r.top_ps,
                 self.k_cache, self.v_cache,
                 n_steps=n, use_pallas=self.use_pallas,
-                penalties=(self._freq_pens, self._pres_pens, self._rep_pens)
+                penalties=(r.freq_pens, r.pres_pens, r.rep_pens)
                 if penalized else None,
                 pen_state=(self._pen_counts, self._pen_mask)
                 if penalized else None,
@@ -4712,25 +4695,14 @@ class JaxEngine(AsyncEngine):
             # device handles; materialized at emission
             self._window_logprobs = rest.pop(0) if want_lp else None
             return toks
-        if tokens_in is None:
-            tokens_in = jnp.asarray(self._last_tokens)
-        args = (
-            self.params,
-            cfg.model,
-            tokens_in,
-            jnp.asarray(positions),
-            self._tables(self._block_tables, self._wtables),
-            jnp.asarray(seq_lens),
-            jnp.asarray(self._seeds),
-            jnp.asarray(steps),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._top_ks),
-            jnp.asarray(self._top_ps),
-            self.k_cache,
-            self.v_cache,
-        )
+        # (a window in flight has left the resident rows ``pending``
+        # steps ahead of the mirror, its last sampled tokens in them:
+        # the chain stays on the device)
         want_lp = self._logprobs_active()
         kw = dict(
+            self._decode_rows("decode", pending),
+            k_cache=self.k_cache,
+            v_cache=self.v_cache,
             n_steps=n,
             use_pallas=self.use_pallas,
             mesh=self.mesh,
@@ -4743,27 +4715,21 @@ class JaxEngine(AsyncEngine):
         if quantized:
             self._flush_scale_resets()
             kw.update(k_scales=self.k_scales, v_scales=self.v_scales)
-        if self._penalties_active():
-            out = self._timed_dispatch(lambda: llama.decode_window(
-                *args, **kw,
-                freq_pens=jnp.asarray(self._freq_pens),
-                pres_pens=jnp.asarray(self._pres_pens),
-                rep_pens=jnp.asarray(self._rep_pens),
-                counts=self._pen_counts,
-                prompt_mask=self._pen_mask,
-            ), key=("decode", n, True, want_lp) + self._lora_key(), n=n)
-            penalized = True
-        else:
-            out = self._timed_dispatch(
-                lambda: llama.decode_window(*args, **kw),
-                key=("decode", n, False, want_lp) + self._lora_key(), n=n,
-            )
-            penalized = False
+        penalized = self._penalties_active()
+        if penalized:
+            kw.update(counts=self._pen_counts, prompt_mask=self._pen_mask)
+        out = self._timed_dispatch(
+            lambda: llama.decode_window(
+                self.params, cfg.model, *llama.ROWS_RESIDENT, **kw),
+            key=("decode", n, penalized, want_lp) + self._lora_key(), n=n,
+        )
         toks, self.k_cache, self.v_cache = out[:3]
         rest = list(out[3:])
+        r.took(rest.pop(), n)
+        live_rows = int((r.seq_lens > 0).sum())
         if self.state is not None:
             self.state = rest.pop(0)
-            self._state_rows += int((self._seq_lens > 0).sum())
+            self._state_rows += live_rows
             self._note_state(0, n)
         if quantized:
             self.k_scales = rest.pop(0)
@@ -4776,8 +4742,7 @@ class JaxEngine(AsyncEngine):
             self._pen_counts = rest.pop(0)
         lps = rest.pop(0) if want_lp else None
         if self._moe_layers:
-            self._note_moe(rest.pop(0), n,
-                           int((self._seq_lens > 0).sum()) * n)
+            self._note_moe(rest.pop(0), n, live_rows * n)
         # device handles; materialized at emission (fetching here would
         # block the pipelined dispatch on the window's full execution)
         self._window_logprobs = lps
@@ -4858,10 +4823,7 @@ class JaxEngine(AsyncEngine):
         and preemption so the teardown can't drift between them)."""
         if seq.slot >= 0:
             self._active[seq.slot] = None
-            self._seq_lens[seq.slot] = 0
-            self._block_tables[seq.slot] = 0
-            self._wtables[seq.slot] = 0
-            self._adapter_ids[seq.slot] = -1
+            self._rows.release(seq.slot)
             self._n_active -= 1
             seq.slot = seq.state_slot = -1
 

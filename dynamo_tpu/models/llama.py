@@ -551,6 +551,70 @@ def _table_rows(tables, m):
     return jax.tree.map(lambda t: t[m], tables)
 
 
+# ---------------- the decode batch's resident rows ----------------
+# Everything a step program is told of its decode slots, in ONE int32
+# matrix ``[B, W]`` that lives on the device from dispatch to dispatch
+# (``engine/step_state.StepState`` keeps its host mirror): a column a
+# field (a float field as its bits), then a slot's block-table row (two
+# for a model with a window pool, the full pool's first). A program that
+# is given ``rows`` takes none of its per-slot arguments, applies
+# ``rows_delta`` ([K, 3] int32 cells of (slot, column, value); a slot
+# past the batch: dropped) before its first layer, and returns the
+# matrix as its last output with the tokens, lengths and steps its own
+# steps left, which is what the next dispatch of the same batch wants.
+
+ROW_FIELDS = ("tokens", "seq_lens", "steps", "seeds", "top_ks",
+              "adapter_ids", "temps", "top_ps", "freq_pens", "pres_pens",
+              "rep_pens")
+ROW_FLOATS = frozenset(ROW_FIELDS[6:])
+ROW_TABLES = len(ROW_FIELDS)  # the first table column
+#: what a caller that gives ``rows`` passes for the nine per-slot
+#: arguments of ``decode_window`` and ``mixed_step``
+ROWS_RESIDENT = (None,) * 9
+
+
+def rows_enter(rows: jnp.ndarray, delta: jnp.ndarray, n_tables: int):
+    """``rows`` with ``delta``'s cells written, and its fields by name
+    (``tables`` in a step program's form: one table or the pair)."""
+    rows = rows.at[delta[:, 0], delta[:, 1]].set(delta[:, 2], mode="drop")
+    f = {}
+    for i, name in enumerate(ROW_FIELDS):
+        col = rows[:, i]
+        f[name] = (lax.bitcast_convert_type(col, jnp.float32)
+                   if name in ROW_FLOATS else col)
+    M = (rows.shape[1] - ROW_TABLES) // n_tables
+    tabs = tuple(rows[:, ROW_TABLES + t * M: ROW_TABLES + (t + 1) * M]
+                 for t in range(n_tables))
+    f["tables"] = tabs[0] if n_tables == 1 else tabs
+    return rows, f
+
+
+def rows_leave(rows, live, tokens, seq_lens, steps, mesh=None):
+    """``rows`` after a program's own steps: the ``live`` slots' last
+    token, length and step count; a slot that entered dead stays as it
+    was (length 0). Under a mesh the matrix is pinned replicated, the
+    sharding it came in with: a program fed its own output must not
+    compile again."""
+    new = jnp.stack([tokens, seq_lens, steps], axis=1)
+    rows = rows.at[:, :3].set(jnp.where(live[:, None], new, rows[:, :3]))
+    if mesh is not None:
+        rows = lax.with_sharding_constraint(
+            rows, jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec()))
+    return rows
+
+
+def _rows_args(cfg: ModelConfig, rows, rows_delta):
+    """A step program's per-slot arguments out of its resident rows:
+    (rows, tokens, positions, tables, seq_lens, seeds, steps, temps,
+    top_ks, top_ps, penalties by keyword, adapter ids)."""
+    rows, f = rows_enter(rows, rows_delta, 2 if cfg.window_kv_pool else 1)
+    pens = {k: f[k] for k in ("freq_pens", "pres_pens", "rep_pens")}
+    return (rows, f["tokens"], jnp.maximum(f["seq_lens"] - 1, 0),
+            f["tables"], f["seq_lens"], f["seeds"], f["steps"], f["temps"],
+            f["top_ks"], f["top_ps"], pens, f["adapter_ids"])
+
+
 # ---------------- building blocks ----------------
 
 
@@ -2554,7 +2618,7 @@ def decode_step(
     jax.jit,
     static_argnames=("cfg", "n_steps", "use_pallas", "mesh", "interpret",
                      "with_logprobs", "moe_counters"),
-    donate_argnames=("k_cache", "v_cache", "counts", "state"),
+    donate_argnames=("k_cache", "v_cache", "counts", "state", "rows"),
 )
 def decode_window(
     params: dict,
@@ -2597,6 +2661,12 @@ def decode_window(
     # LFM2: the conv layers' state (init_state, donated; row b = slot
     # b); it rides the scan carry and follows v_cache in the output
     state: Optional[dict] = None,
+    # the batch's resident rows (donated) and this dispatch's cells: they
+    # stand for all nine per-slot arguments above (None then), the
+    # penalties and the adapter ids, and the rows come back, advanced, as
+    # the LAST output ("the decode batch's resident rows", above)
+    rows: Optional[jnp.ndarray] = None,
+    rows_delta: Optional[jnp.ndarray] = None,
 ):
     """``n_steps`` fused decode+sample steps in ONE dispatch (lax.scan):
     the sampled token of step i feeds step i+1 entirely on device, so the
@@ -2617,6 +2687,13 @@ def decode_window(
     penalized = counts is not None
     quantized = k_scales is not None
     stateful = state is not None
+    if rows is not None:
+        (rows, tokens, positions, block_tables, seq_lens, seeds, steps,
+         temps, top_ks, top_ps, pens, ids) = _rows_args(cfg, rows, rows_delta)
+        if penalized:
+            freq_pens, pres_pens, rep_pens = pens.values()
+        if lora is not None:
+            adapter_ids = ids
     # a dead slot enters with length 0 (the scan then counts it up:
     # attention is handed 0 for it at every step, and walks no page; the
     # expert layers send it to no group)
@@ -2691,6 +2768,8 @@ def decode_window(
         out = out + (rest[0],)
     if with_logprobs:
         out = out + (lps,)
+    if rows is not None:
+        moe = moe + (rows_leave(rows, live, fin[0], fin[2], fin[3], mesh),)
     return out + moe
 
 
@@ -2883,7 +2962,7 @@ def _mixed_fused_forward(
     jax.jit,
     static_argnames=("cfg", "use_pallas", "mesh", "interpret",
                      "with_logprobs", "moe_counters"),
-    donate_argnames=("k_cache", "v_cache", "counts", "state"),
+    donate_argnames=("k_cache", "v_cache", "counts", "state", "rows"),
 )
 def mixed_step(
     params: dict,
@@ -2943,6 +3022,11 @@ def mixed_step(
     # [MP] int32: the snapshot row that takes each segment's end state
     # (``prefill``'s ``snap_row``; past the pool: none)
     p_snaps: Optional[jnp.ndarray] = None,
+    # the batch's resident rows (donated) and this dispatch's cells, for
+    # the nine decode-side arguments, the penalties and ``d_adapter_ids``
+    # (``decode_window``); the rows come back as the LAST output
+    rows: Optional[jnp.ndarray] = None,
+    rows_delta: Optional[jnp.ndarray] = None,
 ):
     """ONE device dispatch fusing M prefill chunks into a decode step.
 
@@ -2994,6 +3078,13 @@ def mixed_step(
 
     MP, T = p_tokens.shape
     quantized = k_scales is not None
+    if rows is not None:
+        (rows, d_tokens, d_positions, d_tables, d_seq_lens, seeds, steps,
+         temps, top_ks, top_ps, pens, ids) = _rows_args(cfg, rows, rows_delta)
+        if counts is not None:
+            freq_pens, pres_pens, rep_pens = pens.values()
+        if lora is not None:
+            d_adapter_ids = ids
     if quantized:
         # scales only grow within a step — plane entries above their
         # step-entry value count the pages requantized this dispatch
@@ -3096,6 +3187,9 @@ def mixed_step(
         result.append(token_logprobs(raw_logits, nxt))
     if moe_counters:
         result.append(tally.sums)
+    if rows is not None:
+        result.append(rows_leave(rows, d_seq_lens > 0, nxt, d_seq_lens + 1,
+                                 steps + 1, mesh))
     return tuple(result)
 
 
@@ -3266,6 +3360,12 @@ def verify_window(
     counts: Optional[jnp.ndarray] = None,  # [B, V] i32, donated
     prompt_mask: Optional[jnp.ndarray] = None,  # [B, V] bool
     with_logprobs: bool = False,
+    # the batch's resident rows and this dispatch's cells, for every
+    # per-slot argument above but ``proposals`` (``tokens``: the rows'
+    # last tokens before the proposals). NOT returned: a verify step's
+    # rows advance by their own accepted counts, which the host applies
+    rows: Optional[jnp.ndarray] = None,
+    rows_delta: Optional[jnp.ndarray] = None,
 ):
     """Speculative verify + acceptance (greedy AND sampled rows):
 
@@ -3297,6 +3397,14 @@ def verify_window(
         token_logprobs,
     )
 
+    if rows is not None:
+        (rows, last, positions, block_tables, seq_lens, seeds, steps,
+         temps, top_ks, top_ps, pens, _ids) = _rows_args(cfg, rows, rows_delta)
+        # (-1 -> 0 for a safe embed; acceptance uses the ORIGINAL -1s)
+        tokens = jnp.concatenate(
+            [last[:, None], jnp.maximum(proposals, 0)], axis=1)
+        if counts is not None:
+            freq_pens, pres_pens, rep_pens = pens.values()
     T = n_spec + 1
     B = tokens.shape[0]
     logits, k_cache, v_cache = _verify_forward(

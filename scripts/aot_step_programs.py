@@ -181,19 +181,26 @@ def main() -> int:
         if cfg.linear_layers:  # snapshotted at a chunk's end, by row
             snap = {"snap_row": chip((), jnp.int32)}
             snaps = {"p_snaps": chip((1,), jnp.int32)}
-    ints, floats = chip((b,), jnp.int32), chip((b,), jnp.float32)
-    batch = (ints, ints, tables(b), ints, ints, ints, floats, ints, floats)
+    # the decode rows as the engine hands them since PR 48: the resident
+    # matrix and a dispatch's cells (engine/step_state.py)
+    from dynamo_tpu.engine.step_state import DELTA_CELLS
+
+    n_tables = 2 if cfg.window_kv_pool else 1
+    batch = llama.ROWS_RESIDENT
+    rows = {"rows": chip((b, llama.ROW_TABLES + n_tables * m), jnp.int32),
+            "rows_delta": chip((DELTA_CELLS, 3), jnp.int32)}
     one_i, scalar = chip((1,), jnp.int32), chip((), jnp.int32)
     lowered = {
         "mixed": lambda t: llama.mixed_step.lower(
             params, cfg, *batch, chip((1, t), jnp.int32),
             tables(1), one_i, one_i, cache, cache_v,
-            use_pallas=True, **kw,
+            use_pallas=True, **kw, **rows,
             **({"state": state, "p_slots": one_i, **snaps}
                if state else {})),
         "decode": lambda t: llama.decode_window.lower(
             params, cfg, *batch, cache, cache_v, n_steps=args.window,
-            use_pallas=True, **kw, **({"state": state} if state else {})),
+            use_pallas=True, **kw, **rows,
+            **({"state": state} if state else {})),
         "prefill": lambda t: llama.prefill.lower(
             params, cfg, chip((t,), jnp.int32), tables(),
             scalar, scalar, cache, cache_v, use_pallas=True, **kw,

@@ -101,6 +101,9 @@ EXPERT_CELLS = ["olmoe-1b-7b.chat", "lfm2-8b-a1b.chat", "gigachat35.reason"]
 NEW_IN_47 = ("window_kv_resident_share", "window_attn_walked_share",
              "window_prefix_missed_share")
 CELL_47 = "mellum2.repo"
+#: the two PR 48 added for all five cells: the hand-overs' counters are
+#: what a server before the resident step state lacks
+NEW_IN_48 = ("step_handovers_per_dispatch", "step_state_resident_share")
 
 
 def test_benchmark_json_lists_the_five_for_every_cell():
@@ -135,7 +138,8 @@ def test_every_metric_file_passes_a_server_that_lacks_its_series(name, model):
            "peaks": {}}
     got = reducer.reduce(ctx, s.get("selector", {}))
     assert got is None or isinstance(got, float)
-    if name in EXPECTED or name in NEW_IN_43 + NEW_IN_44 + NEW_IN_47:
+    if name in EXPECTED or name in (
+            NEW_IN_43 + NEW_IN_44 + NEW_IN_47 + NEW_IN_48):
         assert got is None
     elif s["reducer"] == "counter_ratio" and not name.startswith(
             ("moe_", "state_")):
@@ -178,23 +182,24 @@ def test_benchmark_json_lists_pr44s_metrics_for_the_expert_cells():
 
 
 def test_benchmark_json_lists_pr47s_metrics_for_its_cell_only():
-    """The three stand last, for ``mellum2.repo`` alone; the cell is
+    """The three stand last (before PR 48's two), for ``mellum2.repo``
+    alone; the cell is
     appended to ``tpot_mean_ms`` and to the per-layer metrics whose
     instrument the model has (every one that lists all four older cells,
     and the expert layer's counters), and to none of the state's."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [m["name"] for m in bench["per_layer"][-3:]] == list(NEW_IN_47)
-    for m in bench["per_layer"][-3:]:
+    assert [m["name"] for m in bench["per_layer"][-5:-2]] == list(NEW_IN_47)
+    for m in bench["per_layer"][-5:-2]:
         s = spec(m["name"])
         assert m["workloads"] == [CELL_47] and m["source"] == "program_counter"
         assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
             s["unit"], s["better"], s["layer"], s["moves"])
         assert s["reducer"] == "counter_ratio" and m["better"] == "lower"
     older = [w["name"] for w in bench["workloads"] if w["name"] != CELL_47]
-    joined = {m["name"] for m in bench["per_layer"][:-3]
+    joined = {m["name"] for m in bench["per_layer"][:-5]
               if CELL_47 in m["workloads"]}
-    for m in bench["per_layer"][:-3]:
+    for m in bench["per_layer"][:-5]:
         if CELL_47 in m["workloads"]:
             assert m["workloads"][-1] == CELL_47, m["name"]
         if m["workloads"][:4] == older:
@@ -284,3 +289,47 @@ def test_pr43s_metrics_read_a_server_that_has_their_series(name):
         "linear_state_mib_per_step": 1024.0,
         "prefix_unsnapshotted_share": 10.0,
     }[name])
+
+
+def test_benchmark_json_lists_pr48s_metrics_for_every_cell():
+    """The two stand last, for all five cells, under the scheduler."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert [m["name"] for m in bench["per_layer"][-2:]] == list(NEW_IN_48)
+    for m in bench["per_layer"][-2:]:
+        s = spec(m["name"])
+        assert m["workloads"] == cells and m["source"] == "program_counter"
+        assert s["reducer"] == "counter_ratio" and m["layer"] == "scheduler"
+        assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
+            s["unit"], s["better"], s["layer"], s["moves"])
+
+
+@pytest.mark.parametrize("name,want", [
+    ("step_handovers_per_dispatch", 1.5),   # (90 + 50 + 10) / 100 steps
+    ("step_state_resident_share", 80.0),    # 100 x 68 / (68 + 17)
+])
+def test_pr48s_metrics_read_a_server_that_has_their_series(name, want):
+    """The made-up window above with its hand-overs: 90 by the 60 decode
+    windows, 50 by the 25 mixed steps, 10 by the 15 prefills; and of the
+    85 dispatches with decode rows 17 sent the whole mirror. The engine
+    spells the series as the selectors do."""
+    handed = 'engine_step_handovers_total{kind="%s"}'
+    delta = dict(DELTA, **{
+        handed % "decode_window": 90, handed % "mixed_step": 50,
+        handed % "prefill": 10, handed % "verify": 0,
+        "engine_step_state_resident_total": 68,
+        "engine_step_state_resyncs_total": 17})
+    sel = spec(name)["selector"]
+    assert counter_ratio.reduce({"delta": delta}, sel) == pytest.approx(want)
+    with open(os.path.join(REPO, "dynamo_tpu", "engine", "engine.py")) as f:
+        engine = f.read()
+    for series in sel["num"] + sel["den"]:
+        base, _, label = series.partition("{")
+        if label:
+            assert label.split('"')[1] in KINDS, series
+            assert f"'{base}{{{{kind=" in engine, series
+        else:
+            assert base.removeprefix("engine_").removesuffix("_total") in (
+                "step_state_resident", "step_state_resyncs")
+            assert 'out[f"engine_{name}_total"]' in engine

@@ -474,10 +474,10 @@ def test_chunked_prefill_interleaves_decode(run, engine_cfg):
             decode_steps_during_chunk.append(engine.stats["decode_steps"])
             return orig_chunk(st)
 
-        def spy_mixed(st, steps):
+        def spy_mixed(st):
             # mixed-batch chunks: the chunk rides the decode step itself
             decode_steps_during_chunk.append(engine.stats["decode_steps"])
-            return orig_mixed(st, steps)
+            return orig_mixed(st)
 
         engine._prefill_chunk_device = spy_chunk
         engine._dispatch_mixed = spy_mixed

@@ -281,7 +281,7 @@ def test_a_model_with_one_kind_of_layer_keeps_one_pool(tiny):
             word in k for k in engine.device_path_stats()
             for word in ("kv_window", "attn_window", "window_missed",
                          "kv_pool_blocks", "prefix_matched"))
-        assert not isinstance(engine._tables(engine._block_tables), tuple)
+        assert engine._rows.wtables is None
 
 
 def test_the_window_pools_size_is_derived_or_asked(tiny):

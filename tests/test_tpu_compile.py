@@ -239,6 +239,65 @@ def test_decode_window_keeps_no_copy_of_the_pool(chip, experts, head):
     assert compiled.memory_analysis().temp_size_in_bytes < slab // 2
 
 
+@pytest.mark.parametrize("tp", [0, 4], ids=["one-chip", "tp4-shard"])
+@pytest.mark.parametrize("program", ["decode_window", "mixed_step"])
+def test_a_step_program_returns_its_rows_as_it_took_them(
+        topo, chip, program, tp):
+    """The resident step state (PR 48: ``engine/step_state.py``) is a
+    program's donated input and its last output, and the next dispatch's
+    input again: the compiled program gives the matrix back with the
+    shape, dtype and sharding it took (replicated under a ``tp`` mesh,
+    where the output is pinned), or the second dispatch of every program
+    would compile anew; and the donation is taken (an alias, no copy)."""
+    from dynamo_tpu.engine.step_state import DELTA_CELLS
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.parallel import mesh as pm
+
+    cfg = ModelConfig(
+        vocab_size=2048, hidden_size=512, intermediate_size=1024,
+        num_layers=2, num_heads=4, num_kv_heads=4, head_dim=128)
+    b, m, n, t = 8, 32, 1024 * max(tp, 1), 64
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    pool = llama.kv_cache_shapes(cfg, n, BS)[0]
+    mesh = None
+    if tp:
+        from jax.sharding import NamedSharding
+
+        mesh = pm.make_mesh(pm.MeshConfig(tp=tp), devices=topo.devices)
+        params = jax.tree.map(
+            lambda a, spec: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+            shapes, pm.spec_tree(shapes, mesh=mesh))
+        cache = jax.ShapeDtypeStruct(
+            pool, jnp.bfloat16, sharding=pm.cache_sharding(mesh, cfg))
+        rep = pm.replicated(mesh)
+
+        def arr(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+    else:
+        params = jax.tree.map(lambda a: chip(a.shape, a.dtype), shapes)
+        cache, arr = chip(pool, jnp.bfloat16), chip
+    rows = arr((b, llama.ROW_TABLES + m), jnp.int32)
+    one = arr((1,), jnp.int32)
+    segs = (arr((1, t), jnp.int32), arr((1, m), jnp.int32), one, one)
+    lowered = getattr(llama, program).lower(
+        params, cfg, *llama.ROWS_RESIDENT,
+        *(segs if program == "mixed_step" else ()), cache, cache,
+        use_pallas=True, mesh=mesh, rows=rows,
+        rows_delta=arr((DELTA_CELLS, 3), jnp.int32))
+    compiled = lowered.compile()
+    back = jax.tree.leaves(lowered.out_info)[-1]
+    assert (back.shape, back.dtype) == (rows.shape, rows.dtype)
+    out_sharding = jax.tree.leaves(compiled.output_shardings)[-1]
+    assert out_sharding.is_equivalent_to(rows.sharding, 2)
+    # the rows' buffer is one of the aliased (donated and reused) inputs
+    aliases = compiled.as_text().split("input_output_alias=")[1].split(
+        "entry_computation_layout")[0]
+    assert aliases.count("-alias") == 3, "the caches' and the rows'"
+
+
 @pytest.mark.parametrize(
     "experts,head,tp", [(0, 128, 0), (8, 128, 0), (0, 64, 0), (0, 128, 4)],
     ids=["dense", "experts", "head64-sinks-windows", "tp4-shard"])
