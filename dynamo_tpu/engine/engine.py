@@ -339,23 +339,32 @@ class EngineConfig:
     # windows whenever admission work is pending (fairness) and clamps to
     # each sequence's stop/context headroom. Power of two.
     decode_window: int = 4
-    # pipelined decode: dispatch window k+1 (fed window k's last sampled
-    # tokens as a device array) BEFORE the host consumes window k, hiding
-    # host emission + dispatch latency behind device compute (the async
-    # scheduling overlap vLLM gets from multi-step scheduling). Drained
-    # whenever batch membership changes, and never the CAUSE of a
-    # preemption (speculative window blocks are returned first under pool
-    # pressure) — but the overlapped schedule can still shift WHICH
-    # sequence a genuine preemption picks, and a replay whose prefix
-    # blocks were evicted recomputes with different reduction orders than
-    # the original decode (near-tie greedy tokens may flip). Default OFF
-    # so the uncontended==contended bit-exactness guarantee holds;
-    # opt in for throughput on pools provisioned to rarely preempt.
-    # Measured: the serving-layer on/off pair lives in
-    # benchmarks/serving_cpu.json (pipeline_speedup; ~1.0x on CPU where
-    # dispatch gaps are a tiny share of step time); on the chip: not
-    # measured.
-    decode_pipeline: bool = False
+    # chained decode (the engine's decode path since PR 49): the loop
+    # enqueues program i (a decode window or a mixed step) BEFORE it
+    # fetches program i-1's result, so the chip has its next program
+    # queued while the host emits, provisions and admits. What is in
+    # flight is a queue of programs over the resident step state
+    # (engine/step_state.py), not a frozen batch: a stream's end goes up
+    # as cells with the next dispatch and its queued steps are discarded,
+    # a mixed step is enqueued behind a window in flight, and only pool
+    # pressure (the queued program's blocks go back first: chaining is
+    # never the CAUSE of a preemption), a verify step, a reshard, a
+    # whole-state resynchronisation and the loop going idle drain the
+    # chain. A chained window runs decode_window // 2 steps: two of them
+    # cost an arrival what one unchained window did. The multi-host
+    # mirror keeps the frozen form (its rows are host arrays: drained at
+    # every membership change and before a mixed phase).
+    # False = the UNCHAINED loop: enqueue, wait, emit. It is the tests'
+    # bit-exact reference for the chained schedule (streams are token
+    # for token the same; under pool starvation the overlapped schedule
+    # can shift WHICH sequence a genuine preemption picks, and a replay
+    # whose prefix blocks were evicted recomputes with other reduction
+    # orders) and the ablation of scripts/serve_bench.py
+    # (benchmarks/serving_cpu.json: pipeline_speedup, ~1.0x on a CPU
+    # where dispatch gaps are a tiny share of a step). On the chip:
+    # PERF.md section 6, PR 49. ROADMAP C3: a simplicity PR deletes the
+    # field once the ledger has PR 49's lines.
+    decode_pipeline: bool = True
     # speculative decoding via prompt-lookup (n-gram) drafts: propose up
     # to spec_gamma continuation tokens from the sequence's own history
     # (last spec_ngram tokens matched against earlier occurrences) and
@@ -846,7 +855,11 @@ class JaxEngine(AsyncEngine):
         # Named for the runtime sanitizer: when active, its hold times
         # histogram under "device_lock" instead of an acquire site.
         self._device_lock = sanitizer.name_lock(asyncio.Lock(), "device_lock")
-        # pipelined decode: the not-yet-drained window's device tokens
+        # chained decode: the record of the ONE program (a decode window
+        # or a mixed step) enqueued and not yet emitted: its device
+        # tokens, its steps, and which sequence held each slot when it
+        # was enqueued (_pending: a row's steps on the device that the
+        # host has not seen)
         self._inflight: Optional[dict] = None
         self._wake = asyncio.Event()
         self._closed = False
@@ -877,7 +890,8 @@ class JaxEngine(AsyncEngine):
         # device's resident copy and what differs (engine/step_state.py)
         self._rows = StepState(
             cfg.max_batch_size, cfg.max_blocks_per_seq,
-            window=self.wpool is not None, sharding=self._rows_sharding())
+            window=self.wpool is not None, sharding=self._rows_sharding(),
+            head=-(-max(cfg.decode_window, 1) // cfg.block_size))
         # sampling penalties (vLLM semantics — see ops/sampling):
         # device [B, V] output-token counts + prompt-membership mask,
         # allocated lazily on the first request that asks for a penalty
@@ -1241,6 +1255,10 @@ class JaxEngine(AsyncEngine):
                 self.stats[f"step_exposed_seconds_{kind}"], 6)
             out[f'engine_step_handovers_total{{kind="{kind}"}}'] = self.stats[
                 f"step_handovers_{kind}"]
+            # programs of the kind enqueued while another of the loop's
+            # was still outstanding (LoopClock.enqueued)
+            out[f'engine_dispatch_chained_total{{kind="{kind}"}}'] = (
+                self.stats[f"dispatch_chained_{kind}"])
         for name in ("step_state_resyncs", "step_state_resident"):
             out[f"engine_{name}_total"] = self.stats[name]
         for name in WORK_COUNTERS + (MOE_COUNTERS if self._moe_layers else ()):
@@ -1344,7 +1362,8 @@ class JaxEngine(AsyncEngine):
           * the first request's max_tokens is 2*decode_window: one token
             comes from the prefill sample, so decode has a 2W-1 budget
             and _pick_window walks the whole power-of-two ladder
-            W, W/2, ..., 1 — the smaller windows (especially 1) are
+            W, W/2, ..., 1 (the chained loop: W/2, ..., 1, the windows
+            it ever runs) — the smaller windows (especially 1) are
             exactly what concurrent admission traffic dispatches, so
             leaving them cold would inject the compile stall mid-stream
             under real load;
@@ -1407,8 +1426,10 @@ class JaxEngine(AsyncEngine):
         )
         reachable = len(sizes)
         if decode:
-            w = W
-            while w >= 1:  # the _pick_window power-of-two ladder
+            # the _pick_window power-of-two ladder (a chained window runs
+            # at most half decode_window: _chained_window_steps)
+            w = max(W // 2, 1) if self._chained() else W
+            while w >= 1:
                 reachable += 1
                 w //= 2
         self.stats["xla_warm_buckets"] = warm
@@ -3128,7 +3149,7 @@ class JaxEngine(AsyncEngine):
             seq.slot, self._table_for(seq),
             None if self.wpool is None else self._wtable_for(seq))
 
-    def _decode_rows(self, kind: str, pending: int = 0) -> dict:
+    def _decode_rows(self, kind: str, pending=0) -> dict:
         """A step program's decode rows, for all three thunks: the
         resident step state and what this dispatch must add to it
         (``StepState.hand_over``: the mirror whole, a few cells, or
@@ -3676,6 +3697,8 @@ class JaxEngine(AsyncEngine):
         )
         if actionable or self.cfg.decode_window <= 1:
             return 1
+        if self._chained():
+            return self._chained_window_steps()
         headroom = self.cfg.decode_window
         for seq in self._active:
             if seq is None:
@@ -3687,6 +3710,109 @@ class JaxEngine(AsyncEngine):
         n = 1
         while n * 2 <= headroom and n * 2 <= self.cfg.decode_window:
             n *= 2
+        return n
+
+    # ---- the chained loop's view of what is in flight ----
+
+    def _chained(self) -> bool:
+        """Does the loop enqueue a program before it has fetched the one
+        before, whatever the two programs' kinds? Not under the
+        multi-host mirror: its rows are host arrays that describe a
+        frozen batch (it keeps the frozen form: ``_decode_once``)."""
+        return self.cfg.decode_pipeline and self.mirror is None
+
+    def _pending(self, seq: _Sequence) -> int:
+        """Device steps enqueued for ``seq`` that the host has not
+        emitted: those of the program in flight, if it held the slot
+        when that was enqueued."""
+        w = self._inflight
+        if w is None or w["slots"].get(seq.slot) is not seq:
+            return 0
+        return w["n"]
+
+    def _pending_rows(self) -> np.ndarray:
+        """``_pending`` by decode slot (a vacated slot's: 0)."""
+        out = np.zeros(self.cfg.max_batch_size, np.int32)
+        w = self._inflight
+        if w is not None:
+            for i, seq in w["slots"].items():
+                if self._active[i] is seq:
+                    out[i] = w["n"]
+        return out
+
+    def _tokens_left(self, seq: _Sequence) -> tuple[int, int]:
+        """(tokens ``seq`` can still be given, positions its context
+        still has), both behind what is already enqueued for it. The
+        first never exceeds the second; EOS or a stop token may end the
+        stream sooner, never later."""
+        pending = self._pending(seq)
+        room = self.cfg.max_context - seq.seq_len - pending
+        sc = seq.request.stop_conditions
+        if sc.max_tokens is None:
+            return room, room
+        return min(room, sc.max_tokens - seq.generated - pending), room
+
+    def _reach(self, seq: _Sequence, n: int) -> int:
+        """One past the last position a dispatch of ``n`` steps must
+        have a block of ``seq``'s for: behind its steps already
+        enqueued, and no further than the tokens it can still be given.
+        A step past that (a chained window outlives its shortest row)
+        writes through a zero table entry into reserved page 0, or into
+        the spare room of the row's last block, and its token is a
+        discard: it costs no block, so chaining asks the pool for
+        nothing the unchained loop would not."""
+        return seq.seq_len + self._pending(seq) + max(
+            min(n, self._tokens_left(seq)[0]), 0)
+
+    def _leaving(self, seq: _Sequence) -> bool:
+        """Has ``seq``'s leave gone up already (``_retire_ended``)? The
+        step state then holds length 0 for a slot that still has its
+        sequence: every token it will get is in the program in flight."""
+        return seq.slot >= 0 and self._rows.seq_lens[seq.slot] == 0
+
+    def _retire_ended(self) -> None:
+        """The host knows ``max_tokens`` and ``max_context``: a row whose
+        every remaining token is already enqueued leaves the device's
+        batch with the NEXT dispatch (``StepState.release``: cells), so
+        no program runs a row that has ended and none is queued behind
+        the last row's end. The host's side of the leave (``_finish``)
+        follows when the program in flight is emitted. A row that ends
+        by EOS or a stop token is only found there: its queued steps
+        are discarded (``_emit_window``)."""
+        if self._inflight is None:
+            return
+        for seq in self._active:
+            if (seq is not None and not seq.finished
+                    and not self._leaving(seq)
+                    and self._tokens_left(seq)[0] <= 0):
+                self._rows.release(seq.slot)
+
+    def _chained_window_steps(self) -> int:
+        """A chained window's steps: as many as the longest-lived row
+        still needs, rounded up to a power of two (a row that ends
+        sooner has its tail discarded), at most HALF ``decode_window``
+        (with the host's gap hidden a
+        window's length has no host cost to amortise, and what is queued
+        behind the running program is what an arrival waits out: two
+        chained windows of 2 cost it what one unchained window of 4
+        did), and never past a row's ``max_context``: a write past the
+        table is not a discard. 0: no row has a step to take."""
+        cap = max(self.cfg.decode_window // 2, 1)
+        want, room = 0, cap
+        for seq in self._active:
+            if seq is None or seq.finished or self._leaving(seq):
+                continue
+            left, ctx = self._tokens_left(seq)
+            if left <= 0:
+                continue  # (leaves with this dispatch: _retire_ended)
+            want, room = max(want, left), min(room, ctx)
+        if want == 0:
+            return 0
+        n = 1
+        while n < min(want, cap):
+            n *= 2
+        while n > room:
+            n //= 2
         return n
 
     def _preempt(self, seq: _Sequence) -> None:
@@ -3740,42 +3866,57 @@ class JaxEngine(AsyncEngine):
         return False
 
     async def _decode_once(self) -> None:
+        """One turn of the decode path: enqueue the next program (a
+        decode window, or a mixed step while prompts are prefilling),
+        THEN fetch and emit the one before it (``_chained``; the
+        unchained reference fetches its own at once). See
+        ``EngineConfig.decode_pipeline`` for what drains the chain."""
         cfg = self.cfg
         self._clock.mark("provision")
         faultpoints.hit_sync("mid_decode")
+        chained = self._chained()
         if self._mixed_fusable():
-            # chunked prefills fuse into this iteration's decode step: a
-            # pipelined window can't chain across the membership change a
-            # completing prefill brings, so drain first (cheap — mixed
-            # phases force 1-step windows anyway)
-            await self._drain_inflight()
+            if not chained:
+                # the frozen form (the mirror's) cannot chain across the
+                # membership change a completing prefill brings, and the
+                # unchained one has nothing in flight
+                await self._drain_inflight()
             if self._mixed_fusable():
                 await self._mixed_step_once()
                 return
             if self._n_active == 0:
                 return
+        if chained:
+            self._retire_ended()
+            if all(s is None or s.finished or self._leaving(s)
+                   for s in self._active):
+                # every row's last token is in the program in flight:
+                # nothing to enqueue behind it
+                await self._drain_inflight()
+                return
         n = self._pick_window()
-        # tokens already written/writing on device for an undrained window
-        pending = self._inflight["n"] if self._inflight else 0
-        # ensure every active sequence has blocks for the window's tokens
+        # ensure every active sequence has blocks for the window's tokens,
+        # behind those of its steps that are already enqueued (_pending)
         for seq in list(self._active):
             if seq is None or seq.finished or seq.slot < 0:
                 continue  # may have been preempted earlier this pass
+            if self._leaving(seq):
+                continue
             if seq.context.is_stopped():
                 self._finish(seq, FinishReason.CANCELLED)
                 continue
             while (
-                self._blocks_short(seq, seq.seq_len + pending + n)
-                and seq.slot >= 0
+                seq.slot >= 0
                 and not seq.finished
+                and self._blocks_short(seq, self._reach(seq, n))
             ):
                 if len(seq.blocks) >= cfg.max_blocks_per_seq:
                     if self._inflight is not None:
-                        # the requirement is inflated by the speculative
-                        # pending window — drain (emits its tokens,
-                        # advances seq_len, pending -> 0), re-pick the
-                        # window from fresh lengths, and re-evaluate
-                        # before declaring a context-limit finish, or the
+                        # the requirement is inflated by the steps in
+                        # flight — drain (emits their tokens, advances
+                        # seq_len, nothing pending), re-pick the window
+                        # from fresh lengths, and re-evaluate before
+                        # declaring a context-limit finish, or the
                         # in-flight tokens would be discarded and the
                         # stream truncated up to a window early.
                         # min(): CLAMP to the previously validated n.
@@ -3787,43 +3928,53 @@ class JaxEngine(AsyncEngine):
                         # sequence) would write past their blocks through
                         # zero table entries into reserved page 0
                         await self._drain_inflight()
-                        pending, n = 0, min(n, self._pick_window())
+                        n = min(n, self._pick_window())
                         continue
                     self._finish(seq, FinishReason.LENGTH)  # true ctx limit
                     break
-                if self._grow_blocks(seq, seq.seq_len + pending + n):
+                if self._grow_blocks(seq, self._reach(seq, n)):
                     continue
                 if self._inflight is not None:
-                    # pipelining must never CAUSE a preemption: the
-                    # speculative pending-window blocks are the first thing
-                    # to give back under pressure. Draining emits the
-                    # window (advancing seq_len by `pending`) and frees the
-                    # speculation headroom requirement. min(): same
+                    # chaining must never CAUSE a preemption: the blocks
+                    # of the queued program's steps are the first thing to
+                    # give back under pressure. Draining emits it
+                    # (advancing seq_len by what was pending) and frees
+                    # the headroom requirement. min(): same
                     # already-validated-sequences clamp as above.
                     await self._drain_inflight()
-                    pending, n = 0, min(n, self._pick_window())
+                    n = min(n, self._pick_window())
+                    continue
+                if chained and n > 1:
+                    # nor must the window's length: a shorter one asks
+                    # for fewer blocks ahead (the unchained ladder walks
+                    # down with its shortest row; this window is as long
+                    # as its LONGEST-lived row needs)
+                    n //= 2
                     continue
                 # pool exhausted: preempt the youngest running sequence
                 # (possibly this one) instead of truncating output
                 if self._evict_for_headroom(seq):
                     break
-        if self._n_active == 0:
+        if self._n_active == 0 or n == 0:
             await self._drain_inflight()
             return
 
-        # The in-flight window froze a batch membership; if it changed
-        # (finish, cancellation, preemption, admission), the chained
-        # device tokens and the `pending` offset no longer describe the
-        # current batch — drain first (survivors' tokens still emit; a
-        # vacated slot's are discarded) and start an unchained window.
-        if self._inflight is not None:
+        # The frozen form (the multi-host mirror's rows are host arrays
+        # that describe ONE batch): if the membership changed under the
+        # window in flight (finish, cancellation, preemption, admission),
+        # the chained device tokens and the pending offset no longer
+        # describe the current batch — drain first (survivors' tokens
+        # still emit; a vacated slot's are discarded) and start an
+        # unchained window. The chained loop needs none of this: a leave
+        # is a few cells of the next dispatch (StepState.release) and a
+        # row's pending steps are its own (_pending).
+        if self._inflight is not None and not chained:
             infl = self._inflight["slots"]
             cur = {i: s for i, s in enumerate(self._active) if s is not None}
             if cur.keys() != infl.keys() or any(
                 cur[i] is not infl[i] for i in cur
             ):
                 await self._drain_inflight()
-                pending = 0
                 if self._n_active == 0:  # drain may finish survivors
                     return
 
@@ -3839,6 +3990,7 @@ class JaxEngine(AsyncEngine):
         # gpt-oss models (per-layer windows and sinks thread through
         # the unrolled XLA verify), and the multi-host mirror (the
         # verify is a broadcast op). NO model family is gated off.
+        spec_hot = False
         if (
             cfg.spec_gamma > 0
             and n > 1
@@ -3858,7 +4010,6 @@ class JaxEngine(AsyncEngine):
             if proposals is not None:
                 if self._inflight is not None:
                     await self._drain_inflight()
-                    pending = 0
                     if self._n_active == 0:
                         return
                     proposals = self._propose_ngram()
@@ -3867,7 +4018,7 @@ class JaxEngine(AsyncEngine):
                 ):
                     return
                 # a stale hit whose fresh re-probe (or verify) missed:
-                # the tail is HOT — a match existed ``pending`` tokens
+                # the tail is HOT — a match existed a window's tokens
                 # ago. Re-entering pipelined mode here would keep every
                 # future probe one window behind the repetition, so
                 # speculation could NEVER engage on a pipelined engine
@@ -3875,37 +4026,52 @@ class JaxEngine(AsyncEngine):
                 # starved to 0 accepted tokens). Dispatch this one
                 # window unchained so the next iteration probes fresh.
                 spec_hot = True
-            else:
-                spec_hot = False
-        else:
-            spec_hot = False
 
-        # Pipelined mode: dispatch window k+1 BEFORE draining window k.
-        # Its token inputs are window k's last sampled tokens — a device
-        # array, no host round trip — and positions/lengths/steps advance
-        # by the pending step count host-side. Safe without draining on
-        # finish/preempt because (a) in-flight writes land only ABOVE the
-        # commit horizon (never into hash-claimable blocks) and (b) any
-        # re-used block is re-prefilled by a dispatch device-ordered after
-        # the in-flight window. Admission pressure forces n == 1
-        # (_pick_window), which drains first — new sequences never join a
-        # frozen in-flight batch.
+        # Enqueue this window BEFORE the one in flight is fetched. Its
+        # token inputs are that program's last sampled tokens, its
+        # lengths and steps what that program left: all in the resident
+        # step state (under the mirror: the in-flight token array and
+        # the host arrays advanced by the pending count). Safe without
+        # draining on a finish or a preemption because (a) the in-flight
+        # program's writes land only ABOVE the commit horizon (a row
+        # writes at its device length - 1 and up, the host commits blocks
+        # below its own, smaller, length - 1: never into a
+        # hash-claimable block), (b) a block given back and re-used is
+        # written again by a program that is device-ordered AFTER the one
+        # in flight (the next tenant's prefill, a restore's scatter; an
+        # eviction's gather reads committed blocks only), (c) the window
+        # pool gives pages back behind the HOST's position
+        # (_window_advance), which lags the device's: conservative, and
+        # (d) a state row's next tenant begins it (_begin_state_row) in
+        # a program ordered after too, and a dead row keeps its state
+        # row as it was. The alternating scheduler (a prefill that cannot
+        # fuse: mixed batching off, a ring chunk, the mirror) places its
+        # row between two windows and keeps the unchained window.
         pipe = (
             cfg.decode_pipeline
-            and n > 1
+            and (chained or n > 1)
             and not self._prefill_states
             and not spec_hot
         )
+        if pipe and chained and self._inflight is not None and (
+                self._rows.stale(self._pending_rows())):
+            # the step state has to go up whole (a slot was taken by a
+            # remote admission, more cells than a delta holds, a dispatch
+            # that raised): that describes a batch with nothing in flight
+            await self._drain_inflight()
         if not pipe:
             await self._drain_inflight()
-            pending = 0
+        if not pipe or self._inflight is None:
             if self._n_active == 0:
                 return
             # min(): the provisioning pass above validated blocks for at
             # most n tokens per sequence; a fresh pick may shrink (e.g.
-            # admission became actionable after a preemption) but must
-            # never grow past what was provisioned
+            # admission became actionable after a preemption, or a drain
+            # finished the longest-lived row) but must never grow past
+            # what was provisioned
             n = min(n, self._pick_window())
+            if n == 0:
+                return
         prev = self._inflight
         # a window in flight chains its tokens on the device: in the
         # resident step state, or under the mirror, whose previous output
@@ -3914,23 +4080,22 @@ class JaxEngine(AsyncEngine):
         # slice their own copy).
         tokens_in = (prev["toks"] if prev is not None
                      and self.mirror is not None else None)
-        toks = await self._on_device(
-            self._dispatch_window, n, pending, tokens_in
-        )
+        toks = await self._on_device(self._dispatch_window, n, tokens_in)
         self._inflight = {
             "toks": toks, "n": n,
             "lps": self._window_logprobs,
-            # the clock's number of the window's program: its fetch
-            # settles the programs up to it (tracing/loop_clock.py)
+            # the clock's number of the window's program (its fetch
+            # settles the programs up to it) and its description, for
+            # the step that closes at its emission (tracing/loop_clock.py)
             "program": self._clock.programs,
+            "info": self._clock.described(),
             "slots": {i: s for i, s in enumerate(self._active)
-                      if s is not None},
+                      if s is not None and not self._leaving(s)},
         }
         if prev is not None:
             await self._emit_window(prev)
         if not pipe:
             await self._drain_inflight()
-        self._step_done()
 
     def _propose_ngram(self) -> Optional[np.ndarray]:
         """Prompt-lookup drafts: match each sequence's trailing n-gram
@@ -4044,28 +4209,42 @@ class JaxEngine(AsyncEngine):
                 self._abort_prefill(st, FinishReason.CANCELLED)
         if not self._prefill_states:
             return
-        # provision one decode token per active sequence (no window is in
-        # flight here — _decode_once drained before calling)
+        # provision one decode token per active sequence, behind those of
+        # its steps that are already enqueued (the chained loop enqueues
+        # this step behind a window in flight; the other forms drained)
+        if self._chained():
+            self._retire_ended()
         for seq in list(self._active):
             if seq is None or seq.finished or seq.slot < 0:
+                continue
+            if self._leaving(seq):
                 continue
             if seq.context.is_stopped():
                 self._finish(seq, FinishReason.CANCELLED)
                 continue
             while (
-                self._blocks_short(seq, seq.seq_len + 1)
-                and seq.slot >= 0
+                seq.slot >= 0
                 and not seq.finished
+                and self._blocks_short(seq, self._reach(seq, 1))
             ):
+                if (len(seq.blocks) < cfg.max_blocks_per_seq
+                        and self._grow_blocks(seq, self._reach(seq, 1))):
+                    continue
+                if self._inflight is not None:
+                    # the queued program's blocks go back first: chaining
+                    # never causes a preemption or an early LENGTH finish
+                    await self._drain_inflight()
+                    continue
                 if len(seq.blocks) >= cfg.max_blocks_per_seq:
                     self._finish(seq, FinishReason.LENGTH)
                     break
-                if self._grow_blocks(seq, seq.seq_len + 1):
-                    continue
                 if self._evict_for_headroom(seq):
                     break
         if self._n_active == 0 and len(self._prefill_states) < 2:
-            return  # a lone prefill alone: the alternating step is cheaper
+            # a lone prefill alone: the alternating step is cheaper (what
+            # is in flight holds finished rows' tokens: discards)
+            await self._drain_inflight()
+            return
         self._clock.mark("admit")
         packed = self._split_mixed_budget()
         self._clock.mark("provision")
@@ -4075,10 +4254,12 @@ class JaxEngine(AsyncEngine):
                 packed.remove((st, take))
         if not packed:
             return
+        if self._inflight is not None and self._rows.stale(
+                self._pending_rows()):
+            # the step state has to go up whole: nothing may be in flight
+            await self._drain_inflight()
         try:
-            toks, lps, completed = await self._on_device(
-                self._dispatch_mixed, packed
-            )
+            step = await self._on_device(self._dispatch_mixed, packed)
         except Exception:  # noqa: BLE001
             # a fused-dispatch failure (lowering/compile) is not
             # attributable to one prompt: fail every in-flight prefill,
@@ -4091,40 +4272,35 @@ class JaxEngine(AsyncEngine):
             )
             for st in list(self._prefill_states):
                 self._abort_prefill(st, FinishReason.ERROR)
+            await self._drain_inflight()
             return
-        self._clock.mark("emit")
-        self.stats["decode_steps"] += 1
-        self.stats["mixed_steps"] += 1
-        self.stats["mixed_prefill_segments"] += len(packed)
-        # decode emission: exactly a drained 1-step window
-        if self._n_active:
-            for i, seq in list(enumerate(self._active)):
-                if seq is None or seq.finished:
-                    continue
-                entry = None
-                k = int(self._logprob_ks[i])
-                if lps is not None and k >= 0:
-                    chosen, top_ids, top_lps = lps
-                    entry = {
-                        "logprob": float(chosen[i]),
-                        "top": [
-                            [int(top_ids[i, j]), float(top_lps[i, j])]
-                            for j in range(k)
-                        ],
-                    }
-                self._emit_token(seq, int(toks[i]), entry)
-                if seq.finished or self._active[i] is not seq:
-                    continue
-                self._rows.advance(
-                    i, seq.seq_len, seq.tokens[-1], seq.generated)
-                self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
-        # prompts whose FINAL chunk just ran: first token sampled on
-        # device in _dispatch_mixed — emit + join the batch, in
-        # admission order (multiple prompts may complete in one step)
+        # a prompt with chunks to go: its blocks commit chunk by chunk
+        # (a window pool's go back from behind the window only once
+        # committed). The step is enqueued: whatever reads those blocks
+        # is enqueued after it
         for st, _take in packed:
             if st.pos < len(st.seq.tokens):
                 self._commit_chunk(st)
-        for st, first in completed:
+        prev, self._inflight = self._inflight, step
+        if prev is not None:
+            # the window ahead of it is fetched and emitted while the
+            # mixed step runs
+            await self._emit_window(prev)
+        if step["completed"] or not self._chained():
+            # the step's OWN result, before the next enqueue: a prompt it
+            # completed gets its first token sampled on the host and its
+            # row placed (the one exposed gap an admission leaves)
+            await self._drain_inflight()
+
+    def _emit_prefills(self, step: dict, firsts: list) -> None:
+        """The prefill side of an emitted mixed step: the prompts whose
+        FINAL chunk it ran (``firsts``: their first tokens, sampled
+        where the step was fetched) emit and join the batch, in
+        admission order (several may complete in one step)."""
+        cfg = self.cfg
+        self.stats["mixed_steps"] += 1
+        self.stats["mixed_prefill_segments"] += len(step["packed"])
+        for st, first in firsts:
             seq_p = st.seq
             first_token, first_lp = first
             if seq_p.generated == 0:
@@ -4150,7 +4326,6 @@ class JaxEngine(AsyncEngine):
                     # the KV is landed, so queue for the next free slot
                     # exactly like a remotely-prefilled sequence
                     self._remote_ready.append(seq_p)
-        self._step_done()
 
     def _split_mixed_budget(self) -> list[tuple["_PrefillState", int]]:
         """Pack the Sarathi token budget across the in-flight prefills:
@@ -4177,10 +4352,12 @@ class JaxEngine(AsyncEngine):
         return list(zip(sts, takes))
 
     def _dispatch_mixed(self, packed: list[tuple["_PrefillState", int]]):
-        """Executor thread: the fused mixed dispatch over M prefill
-        segments + the decode batch. Returns (decode_tokens [B] np,
-        logprob arrays or None, completed: [(state, (first_token,
-        lp_entry))] for every prompt whose final chunk just ran).
+        """Executor thread: enqueue the fused mixed step over M prefill
+        segments + the decode batch WITHOUT fetching its result. Returns
+        its in-flight record (``_emit_window`` fetches and emits it):
+        the decode tokens [B] and logprob arrays as device handles, and
+        ``completed``: (segment, state) of every prompt whose final
+        chunk it runs, with the segments' last logits ``p_logits``.
 
         Shape discipline: the segment count pads to a power-of-two
         bucket (dead segments: valid 0, zero tables — their rows land
@@ -4193,17 +4370,18 @@ class JaxEngine(AsyncEngine):
         # window dispatch makes): every active sequence must have a block
         # for this step's token, or its write would scatter through zero
         # table entries into reserved page 0 as silent garbage
+        pending = self._pending_rows()
         for seq in self._active:
-            if seq is None or seq.finished or seq.slot < 0:
+            if (seq is None or seq.finished or seq.slot < 0
+                    or self._leaving(seq)):
                 continue
-            if self._blocks_short(seq, seq.seq_len + 1):
+            if self._blocks_short(seq, self._reach(seq, 1)):
                 raise RuntimeError(
                     f"mixed step exceeds provisioned blocks for request "
                     f"{getattr(seq.context, 'id', '?')} "
                     f"(seq_len={seq.seq_len}, blocks={len(seq.blocks)})"
                 )
         t0 = time.perf_counter()
-        total_take = sum(take for _st, take in packed) or 1
         try:
             # land each prompt's reserved host chain (first step only);
             # eviction flushes are shared across the pack
@@ -4230,7 +4408,7 @@ class JaxEngine(AsyncEngine):
                 valids_p[i] = len(chunk)
             penalized = self._penalties_active()
             want_lp = self._logprobs_active()
-            kwargs = self._decode_rows("mixed")
+            kwargs = self._decode_rows("mixed", pending)
             # what the segments need, in ONE transfer
             segs = {"p_tokens": toks_p, "p_hists": hists_p,
                     "p_valids": valids_p, "p_tables": (
@@ -4275,12 +4453,13 @@ class JaxEngine(AsyncEngine):
             kwargs.update(jax.device_put(segs, self._rows.sharding))
             self._handed("mixed", 1)
             self._note_prefill_work(MP * T, int(valids_p.sum()))
-            self._note_decode_work(1, self._rows.seq_lens, seg_pages=(
+            seq_lens = self._rows.seq_lens + pending
+            self._note_decode_work(1, seq_lens, seg_pages=(
                 MP * cfg.max_blocks_per_seq,
                 int(((hists_p + valids_p + cfg.block_size - 1)
                      // cfg.block_size).sum()),
             ))
-            self._note_window_work(1, self._rows.seq_lens, [
+            self._note_window_work(1, seq_lens, [
                 (st.pos, take) for st, take in packed])
             live_rows = int((self._rows.seq_lens > 0).sum())
             out = self._timed_dispatch(lambda: llama.mixed_step(
@@ -4305,7 +4484,7 @@ class JaxEngine(AsyncEngine):
                 self.k_scales = rest.pop(0)
                 self.v_scales = rest.pop(0)
                 self._note_quant_step(
-                    rest.pop(0), self._n_active + total_take,
+                    rest.pop(0), self._n_active + int(valids_p.sum()),
                     gen_tokens=self._n_active,
                 )
             if penalized:
@@ -4318,26 +4497,33 @@ class JaxEngine(AsyncEngine):
             for i, (st, take) in enumerate(packed):
                 st.pos += take
                 if st.pos >= len(st.seq.tokens):
-                    completed.append(
-                        (st, self._sample_prefill(st.seq, p_logits[i]))
-                    )
-            toks_host = np.asarray(jax.device_get(toks))
-            lps = (
-                tuple(np.asarray(jax.device_get(a)) for a in lps_dev)
-                if lps_dev is not None else None
-            )
-            self._clock.landed()
-            return toks_host, lps, completed
-        finally:
-            # the fused dispatch's device time lands on the traced
-            # prefill components, split across the advancing prompts in
-            # proportion to their token take (the chunks dominate the
-            # step; attributing the decode row share too slightly
-            # overcounts prefill but keeps decode ITL honest — the span
-            # decode streams no longer wait on)
-            dt_ms = (time.perf_counter() - t0) * 1e3
-            for st, take in packed:
-                st.dev_ms += dt_ms * (take / total_take)
+                    completed.append((i, st))
+            # device handles: fetched where the step is emitted, so that
+            # the window ahead of it is emitted while it runs
+            return {
+                "toks": toks, "n": 1, "lps": lps_dev,
+                "program": self._clock.programs,
+                "info": self._clock.described(),
+                "slots": {i: s for i, s in enumerate(self._active)
+                          if s is not None and not self._leaving(s)},
+                "packed": packed, "completed": completed,
+                "p_logits": p_logits, "t0": t0,
+            }
+        except BaseException:
+            self._mixed_device_ms(packed, t0)
+            raise
+
+    def _mixed_device_ms(self, packed, t0: float) -> None:
+        """The fused dispatch's time, enqueue to result, lands on the
+        traced prefill components, split across the advancing prompts in
+        proportion to their token take (the chunks dominate the step;
+        attributing the decode row share too slightly overcounts prefill
+        but keeps decode ITL honest — the span decode streams no longer
+        wait on)."""
+        dt_ms = (time.perf_counter() - t0) * 1e3
+        total_take = sum(take for _st, take in packed) or 1
+        for st, take in packed:
+            st.dev_ms += dt_ms * (take / total_take)
 
     def _note_compile(self, key: tuple, wall_ms: float, trace=None) -> None:
         """First dispatch of a program bucket: ledger it. The wall time
@@ -4403,11 +4589,12 @@ class JaxEngine(AsyncEngine):
             self._moe_layers * m.num_experts_per_tok * live_rows,
         ))
 
-    def _step_done(self) -> None:
-        """Close the loop clock's step. An expert model's step carries,
-        as the span's ``moe``, the routing counters that had come back
-        from the device by now (a pipelined window's arrive with the
-        step that emits its tokens)."""
+    def _step_done(self, info: Optional[dict] = None) -> None:
+        """Close the loop clock's step (``info``: the description of the
+        program it emitted, where the loop has enqueued another since).
+        An expert model's step carries, as the span's ``moe``, the
+        routing counters that had come back from the device by now (a
+        chained program's arrive with the step that emits its tokens)."""
         attrs = {}
         if self._filter_steps:
             attrs["filter_steps"], self._filter_steps = self._filter_steps, 0
@@ -4427,11 +4614,11 @@ class JaxEngine(AsyncEngine):
             for name, v in zip(MOE_COUNTERS, (slots, assignments, *sums)):
                 moe[name] += v
         if not moe["moe_expert_slots"]:  # a dense model; nothing back yet
-            self._clock.step_done(**attrs)
+            self._clock.step_done(info, **attrs)
             return
         for name, v in moe.items():
             self.stats[name] += v
-        self._clock.step_done(moe={k[4:]: v for k, v in moe.items()},
+        self._clock.step_done(info, moe={k[4:]: v for k, v in moe.items()},
                               **attrs)
 
     def _note_decode_work(self, n: int, seq_lens: np.ndarray,
@@ -4566,43 +4753,57 @@ class JaxEngine(AsyncEngine):
         return toks, n_acc, lps
 
     async def _drain_inflight(self) -> None:
-        """Sync + emit the pending pipelined window, if any."""
+        """Fetch + emit the program in flight, if any."""
         inflight, self._inflight = self._inflight, None
         if inflight is not None:
             await self._emit_window(inflight)
 
     async def _emit_window(self, window: dict) -> None:
-        def materialize():
-            t = window["toks"]
-            if hasattr(t, "addressable_data") and not getattr(
-                t, "is_fully_addressable", True
+        """Fetch a program's result (a decode window's [n, B] tokens, or
+        a mixed step's [B] and its completed prompts' first tokens),
+        emit it, and close the loop clock's step under its description."""
+        packed = window.get("packed")  # a mixed step's segments
+
+        def fetch(a):
+            if hasattr(a, "addressable_data") and not getattr(
+                a, "is_fully_addressable", True
             ):
-                # multi-process replicated array: read the local shard
-                # (device_get would wait on a collective followers never
-                # join)
-                toks = np.asarray(t.addressable_data(0))
-            else:
-                toks = np.asarray(jax.device_get(t))
+                # multi-process replicated array: read the local shard,
+                # complete for a replicated output (device_get would
+                # wait on a collective followers never join)
+                return np.asarray(a.addressable_data(0))
+            return np.asarray(jax.device_get(a))
+
+        def materialize():
+            # a completed prompt's first token is sampled on the host's
+            # side of the step (llama.mixed_step returns its logits)
+            firsts = [
+                (st, self._sample_prefill(st.seq, window["p_logits"][i]))
+                for i, st in window.get("completed", ())
+            ]
+            toks = fetch(window["toks"])
             lp = window.get("lps")
             if lp is not None:
-                # local shards: complete for replicated outputs, and the
-                # only safe fetch on multi-process arrays (device_get
-                # would wait on a cross-process collective the followers
-                # never join)
-                lp = tuple(np.asarray(a.addressable_data(0)) for a in lp)
+                lp = tuple(fetch(a) for a in lp)
+            if packed is not None:  # one step: [B] -> [1, B]
+                toks = toks[None]
+                lp = lp and tuple(a[None] for a in lp)
+                self._mixed_device_ms(packed, window["t0"])
             self._clock.landed(window["program"])
-            return toks, lp
+            return toks, lp, firsts
 
-        toks_host, lps = await self._on_device(
+        toks_host, lps, firsts = await self._on_device(
             materialize, lock=False, first="device"
         )
         phase = self._clock.mark("emit")
         n = window["n"]
         self.stats["decode_steps"] += n
         # emit window tokens in step order; a sequence that hits a stop
-        # condition mid-window has its tail tokens discarded, and a slot
-        # that changed hands since dispatch (finish -> re-admission) must
-        # not receive the old occupant's tokens
+        # condition mid-window has its tail tokens discarded (and so has
+        # one that ended in the program before this one, which was
+        # enqueued already: its slot's tokens here are all discards), and
+        # a slot that changed hands since dispatch (finish ->
+        # re-admission) must not receive the old occupant's tokens
         live = [
             (i, seq) for i, seq in window["slots"].items()
             if self._active[i] is seq and not seq.finished
@@ -4629,22 +4830,27 @@ class JaxEngine(AsyncEngine):
                 continue
             self._rows.advance(i, seq.seq_len, seq.tokens[-1], seq.generated)
             self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
+        if packed is not None:
+            self._emit_prefills(window, firsts)
         self._clock.mark(phase)
+        self._step_done(window["info"])
 
-    def _dispatch_window(self, n: int, pending: int, tokens_in=None):
+    def _dispatch_window(self, n: int, tokens_in=None):
         """Runs in an executor thread: dispatch one fused n-step
         decode+sample window WITHOUT syncing its result. Returns the
         sampled-token device array [n, B] (host np array on the mirror
         path, which syncs internally).
 
-        ``pending`` > 0 means an undrained window is in flight: this
-        window's token inputs are that window's last sampled tokens and
-        its lengths and steps are ``pending`` ahead of the host's
-        mirror. The resident step state already holds all three (the
-        chain stays on device); on the mirror path ``tokens_in`` is the
-        in-flight window's token array and the host arrays advance."""
+        With a program in flight (``self._inflight``: the loop records
+        this window only after the thunk returns) this window's token
+        inputs are that program's last sampled tokens and a row's length
+        and steps are its ``_pending`` count ahead of the host's mirror.
+        The resident step state already holds all three (the chain stays
+        on device); on the mirror path ``tokens_in`` is the in-flight
+        window's token array and the host arrays advance."""
         cfg = self.cfg
-        if pending and tokens_in is None and self.mirror is not None:
+        pending = self._pending_rows()
+        if pending.any() and tokens_in is None and self.mirror is not None:
             raise RuntimeError(
                 "pending window without a chained token source"
             )
@@ -4653,12 +4859,13 @@ class JaxEngine(AsyncEngine):
         # would scatter through zero block-table entries into reserved
         # page 0 — garbage K/V that later reads silently consume.
         for seq in self._active:
-            if seq is None or seq.finished or seq.slot < 0:
+            if (seq is None or seq.finished or seq.slot < 0
+                    or self._leaving(seq)):
                 continue
-            if self._blocks_short(seq, seq.seq_len + pending + n):
+            if self._blocks_short(seq, self._reach(seq, n)):
                 raise RuntimeError(
-                    f"window n={n} pending={pending} exceeds provisioned "
-                    f"blocks for request "
+                    f"window n={n} pending={int(pending[seq.slot])} "
+                    f"exceeds provisioned blocks for request "
                     f"{getattr(seq.context, 'id', '?')} "
                     f"(seq_len={seq.seq_len}, blocks={len(seq.blocks)})"
                 )
@@ -4695,9 +4902,9 @@ class JaxEngine(AsyncEngine):
             # device handles; materialized at emission
             self._window_logprobs = rest.pop(0) if want_lp else None
             return toks
-        # (a window in flight has left the resident rows ``pending``
-        # steps ahead of the mirror, its last sampled tokens in them:
-        # the chain stays on the device)
+        # (a program in flight has left the resident rows their
+        # ``pending`` steps ahead of the mirror, its last sampled tokens
+        # in them: the chain stays on the device)
         want_lp = self._logprobs_active()
         kw = dict(
             self._decode_rows("decode", pending),

@@ -15,18 +15,33 @@ decides and counts (``_note_decode_work`` and its kind read these views,
 never the device), and every change to it goes through a writer here
 that says what the device now lacks:
 
-* ``place`` / ``release`` / ``move``: a slot changed hands, or a reshard
-  moved the device's copy. The next dispatch sends the whole mirror, in
-  one transfer (a resynchronisation); so does the one after a dispatch
-  that did not bring the matrix back (``took``): a verify step, whose
-  rows advance by their own accepted counts, or one that raised;
+* ``place`` / ``move``: a sequence took a slot, or a reshard moved the
+  device's copy. The next dispatch sends the whole mirror, in one
+  transfer (a resynchronisation); so does the one after a dispatch that
+  did not bring the matrix back (``took``): a verify step, whose rows
+  advance by their own accepted counts, or one that raised;
 * ``set_tables``: a block-table row changed. The cells that differ are
   remembered and go up as the next dispatch's delta (a crossed page is
   one cell, a page released behind a window one cell with page 0);
+* ``release``: a slot is vacated. Cells too: length 0, no adapter, and
+  page 0 in the table columns a dead row's steps write through (its
+  position starts at 0 in every program, so the first ``head``); the
+  rest of its table row stays, on both sides, until the next tenant's
+  ``set_tables`` overwrites it: nothing reads it (a row of length 0
+  walks no page). A leave therefore needs no resynchronisation, and a
+  program that is already enqueued runs the row as it found it;
 * ``advance``: the host emitted what the device sampled. Nothing to
   send: the device's copy is already there. ``hand_over`` holds the
   lengths against what the programs must have left and resynchronises
   if they ever disagree.
+
+The device may be AHEAD of the mirror: the loop enqueues a program
+before it has fetched the one before, so a row's length there is the
+mirror's plus the steps enqueued for it and not yet emitted
+(``pending``, a count a row). Cells still reach the right program: they
+are applied in device order. A resynchronisation does not (the mirror
+knows nothing of the tokens in flight), so ``hand_over`` refuses one
+while anything is pending: the loop drains first (``stale`` tells it).
 
 ``hand_over`` is the one place a dispatch thunk gets its decode rows'
 arguments from; ``took`` takes the program's returned matrix back.
@@ -47,12 +62,16 @@ from ..models.llama import ROW_FIELDS, ROW_FLOATS, ROW_TABLES
 DELTA_CELLS = 128
 
 _DEFAULTS = {"top_ps": 1.0, "rep_pens": 1.0, "adapter_ids": -1}
+_COL = {name: i for i, name in enumerate(ROW_FIELDS)}
 
 
 class StepState:
     def __init__(self, batch: int, width: int, window: bool = False,
-                 sharding=None):
+                 sharding=None, head: int = 1):
         self.batch, self.width = batch, width
+        #: the leading table columns a dead row's steps write through
+        #: (the longest window over the block size, rounded up)
+        self.head = head
         n_tables = 2 if window else 1
         self.host = np.zeros((batch, ROW_TABLES + n_tables * width), np.int32)
         for i, name in enumerate(ROW_FIELDS):
@@ -98,13 +117,20 @@ class StepState:
         self._stale = True
 
     def release(self, slot: int) -> None:
-        """``slot`` is vacated: length 0 and no page, so that nothing the
-        next program does for the row reaches a page given away."""
-        self.seq_lens[slot] = 0
-        self.adapter_ids[slot] = -1
-        for t in self.table_views():
-            t[slot] = 0
-        self._stale = True
+        """``slot`` is vacated: length 0 and page 0 where a dead row
+        writes, so that nothing the next program does for the row
+        reaches a page given away. A few cells, not a
+        resynchronisation."""
+        self._set(slot, _COL["seq_lens"], 0)
+        self._set(slot, _COL["adapter_ids"], -1)
+        for pool in range(len(self.table_views())):
+            for col in range(self.head):
+                self._set(slot, self.table_column(pool, col), 0)
+
+    def _set(self, slot: int, col: int, value: int) -> None:
+        if self.host[slot, col] != value:
+            self.host[slot, col] = value
+            self._cells[slot, col] = value
 
     def set_tables(self, slot: int, table: np.ndarray,
                    wtable: Optional[np.ndarray] = None) -> None:
@@ -138,15 +164,25 @@ class StepState:
             out[: len(cells)] = cells
         return out
 
-    def stale(self, pending: int = 0) -> bool:
+    def _lens_with_cells(self) -> np.ndarray:
+        """The lengths the device's copy holds once the waiting cells
+        are written (a leave's length 0)."""
+        lens = self._dev_lens.copy()
+        for (slot, col), v in self._cells.items():
+            if col == _COL["seq_lens"]:
+                lens[slot] = v
+        return lens
+
+    def stale(self, pending=0) -> bool:
         """Must the next dispatch send the whole mirror? ``pending``:
-        the steps of an undrained window, which the device is ahead."""
+        the steps enqueued and not yet emitted, a count a row (or one
+        for all), which the device is ahead."""
         ahead = np.where(self.seq_lens > 0, self.seq_lens + pending, 0)
         return (self._dev is None or self._stale
                 or len(self._cells) > DELTA_CELLS
-                or not np.array_equal(self._dev_lens, ahead))
+                or not np.array_equal(self._lens_with_cells(), ahead))
 
-    def hand_over(self, pending: int = 0):
+    def hand_over(self, pending=0):
         """(rows, delta, what was sent) for the next dispatch. The ONE
         sanctioned host-to-device transfer of a decode dispatch: the
         whole ``"mirror"`` when ``stale``, else the ``"cells"`` that
@@ -155,9 +191,10 @@ class StepState:
             self._no_delta = jax.device_put(self.pack_delta([]),
                                             self.sharding)
         if self.stale(pending):
-            if pending:
+            if np.any(pending):
                 raise RuntimeError(
-                    "the decode batch changed under a window in flight")
+                    "the step state must go up whole under a program in "
+                    "flight: the loop drains before such a dispatch")
             # (a copy: the mirror is written again before the transfer
             # has to be over)
             rows = jax.device_put(self.host.copy(), self.sharding)
@@ -172,6 +209,7 @@ class StepState:
             return rows, self._no_delta, None
         delta = jax.device_put(self.pack_delta(
             [(s, c, v) for (s, c), v in self._cells.items()]), self.sharding)
+        self._dev_lens = self._lens_with_cells()
         self._cells.clear()
         return rows, delta, "cells"
 

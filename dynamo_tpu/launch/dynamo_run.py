@@ -1137,8 +1137,12 @@ def main(argv=None) -> None:
     p.add_argument("--max-batch", type=int, default=8)
     p.add_argument("--decode-window", type=int, default=4,
                    help="fused decode steps per device dispatch")
-    p.add_argument("--decode-pipeline", action="store_true",
-                   help="overlap host work with the next decode window")
+    p.add_argument("--decode-pipeline", default=True,
+                   action=argparse.BooleanOptionalAction,
+                   help="chained decode (the default): enqueue the next "
+                        "decode window or mixed step before fetching the "
+                        "one before; --no-decode-pipeline is the "
+                        "unchained loop, the ablation")
     p.add_argument("--no-mixed-batch", action="store_true",
                    help="disable fused mixed prefill+decode steps (fall "
                         "back to the alternating chunk/window scheduler)")

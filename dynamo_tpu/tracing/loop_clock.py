@@ -12,15 +12,20 @@ the thunk is timed on its own thread (:meth:`thunk`, split at
 :meth:`enqueued` into ``dispatch`` and ``device``) and :meth:`settle`
 books the await's wall time as dispatch + device + lag.
 
-A *step* is everything between two dispatches' ends (:meth:`step_done`):
-its phase times tile the loop's wall time, and three readers share them:
+A *step* is everything between two programs' results
+(:meth:`step_done`, called where a program's tokens have been emitted;
+the loop has as a rule enqueued the next program by then, so the step is
+booked under the description it is given, the emitted program's, and
+not under the last one described): its phase times tile the loop's wall
+time, and three readers share them:
 
 * always on — cumulative seconds per phase and dispatches per kind in
   the engine's ``stats`` (``/metrics``); by the step's kind, its seconds
   (``idle`` apart), its device steps and its *exposed* seconds, those
-  with no program of the loop's outstanding on the device (below); and
-  ONE warning for a step that took far longer than its kind leads one
-  to expect;
+  with no program of the loop's outstanding on the device (below); by
+  a program's kind, how often it was enqueued *chained*, while another
+  was still outstanding; and ONE warning for a step that took far
+  longer than its kind leads one to expect;
 * under ``--trace`` — one ``engine.step`` span per step in the
   recorder's ring (never handed to the sink: the loop is one endless
   trace), and the same boundaries as ``jax.profiler.TraceAnnotation``
@@ -65,6 +70,8 @@ _STEPS = {k: f"steps_{k}" for k in KINDS.values()}
 #: booked once a step under its kind: (seconds, device steps, exposed)
 _BY_KIND = {k: (f"step_seconds_{k}", f"device_steps_{k}",
                 f"step_exposed_seconds_{k}") for k in KINDS.values()}
+#: a program of the kind enqueued while another was outstanding
+_CHAINED = {k: f"dispatch_chained_{k}" for k in KINDS.values()}
 
 # The slow-step rule: a step is slow when its wall time (idle apart)
 # exceeds what its kind leads one to expect — the mean wall time per
@@ -93,6 +100,7 @@ class LoopClock:
         stats.update(dict.fromkeys(_STEPS.values(), 0), slow_steps=0)
         for seconds, steps, exposed in _BY_KIND.values():
             stats.update({seconds: 0.0, steps: 0, exposed: 0.0})
+        stats.update(dict.fromkeys(_CHAINED.values(), 0))
         self.recorder = recorder
         self.trace = TraceContext.new()  # the loop's own: one per engine
         #: number of the open step; request spans name it (``step=``)
@@ -109,6 +117,9 @@ class LoopClock:
         self._th_thread: Optional[int] = None
         self._th_ann = None
         self._info: dict = {}
+        # did a thunk of the open step dispatch a program for the first
+        # time? (a chained step holds the NEXT program's enqueue)
+        self._step_cold = False
         # programs enqueued by the loop's thunks, how many of them are
         # known done, when the count outstanding rose from 0 (None at
         # 0) and the open step's covered seconds
@@ -200,16 +211,25 @@ class LoopClock:
             out[self.phase] += time.perf_counter() - self._t
         return out
 
-    def step_done(self, **attrs: Any) -> None:
-        """A dispatch and its emission are over: close the step.
-        ``attrs`` go onto its span as they are (an expert model's
-        ``moe``: the routing counters that came back during the step)."""
+    def described(self) -> dict:
+        """What the last dispatch thunk described. A loop that enqueues
+        the next program before it fetches this one keeps it with the
+        program's record and hands it back to :meth:`step_done`."""
+        return self._info
+
+    def step_done(self, info: Optional[dict] = None, **attrs: Any) -> None:
+        """A program's result is emitted: close the step. ``info``: that
+        program's description (:meth:`described`), where another has
+        been described since; ``attrs`` go onto the step's span as they
+        are (an expert model's ``moe``: the routing counters that came
+        back during the step)."""
         if self.phase is None:
             return
         self.mark(self.phase)
         step, self._step = self._step, dict.fromkeys(PHASES, 0.0)
         ts, self._step_ts = self._step_ts, time.time()
-        info, self._info = self._info, {}
+        info, self._info = (self._info if info is None else info), {}
+        step_cold, self._step_cold = self._step_cold, False
         kind, n = info.get("kind", "?"), max(info.get("n", 1), 1)
         dur = sum(step.values())
         busy = dur - step["idle"]
@@ -224,7 +244,7 @@ class LoopClock:
             for name, d in zip(_BY_KIND[kind], (busy, n, exposed)):
                 self.stats[name] += d
             hist = self._history[kind]
-            cold = bool(info.get("cold"))
+            cold = bool(info.get("cold")) or step_cold
             expected = n * sum(hist) / len(hist) if hist else 0.0
             # a kind's first warm steps have nothing to be judged by
             if (hist or cold) and (
@@ -287,6 +307,7 @@ class LoopClock:
         kind = KINDS.get(key[0], key[0])
         self._info = {"kind": kind, "key": key[1:], "cold": cold, "n": n,
                       "live": live, "rows": rows}
+        self._step_cold |= cold
         if self._th_ann is not None:
             self._th_ann.set_metadata(
                 kind=kind, key=str(key[1:]), n=n, live=live)
@@ -298,6 +319,10 @@ class LoopClock:
         if threading.get_ident() != self._th_thread:
             return
         now = time.perf_counter()
+        if self.programs > self._landed and self._info.get("kind") in _CHAINED:
+            # the device has its next program before it is done with the
+            # one before: nothing of this dispatch is exposed
+            self.stats[_CHAINED[self._info["kind"]]] += 1
         self.programs += 1
         if self._out_since is None:
             self._out_since = now

@@ -219,9 +219,11 @@ def main() -> None:
     p.add_argument("--block-size", type=int, default=16)
     p.add_argument("--max-batch", type=int, default=16)
     p.add_argument("--decode-window", type=int, default=8)
-    p.add_argument("--decode-pipeline", action="store_true",
-                   help="overlapped window dispatch (EngineConfig."
-                        "decode_pipeline) — the ablation knob")
+    p.add_argument("--decode-pipeline", default=True,
+                   action=argparse.BooleanOptionalAction,
+                   help="chained decode (EngineConfig.decode_pipeline, "
+                        "the default); --no-decode-pipeline serves the "
+                        "unchained loop — the ablation knob")
     p.add_argument("--quantization", default="none")
     p.add_argument("--kv-cache-dtype", default="model")
     p.add_argument("--cpu", action="store_true",
@@ -288,7 +290,7 @@ def main() -> None:
          "--decode-window", str(args.decode_window),
          "--quantization", args.quantization,
          "--kv-cache-dtype", args.kv_cache_dtype,
-         *(["--decode-pipeline"] if args.decode_pipeline else []),
+         *([] if args.decode_pipeline else ["--no-decode-pipeline"]),
          *tokenizer_args],
         env=env, cwd=REPO, start_new_session=True,
     )

@@ -13,8 +13,9 @@ Runs serve_bench presets through real OS-process servers:
   * tiny / byte tokenizer          (config-1-shaped workload)
   * tiny-mla / byte tokenizer      (config-5's model family)
   * tiny / real WordLevel tokenizer (tokenize + detokenize on the path)
-  * tiny / byte with --decode-pipeline on AND off — the ablation for
-    the default-off knob (VERDICT r4 weak #2): the pair lands in the
+  * tiny / byte with the chained decode loop on (the default) AND off
+    (--no-decode-pipeline) — the ablation of EngineConfig.
+    decode_pipeline (VERDICT r4 weak #2): the pair lands in the
     artifact so the overlap win/loss is a recorded number, not a claim.
 
 Writes benchmarks/serving_cpu.json (full records) and appends one
@@ -42,11 +43,11 @@ PRESETS = [
     dict(name="tiny-mla-byte", args=["--model-path", "tiny-mla"]),
     dict(name="tiny-hf-wordlevel",
          args=["--model-path", "tiny", "--sim-tokenizer"]),
-    # the pipeline ablation's OFF arm IS tiny-byte (identical args) —
-    # running it twice would double-pay a full server spawn for a
-    # duplicate record
-    dict(name="tiny-pipeline-on",
-         args=["--model-path", "tiny", "--decode-pipeline"]),
+    # the pipeline ablation's ON arm IS tiny-byte (identical args: the
+    # chained loop is the default) — running it twice would double-pay
+    # a full server spawn for a duplicate record
+    dict(name="tiny-pipeline-off",
+         args=["--model-path", "tiny", "--no-decode-pipeline"]),
 ]
 COMMON = ["--cpu", "--n", "12", "--isl", "64", "--osl", "24",
           "--concurrency", "4", "--num-blocks", "256", "--max-batch", "8",
@@ -128,13 +129,13 @@ def main():
             print(f"SERVING REGRESSION: {ratio:.2f}x recent median",
                   flush=True)
 
-    # pipeline ablation delta as a first-class field (OFF arm =
-    # tiny-byte, the identical configuration)
-    on = next((r for r in records if r["preset"] == "tiny-pipeline-on"
-               and "error" not in r), None)
-    if base and on and base.get("tokens_per_sec"):
+    # pipeline ablation delta as a first-class field (ON arm =
+    # tiny-byte, the identical configuration but for the knob)
+    off = next((r for r in records if r["preset"] == "tiny-pipeline-off"
+                and "error" not in r), None)
+    if base and off and off.get("tokens_per_sec"):
         summary["pipeline_speedup"] = round(
-            on["tokens_per_sec"] / base["tokens_per_sec"], 4)
+            base["tokens_per_sec"] / off["tokens_per_sec"], 4)
 
     with open(ARTIFACT, "w") as f:
         json.dump({"summary": summary, "records": records,

@@ -104,6 +104,9 @@ CELL_47 = "mellum2.repo"
 #: the two PR 48 added for all five cells: the hand-overs' counters are
 #: what a server before the resident step state lacks
 NEW_IN_48 = ("step_handovers_per_dispatch", "step_state_resident_share")
+#: the one PR 49 added for all five cells: the chained dispatches'
+#: counter is what a server before the chained loop lacks
+NEW_IN_49 = ("decode_chained_share",)
 
 
 def test_benchmark_json_lists_the_five_for_every_cell():
@@ -139,7 +142,7 @@ def test_every_metric_file_passes_a_server_that_lacks_its_series(name, model):
     got = reducer.reduce(ctx, s.get("selector", {}))
     assert got is None or isinstance(got, float)
     if name in EXPECTED or name in (
-            NEW_IN_43 + NEW_IN_44 + NEW_IN_47 + NEW_IN_48):
+            NEW_IN_43 + NEW_IN_44 + NEW_IN_47 + NEW_IN_48 + NEW_IN_49):
         assert got is None
     elif s["reducer"] == "counter_ratio" and not name.startswith(
             ("moe_", "state_")):
@@ -182,22 +185,24 @@ def test_benchmark_json_lists_pr44s_metrics_for_the_expert_cells():
 
 
 def test_benchmark_json_lists_pr47s_metrics_for_its_cell_only():
-    """The three stand last (before PR 48's two), for ``mellum2.repo``
-    alone; the cell is
+    """The three stand where PR 47 appended them (before PR 48's two),
+    for ``mellum2.repo`` alone; the cell is
     appended to ``tpot_mean_ms`` and to the per-layer metrics whose
     instrument the model has (every one that lists all four older cells,
     and the expert layer's counters), and to none of the state's."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [m["name"] for m in bench["per_layer"][-5:-2]] == list(NEW_IN_47)
-    for m in bench["per_layer"][-5:-2]:
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW_IN_47[0])  # entries are only ever appended
+    assert names[at:at + 5] == list(NEW_IN_47 + NEW_IN_48)
+    for m in bench["per_layer"][at:at + 3]:
         s = spec(m["name"])
         assert m["workloads"] == [CELL_47] and m["source"] == "program_counter"
         assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
             s["unit"], s["better"], s["layer"], s["moves"])
         assert s["reducer"] == "counter_ratio" and m["better"] == "lower"
     older = [w["name"] for w in bench["workloads"] if w["name"] != CELL_47]
-    joined = {m["name"] for m in bench["per_layer"][:-5]
+    joined = {m["name"] for m in bench["per_layer"][:at]
               if CELL_47 in m["workloads"]}
     for m in bench["per_layer"][:-5]:
         if CELL_47 in m["workloads"]:
@@ -292,12 +297,14 @@ def test_pr43s_metrics_read_a_server_that_has_their_series(name):
 
 
 def test_benchmark_json_lists_pr48s_metrics_for_every_cell():
-    """The two stand last, for all five cells, under the scheduler."""
+    """The two stand where PR 48 appended them and PR 49's one last, for
+    all five cells, under the scheduler."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
-    assert [m["name"] for m in bench["per_layer"][-2:]] == list(NEW_IN_48)
-    for m in bench["per_layer"][-2:]:
+    assert [m["name"] for m in bench["per_layer"][-3:]] == list(
+        NEW_IN_48 + NEW_IN_49)
+    for m in bench["per_layer"][-3:]:
         s = spec(m["name"])
         assert m["workloads"] == cells and m["source"] == "program_counter"
         assert s["reducer"] == "counter_ratio" and m["layer"] == "scheduler"
@@ -333,3 +340,29 @@ def test_pr48s_metrics_read_a_server_that_has_their_series(name, want):
             assert base.removeprefix("engine_").removesuffix("_total") in (
                 "step_state_resident", "step_state_resyncs")
             assert 'out[f"engine_{name}_total"]' in engine
+
+
+@pytest.mark.parametrize("chained,want", [
+    ((48, 20), 80.0),   # 100 x (48 + 20) / (60 + 25)
+    ((0, 0), 0.0),      # the unchained loop: the series stand at 0
+])
+def test_pr49s_metric_reads_a_server_that_has_its_series(chained, want):
+    """The made-up window above: of its 60 decode windows 48 were
+    enqueued while another program was outstanding, of its 25 mixed
+    steps 20 (a lone prefill or a verify step is neither counted nor
+    divided by). The engine spells the series as the selector does, and
+    a server without it (every one before PR 49) reads nothing."""
+    series = 'engine_dispatch_chained_total{kind="%s"}'
+    delta = dict(DELTA, **{
+        series % "decode_window": chained[0],
+        series % "mixed_step": chained[1],
+        series % "prefill": 0, series % "verify": 0})
+    sel = spec(NEW_IN_49[0])["selector"]
+    assert counter_ratio.reduce({"delta": delta}, sel) == pytest.approx(want)
+    assert counter_ratio.reduce({"delta": DELTA}, sel) is None
+    with open(os.path.join(REPO, "dynamo_tpu", "engine", "engine.py")) as f:
+        engine = f.read()
+    for s in sel["num"] + sel["den"]:
+        base, _, label = s.partition("{")
+        assert label.split('"')[1] in ("decode_window", "mixed_step"), s
+        assert f"'{base}{{{{kind=" in engine, s

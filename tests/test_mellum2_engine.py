@@ -110,10 +110,10 @@ def test_chunked_prefill_and_decode_hold_a_window_not_a_history(forward, tiny):
     peak = [0, 0]
     step_done = engine._step_done
 
-    def watching():
+    def watching(*info):
         full, win = _used(engine)
         peak[0], peak[1] = max(peak[0], full), max(peak[1], win)
-        step_done()
+        step_done(*info)
 
     engine._step_done = watching
     prompt = _prompt(10, 5 * W + 3)
@@ -154,9 +154,9 @@ def test_mixed_steps_and_many_rows_stay_under_the_bound(forward, tiny):
     peak = [0]
     step_done = engine._step_done
 
-    def watching():
+    def watching(*info):
         peak[0] = max(peak[0], engine.wpool.allocator.used_count)
-        step_done()
+        step_done(*info)
 
     engine._step_done = watching
     prompts = [_prompt(20 + i, n) for i, n in enumerate((3 * W, 4 * W + 5, 50))]

@@ -1,10 +1,13 @@
 """The decode batch's resident step state (``engine/step_state.py``):
 the step programs are told of their decode slots through one matrix that
-lives on the device, and a dispatch hands over only what changed.
+lives on the device, and a dispatch hands over only what changed; and
+the chained loop over it (``EngineConfig.decode_pipeline``): a program is
+enqueued before the one before it is fetched, whatever the two are.
 
-The plain reference of (a) is the SAME engine made to send its numpy
-mirror whole before every dispatch, which is what the engine did before
-the state was resident (one ``jnp.asarray`` a field, every dispatch)."""
+The plain reference of (a) is the SAME engine, unchained, made to send
+its numpy mirror whole before every dispatch, which is what the engine
+did before the state was resident (one ``jnp.asarray`` a field, every
+dispatch) and before the loop was chained (enqueue, wait, emit)."""
 
 import asyncio
 
@@ -92,30 +95,43 @@ async def _drive(engine, schedule):
 
 
 def _host_fed(engine):
-    """The reference: every dispatch sends the whole mirror."""
+    """The reference: every dispatch sends the whole mirror (so nothing
+    is ever in flight when it is enqueued: the unchained loop)."""
+    assert not engine.cfg.decode_pipeline
     engine._rows.stale = lambda pending=0: True
     return engine
 
 
 def _watch(engine, seen):
     """After every step: the resident matrix (with the cells still to be
-    sent) equals the mirror on every live row, and holds length 0 and no
-    page on every dead one. A state that is to go up whole says nothing."""
+    sent) IS the mirror, on every row, but for what the program in
+    flight has advanced (a live row's token, and its length and step
+    count by its pending steps; a row that left keeps the token and step
+    count its last program left it, which nothing reads); a dead row
+    holds length 0 and page 0 where a dead row writes. A state that is to
+    go up whole says nothing."""
     done = engine._step_done
 
-    def step_done():
+    def step_done(*info):
         rows = engine._rows
-        if not rows.stale():
+        pend = engine._pending_rows()
+        if not rows.stale(pend):
             dev = np.array(rows._dev)
             for (slot, col), v in rows._cells.items():
                 dev[slot, col] = v
             live = rows.seq_lens > 0
-            np.testing.assert_array_equal(dev[live], rows.host[live])
+            np.testing.assert_array_equal(dev[:, 3:], rows.host[:, 3:])
+            still = live & (pend == 0)
+            np.testing.assert_array_equal(dev[still, :3], rows.host[still, :3])
+            np.testing.assert_array_equal(
+                dev[live, 1:3], rows.host[live, 1:3] + pend[live, None])
             assert not dev[~live, 1].any(), "a dead row has a length"
-            assert not dev[~live, llama.ROW_TABLES:].any(), (
-                "a dead row names a page")
+            for pool in range(len(rows.table_views())):
+                head = [rows.table_column(pool, c) for c in range(rows.head)]
+                assert not dev[~live][:, head].any(), (
+                    "a dead row writes through a page")
             seen.append(int(live.sum()))
-        done()
+        done(*info)
 
     engine._step_done = step_done
 
@@ -169,17 +185,20 @@ MODELS = {
         lambda: _tiny_of("test_mellum2"),
         dict(num_blocks=160, max_context=256, prefill_chunk=16,
              mixed_step_budget=16), 6, False),
+    "olmoe-experts": (lambda: _tiny_of("test_olmoe"),
+                      dict(num_blocks=96), 6, False),
 }
 
 
 @pytest.mark.parametrize("name", list(MODELS))
 def test_streams_equal_the_host_fed_engines(name, monkeypatch):
-    """(a) and (b): a seeded random schedule (admission into a running
-    batch, streams that end inside a window, clients that walk away, page
-    crossings every fourth token, sampled and greedy rows, penalties and
-    logprobs on some) gives the streams of the host-fed engine, token for
-    token and logprob for logprob; and after every step the device's
-    matrix is the mirror."""
+    """(a) and (b): a seeded random schedule (admissions that land while
+    a window is in flight, streams that end inside a window, clients that
+    walk away, page crossings every fourth token, a page that leaves a
+    window while a program is queued, sampled and greedy rows, penalties
+    and logprobs on some) gives, on the chained engine, the streams of
+    the unchained host-fed one, token for token and logprob for logprob;
+    and after every step the device's matrix is the mirror."""
     make, opts, n, penalties = MODELS[name]
     cfg, params = make()
     if "gigachat35" in name:
@@ -189,9 +208,18 @@ def test_streams_equal_the_host_fed_engines(name, monkeypatch):
     base.update(opts)
     long_answers = 90 if "mellum2" in name else 44
     schedule = _schedule(7, cfg.vocab_size, n, penalties, long_answers)
-    engines = [JaxEngine(EngineConfig(model=cfg, **base), params=params)
-               for _ in range(2)]
+    engines = [JaxEngine(EngineConfig(model=cfg, decode_pipeline=pipe, **base),
+                         params=params) for pipe in (True, False)]
     seen = []
+
+    evicted_under = []
+    evict = engines[0]._evict_for_headroom
+
+    def evict_spy(seq):
+        evicted_under.append(engines[0]._inflight)
+        return evict(seq)
+
+    engines[0]._evict_for_headroom = evict_spy
 
     async def main():
         _watch(engines[0], seen)
@@ -209,8 +237,14 @@ def test_streams_equal_the_host_fed_engines(name, monkeypatch):
     # the reference resynchronised at every dispatch, the engine did not
     assert ref["step_state_resident"] == 0 < ref["step_state_resyncs"]
     assert st["step_state_resident"] > st["step_state_resyncs"] // 4
+    assert st["dispatch_chained_" + KINDS["decode"]] > 0
+    assert ref["dispatch_chained_" + KINDS["decode"]] == 0
     if name == "dense-starved":
+        # every request at full length all the same (_assert_same_
+        # streams), and the queued program's blocks went back before
+        # any sequence was evicted: chaining never causes a preemption
         assert st["preemptions"] > 0
+        assert evicted_under and all(w is None for w in evicted_under)
     if name == "mellum2-window-pool":
         assert engines[0].wpool.released > 0, "no page left a window"
 
@@ -233,9 +267,156 @@ def test_pipelined_windows_chain_in_the_resident_state():
         return out, e.stats
 
     piped, st = asyncio.run(main(decode_pipeline=True))
-    plain, _ = asyncio.run(main())
+    plain, _ = asyncio.run(main(decode_pipeline=False))
     _assert_same_streams(piped, plain, schedule)
     assert st["step_state_resident"] > 0
+
+
+# ---------------- the chain: what does not drain it ----------------
+
+
+def _spec(prompt, max_tokens, after=0):
+    return dict(prompt=list(prompt), max_tokens=max_tokens, after=after,
+                so=dict(temperature=0.0), cancel_at=0)
+
+
+def _outstanding(engine) -> int:
+    clk = engine._clock
+    return clk.programs - clk._landed
+
+
+@pytest.mark.parametrize("event", ["a-streams-end", "a-mixed-step"])
+def test_the_chain_is_not_drained_by(event):
+    """Two streams decode together, one ends long before the other, and
+    a third request arrives while the survivor decodes. Neither the end
+    nor the admission's mixed step finds the device without a program:
+    where the host finishes the short stream the next program is already
+    enqueued, the leave goes up as cells (no resynchronisation between it
+    and the next admission), and the mixed step is enqueued behind a
+    window in flight."""
+    cfg, params = _tiny_dense()
+    engine = JaxEngine(EngineConfig(
+        model=cfg, num_blocks=96, block_size=BS, max_batch_size=4,
+        max_context=128, prefill_chunk=32), params=params)
+    schedule = [_spec(range(10, 19), 60), _spec(range(30, 41), 13),
+                _spec(range(50, 70), 10, after=24)]
+    seen = {"ends": [], "mixed": [], "admits": []}
+    finish, mixed, begin = (
+        engine._finish, engine._dispatch_mixed, engine._begin_prefill)
+
+    def finish_spy(seq, reason, emit=True):
+        if seq.slot >= 0 and engine._n_active > 1:
+            seen["ends"].append((_outstanding(engine), seq.generated,
+                                 engine.stats["step_state_resyncs"]))
+        return finish(seq, reason, emit)
+
+    def mixed_spy(packed):
+        if engine._n_active:
+            seen["mixed"].append((_outstanding(engine),
+                                  engine._inflight is not None))
+        return mixed(packed)
+
+    def begin_spy(seq, **kw):
+        seen["admits"].append(engine.stats["step_state_resyncs"])
+        return begin(seq, **kw)
+
+    engine._finish, engine._dispatch_mixed = finish_spy, mixed_spy
+    engine._begin_prefill = begin_spy
+
+    async def main():
+        out = await _drive(engine, schedule)
+        assert engine._n_active == 0
+        await engine.close()
+        return out
+
+    out = asyncio.run(main())
+    assert [len(t) for t, _l, _r in out] == [60, 13, 10]
+    assert engine.stats["preemptions"] == 0
+    if event == "a-streams-end":
+        # the 13-token stream ended beside the long one
+        (outstanding, generated, resyncs), = [
+            e for e in seen["ends"] if e[1] == 13]
+        assert outstanding >= 1, "the end found nothing enqueued behind it"
+        # its leave was cells: the state next went up whole for the third
+        # request's row, not for the slot given up
+        assert seen["admits"][-1] == resyncs
+    else:
+        # the third request's chunk rode a mixed step behind a window
+        # (the second request's rode one too, right behind the first's
+        # own admission: the state had to go up whole there)
+        assert seen["mixed"], "no mixed step beside a running stream"
+        outstanding, inflight = seen["mixed"][-1]
+        assert outstanding >= 1 and inflight
+        assert engine.stats["dispatch_chained_" + KINDS["mixed"]] == sum(
+            out >= 1 for out, _inflight in seen["mixed"]) >= 1
+
+
+@pytest.mark.parametrize("window", [1, 4, 8])
+def test_chained_dispatches_are_counted(window):
+    """A lone stream of 41 tokens: the prefill samples one, the windows
+    (half ``decode_window`` steps each, chained) the other 40. Every
+    window but the first is enqueued while the one before it is
+    outstanding; the last is followed by none (the host knows
+    ``max_tokens``: nothing is queued behind a stream's end)."""
+    cfg, params = _tiny_dense()
+    engine = JaxEngine(EngineConfig(
+        model=cfg, num_blocks=96, block_size=BS, max_batch_size=4,
+        max_context=128, prefill_chunk=32, decode_window=window),
+        params=params)
+
+    async def main():
+        out = await _drive(engine, [_spec(range(10, 20), 41)])
+        await engine.close()
+        return out
+
+    (toks, _lps, reason), = asyncio.run(main())
+    assert len(toks) == 41 and reason == FinishReason.LENGTH
+    steps = max(window // 2, 1)
+    st = engine.stats
+    assert st["steps_" + KINDS["decode"]] == 40 // steps
+    assert st["device_steps_" + KINDS["decode"]] == 40  # no overrun
+    assert st["dispatch_chained_" + KINDS["decode"]] == 40 // steps - 1
+    series = engine.device_path_stats()
+    for kind in KINDS.values():
+        assert series[f'engine_dispatch_chained_total{{kind="{kind}"}}'] == (
+            st["dispatch_chained_" + kind])
+    assert st["dispatch_chained_" + KINDS["prefill"]] == 0
+
+
+@pytest.mark.parametrize("window", [4, 8])
+def test_max_context_is_never_written_past(window):
+    """Rows that run into ``max_context`` with programs chained behind
+    one another: no window is enqueued that would write a position the
+    table does not have (a step past ``max_tokens`` is a discard into
+    page 0, a step past the table is not), and the streams are the
+    unchained engine's, up to the context's last token."""
+    cfg, params = _tiny_dense()
+    schedule = [_spec(range(10, 22), 64), _spec(range(40, 49), 64, after=3),
+                _spec(range(60, 75), 5, after=6)]
+
+    async def main(**kw):
+        engine = JaxEngine(EngineConfig(
+            model=cfg, num_blocks=64, block_size=BS, max_batch_size=4,
+            max_context=32, prefill_chunk=32, decode_window=window, **kw),
+            params=params)
+        dispatch, reached = engine._dispatch_window, []
+
+        def spy(n, tokens_in=None):
+            for seq in engine._active:
+                if seq is not None and not engine._leaving(seq):
+                    reached.append(seq.seq_len + engine._pending(seq) + n)
+            return dispatch(n, tokens_in)
+
+        engine._dispatch_window = spy
+        out = await _drive(engine, schedule)
+        await engine.close()
+        return out, reached
+
+    got, reached = asyncio.run(main())
+    want, _ = asyncio.run(main(decode_pipeline=False))
+    assert max(reached) == 32, "no row ran into the context's end"
+    assert [len(t) for t, _l, _r in got] == [20, 23, 5]
+    assert [t for t, _l, _r in got] == [t for t, _l, _r in want]
 
 
 # ---------------- (c) what a dispatch hands over ----------------
