@@ -2181,7 +2181,7 @@ def _cost_routing_stats() -> dict:
             )
             # ground truth for the constructed overlap view
             assert all(deep.offload.tier_contains(h) for h in chain)
-            assert all(hot.allocator.has_hash(h)
+            assert all(hot.kv.allocator.has_hash(h)
                        for h in chain[:HOT_BLOCKS])
 
             async def decide_and_serve(mode: str):
@@ -2679,7 +2679,7 @@ def _autopilot_stats() -> dict:
             _t, toks_ref = await serve(b, measured)
             overlaps = OverlapScores(scores={2: PREFIX // BS},
                                      total_blocks=isl)
-            assert all(b.allocator.has_hash(h) for h in chain)
+            assert all(b.kv.allocator.has_hash(h) for h in chain)
 
             # pre-pathology baseline scrape (the tail window's base)
             eps0 = scrape()
@@ -2691,7 +2691,7 @@ def _autopilot_stats() -> dict:
                 await collect(b.generate(
                     Context(req(filler(100 + i), max_tokens=2))))
                 await fut
-            assert all(b.allocator.has_hash(h) for h in chain)
+            assert all(b.kv.allocator.has_hash(h) for h in chain)
 
             async def wave(tail_aware: bool):
                 sched = KvScheduler(config=SchedulerConfig(

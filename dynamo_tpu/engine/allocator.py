@@ -339,11 +339,8 @@ class WindowPool:
     page 0 reaches a logit. What falls behind the window is RELEASED as
     the sequence advances, still hashed (under the hash of the full-pool
     block of the same tokens), so that a later prompt can hit it until
-    the block is reused.
-
-    The prefix rule: a prompt hits up to the longest block boundary p
-    such that the full pool holds blocks [0, p) AND the window pool
-    holds the blocks covering [p - window, p)."""
+    the block is reused (``match_tail``: this pool's half of the prefix
+    rule, kv_manager.py)."""
 
     def __init__(self, num_blocks: int, block_size: int, window: int):
         self.allocator = BlockAllocator(num_blocks, block_size)

@@ -53,13 +53,8 @@ from ..resilience.policy import MIGRATION_SIGNAL
 from ..runtime.engine import AsyncEngine, Context
 from .. import tracing
 from ..tracing.loop_clock import KINDS, LoopClock
-from .allocator import (
-    Block,
-    BlockAllocator,
-    WindowPool,
-    model_hash_salt,
-    sequence_block_hashes,
-)
+from .allocator import model_hash_salt, sequence_block_hashes
+from .kv_manager import Hold, KvManager
 from .offload import OffloadManager
 from .step_state import StepState
 
@@ -79,28 +74,10 @@ MOE_COUNTERS = ("moe_expert_slots", "moe_assignments", "moe_experts_touched",
 
 #: the same for a model whose layers carry a per-sequence state (LFM2's
 #: conv layers, GigaChat 3.5's linear-attention layers; absent
-#: otherwise): prompt tokens whose block hashes matched the prefix
-#: cache, snapshots of the state taken (a block committed with its
-#: snapshot, or a chunk's end state kept), admissions that started from
-#: a snapshot; snapshots that lost their row to a newer one, matched
-#: tokens a hit was cut short by for want of a snapshot, and the bytes
-#: of recurrent matrices the step programs read and wrote (the last
-#: three stay 0 for a state that is snapshotted a row a block)
-STATE_COUNTERS = ("prefix_matched_tokens", "state_snapshots",
-                  "state_restores", "state_snapshot_evictions",
-                  "prefix_unsnapshotted_tokens", "linear_state_bytes")
-
-#: the same for a model whose window layers' KV lives in a pool of its
-#: own (``ModelConfig.window_kv_pool``; absent otherwise), all counted on
-#: the host: prompt tokens whose block hashes the full pool matched and
-#: those of them the window pool could not back (the hit was cut short);
-#: booked once a decode or mixed step over the live rows, the tokens of
-#: window-layer KV a row holds against its context's length, and the
-#: pages the window layers' kernels walk for it against the pages its
-#: context spans
-WINDOW_COUNTERS = ("prefix_matched_tokens", "prefix_window_missed_tokens",
-                   "kv_window_resident_tokens", "kv_window_context_tokens",
-                   "attn_window_pages", "attn_window_context_pages")
+#: otherwise), beside the KV manager's (kv_manager.SNAPSHOT_COUNTERS):
+#: admissions that started from a snapshot, and the bytes of recurrent
+#: matrices the step programs read and wrote (0 for conv rows only)
+STATE_COUNTERS = ("state_restores", "linear_state_bytes")
 
 # the prefill-admission first-token sampler, jitted ONCE at module scope:
 # a per-call ``jax.jit(sample_first_token)`` built a fresh wrapper (and a
@@ -158,7 +135,7 @@ def _dequant_gathered(pages, scales, dtype):
 def _begin_state_row(state, slot, snap_row):
     """A sequence takes row ``slot`` of the state (llama.init_state) for
     its prefill: snapshot ``snap_row`` (of the last block of its cached
-    prefix: ``SnapshotPool.row_of``), every part of it, or zeros for a
+    prefix: ``KvManager.restore_from``), every part of it, or zeros for a
     prompt that starts from token 0 (``snap_row`` < 0)."""
     at = jnp.maximum(snap_row, 0)
     out = dict(state)
@@ -168,86 +145,6 @@ def _begin_state_row(state, slot, snap_row):
         out["rec"] = state["rec"].at[:, slot].set(
             jnp.where(snap_row >= 0, state["snap_rec"][:, at], 0))
     return out
-
-
-class SnapshotPool:
-    """Which KV block's state snapshot lies in which row of the state's
-    snapshot pool (``llama.init_state``'s ``snap`` arrays): the host
-    side of ONE mechanism for every kind of state.
-
-    WHERE a snapshot can be taken is the kind of state's rule
-    (``llama.StateTrack``). A state the programs can write at every
-    block end (``at_block_ends``: a conv layer's window of rows) has a
-    row a block, which it is small enough for: the pool is ``dense``,
-    the row IS the block id and every committed block has its snapshot.
-    A state that exists at a chunk's end only (a recurrent matrix) is a
-    map with LRU reuse, whatever the pool's size: a row is taken
-    (``take``) for the block at which a prefill chunk ends, the program
-    writes the chunk's end state there, and a block whose row went to a
-    newer snapshot has none any more. An entry carries the block's
-    chained hash, so a block id the allocator recycled for other content
-    does not answer for the old one. A prefix hit counts up to the last
-    matched block for which ``row_of`` answers."""
-
-    def __init__(self, rows: int, num_blocks: int, at_block_ends: bool = False):
-        if at_block_ends and rows < num_blocks:
-            raise ValueError(
-                f"a state snapshotted at every block end needs a row a "
-                f"block: {rows} rows for {num_blocks} blocks")
-        self.rows = rows
-        self.dense = at_block_ends
-        self._by_block: OrderedDict = OrderedDict()  # idx -> [row, hash]
-        self._free = list(range(rows - 1, -1, -1))
-        self._pins: dict[int, int] = {}
-        self.evictions = 0
-
-    def row_of(self, block: Block) -> int:
-        """The row that holds ``block``'s snapshot, or -1."""
-        if self.dense:
-            return block.idx
-        e = self._by_block.get(block.idx)
-        if e is None or e[1] is None or e[1] != block.seq_hash:
-            return -1
-        self._by_block.move_to_end(block.idx)
-        return e[0]
-
-    def take(self, block: Block) -> int:
-        """A row for the snapshot about to be written for ``block``: the
-        one it has, a free one, or the least recently used unpinned one
-        (its block loses its snapshot); -1 if every row is pinned."""
-        e = self._by_block.pop(block.idx, None)
-        if e is not None:
-            row = e[0]
-        elif self._free:
-            row = self._free.pop()
-        else:
-            victim = next((b for b, (r, _h) in self._by_block.items()
-                           if r not in self._pins), None)
-            if victim is None:
-                return -1
-            row = self._by_block.pop(victim)[0]
-            self.evictions += 1
-        self._by_block[block.idx] = [row, block.seq_hash]
-        return row
-
-    def bind(self, block: Block) -> None:
-        """``block`` was committed: its pending snapshot (taken before
-        the block had a hash) answers for this content from now on."""
-        e = self._by_block.get(block.idx)
-        if e is not None and e[1] is None:
-            e[1] = block.seq_hash
-
-    def pin(self, row: int) -> None:
-        """An admission will restore from ``row``: it is not reused
-        until ``unpin``."""
-        self._pins[row] = self._pins.get(row, 0) + 1
-
-    def unpin(self, row: int) -> None:
-        n = self._pins.get(row, 0) - 1
-        if n > 0:
-            self._pins[row] = n
-        else:
-            self._pins.pop(row, None)
 
 
 @jax.jit
@@ -359,11 +256,10 @@ class EngineConfig:
     # for token the same; under pool starvation the overlapped schedule
     # can shift WHICH sequence a genuine preemption picks, and a replay
     # whose prefix blocks were evicted recomputes with other reduction
-    # orders) and the ablation of scripts/serve_bench.py
-    # (benchmarks/serving_cpu.json: pipeline_speedup, ~1.0x on a CPU
-    # where dispatch gaps are a tiny share of a step). On the chip:
-    # PERF.md section 6, PR 49. ROADMAP C3: a simplicity PR deletes the
-    # field once the ledger has PR 49's lines.
+    # orders). On the chip the chained loop wins in every cell (ledger,
+    # PR 49; PERF.md section 6). ROADMAP C3: the reference stays; the
+    # user's switch and the mirror's frozen form go with C8's verdict
+    # on the mirror.
     decode_pipeline: bool = True
     # speculative decoding via prompt-lookup (n-gram) drafts: propose up
     # to spec_gamma continuation tokens from the sequence's own history
@@ -385,8 +281,8 @@ class EngineConfig:
     state_snapshots: int = 0
     # blocks of the window pool of a model whose window layers keep their
     # KV in a pool of their own (ModelConfig.window_kv_pool); 0 = derived
-    # (window_pool_blocks: what the decode slots and one lone prefill
-    # hold at most, and a window a slot of cached tails). How many
+    # (kv_manager.window_pool_blocks: what the decode slots and one lone
+    # prefill hold at most, and a window a slot of cached tails). How many
     # contexts' tails stay hittable beyond that is the deployment's to
     # say, as num_blocks is for the histories
     window_blocks: int = 0
@@ -510,29 +406,6 @@ class EngineConfig:
         ) // self.block_size
 
 
-def window_pool_blocks(model: ModelConfig, max_batch: int, block_size: int,
-                       mixed_budget: int, prefill_chunk: int,
-                       asked: int = 0) -> int:
-    """The window pool's size (0: the model has no window pool). Derived:
-    what every decode slot holds at most while a mixed step's chunk
-    advances it (window + chunk, and a block of slack) and one lone
-    prefill's chunk on top (the FLOOR: under it a sequence could find no
-    block for its window), a window a slot of cached tails (what a
-    prefix hit needs of a context that nobody holds any more), plus page
-    0. ``asked`` (``EngineConfig.window_blocks``) replaces the derived
-    size and may not lie under the floor."""
-    if not model.window_kv_pool:
-        return 0
-    blocks = lambda tokens: -(-tokens // block_size)  # noqa: E731
-    floor = (max_batch * (blocks(model.kv_window + mixed_budget) + 1)
-             + blocks(prefill_chunk) + 1)
-    if asked and asked < floor:
-        raise ValueError(
-            f"window_blocks={asked} is under the {floor} blocks that "
-            f"{max_batch} slots' windows and chunks can hold at once")
-    return asked or floor + max_batch * blocks(model.kv_window)
-
-
 class OutOfBlocks(Exception):
     """KV pool exhausted — caller should backpressure/retry (the prefill
     queue nacks the item so another worker, or this one later, retries)."""
@@ -553,16 +426,12 @@ class _Sequence:
     out_queue: asyncio.Queue
     tokens: list[int] = field(default_factory=list)  # prompt + generated
     prompt_len: int = 0
-    blocks: list[Block] = field(default_factory=list)
-    # the window pool's blocks by position (allocator.WindowPool; None:
-    # behind the window, released); empty for a model with one pool
-    wblocks: list = field(default_factory=list)
-    wfloor: int = 0  # its first entry that may still hold a block
-    wcold: range = range(0)  # its entries that go back cold (cold_entries)
-    committed: int = 0  # number of blocks committed (full+hashed)
-    parent_hash: Optional[int] = None
+    # what it holds in the pools (kv_manager.Hold): KvManager.reserve's
+    # answer, empty before it and after KvManager.release
+    hold: Hold = field(default_factory=Hold)
     generated: int = 0
-    cached_prefix: int = 0  # tokens served from prefix cache
+    # tokens the scheduler need not prefill (reserve's answer too)
+    cached_prefix: int = 0
     slot: int = -1  # decode batch slot
     # multi-LoRA lane: resolved adapter slot in the device stack (-1 =
     # base model, no delta) and the public model name the request
@@ -574,21 +443,12 @@ class _Sequence:
     # the row of the conv state (a decode slot's) this sequence holds
     # from its first prefill chunk on; -1: none (no conv layers)
     state_slot: int = -1
-    # a state snapshotted at a chunk's end only (SnapshotPool, not
-    # dense): the token counts at which a prefill chunk of this sequence
-    # has to end and leave a snapshot, and the row its first chunk
-    # restores (-1: starts from zeros)
-    snap_points: tuple = ()
-    restore_row: int = -1
     finished: bool = False
     arrival_t: float = field(default_factory=time.monotonic)
     # request trace (tracing.TraceContext), captured at generate() entry
     # while the caller's contextvar is still in scope; None = untraced,
     # and every hot-path instrumentation site gates on that None first
     trace: Optional[object] = None
-    # full-pool blocks a prefix hit was cut short by for want of their
-    # tail in the window pool (``engine.prefill``'s ``window_cut``)
-    window_cut: int = 0
 
     @property
     def seq_len(self) -> int:
@@ -607,8 +467,12 @@ class JaxEngine(AsyncEngine):
     ):
         self.cfg = cfg
         mcfg = cfg.model
-        self._refuse_stateful(mirror)
-        self._refuse_window_pool(mirror)
+        # what a sequence holds in the pools, who may hit it, and what
+        # cannot serve a model whose sequences hold more than one paged
+        # cache (refused in there, by name): engine/kv_manager.py
+        self.kv = KvManager(
+            cfg, mirror=mirror, snapshot_rows=llama.state_snapshot_rows(
+                mcfg, cfg.num_blocks, cfg.state_snapshots))
         # multi-host: a StepMirror (parallel/multihost.py) makes this engine
         # the leader of a process-spanning mesh — every device dispatch is
         # broadcast to follower ranks which replay the identical jit call
@@ -641,21 +505,19 @@ class JaxEngine(AsyncEngine):
         else:
             k, v = llama.init_kv_cache(
                 mcfg, cfg.num_blocks, cfg.block_size, dtype=cache_dt,
-                window_blocks=self._window_blocks(),
+                window_blocks=self.kv.window_blocks,
             )
             sh = self.layout.cache_sharding(self.mesh)
             if sh is not None:
                 k, v = jax.device_put(k, sh), jax.device_put(v, sh)
         self.k_cache, self.v_cache = k, v
         # the per-sequence state that is not keys and values (LFM2's
-        # conv layers; None otherwise): a row a decode slot, and a
-        # snapshot a KV block (llama.init_state). A sequence holds its
-        # row from its first prefill chunk on (_Sequence.state_slot)
+        # conv layers; None otherwise): a row a decode slot, and the
+        # snapshot pool's rows (llama.init_state; which block's snapshot
+        # lies in which row is the KV manager's to say). A sequence holds
+        # its row from its first prefill chunk on (_Sequence.state_slot)
         self.state = llama.init_state(
             mcfg, cfg.max_batch_size, cfg.num_blocks, cfg.state_snapshots)
-        self.snapshots = None if self.state is None else SnapshotPool(
-            self.state["snap"].shape[0], cfg.num_blocks,
-            at_block_ends=mcfg.conv_layers > 0)
         # bytes of recurrent matrices ONE sequence holds (0: conv rows only)
         rec = (self.state or {}).get("rec")  # [Ll, max_batch, Hv, Dk, Dv]
         self._rec_row_bytes = 0 if rec is None else (
@@ -705,16 +567,6 @@ class JaxEngine(AsyncEngine):
                     plane, NamedSharding(self.mesh, PartitionSpec())
                 )
             self.k_scales, self.v_scales = plane, plane
-        self.allocator = BlockAllocator(cfg.num_blocks, cfg.block_size)
-        # window and full layers in one model (ModelConfig.window_kv_pool):
-        # the window layers' KV in a pool of its own, by window and not by
-        # history (allocator.WindowPool). k_cache / v_cache are then PAIRS
-        # (full, window), as the step programs take them, and so are the
-        # block tables they are handed (_tables)
-        self.wpool: Optional[WindowPool] = None
-        if mcfg.window_kv_pool:
-            self.wpool = WindowPool(
-                self._window_blocks(), cfg.block_size, mcfg.kv_window)
         # recycled pages must not inherit a previous tenant's absmax
         # scale: every fresh-mutable allocation queues a scale reset,
         # flushed as ONE scatter on the next dispatch preamble
@@ -728,7 +580,7 @@ class JaxEngine(AsyncEngine):
         # decode-throughput EMA for the low-precision lane (lowprec_tok_s)
         self._lowprec_rate_t = 0.0
         if self.k_scales is not None:
-            self.allocator.on_allocated = self._pending_scale_resets.append
+            self.kv.allocator.on_allocated = self._pending_scale_resets.append
             # bytes one token's K+V rows save landing int8 instead of
             # full width (per-page scale overhead is L*8 bytes per block
             # against Hkv*D*bs*itemsize — sub-1% — and is counted in
@@ -748,10 +600,10 @@ class JaxEngine(AsyncEngine):
         # transfer-cost calibration (kv_router/costmodel.py): one model
         # per engine, fed by the restore/pull/handoff/prefill paths and
         # advertised through load_metrics. Block bytes from the real
-        # cache geometry (k and v differ for MLA latents).
-        k_full, v_full = (
-            (self.k_cache[0], self.v_cache[0]) if self.wpool is not None
-            else (self.k_cache, self.v_cache))
+        # cache geometry (k and v differ for MLA latents; a model with a
+        # window pool has PAIRS of caches, the full pool's first).
+        k_full = jax.tree.leaves(self.k_cache)[0]
+        v_full = jax.tree.leaves(self.v_cache)[0]
         self.kv_block_bytes = int(
             (k_full.nbytes + v_full.nbytes) // max(cfg.num_blocks, 1)
         )
@@ -790,11 +642,7 @@ class JaxEngine(AsyncEngine):
                     else str(self.k_cache.dtype)
                 ),
             )
-            self.allocator.on_evict = lambda h, b: self.offload.on_evict(h, b.idx)
-            # tier-drop removals re-check device residency before
-            # publishing (offload.flush_dropped): a stale lower-tier
-            # copy aging out must not un-index a device-resident block
-            self.offload.device_has = self.allocator.has_hash
+            self.kv.attach_offload(self.offload)
             if self.k_scales is not None:
                 # publish the scale planes so tier traffic speaks the
                 # device codec: flushes gather int8 pages + scales (an
@@ -890,7 +738,7 @@ class JaxEngine(AsyncEngine):
         # device's resident copy and what differs (engine/step_state.py)
         self._rows = StepState(
             cfg.max_batch_size, cfg.max_blocks_per_seq,
-            window=self.wpool is not None, sharding=self._rows_sharding(),
+            window=mcfg.window_kv_pool, sharding=self._rows_sharding(),
             head=-(-max(cfg.decode_window, 1) // cfg.block_size))
         # sampling penalties (vLLM semantics — see ops/sampling):
         # device [B, V] output-token counts + prompt-membership mask,
@@ -1001,9 +849,6 @@ class JaxEngine(AsyncEngine):
             self.stats.update(dict.fromkeys(MOE_COUNTERS, 0))
         if self.state is not None:
             self.stats.update(dict.fromkeys(STATE_COUNTERS, 0))
-        if self.wpool is not None:
-            self.stats.update(dict.fromkeys(WINDOW_COUNTERS, 0))
-        self._window_released = 0  # of the open step, for its span
         # the loop's clock (tracing/loop_clock.py): seconds by phase,
         # dispatches by kind and slow steps, kept in self.stats
         self._clock = LoopClock(self.stats, tracing.RECORDER)
@@ -1060,92 +905,6 @@ class JaxEngine(AsyncEngine):
                         path["reason"])
         self.attention_path = path
         return not why_not
-
-    def _refuse_stateful(self, mirror) -> None:
-        """A model whose layers carry a per-sequence state beside keys
-        and values (LFM2's conv layers) is served by the scheduler, the
-        allocator, the prefix cache, chunked and mixed prefill and
-        preemption. What cannot carry the state yet refuses the model
-        here, by name — none of it is bypassed in silence."""
-        cfg = self.cfg
-        if not cfg.model.state_layers:
-            return
-        kind = ("linear-attention" if cfg.model.linear_layers else "conv")
-        asked = [name for name, on in (
-            ("spec_gamma (the verify forward)", cfg.spec_gamma > 0),
-            ("ring_prefill_threshold (ring prefill)",
-             cfg.ring_prefill_threshold > 0),
-            ("mesh (tp / ep / pp / sp sharding)", cfg.mesh is not None),
-            ("the multi-host mirror", mirror is not None),
-            ("host_cache_blocks / disk_cache_blocks (the KV tiers)",
-             cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0),
-            ("adapters", bool(cfg.adapters)),
-            ("kv_cache_dtype=int8 (the scale planes)",
-             cfg.kv_cache_dtype == "int8"),
-        ) if on]
-        if asked:
-            raise ValueError(
-                f"{', '.join(asked)}: not supported for a model with {kind} "
-                f"layers ({cfg.model.state_layers} of "
-                f"{cfg.model.num_layers} here): their per-sequence state "
-                "rides the scheduler, the allocator and the prefix cache "
-                "only (no model-native drafting either: the verify "
-                "forward cannot roll a state back)")
-
-    def _refuse_window_pool(self, mirror) -> None:
-        """A model whose window layers keep their KV in a pool of their
-        own (``ModelConfig.window_kv_pool``: two caches, two block tables
-        a sequence) is served by the scheduler, both allocators, the
-        prefix cache, chunked and mixed prefill and preemption. What
-        moves, shards or re-encodes ONE cache under ONE table refuses the
-        model here, by name (and ``_no_window_transfer`` at the call)."""
-        cfg = self.cfg
-        if not cfg.model.window_kv_pool:
-            return
-        asked = [name for name, on in (
-            ("host_cache_blocks / disk_cache_blocks (the KV tiers)",
-             cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0),
-            ("mesh (tp / ep / pp / sp sharding)", cfg.mesh is not None),
-            ("the multi-host mirror", mirror is not None),
-            ("ring_prefill_threshold (ring prefill)",
-             cfg.ring_prefill_threshold > 0),
-            ("kv_cache_dtype=int8 (the scale planes)",
-             cfg.kv_cache_dtype == "int8"),
-            ("spec_gamma (the verify forward)", cfg.spec_gamma > 0),
-            ("adapters", bool(cfg.adapters)),
-        ) if on]
-        if asked:
-            raise ValueError(
-                f"{', '.join(asked)}: not supported for a model with window "
-                "and full attention layers in two KV pools "
-                f"({cfg.model.kv_pool_layers[1]} window layers of "
-                f"{cfg.model.num_layers} here): the window pool rides the "
-                "scheduler, the allocators and the prefix cache only")
-
-    def _no_window_transfer(self, what: str) -> None:
-        """The disaggregation, fleet-prefix and resharding hooks move ONE
-        cache's blocks between engines; the window pool has no lane
-        there."""
-        if self.wpool is not None:
-            raise ValueError(
-                f"{what}: not supported for a model with window and full "
-                "attention layers in two KV pools (the KV wire carries one "
-                "cache under one block table)")
-
-    def _window_blocks(self) -> int:
-        cfg = self.cfg
-        return window_pool_blocks(
-            cfg.model, cfg.max_batch_size, cfg.block_size,
-            cfg.mixed_step_budget, cfg.prefill_chunk, cfg.window_blocks)
-
-    def _no_state_transfer(self, what: str) -> None:
-        """The disaggregation and resharding hooks move keys and values
-        between engines; a conv layer's state has no lane there."""
-        if self.state is not None:
-            raise ValueError(
-                f"{what}: not supported for a model with "
-                f"{'linear-attention' if self.cfg.model.linear_layers else 'conv'}"
-                " layers (the KV wire carries no per-sequence state)")
 
     def _pallas_gate(self, mesh) -> Optional[str]:
         """None when the Pallas kernels serve ``mesh``; otherwise the
@@ -1237,8 +996,8 @@ class JaxEngine(AsyncEngine):
             "engine_decode_steps_total": self.stats["decode_steps"],
             "engine_slow_steps_total": self.stats["slow_steps"],
             "engine_preemptions_total": self.stats["preemptions"],
-            "engine_kv_pages_used": self.allocator.used_count,
-            "engine_kv_pages_total": self.allocator.num_blocks - 1,
+            "engine_kv_pages_used": self.kv.allocator.used_count,
+            "engine_kv_pages_total": self.kv.allocator.num_blocks - 1,
         }
         for phase, s in self._clock.totals().items():
             out[f'engine_loop_seconds_total{{phase="{phase}"}}'] = round(s, 6)
@@ -1263,13 +1022,10 @@ class JaxEngine(AsyncEngine):
             out[f"engine_{name}_total"] = self.stats[name]
         for name in WORK_COUNTERS + (MOE_COUNTERS if self._moe_layers else ()):
             out[f"engine_{name}_total"] = self.stats[name]
-        if self.wpool is not None:
-            for name in WINDOW_COUNTERS:
-                out[f"engine_{name}_total"] = self.stats[name]
-            for pool, a in zip(("full", "window"), self._allocators()):
-                for state, v in a.state_counts().items():
-                    out[f'engine_kv_pool_blocks{{pool="{pool}",'
-                        f'state="{state}"}}'] = v
+        # the KV manager's own (a model with a window pool or a state)
+        for name, v in self.kv.stats.items():
+            out[f"engine_{name}_total"] = v
+        out.update(self.kv.gauges())
         if self.state is not None:
             for name in STATE_COUNTERS:
                 out[f"engine_{name}_total"] = self.stats[name]
@@ -1514,9 +1270,6 @@ class JaxEngine(AsyncEngine):
             model=model_name,
             trace=tracing.current_trace() if tracing.enabled() else None,
         )
-        # the chain's root is the model's salted namespace from the very
-        # first committed block (None for base = pre-multi-model bytes)
-        seq.parent_hash = model_hash_salt(model_name)
         resume = (
             req.annotations.get("resume")
             if isinstance(req.annotations, dict) else None
@@ -1709,7 +1462,8 @@ class JaxEngine(AsyncEngine):
         # --sanitize (or the test suite) they surface loop stalls and
         # worst lock holds through the scrape -> metrics-gauge plane
         out.update(sanitizer.counters())
-        return out | {
+        kv_share, kv_used, kv_total = self.kv.usage()
+        return out | self.kv.stats | {
             # mixed-batch fusion activity (prefill chunks riding decode
             # steps, and how many prompt segments packed into them) —
             # lets the router/metrics plane see whether decode ITL is
@@ -1717,11 +1471,10 @@ class JaxEngine(AsyncEngine):
             # prompts are advancing together or head-of-line blocking
             "mixed_steps": self.stats["mixed_steps"],
             "mixed_prefill_segments": self.stats["mixed_prefill_segments"],
-            # (both pools, where the window layers have one of their own)
-            "kv_active_blocks": sum(a.used_count for a in self._allocators()),
-            "kv_total_blocks": sum(
-                a.num_blocks - 1 for a in self._allocators()),
-            "gpu_cache_usage_perc": self._kv_usage(),  # dynlint: disable=unscraped-stat -- reference-schema compat key (vLLM ForwardPassMetrics); consumers derive usage from kv_active/kv_total
+            # (over the pools, where the window layers have one of their own)
+            "kv_active_blocks": kv_used,
+            "kv_total_blocks": kv_total,
+            "gpu_cache_usage_perc": kv_share,  # dynlint: disable=unscraped-stat -- reference-schema compat key (vLLM ForwardPassMetrics); consumers derive usage from kv_active/kv_total
             "request_active_slots": self._n_active,
             "request_total_slots": self.cfg.max_batch_size,
             "num_requests_waiting": self._waiting_size(),
@@ -1911,8 +1664,7 @@ class JaxEngine(AsyncEngine):
         Multi-host mirrors raise :class:`ReshardUnsupported` — their
         callers drain-with-handoff instead.  Returns the morph stats
         dict ({"changed", "kv_moved_blocks", "hold_ms", ...})."""
-        self._no_state_transfer("reshard")
-        self._no_window_transfer("reshard")
+        self.kv.refuse_transfer("reshard")
         if self.mirror is not None:
             raise ReshardUnsupported(
                 "multi-host mirrored engines cannot morph live; drain "
@@ -2103,7 +1855,7 @@ class JaxEngine(AsyncEngine):
         self.use_pallas = new_use_pallas
         # dynflow: end-commit-block
         self._rows.move(self._rows_sharding())
-        moved = self.allocator.resident_count
+        moved = self.kv.allocator.resident_count
         self.stats["resharded_total"] += 1
         self.stats["reshard_kv_moved_blocks"] += moved
         # SLO observatory invalidation: every jit program recompiles
@@ -2341,7 +2093,7 @@ class JaxEngine(AsyncEngine):
                 # device failure on THIS request (oom, compile error): fail
                 # it alone — the loop and other requests keep going
                 logger.exception("prefill failed for request %s", seq.context.id)
-                self._free_blocks(seq)
+                self.kv.release(seq.hold)
                 seq.out_queue.put_nowait(
                     LLMEngineOutput(finish_reason=FinishReason.ERROR)
                 )
@@ -2356,11 +2108,8 @@ class JaxEngine(AsyncEngine):
                 # pool can never admit (e.g. preempted late with a grown
                 # token list, or an oversized prompt) — finish it rather
                 # than head-of-line-block the queue forever.
-                bs = self.cfg.block_size
-                min_needed = min(
-                    (seq.seq_len + bs) // bs + 1, self.cfg.max_blocks_per_seq
-                )
-                if min_needed > self.allocator.num_blocks - 1:
+                min_needed = self.kv.blocks_for(seq.seq_len)
+                if min_needed > self.kv.allocator.num_blocks - 1:
                     # a fresh prompt that can never fit is a capacity ERROR
                     # (like prompts >= max_context); a preempted sequence
                     # that outgrew the pool already streamed real tokens,
@@ -2373,7 +2122,7 @@ class JaxEngine(AsyncEngine):
                         "request %s needs %d blocks but the pool holds %d — "
                         "finishing as %s",
                         getattr(seq.context, "id", "?"), min_needed,
-                        self.allocator.num_blocks - 1, reason,
+                        self.kv.allocator.num_blocks - 1, reason,
                     )
                     self._finish(seq, reason)
                     continue
@@ -2421,168 +2170,32 @@ class JaxEngine(AsyncEngine):
             and not cfg.model.attn_softcap
         )
 
-    def _reserve_for_prompt(self, seq: _Sequence, probe_host: bool = False,
-                            hashes=None):
-        """The one allocation protocol shared by local prefill, remote
-        prefill (worker side) and remote decode (decode side): match the
-        device prefix cache on the prompt's full blocks (always recompute
-        the final token so prefill yields fresh last-position logits),
-        optionally probe the host offload tier for the chain's
-        continuation — the reserved chain starts its h2d upload HERE, so
-        by the time the prefill chunk needs the pages the transfer has
-        (usually) already landed — then allocate fresh blocks for prompt
-        + decode headroom. Populates seq.{blocks,committed,parent_hash,
-        cached_prefix}; returns (history, upload_or_None) or None with
-        every claim rolled back."""
-        cfg = self.cfg
-        bs = cfg.block_size
-        prompt = seq.tokens
-        # ``hashes`` may carry the chain the caller already computed
-        # (admission's prejoin) so long prompts hash once, not twice
-        # the adapter's name salts the chain root (allocator.
-        # model_hash_salt): a token-identical prompt under two models
-        # hashes to disjoint chains, so cross-model prefix hits are
-        # structurally impossible — here, in the reuse pool, and on
-        # every plane that speaks these hashes (radix index, peer pulls)
-        all_hashes = hashes if hashes is not None else (
-            sequence_block_hashes(
-                prompt[: len(prompt) - 1], bs,
-                salt=model_hash_salt(seq.model),
-            )
-        )
-        matched = self.allocator.match_prefix(
-            prompt[: len(prompt) - 1], hashes=all_hashes
-        )
-        wtail: list = []
-        if self.wpool is not None:
-            # the prefix rule: a prompt hits up to the longest block
-            # boundary p such that the full pool holds blocks [0, p) AND
-            # the window pool holds the blocks covering [p - window, p)
-            p, wtail = self.wpool.match_tail(all_hashes, len(matched))
-            self.stats["prefix_matched_tokens"] += len(matched) * bs
-            self.stats["prefix_window_missed_tokens"] += (
-                len(matched) - p) * bs
-            seq.window_cut = len(matched) - p
-            self.allocator.free(matched[p:])
-            matched = matched[:p]
-        if self.offload is not None and matched:
-            # blocks that reached the device tier via a router prefetch
-            # hint and are now claimed: the hint saved this request a
-            # cold host restore (or a full recompute). The claimed
-            # hashes ride along so peer-pulled blocks count toward
-            # peer_pull_hidden_frac (their cross-worker transfer was
-            # fully hidden from this request)
-            n_pf = 0
-            pf_hashes = []
-            for b in matched:
-                if b.prefetched:
-                    b.prefetched = False
-                    n_pf += 1
-                    pf_hashes.append(b.seq_hash)
-            if n_pf:
-                self.offload.note_prefetch_hits(n_pf, hashes=pf_hashes)
-        # host-tier probe: continuation of the chain past the device match
-        # (ref docs/kv_cache_manager.md host offload); reserving takes the
-        # blocks out of the pool so they can't be LRU'd before restore
-        restore_hashes: list[int] = []
-        restore_data: list = []
-        if probe_host and self.offload is not None:
-            tail = [s for _l, s in all_hashes[len(matched) :]]
-            restore_hashes, restore_data = self.offload.reserve_chain(tail)
-        total_needed = min(
-            (len(prompt) + bs) // bs + 1, cfg.max_blocks_per_seq
-        )
-        fresh = self.allocator.allocate(max(0, total_needed - len(matched)))
-        if fresh is None:
-            self.allocator.free(matched)
-            if self.wpool is not None:
-                self.wpool.free(wtail)
-            if self.offload is not None and restore_hashes:
-                self.offload.unreserve(restore_hashes, restore_data)
+    def _reserve(self, seq: _Sequence, probe_host: bool = False,
+                 hashes=None):
+        """``seq``'s hold in the pools (``KvManager.reserve``: prefix
+        hits, the host tier's probe, fresh blocks). Returns (history,
+        upload or None), or None with nothing held."""
+        reserved = self.kv.reserve(
+            seq.tokens, model_hash_salt(seq.model), hashes=hashes,
+            probe_host=probe_host)
+        if reserved is None:
             return None
-        seq.blocks = matched + fresh
-        # (the claimed tail, behind Nones: nothing in front of it is held)
-        seq.wblocks = wtail
-        seq.wfloor = len(wtail) - sum(b is not None for b in wtail)
-        if self.wpool is not None:
-            seq.wcold = self.wpool.cold_entries(len(wtail), len(prompt))
-        seq.committed = len(matched)
-        n_hit = len(matched)
-        if self.state is not None:
-            # what the hashes matched, counted where they match: what a
-            # prefill then skips of it (prefix_cache_hits_tokens) is
-            # _begin_prefill's to say
-            self.stats["prefix_matched_tokens"] += len(matched) * bs
-            # a hit counts up to the last matched block that holds a
-            # snapshot of the state: the tokens behind it are computed
-            # again (into the matched blocks they already lie in, with
-            # the same values) to rebuild the state, and the chunk that
-            # reaches the end of the match leaves the snapshot the next
-            # asker restores (SnapshotPool; a dense pool cuts nothing)
-            pool = self.snapshots
-            seq.restore_row = -1
-            while n_hit and (row := pool.row_of(matched[n_hit - 1])) < 0:
-                n_hit -= 1
-            if n_hit:
-                seq.restore_row = row
-            self.stats["prefix_unsnapshotted_tokens"] += (
-                len(matched) - n_hit) * bs
-            points = set()
-            if not pool.dense:
-                if n_hit < len(matched):
-                    points.add(len(matched) * bs)
-                # the prompt's last full block: what a repeat or an
-                # extension of this prompt will match. Not for a prompt
-                # of under two blocks: the cut is one more dispatch, and
-                # what a repeat would skip is less than a dispatch costs
-                # (it also keeps a 2-block prompt in the bucket a
-                # harness warms with it)
-                if len(prompt) >= 2 * bs:
-                    points.add(len(prompt) // bs * bs)
-                if restore_hashes:  # (refused with tiers; belt and braces)
-                    points.clear()
-            seq.snap_points = tuple(sorted(
-                p for p in points if p > n_hit * bs))
-        # no device match: the chain restarts from its model-salted root
-        # (None for base traffic — byte-identical to pre-multi-model)
-        seq.parent_hash = (
-            matched[-1].seq_hash if matched
-            else model_hash_salt(seq.model)
-        )
-        history = (n_hit + len(restore_hashes)) * bs
-        if self.wpool is not None and not self.wpool.grow(
-            seq.wblocks, min(len(prompt), history + max(
-                cfg.prefill_chunk, cfg.mixed_step_budget))
-        ):
-            # the first chunk's blocks in the window pool, taken here so
-            # that a pool that is full holds the prompt back like the
-            # full pool does (later chunks give back what they take)
-            self._free_blocks(seq)
-            return None
-        seq.cached_prefix = history
-        upload = None
-        if self.offload is not None and restore_hashes:
-            upload = self.offload.begin_upload(
-                restore_hashes, restore_data,
-                [b.idx for b in fresh[: len(restore_hashes)]],
-            )
-        return history, upload
+        seq.hold, seq.cached_prefix, upload = reserved
+        return seq.cached_prefix, upload
 
     def _begin_prefill(self, seq: _Sequence, hashes=None) -> bool:
         """Reserve blocks + prefix/host-tier claims and queue the sequence
         as the in-flight chunked prefill. Returns False on pool pressure."""
-        reserved = self._reserve_for_prompt(seq, probe_host=True, hashes=hashes)
+        reserved = self._reserve(seq, probe_host=True, hashes=hashes)
         if reserved is None:
             return False
         history, upload = reserved
         self.stats["prefix_cache_hits_tokens"] += history
         if self.state is not None:
             # the matched tokens up to the last snapshot are skipped
-            # (_reserve_for_prompt); the row is one no sequence decodes
-            # in and no other prefill holds
+            # (the prefix rule); the row is one no sequence decodes in
+            # and no other prefill holds
             self.stats["state_restores"] += history > 0
-            if seq.restore_row >= 0:
-                self.snapshots.pin(seq.restore_row)
             held = {st.seq.state_slot for st in self._prefill_states}
             seq.state_slot = next(
                 i for i, s in enumerate(self._active)
@@ -2623,9 +2236,9 @@ class JaxEngine(AsyncEngine):
             # cached prefix
             self._abort_prefill(st, FinishReason.CANCELLED)
             return False
-        if not self._window_advance(seq, st.pos, min(
+        if not self.kv.grow(seq.hold, st.pos, min(
                 len(seq.tokens), st.pos + self.cfg.prefill_chunk)):
-            self._window_pool_full(st)
+            self._pool_full(st)
             return False
         # device work (jit dispatch + compile + host sync) runs in a worker
         # thread so lease keepalives / bus traffic stay live on the loop
@@ -2638,12 +2251,21 @@ class JaxEngine(AsyncEngine):
             logger.exception("prefill failed for request %s", seq.context.id)
             self._abort_prefill(st, FinishReason.ERROR)
             return False
-        if first_token is None:
-            self._commit_chunk(st)
+        if first_token is None:  # more chunks to go
+            self.kv.commit(seq.hold, seq.tokens, st.pos, chunk=True)
             self._step_done()
-            return False  # more chunks to go
+            return False
         phase = self._clock.mark("emit")
-        first_token, first_lp = first_token
+        self._prefill_done(st, first_token)
+        self._step_done()
+        self._clock.mark(phase)
+        return True
+
+    def _prefill_done(self, st: "_PrefillState", first: tuple) -> None:
+        """A prompt's final chunk ran, alone or in a mixed step: its
+        ``engine.prefill`` span, its blocks committed, its first token
+        (``first``: token, logprob entry) emitted, its row placed."""
+        seq = st.seq
         if seq.generated == 0:
             # first prefill only — a preemption replay's prefill is
             # post-first-token and must not re-enter the decomposition
@@ -2656,11 +2278,12 @@ class JaxEngine(AsyncEngine):
                     prompt_tokens=seq.prompt_len,
                     cached_prefix=seq.cached_prefix,
                     step=self._clock.seq,
-                    **self._restored_attr(seq),
+                    **self.kv.prefill_attrs(seq.hold, seq.cached_prefix),
                 )
-        self._drop_prefill_state(st)
-        self._commit_full_blocks(seq)
-        self._emit_token(seq, first_token, first_lp)
+        if st in self._prefill_states:
+            self._prefill_states.remove(st)
+        self.kv.commit(seq.hold, seq.tokens, seq.seq_len)
+        self._emit_token(seq, *first)
         if not seq.finished:
             if self._n_active < self.cfg.max_batch_size:
                 self._place_in_batch(seq)
@@ -2671,37 +2294,17 @@ class JaxEngine(AsyncEngine):
                 # an unconditional placement would index(None) on a full
                 # batch and crash the scheduler loop
                 self._remote_ready.append(seq)
-        self._step_done()
-        self._clock.mark(phase)
-        return True
 
-    def _commit_chunk(self, st: "_PrefillState") -> None:
-        """A model with a window pool commits a prompt's blocks chunk by
-        chunk, not at the prompt's end: only a committed block can go
-        back from behind the window (still addressed by its content), so
-        a long prompt holds a window and a chunk there, not itself."""
-        if self.wpool is not None:
-            self._commit_full_blocks(st.seq, written_len=st.pos)
-
-    def _window_pool_full(self, st: "_PrefillState") -> None:
-        """The window pool cannot give a prefill its next chunk's blocks
-        (its first chunk's were reserved at admission, and a later chunk
-        gives back what it takes: a pool sized under one sequence's
-        window and chunk). The request fails alone, by name."""
+    def _pool_full(self, st: "_PrefillState") -> None:
+        """A pool cannot give a prefill its next chunk's blocks (its first
+        chunk's were reserved at admission and a later chunk gives back
+        what it takes: a window pool sized under one sequence's window
+        and chunk). The request fails alone, by name."""
         logger.error(
-            "window pool exhausted (%s) under request %s at %d of %d "
-            "tokens: failing it", self.wpool.allocator.state_counts(),
-            getattr(st.seq.context, "id", "?"), st.pos, len(st.seq.tokens))
+            "%s under request %s at %d of %d tokens: failing it",
+            self.kv.why(), getattr(st.seq.context, "id", "?"), st.pos,
+            len(st.seq.tokens))
         self._abort_prefill(st, FinishReason.ERROR)
-
-    def _drop_prefill_state(self, st: "_PrefillState") -> None:
-        if st in self._prefill_states:
-            self._prefill_states.remove(st)
-            if (self.state is not None and not st.state_ready
-                    and st.seq.restore_row >= 0):
-                # dropped before its first chunk restored the row
-                self.snapshots.unpin(st.seq.restore_row)
-                st.seq.restore_row = -1
 
     def _abort_prefill(
         self, st: "_PrefillState", reason: FinishReason,
@@ -2714,8 +2317,9 @@ class JaxEngine(AsyncEngine):
         share it so the rollback protocol cannot drift between them;
         ``text`` lets the drain handoff stamp the migration signal."""
         seq = st.seq
-        self._drop_prefill_state(st)
-        self._free_blocks(seq)
+        if st in self._prefill_states:
+            self._prefill_states.remove(st)
+        self.kv.release(seq.hold)
         seq.state_slot = -1
         self._rollback_upload(st)
         seq.out_queue.put_nowait(
@@ -2771,35 +2375,9 @@ class JaxEngine(AsyncEngine):
         if self.state is None or st.state_ready:
             return
         st.state_ready = True
-        seq = st.seq
         self.state = _begin_state_row(
-            self.state, jnp.int32(seq.state_slot),
-            jnp.int32(seq.restore_row))
-        if seq.restore_row >= 0:
-            self.snapshots.unpin(seq.restore_row)
-
-    def _clip_take(self, seq: _Sequence, pos: int, take: int) -> int:
-        """``take`` tokens of ``seq``'s prompt from ``pos``, cut so that
-        the chunk ends where the sequence wants a snapshot of its state
-        (``_Sequence.snap_points``: a state that exists at a chunk's end
-        only)."""
-        for p in seq.snap_points:
-            if pos < p < pos + take:
-                return p - pos
-        return take
-
-    def _snap_row(self, seq: _Sequence, end: int) -> int:
-        """The snapshot row for ``seq``'s chunk that ends after ``end``
-        tokens: a row of the pool if the sequence wants a snapshot
-        there, else an index past the pool (the program drops it)."""
-        pool = self.snapshots
-        if end in seq.snap_points:
-            row = pool.take(seq.blocks[end // self.cfg.block_size - 1])
-            if row >= 0:
-                self.stats["state_snapshots"] += 1
-                self.stats["state_snapshot_evictions"] = pool.evictions
-                return row
-        return pool.rows
+            self.state, jnp.int32(st.seq.state_slot),
+            jnp.int32(self.kv.restore_from(st.seq.hold)))
 
     def _note_state(self, segments: int, decode_steps: int = 0) -> None:
         """Bytes of recurrent matrices a dispatch reads and writes: a
@@ -2807,17 +2385,6 @@ class JaxEngine(AsyncEngine):
         program carries dead slots' through unchanged)."""
         self.stats["linear_state_bytes"] += 2 * self._rec_row_bytes * (
             segments + decode_steps * self.cfg.max_batch_size)
-
-    def _restored_attr(self, seq: _Sequence) -> dict:
-        """``engine.prefill``'s ``restored``: prompt tokens whose conv
-        state came from a snapshot (a model with conv layers only); its
-        ``cut_by`` (a model with a window pool only): the pool whose
-        match ended the prefix hit, and ``window_cut``, the tokens the
-        full pool matched beyond it."""
-        if self.wpool is not None:
-            return {"cut_by": "window" if seq.window_cut else "full",
-                    "window_cut": seq.window_cut * self.cfg.block_size}
-        return {} if self.state is None else {"restored": seq.cached_prefix}
 
     def _state_kw(self, seq: Optional[_Sequence] = None,
                   end: int = 0) -> dict:
@@ -2829,8 +2396,9 @@ class JaxEngine(AsyncEngine):
         kw = {"state": self.state}
         if seq is not None:
             kw["slot"] = jnp.int32(seq.state_slot)
-            if not self.snapshots.dense:
-                kw["snap_row"] = jnp.int32(self._snap_row(seq, end))
+            snaps = self.kv.snap_rows([(seq.hold, end)])
+            if snaps is not None:
+                kw["snap_row"] = jnp.int32(snaps[0])
         return kw
 
     def _offload_preamble(self, upload=None, seq: Optional[_Sequence] = None) -> None:
@@ -2921,7 +2489,7 @@ class JaxEngine(AsyncEngine):
         self.stats["kv_device_bytes_saved_total"] += (
             tokens_written * self._kv_saved_per_token
         )
-        self.stats["kv_device_quant_pages"] = self.allocator.resident_count
+        self.stats["kv_device_quant_pages"] = self.kv.allocator.resident_count
         if gen_tokens > 0:
             now = time.perf_counter()
             dt = now - self._lowprec_rate_t
@@ -3011,26 +2579,27 @@ class JaxEngine(AsyncEngine):
         ring = self._ring_chunk(seq, pos)
         # ring: the WHOLE prompt is one sequence-parallel chunk
         chunk = seq.tokens[pos:] if ring else (
-            seq.tokens[pos : pos + self._clip_take(
-                seq, pos, cfg.prefill_chunk)]
+            seq.tokens[pos : pos + self.kv.clip_take(
+                seq.hold, pos, cfg.prefill_chunk)]
         )
         T = _bucket(len(chunk))
         toks = np.zeros(T, np.int32)
         toks[: len(chunk)] = chunk
         self._note_prefill_work(T, len(chunk))
+        # the table must cover the padded chunk: page 0 pads it
+        tables = self.kv.tables(seq.hold)
         # tokens, table(s), history, valid: each its own transfer
-        self._handed("prefill", 4 + (self.wpool is not None))
+        self._handed("prefill", 3 + len(jax.tree.leaves(tables)))
         if self.mirror is not None:
             logits, self.k_cache, self.v_cache = self._timed_dispatch(
                 lambda: self.mirror.lead_prefill(
-                    self.params, toks, self._table_for(seq), pos,
+                    self.params, toks, tables, pos,
                     len(chunk), self.k_cache, self.v_cache,
                     use_pallas=self.use_pallas, use_ring=ring,
                 ),
                 key=("prefill", T, ring), trace=seq.trace,
             )
             return logits, pos + len(chunk)
-        # table must cover padded chunk; _table_for pads with trash 0
         if self.k_scales is not None:
             self._flush_scale_resets()
             out = self._timed_dispatch(
@@ -3038,7 +2607,7 @@ class JaxEngine(AsyncEngine):
                     self.params,
                     cfg.model,
                     jnp.asarray(toks),
-                    jnp.asarray(self._table_for(seq)),
+                    jnp.asarray(tables),
                     jnp.int32(pos),
                     jnp.int32(len(chunk)),
                     self.k_cache,
@@ -3066,7 +2635,7 @@ class JaxEngine(AsyncEngine):
                 self.params,
                 cfg.model,
                 jnp.asarray(toks),
-                self._seq_tables(seq),
+                jax.tree.map(jnp.asarray, tables),
                 jnp.int32(pos),
                 jnp.int32(len(chunk)),
                 self.k_cache,
@@ -3116,38 +2685,33 @@ class JaxEngine(AsyncEngine):
                 )
         return self._sample_prefill(seq, logits)
 
-    def _table_for(self, seq: _Sequence) -> np.ndarray:
-        t = np.zeros(self.cfg.max_blocks_per_seq, np.int32)
-        for i, b in enumerate(seq.blocks[: self.cfg.max_blocks_per_seq]):
-            t[i] = b.idx
-        return t
+    def _check_provisioned(self, n: int, what: str) -> None:
+        """Provisioning invariant (loud, not silent): every active
+        sequence must have blocks covering this dispatch's ``n`` steps'
+        writes. A violation would scatter through zero block-table
+        entries into reserved page 0: garbage K/V that later reads
+        silently consume."""
+        for seq in self._active:
+            if (seq is None or seq.finished or seq.slot < 0
+                    or self._leaving(seq)):
+                continue
+            if self.kv.short(seq.hold, self._reach(seq, n)):
+                raise RuntimeError(
+                    f"{what} pending={self._pending(seq)} exceeds "
+                    f"provisioned blocks for request "
+                    f"{getattr(seq.context, 'id', '?')} "
+                    f"(seq_len={seq.seq_len}, blocks={len(seq.hold.blocks)})"
+                )
 
-    def _wtable_for(self, seq: _Sequence) -> np.ndarray:
-        """``seq``'s table in the window pool: page 0 where it holds no
-        block (behind its window)."""
-        t = np.zeros(self.cfg.max_blocks_per_seq, np.int32)
-        for i in range(seq.wfloor, min(len(seq.wblocks), len(t))):
-            t[i] = seq.wblocks[i].idx
-        return t
-
-    def _tables(self, full: np.ndarray, window=None):
-        """A step program's block-table argument: the full pool's, or
-        for a model with a window pool the pair (``window``: the same
-        rows' tables there)."""
-        if self.wpool is None:
-            return jnp.asarray(full)
-        return jnp.asarray(full), jnp.asarray(window)
-
-    def _seq_tables(self, seq: _Sequence):
-        return self._tables(
-            self._table_for(seq),
-            None if self.wpool is None else self._wtable_for(seq))
-
-    def _set_tables(self, seq: _Sequence) -> None:
-        """``seq``'s decode slot's row of the block tables, anew."""
-        self._rows.set_tables(
-            seq.slot, self._table_for(seq),
-            None if self.wpool is None else self._wtable_for(seq))
+    def _grow(self, seq: _Sequence, upto: int) -> bool:
+        """A decode row provisioned up to token ``upto`` (exclusive), in
+        every pool (``KvManager.grow``; what lies behind the HOST's
+        position's window goes back), and its table row(s) anew. False
+        when a pool has no block to give."""
+        if not self.kv.grow(seq.hold, seq.seq_len - 1, upto):
+            return False
+        self._rows.set_tables(seq.slot, *self.kv.table_rows(seq.hold))
+        return True
 
     def _decode_rows(self, kind: str, pending=0) -> dict:
         """A step program's decode rows, for all three thunks: the
@@ -3169,58 +2733,6 @@ class JaxEngine(AsyncEngine):
         """Where the resident step state lives: replicated over the
         mesh, or the default device's own placement."""
         return None if self.mesh is None else replicated(self.mesh)
-
-    def _free_blocks(self, seq: _Sequence) -> None:
-        """Everything ``seq`` holds in the pool(s)."""
-        self.allocator.free(seq.blocks)
-        seq.blocks = []
-        if self.wpool is not None:
-            self.wpool.free(seq.wblocks)
-            seq.wblocks, seq.wfloor, seq.wcold = [], 0, range(0)
-
-    def _kv_usage(self) -> float:
-        """The fuller pool's share in use: what admission is held to."""
-        return max(a.usage() for a in self._allocators())
-
-    def _allocators(self) -> list:
-        return [self.allocator] + (
-            [self.wpool.allocator] if self.wpool is not None else [])
-
-    def _blocks_short(self, seq: _Sequence, upto: int) -> bool:
-        """Does ``seq`` lack a block, in either pool, for a dispatch
-        that writes up to token ``upto`` (exclusive)?"""
-        bs = self.cfg.block_size
-        return upto > len(seq.blocks) * bs or (
-            self.wpool is not None and upto > len(seq.wblocks) * bs)
-
-    def _grow_blocks(self, seq: _Sequence, upto: int) -> bool:
-        """One step of provisioning a decode row up to token ``upto``:
-        one more block in the full pool if that is short, else the
-        window pool's blocks (behind the row's window first given back).
-        False when the pool asked has none to give."""
-        if upto > len(seq.blocks) * self.cfg.block_size:
-            extra = self.allocator.allocate(1)
-            if extra is None:
-                return False
-            seq.blocks.extend(extra)
-        elif not self._window_advance(seq, seq.seq_len - 1, upto):
-            return False
-        self._set_tables(seq)
-        return True
-
-    def _window_advance(self, seq: _Sequence, pos: int, upto: int) -> bool:
-        """The window pool's side of provisioning: ``seq``'s next
-        dispatch has its first query at ``pos`` and writes up to token
-        ``upto`` (exclusive). What lies behind ``pos``'s window goes back
-        (first: it may be what the pool gives out next), then the blocks
-        up to ``upto`` are taken. False if the pool cannot give them."""
-        if self.wpool is None:
-            return True
-        was = self.wpool.released
-        seq.wfloor = self.wpool.release_behind(
-            seq.wblocks, seq.wfloor, pos, seq.committed, seq.wcold)
-        self._window_released += self.wpool.released - was
-        return self.wpool.grow(seq.wblocks, upto)
 
     def _sample_prefill(self, seq: _Sequence, logits):
         """Sample the first token from the prefill logits; returns
@@ -3317,7 +2829,7 @@ class JaxEngine(AsyncEngine):
         self._active[slot] = seq
         self._n_active += 1
         so = seq.request.sampling_options
-        self._set_tables(seq)
+        self._rows.set_tables(seq.slot, *self.kv.table_rows(seq.hold))
         self._rows.place(
             slot, seq_len=seq.seq_len, token=seq.tokens[-1],
             steps=seq.generated,
@@ -3470,14 +2982,13 @@ class JaxEngine(AsyncEngine):
         PCIe (the scales are non-None exactly then); the serving side
         adopts them when the wire codec matches and re-encodes (counted
         in ``kv_device_export_requant_total``) when it doesn't."""
-        self._no_state_transfer("export_device_chain (fleet prefix cache)")
-        self._no_window_transfer("export_device_chain (fleet prefix cache)")
+        self.kv.refuse_transfer("export_device_chain (fleet prefix cache)")
         if self.mirror is not None or not seq_hashes or self._closed:
             return [], None, None, None, None
         # claim refs via the allocator's own chain matcher (hashes are
         # chained, so the local-hash slot is unused by the lookup) —
         # claiming pins the pages against eviction during the gather
-        claimed = self.allocator.match_prefix(
+        claimed = self.kv.allocator.match_prefix(
             (), hashes=[(0, h) for h in seq_hashes[:max_blocks]]
         )
         if not claimed:
@@ -3497,7 +3008,7 @@ class JaxEngine(AsyncEngine):
                         None, self._gather_device, idxs, False
                     )
         finally:
-            self.allocator.free(claimed)
+            self.kv.allocator.free(claimed)
         served = list(seq_hashes[: len(claimed)])
         self.stats["peer_serve_d2h_blocks"] += len(served)
         return served, k, v, ks, vs
@@ -3546,7 +3057,7 @@ class JaxEngine(AsyncEngine):
         only the continuation PAST local coverage is worth wire time."""
         n = 0
         for h in chain:
-            if self.allocator.has_hash(h):
+            if self.kv.allocator.has_hash(h):
                 n += 1
                 continue
             if self.offload is not None and self.offload.tier_contains(h):
@@ -3586,7 +3097,7 @@ class JaxEngine(AsyncEngine):
         await self._offload_prejoin(chain)
         n_dev = 0
         for h in chain:
-            if not self.allocator.has_hash(h):
+            if not self.kv.allocator.has_hash(h):
                 break
             n_dev += 1
         tail = blocks[n_dev:]
@@ -3595,7 +3106,7 @@ class JaxEngine(AsyncEngine):
         hashes, data = self.offload.peek_chain([s for _l, s in tail])
         if not hashes:
             return 0
-        fresh = self.allocator.allocate(len(hashes))
+        fresh = self.kv.allocator.allocate(len(hashes))
         if fresh is None:
             return 0
         upload = self.offload.begin_upload(
@@ -3617,7 +3128,7 @@ class JaxEngine(AsyncEngine):
                 )
         except Exception:  # noqa: BLE001 — prefetch is advisory
             logger.exception("hinted prefetch restore failed")
-            self.allocator.free(fresh)
+            self.kv.allocator.free(fresh)
             self.offload.cancel_upload(upload)
             return 0
         # commit the restored pages into the reuse pool under their
@@ -3631,11 +3142,11 @@ class JaxEngine(AsyncEngine):
         parent = chain[n_dev - 1] if n_dev else None
         adopted = 0
         for b, (local, seq_hash) in zip(fresh, tail):
-            if self.allocator.adopt_restored(b, seq_hash, local, parent):
+            if self.kv.allocator.adopt_restored(b, seq_hash, local, parent):
                 b.prefetched = True
                 adopted += 1
             parent = seq_hash
-        self.allocator.free(fresh)
+        self.kv.allocator.free(fresh)
         self.offload.discard_chain(hashes)
         self.offload.note_prefetch_landed(upload)
         return adopted
@@ -3824,9 +3335,7 @@ class JaxEngine(AsyncEngine):
         and only recomputes the uncommitted tail — never silent
         truncation."""
         self._release_slot(seq)
-        self._free_blocks(seq)
-        seq.committed = 0
-        seq.parent_hash = model_hash_salt(seq.model)
+        self.kv.release(seq.hold)
         seq.cached_prefix = 0
         # resume at the FRONT of the waiting queue: the whole token list
         # (prompt + generated so far) re-admits as a prefill whose final
@@ -3908,9 +3417,9 @@ class JaxEngine(AsyncEngine):
             while (
                 seq.slot >= 0
                 and not seq.finished
-                and self._blocks_short(seq, self._reach(seq, n))
+                and self.kv.short(seq.hold, self._reach(seq, n))
             ):
-                if len(seq.blocks) >= cfg.max_blocks_per_seq:
+                if self.kv.at_limit(seq.hold):
                     if self._inflight is not None:
                         # the requirement is inflated by the steps in
                         # flight — drain (emits their tokens, advances
@@ -3932,7 +3441,7 @@ class JaxEngine(AsyncEngine):
                         continue
                     self._finish(seq, FinishReason.LENGTH)  # true ctx limit
                     break
-                if self._grow_blocks(seq, self._reach(seq, n)):
+                if self._grow(seq, self._reach(seq, n)):
                     continue
                 if self._inflight is not None:
                     # chaining must never CAUSE a preemption: the blocks
@@ -4041,7 +3550,7 @@ class JaxEngine(AsyncEngine):
         # in flight (the next tenant's prefill, a restore's scatter; an
         # eviction's gather reads committed blocks only), (c) the window
         # pool gives pages back behind the HOST's position
-        # (_window_advance), which lags the device's: conservative, and
+        # (_grow), which lags the device's: conservative, and
         # (d) a state row's next tenant begins it (_begin_state_row) in
         # a program ordered after too, and a dead row keeps its state
         # row as it was. The alternating scheduler (a prefill that cannot
@@ -4146,14 +3655,11 @@ class JaxEngine(AsyncEngine):
         for seq in list(self._active):
             if seq is None or seq.finished or seq.slot < 0:
                 continue
-            while seq.seq_len + g > len(seq.blocks) * cfg.block_size:
-                if len(seq.blocks) >= cfg.max_blocks_per_seq:
-                    return False  # near context limit: plain windows clamp
-                extra = self.allocator.allocate(1)
-                if extra is None:
+            if self.kv.short(seq.hold, seq.seq_len + g):
+                # (near the context limit plain windows clamp)
+                if self.kv.at_limit(seq.hold) or not self._grow(
+                        seq, seq.seq_len + g):
                     return False
-                seq.blocks.extend(extra)
-                self._set_tables(seq)
 
         out_toks, n_accs, lps = await self._on_device(
             self._dispatch_verify, proposals.astype(np.int32),
@@ -4184,7 +3690,7 @@ class JaxEngine(AsyncEngine):
             if seq.finished or self._active[i] is not seq:
                 continue
             self._rows.advance(i, seq.seq_len, seq.tokens[-1], seq.generated)
-            self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
+            self.kv.commit(seq.hold, seq.tokens, seq.seq_len - 1)
         self._step_done()
         return True
 
@@ -4225,17 +3731,17 @@ class JaxEngine(AsyncEngine):
             while (
                 seq.slot >= 0
                 and not seq.finished
-                and self._blocks_short(seq, self._reach(seq, 1))
+                and self.kv.short(seq.hold, self._reach(seq, 1))
             ):
-                if (len(seq.blocks) < cfg.max_blocks_per_seq
-                        and self._grow_blocks(seq, self._reach(seq, 1))):
+                if (not self.kv.at_limit(seq.hold)
+                        and self._grow(seq, self._reach(seq, 1))):
                     continue
                 if self._inflight is not None:
                     # the queued program's blocks go back first: chaining
                     # never causes a preemption or an early LENGTH finish
                     await self._drain_inflight()
                     continue
-                if len(seq.blocks) >= cfg.max_blocks_per_seq:
+                if self.kv.at_limit(seq.hold):
                     self._finish(seq, FinishReason.LENGTH)
                     break
                 if self._evict_for_headroom(seq):
@@ -4249,8 +3755,8 @@ class JaxEngine(AsyncEngine):
         packed = self._split_mixed_budget()
         self._clock.mark("provision")
         for st, take in list(packed):
-            if not self._window_advance(st.seq, st.pos, st.pos + take):
-                self._window_pool_full(st)
+            if not self.kv.grow(st.seq.hold, st.pos, st.pos + take):
+                self._pool_full(st)
                 packed.remove((st, take))
         if not packed:
             return
@@ -4280,7 +3786,7 @@ class JaxEngine(AsyncEngine):
         # is enqueued after it
         for st, _take in packed:
             if st.pos < len(st.seq.tokens):
-                self._commit_chunk(st)
+                self.kv.commit(st.seq.hold, st.seq.tokens, st.pos, chunk=True)
         prev, self._inflight = self._inflight, step
         if prev is not None:
             # the window ahead of it is fetched and emitted while the
@@ -4291,41 +3797,6 @@ class JaxEngine(AsyncEngine):
             # completed gets its first token sampled on the host and its
             # row placed (the one exposed gap an admission leaves)
             await self._drain_inflight()
-
-    def _emit_prefills(self, step: dict, firsts: list) -> None:
-        """The prefill side of an emitted mixed step: the prompts whose
-        FINAL chunk it ran (``firsts``: their first tokens, sampled
-        where the step was fetched) emit and join the batch, in
-        admission order (several may complete in one step)."""
-        cfg = self.cfg
-        self.stats["mixed_steps"] += 1
-        self.stats["mixed_prefill_segments"] += len(step["packed"])
-        for st, first in firsts:
-            seq_p = st.seq
-            first_token, first_lp = first
-            if seq_p.generated == 0:
-                self.hist["prefill_ms"].observe(st.dev_ms)
-                if seq_p.trace is not None:
-                    tracing.RECORDER.record_span(
-                        "engine.prefill", seq_p.trace, ts=st.t0_wall,
-                        dur_ms=st.dev_ms,
-                        request_id=seq_p.context.id,
-                        prompt_tokens=seq_p.prompt_len,
-                        cached_prefix=seq_p.cached_prefix,
-                        step=self._clock.seq,
-                        **self._restored_attr(seq_p),
-                    )
-            self._drop_prefill_state(st)
-            self._commit_full_blocks(seq_p)
-            self._emit_token(seq_p, first_token, first_lp)
-            if not seq_p.finished:
-                if self._n_active < cfg.max_batch_size:
-                    self._place_in_batch(seq_p)
-                else:
-                    # slots filled mid-prefill (remote-ready admissions):
-                    # the KV is landed, so queue for the next free slot
-                    # exactly like a remotely-prefilled sequence
-                    self._remote_ready.append(seq_p)
 
     def _split_mixed_budget(self) -> list[tuple["_PrefillState", int]]:
         """Pack the Sarathi token budget across the in-flight prefills:
@@ -4346,9 +3817,8 @@ class JaxEngine(AsyncEngine):
             extra = min(left, rem[i] - takes[i])
             takes[i] += extra
             left -= extra
-        if self.state is not None:
-            takes = [self._clip_take(st.seq, st.pos, t)
-                     for st, t in zip(sts, takes)]
+        takes = [self.kv.clip_take(st.seq.hold, st.pos, t)
+                 for st, t in zip(sts, takes)]
         return list(zip(sts, takes))
 
     def _dispatch_mixed(self, packed: list[tuple["_PrefillState", int]]):
@@ -4366,21 +3836,8 @@ class JaxEngine(AsyncEngine):
         so the compiled program count is bounded by segment-count
         buckets x prefill buckets, never by the live mixture."""
         cfg = self.cfg
-        # provisioning invariant (loud, not silent — the same check the
-        # window dispatch makes): every active sequence must have a block
-        # for this step's token, or its write would scatter through zero
-        # table entries into reserved page 0 as silent garbage
         pending = self._pending_rows()
-        for seq in self._active:
-            if (seq is None or seq.finished or seq.slot < 0
-                    or self._leaving(seq)):
-                continue
-            if self._blocks_short(seq, self._reach(seq, 1)):
-                raise RuntimeError(
-                    f"mixed step exceeds provisioned blocks for request "
-                    f"{getattr(seq.context, 'id', '?')} "
-                    f"(seq_len={seq.seq_len}, blocks={len(seq.blocks)})"
-                )
+        self._check_provisioned(1, "mixed step")
         t0 = time.perf_counter()
         try:
             # land each prompt's reserved host chain (first step only);
@@ -4393,17 +3850,11 @@ class JaxEngine(AsyncEngine):
             MP = _seg_bucket(len(packed))
             T = _bucket(max(take for _st, take in packed))
             toks_p = np.zeros((MP, T), np.int32)
-            tables_p = np.zeros((MP, cfg.max_blocks_per_seq), np.int32)
-            wtables_p = (None if self.wpool is None
-                         else np.zeros_like(tables_p))
             hists_p = np.zeros(MP, np.int32)
             valids_p = np.zeros(MP, np.int32)
             for i, (st, take) in enumerate(packed):
                 chunk = st.seq.tokens[st.pos : st.pos + take]
                 toks_p[i, : len(chunk)] = chunk
-                tables_p[i] = self._table_for(st.seq)
-                if self.wpool is not None:
-                    wtables_p[i] = self._wtable_for(st.seq)
                 hists_p[i] = st.pos
                 valids_p[i] = len(chunk)
             penalized = self._penalties_active()
@@ -4411,9 +3862,9 @@ class JaxEngine(AsyncEngine):
             kwargs = self._decode_rows("mixed", pending)
             # what the segments need, in ONE transfer
             segs = {"p_tokens": toks_p, "p_hists": hists_p,
-                    "p_valids": valids_p, "p_tables": (
-                        tables_p if wtables_p is None
-                        else (tables_p, wtables_p))}
+                    "p_valids": valids_p,
+                    "p_tables": self.kv.stack_tables(
+                        (st.seq.hold for st, _take in packed), MP)}
             if penalized:
                 kwargs.update(
                     counts=self._pen_counts,
@@ -4442,10 +3893,9 @@ class JaxEngine(AsyncEngine):
                     slots_p[i] = st.seq.state_slot
                 kwargs.update(self._state_kw())
                 segs["p_slots"] = slots_p
-                if not self.snapshots.dense:
-                    snaps_p = np.full(MP, self.snapshots.rows, np.int32)
-                    for i, (st, take) in enumerate(packed):
-                        snaps_p[i] = self._snap_row(st.seq, st.pos + take)
+                snaps_p = self.kv.snap_rows(
+                    [(st.seq.hold, st.pos + take) for st, take in packed], MP)
+                if snaps_p is not None:
                     segs["p_snaps"] = snaps_p
                 self._state_rows += len(packed) + int(
                     (self._rows.seq_lens > 0).sum())
@@ -4459,7 +3909,7 @@ class JaxEngine(AsyncEngine):
                 int(((hists_p + valids_p + cfg.block_size - 1)
                      // cfg.block_size).sum()),
             ))
-            self._note_window_work(1, seq_lens, [
+            self.kv.note_work(1, self._live_holds(seq_lens), [
                 (st.pos, take) for st, take in packed])
             live_rows = int((self._rows.seq_lens > 0).sum())
             out = self._timed_dispatch(lambda: llama.mixed_step(
@@ -4601,10 +4051,7 @@ class JaxEngine(AsyncEngine):
         if self.state is not None:
             # sequences whose conv state the step's dispatches advanced
             attrs["state"], self._state_rows = self._state_rows, 0
-        if self.wpool is not None:
-            # blocks released behind their sequences' windows in the step
-            attrs["window_released"] = self._window_released
-            self._window_released = 0
+        attrs.update(self.kv.step_attrs())
         moe = dict.fromkeys(MOE_COUNTERS, 0)
         while self._moe_pending and self._moe_pending[0][0].is_ready():
             sums, slots, assignments = self._moe_pending.popleft()
@@ -4646,33 +4093,12 @@ class JaxEngine(AsyncEngine):
             st["sampler_filter_steps"] += n
             self._filter_steps += n
 
-    def _note_window_work(self, n: int, seq_lens: np.ndarray,
-                          segs=()) -> None:
-        """The window pool's work counters of one decode or mixed
-        dispatch of ``n`` steps (a model with a window pool only; no
-        device read): over the live rows, the tokens of window-layer KV
-        a row holds against its context's length, and the pages the
-        window layers' kernels walk for it (from the window's floor up)
-        against the pages its context spans, at the dispatch's first
-        step; ``segs``: a mixed step's prefill segments, as (history,
-        real tokens), for the two page counters."""
-        if self.wpool is None:
-            return
-        st, bs, wp = self.stats, self.cfg.block_size, self.wpool
-        for i, seq in enumerate(self._active):
-            if seq is None or self._rows.seq_lens[i] <= 0:
-                continue
-            ctx = int(seq_lens[i])
-            pages = -(-ctx // bs)
-            st["kv_window_resident_tokens"] += (
-                len(seq.wblocks) - seq.wfloor) * bs * n
-            st["kv_window_context_tokens"] += ctx * n
-            st["attn_window_pages"] += (pages - wp.first_seen(ctx - 1)) * n
-            st["attn_window_context_pages"] += pages * n
-        for hist, real in segs:
-            pages = -(-(hist + real) // bs)
-            st["attn_window_pages"] += pages - wp.first_seen(hist)
-            st["attn_window_context_pages"] += pages
+    def _live_holds(self, seq_lens: np.ndarray):
+        """(hold, context length) of each live decode row: what the KV
+        manager's work counters read, where it counts any."""
+        return ((seq.hold, int(seq_lens[i]))
+                for i, seq in enumerate(self._active)
+                if seq is not None and self._rows.seq_lens[i] > 0)
 
     def _note_prefill_work(self, dispatched: int, real: int) -> None:
         """Prefill tokens handed to the device (bucket length x
@@ -4829,9 +4255,14 @@ class JaxEngine(AsyncEngine):
             if seq.finished:
                 continue
             self._rows.advance(i, seq.seq_len, seq.tokens[-1], seq.generated)
-            self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
+            self.kv.commit(seq.hold, seq.tokens, seq.seq_len - 1)
         if packed is not None:
-            self._emit_prefills(window, firsts)
+            # a mixed step's prefill side: the prompts whose FINAL chunk
+            # it ran emit and join the batch, in admission order
+            self.stats["mixed_steps"] += 1
+            self.stats["mixed_prefill_segments"] += len(packed)
+            for st, first in firsts:
+                self._prefill_done(st, first)
         self._clock.mark(phase)
         self._step_done(window["info"])
 
@@ -4854,26 +4285,12 @@ class JaxEngine(AsyncEngine):
             raise RuntimeError(
                 "pending window without a chained token source"
             )
-        # Provisioning invariant (loud, not silent): every active sequence
-        # must have blocks covering this window's writes. A violation
-        # would scatter through zero block-table entries into reserved
-        # page 0 — garbage K/V that later reads silently consume.
-        for seq in self._active:
-            if (seq is None or seq.finished or seq.slot < 0
-                    or self._leaving(seq)):
-                continue
-            if self._blocks_short(seq, self._reach(seq, n)):
-                raise RuntimeError(
-                    f"window n={n} pending={int(pending[seq.slot])} "
-                    f"exceeds provisioned blocks for request "
-                    f"{getattr(seq.context, 'id', '?')} "
-                    f"(seq_len={seq.seq_len}, blocks={len(seq.blocks)})"
-                )
+        self._check_provisioned(n, f"window n={n}")
         self._flush_evictions_budgeted()
         r = self._rows
         seq_lens = (r.seq_lens + pending).astype(np.int32)
         self._note_decode_work(n, seq_lens)
-        self._note_window_work(n, seq_lens)
+        self.kv.note_work(n, self._live_holds(seq_lens))
         if self.mirror is not None:
             penalized = self._penalties_active()
             want_lp = self._logprobs_active()
@@ -5022,7 +4439,7 @@ class JaxEngine(AsyncEngine):
                 )
             )
         self._release_slot(seq)
-        self._free_blocks(seq)
+        self.kv.release(seq.hold)
         self._wake.set()
 
     def _release_slot(self, seq: _Sequence) -> None:
@@ -5033,39 +4450,6 @@ class JaxEngine(AsyncEngine):
             self._rows.release(seq.slot)
             self._n_active -= 1
             seq.slot = seq.state_slot = -1
-
-    def _commit_full_blocks(self, seq: _Sequence, written_len: int = -1) -> None:
-        """Content-address blocks that just became full AND fully written.
-
-        ``written_len`` is the number of positions whose KV is actually in
-        the device cache. After a decode window (and after complete_remote's
-        first-token emit) the final sampled token is in ``seq.tokens`` but
-        its KV is only written at the start of the NEXT dispatch — callers
-        there pass ``seq.seq_len - 1`` so a block whose last row is pending
-        is never exposed to match_prefix (a concurrent prefix hit would
-        attend garbage). Prefill-side callers commit at ``seq.seq_len``
-        (tokens list holds only written positions there)."""
-        bs = self.cfg.block_size
-        if written_len < 0:
-            written_len = seq.seq_len
-        full = written_len // bs
-        while seq.committed < full and seq.committed < len(seq.blocks):
-            i = seq.committed
-            tokens = seq.tokens[i * bs : (i + 1) * bs]
-            parent = seq.parent_hash
-            seq.parent_hash = self.allocator.commit_full_block(
-                seq.blocks[i], tokens, parent
-            )
-            if i < len(seq.wblocks):  # the same tokens' window-layer KV
-                self.wpool.commit(seq.wblocks[i], seq.blocks[i], parent)
-            seq.committed += 1
-            if self.state is not None:
-                if self.snapshots.dense:
-                    # the program that filled the block left the state at
-                    # its last token under its id (llama.StateTrack)
-                    self.stats["state_snapshots"] += 1
-                else:  # a snapshot taken for it answers for this content
-                    self.snapshots.bind(seq.blocks[i])
 
     # ---------------- disaggregation hooks ----------------
     # (ref docs/disagg_serving.md:58-91; vllm patch remote-prefill states)
@@ -5092,6 +4476,29 @@ class JaxEngine(AsyncEngine):
                 "traffic to monolithic workers"
             )
 
+    def _extract_begin(self, what: str, req: PreprocessedRequest,
+                       context) -> tuple[_Sequence, int]:
+        """The prefill worker's side of a remote prefill, before its
+        first chunk: what cannot be extracted is refused by name, and
+        the prompt's hold is reserved (with this worker's own prefix
+        cache). Returns the sequence and its cached history."""
+        self.kv.refuse_transfer(f"{what} (disaggregation)")
+        self._guard_remote_adapter(req)
+        prompt = list(req.token_ids)
+        seq = _Sequence(
+            request=req,
+            context=context,
+            out_queue=asyncio.Queue(),
+            tokens=prompt,
+            prompt_len=len(prompt),
+            trace=tracing.current_trace() if tracing.enabled() else None,
+        )
+        reserved = self._reserve(seq)
+        if reserved is None:
+            raise OutOfBlocks(f"cannot cover {len(prompt)}-token prompt")
+        self.stats["prefix_cache_hits_tokens"] += reserved[0]
+        return seq, reserved[0]
+
     async def prefill_extract(
         self, req: PreprocessedRequest, context, skip_blocks: int = 0,
         keep_on_device: bool = False, timings: Optional[dict] = None,
@@ -5115,25 +4522,10 @@ class JaxEngine(AsyncEngine):
         LEADER ships full host blocks over the transfer plane;
         ``keep_on_device`` is ignored there (a multi-process array cannot
         hand over in-process to a differently-meshed engine)."""
-        self._no_state_transfer("prefill_extract (disaggregation)")
-        self._no_window_transfer("prefill_extract (disaggregation)")
         if self.mirror is not None:
             keep_on_device = False
-        self._guard_remote_adapter(req)
-        prompt = list(req.token_ids)
-        seq = _Sequence(
-            request=req,
-            context=context,
-            out_queue=asyncio.Queue(),
-            tokens=prompt,
-            prompt_len=len(prompt),
-            trace=tracing.current_trace() if tracing.enabled() else None,
-        )
-        reserved = self._reserve_for_prompt(seq)
-        if reserved is None:
-            raise OutOfBlocks(f"cannot cover {len(prompt)}-token prompt")
-        history = reserved[0]
-        self.stats["prefix_cache_hits_tokens"] += history
+        seq, history = self._extract_begin("prefill_extract", req, context)
+        prompt = seq.tokens
         try:
             async with self._device_lock:
                 first_token, first_lp = await (
@@ -5142,7 +4534,7 @@ class JaxEngine(AsyncEngine):
                     )
                 )
                 n_prompt = self.n_prompt_blocks(len(prompt))
-                idxs = [b.idx for b in seq.blocks[skip_blocks:n_prompt]]
+                idxs = [b.idx for b in seq.hold.blocks[skip_blocks:n_prompt]]
                 if idxs:
                     t_g = time.perf_counter()
                     k_np, v_np = await asyncio.get_running_loop().run_in_executor(
@@ -5158,9 +4550,9 @@ class JaxEngine(AsyncEngine):
                         )
                 else:
                     k_np = v_np = None
-            self._commit_full_blocks(seq)
+            self.kv.commit(seq.hold, seq.tokens, seq.seq_len)
         finally:
-            self._free_blocks(seq)
+            self.kv.release(seq.hold)
         return first_token, first_lp, k_np, v_np
 
     async def prefill_extract_stream(
@@ -5186,25 +4578,11 @@ class JaxEngine(AsyncEngine):
         bulk path, so the compiled-program count is bounded by segment
         GEOMETRY buckets, not per-request shapes (test_compiled_perf).
         Returns (first_token, first_lp, blocks_emitted)."""
-        self._no_state_transfer("prefill_extract_stream (disaggregation)")
-        self._no_window_transfer("prefill_extract_stream (disaggregation)")
         if self.mirror is not None:
             keep_on_device = False
-        self._guard_remote_adapter(req)
-        prompt = list(req.token_ids)
-        seq = _Sequence(
-            request=req,
-            context=context,
-            out_queue=asyncio.Queue(),
-            tokens=prompt,
-            prompt_len=len(prompt),
-            trace=tracing.current_trace() if tracing.enabled() else None,
-        )
-        reserved = self._reserve_for_prompt(seq)
-        if reserved is None:
-            raise OutOfBlocks(f"cannot cover {len(prompt)}-token prompt")
-        history = reserved[0]
-        self.stats["prefix_cache_hits_tokens"] += history
+        seq, history = self._extract_begin(
+            "prefill_extract_stream", req, context)
+        prompt = seq.tokens
         bs = self.cfg.block_size
         n_prompt = self.n_prompt_blocks(len(prompt))
         sent = skip_blocks
@@ -5217,7 +4595,7 @@ class JaxEngine(AsyncEngine):
                     min(full, sent + segment_blocks)
                     if segment_blocks > 0 else full
                 )
-                idxs = [b.idx for b in seq.blocks[sent:hi]]
+                idxs = [b.idx for b in seq.hold.blocks[sent:hi]]
                 t_g = time.perf_counter()
                 async with self._device_lock:
                     k_seg, v_seg = await loop.run_in_executor(
@@ -5271,9 +4649,9 @@ class JaxEngine(AsyncEngine):
                 first_token, first_lp = await loop.run_in_executor(
                     None, self._sample_prefill, seq, logits
                 )
-            self._commit_full_blocks(seq)
+            self.kv.commit(seq.hold, seq.tokens, seq.seq_len)
         finally:
-            self._free_blocks(seq)
+            self.kv.release(seq.hold)
         return first_token, first_lp, max(n_prompt - skip_blocks, 0)
 
     def _gather_device(self, idxs: list[int], keep_on_device: bool = False,
@@ -5333,8 +4711,7 @@ class JaxEngine(AsyncEngine):
         host-side allocator work, and the eventual remote-KV landing
         (complete_remote -> _scatter_device) broadcasts the blocks so
         every process scatters its shards in lockstep."""
-        self._no_state_transfer("begin_remote (disaggregation)")
-        self._no_window_transfer("begin_remote (disaggregation)")
+        self.kv.refuse_transfer("begin_remote (disaggregation)")
         req: PreprocessedRequest = request.data
         if isinstance(req, dict):
             req = PreprocessedRequest.from_dict(req)
@@ -5362,13 +4739,13 @@ class JaxEngine(AsyncEngine):
             prompt_len=len(prompt),
             trace=tracing.current_trace() if tracing.enabled() else None,
         )
-        if self._reserve_for_prompt(seq) is None:
+        if self._reserve(seq) is None:
             return None
         self.stats["requests_total"] += 1
         self.stats["prompt_tokens_total"] += seq.prompt_len
         return RemoteHandle(
             seq=seq,
-            skip_blocks=seq.committed,
+            skip_blocks=seq.hold.committed,
             n_prompt_blocks=self.n_prompt_blocks(len(prompt)),
         )
 
@@ -5377,7 +4754,7 @@ class JaxEngine(AsyncEngine):
         blocks untouched (no output emitted; caller re-submits locally)."""
         self.stats["requests_total"] -= 1
         self.stats["prompt_tokens_total"] -= handle.seq.prompt_len
-        self._free_blocks(handle.seq)
+        self.kv.release(handle.seq.hold)
 
     async def complete_remote(
         self,
@@ -5398,10 +4775,8 @@ class JaxEngine(AsyncEngine):
         seq = handle.seq
         if k_data is not None and k_data.shape[2]:
             n = int(k_data.shape[2])
-            idxs = [
-                b.idx
-                for b in seq.blocks[handle.skip_blocks : handle.skip_blocks + n]
-            ]
+            lo = handle.skip_blocks
+            idxs = [b.idx for b in seq.hold.blocks[lo : lo + n]]
             async with self._device_lock:
                 await asyncio.get_running_loop().run_in_executor(
                     None, self._scatter_device, idxs, k_data, v_data,
@@ -5410,7 +4785,7 @@ class JaxEngine(AsyncEngine):
         self.stats["prefix_cache_hits_tokens"] += seq.cached_prefix
         self._emit_token(seq, first_token, first_lp)
         if not seq.finished:
-            self._commit_full_blocks(seq, written_len=seq.seq_len - 1)
+            self.kv.commit(seq.hold, seq.tokens, seq.seq_len - 1)
             self._remote_ready.append(seq)
             self._wake.set()
         return seq.out_queue
@@ -5435,7 +4810,8 @@ class JaxEngine(AsyncEngine):
         n = int(k_data.shape[2])
         if n == 0:
             return
-        blocks = seq.blocks[handle.skip_blocks + b0 : handle.skip_blocks + b0 + n]
+        lo = handle.skip_blocks + b0
+        blocks = seq.hold.blocks[lo : lo + n]
         if seq.finished or len(blocks) != n:
             raise RuntimeError(
                 f"remote segment [{b0}, {b0 + n}) outside the live "
@@ -5470,7 +4846,7 @@ class JaxEngine(AsyncEngine):
 
     def abort_remote(self, handle: "RemoteHandle", message: str = "") -> None:
         seq = handle.seq
-        self._free_blocks(seq)
+        self.kv.release(seq.hold)
         seq.finished = True
         seq.out_queue.put_nowait(
             LLMEngineOutput(finish_reason=FinishReason.ERROR, text=message or None)

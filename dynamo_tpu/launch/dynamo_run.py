@@ -678,7 +678,7 @@ async def run_endpoint(args) -> None:
         # with an offload tier, demotions keep their radix residency and
         # last-tier drops publish the real removals (fleet prefix cache)
         KvEventPublisher(drt, component, drt.primary_lease_id).attach(
-            jax_core.allocator, offload=jax_core.offload
+            jax_core.kv.allocator, offload=jax_core.offload
         )
         if jax_core.offload is not None:
             # router-hinted host-tier prefetch: the KV router ships the

@@ -155,7 +155,7 @@ def main() -> int:
     if cfg.window_kv_pool:
         # window and full layers in one model: a pair of caches (the
         # window pool's size is the engine's own derivation) and of tables
-        from dynamo_tpu.engine.engine import window_pool_blocks
+        from dynamo_tpu.engine.kv_manager import window_pool_blocks
 
         wn = window_pool_blocks(
             cfg, b, bs, int(_flag(flags, "--mixed-step-budget", 2048)), 2048,

@@ -138,7 +138,7 @@ def poison(engine, keep_hashes: set) -> int:
     import jax.numpy as jnp
 
     fill = jax.jit(lambda c, idx, v: c.at[:, :, idx].set(v), donate_argnums=0)
-    pool = engine.wpool
+    pool = engine.kv.window
 
     def write(blocks, value):
         idx = jnp.asarray(blocks, jnp.int32)
@@ -178,7 +178,8 @@ async def drive(engine, prompt, busy_prompt, say):
     while engine._n_active == 0:  # the busy stream decodes
         await asyncio.sleep(0.01)
     got = {}
-    stats = lambda: {k: engine.stats[k] for k in (  # noqa: E731
+    both = lambda: engine.stats | engine.kv.stats  # noqa: E731
+    stats = lambda: {k: both()[k] for k in (  # noqa: E731
         "prefix_cache_hits_tokens", "prefix_matched_tokens",
         "prefix_window_missed_tokens", "mixed_steps")}
     for name, cut, n in (*((k, v, 1) for k, v in cuts(len(prompt)).items()),
@@ -189,22 +190,22 @@ async def drive(engine, prompt, busy_prompt, say):
         after = stats()
         say(f"{name}: {cut} prompt tokens, counters "
             f"{ {k: after[k] - before[k] for k in after} }; pools "
-            f"{engine.wpool.allocator.state_counts()}")
+            f"{engine.kv.window.allocator.state_counts()}")
     # what the third ask's hit needs of the window pool: the window's
     # worth of blocks in front of the hit's boundary
     hashes = [h for _l, h in sequence_block_hashes(
         prompt[: len(prompt) - 1], bs)]
     p = len(hashes)
-    keep = set(hashes[engine.wpool.first_seen(p * bs): p])
+    keep = set(hashes[engine.kv.window.first_seen(p * bs): p])
     n = poison(engine, keep)
     say(f"poisoned {n} window-pool blocks of "
-        f"{engine.wpool.allocator.num_blocks - 1} (kept: the busy stream's "
-        f"and the {len(keep)} behind P's hit)")
+        f"{engine.kv.window.allocator.num_blocks - 1} (kept: the busy "
+        f"stream's and the {len(keep)} behind P's hit)")
     before = stats()
     got["poisoned"] = await ask(engine, prompt, ANSWER_TOKENS)
     after = stats()
     say(f"poisoned: counters { {k: after[k] - before[k] for k in after} }; "
-        f"released behind windows so far {engine.wpool.released}")
+        f"released behind windows so far {engine.kv.window.released}")
     busy.cancel()
     try:
         await busy
@@ -243,8 +244,9 @@ def main() -> int:
         prompt = [int(t) for t in rng.integers(16, vocab, args.words)]
         busy_prompt = [int(t) for t in rng.integers(16, vocab, 32)]
         engine = build_engine(config_dir, args.rehearse)
-        say(f"engine: window pool {engine.wpool.allocator.num_blocks} blocks, "
-            f"full pool {engine.allocator.num_blocks}, attention path "
+        say("engine: window pool "
+            f"{engine.kv.window.allocator.num_blocks} blocks, "
+            f"full pool {engine.kv.allocator.num_blocks}, attention path "
             f"{engine.attention_path}")
         got = asyncio.run(drive(engine, prompt, busy_prompt, say))
     except BaseException:

@@ -136,7 +136,7 @@ async def _replay(trace: list[dict], speedup: float) -> dict:
         engine = _mk_engine()
         comp = w.namespace("replay").component("worker")
         pub = KvEventPublisher(w, comp, w.primary_lease_id)
-        pub.attach(engine.allocator)
+        pub.attach(engine.kv.allocator)
         await comp.endpoint("gen").serve(
             engine, stats_handler=engine.load_metrics)
         workers.append(w)

@@ -766,7 +766,7 @@ def test_quarantined_worker_streams_drain_cleanly(run):
             engine = _mk_engine()
             comp = w.namespace("dyn").component("worker")
             pub = KvEventPublisher(w, comp, w.primary_lease_id)
-            pub.attach(engine.allocator)
+            pub.attach(engine.kv.allocator)
             await comp.endpoint("gen").serve(
                 engine, stats_handler=engine.load_metrics)
             workers.append(w)

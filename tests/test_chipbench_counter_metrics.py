@@ -235,7 +235,7 @@ def test_pr47s_metrics_read_a_server_that_has_their_series(name):
         "window_kv_resident_share": 16.0,
         "window_attn_walked_share": 100 * 70 / 470,
         "window_prefix_missed_share": 1.0}[name])
-    from dynamo_tpu.engine.engine import WINDOW_COUNTERS
+    from dynamo_tpu.engine.kv_manager import WINDOW_COUNTERS
 
     for series in s["selector"]["num"] + s["selector"]["den"]:
         assert series[len("engine_"):-len("_total")] in WINDOW_COUNTERS

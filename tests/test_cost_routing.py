@@ -617,7 +617,7 @@ def test_export_device_chain_bounded_and_nondestructive(run):
         assert len(short) == 2 and k2.shape[2] == 2
         # non-destructive: the chain is still device-resident and a
         # prefix-hit serve afterwards still claims it (stats bump)
-        assert all(eng.allocator.has_hash(h) for h in served)
+        assert all(eng.kv.allocator.has_hash(h) for h in served)
         hits0 = eng.stats["prefix_cache_hits_tokens"]
         await collect(eng.generate(Context(make_req(prompt))))
         assert eng.stats["prefix_cache_hits_tokens"] > hits0
@@ -655,7 +655,7 @@ def test_peer_server_serves_device_only_chain(run):
             pairs = sequence_block_hashes(prompt, 4)
             chain = [s for _l, s in pairs]
             # the chain is device-resident on the peer, host pool EMPTY
-            assert all(peer_eng.allocator.has_hash(h) for h in chain[:5])
+            assert all(peer_eng.kv.allocator.has_hash(h) for h in chain[:5])
             assert len(peer_eng.offload.pool) == 0
             hint = KvPrefetchHint(
                 2, [[l, s] for l, s in pairs[:5]],

@@ -606,7 +606,7 @@ def test_disagg_timeout_fails_request(run):
         outs = await collect(eng.generate(Context(make_req(list(range(20))))))
         assert outs[-1].finish_reason == FinishReason.ERROR
         # blocks were returned to the pool
-        assert decode.allocator.used_count == 0
+        assert decode.kv.allocator.used_count == 0
         await decode.close()
         await router.stop()
         await drt.shutdown()

@@ -365,14 +365,15 @@ def test_commit_respects_written_horizon(run, engine_cfg, shared_engine):
         engine = shared_engine
         bs = engine.cfg.block_size  # 4
         decode_commits = []
-        orig = engine._commit_full_blocks
+        orig = engine.kv.commit
 
-        def spy(seq, written_len=-1):
-            orig(seq, written_len)
-            if seq.slot >= 0:  # decode-window site (prefill commits pre-slot)
-                decode_commits.append((seq.committed * bs, seq.seq_len))
+        def spy(hold, tokens, written_len, chunk=False):
+            orig(hold, tokens, written_len, chunk)
+            # a decode-window site (a prefill commits its prompt, whole)
+            if written_len == len(tokens) - 1:
+                decode_commits.append((hold.committed * bs, len(tokens)))
 
-        engine._commit_full_blocks = spy
+        engine.kv.commit = spy
         try:
             # prompt 11 + admission token = 12, then window=4 dispatches
             # land a commit exactly at the seq_len=16 block boundary while
@@ -382,7 +383,7 @@ def test_commit_respects_written_horizon(run, engine_cfg, shared_engine):
             req = make_req(range(30, 41), max_tokens=8, ignore_eos=True)
             await collect(engine.generate(Context(req)))
         finally:
-            engine._commit_full_blocks = orig
+            engine.kv.commit = orig
         boundary = [c for c, sl in decode_commits if sl % bs == 0]
         assert boundary, "no window ended on a block boundary — bad geometry"
         for committed_tokens, seq_len in decode_commits:

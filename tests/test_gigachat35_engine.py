@@ -14,7 +14,8 @@ import pytest
 
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.allocator import Block
-from dynamo_tpu.engine.engine import STATE_COUNTERS, SnapshotPool
+from dynamo_tpu.engine.engine import STATE_COUNTERS
+from dynamo_tpu.engine.kv_manager import SNAPSHOT_COUNTERS, SnapshotPool
 from dynamo_tpu.models import llama
 from dynamo_tpu.parallel.mesh import MeshConfig
 from dynamo_tpu.protocols.common import (
@@ -66,9 +67,14 @@ def _check(forward, params, hf, prompt, toks, lps):
             np.testing.assert_allclose(lp, row[tid], atol=ATOL)
 
 
+def _stats(engine):
+    """The scheduler's counters and the KV manager's, as one mapping."""
+    return {**engine.stats, **engine.kv.stats}
+
+
 def _delta(engine, before):
-    return {k: engine.stats[k] - before[k] for k in (
-        "prefix_cache_hits_tokens", *STATE_COUNTERS)}
+    return {k: _stats(engine)[k] - before[k] for k in (
+        "prefix_cache_hits_tokens", *STATE_COUNTERS, *SNAPSHOT_COUNTERS)}
 
 
 def test_prefix_hits_count_up_to_the_last_snapshot(forward, tiny, sparse):
@@ -86,14 +92,14 @@ def test_prefix_hits_count_up_to_the_last_snapshot(forward, tiny, sparse):
 
     async def main():
         engine = _engine(cfg, params)
-        assert not engine.snapshots.dense and engine.snapshots.rows == 8
+        assert not engine.kv.snapshots.dense and engine.kv.snapshots.rows == 8
         # (prompt, tokens matched, tokens skipped, snapshots it takes)
         steps = ((prompt, 0, 0, 1),       # at 36, its last full block
                  (fork(9), 20, 0, 2),     # at 20 (pays) and at 28
                  (fork(11), 20, 20, 1),   # restores 20; leaves one at 32
                  (prompt, 36, 36, 0))     # the whole prompt again
         for p, matched, hit, snaps in steps:
-            before = dict(engine.stats)
+            before = _stats(engine)
             toks, lps = await _serve(engine, p, 6)
             _check(forward, params, hf, p, toks, lps)
             got = _delta(engine, before)
@@ -128,9 +134,9 @@ def test_snapshot_rows_are_reused_and_a_lost_snapshot_cuts_the_hit(
         engine = _engine(cfg, params, state_snapshots=2)
         for p in prompts:
             await _serve(engine, p, 2)
-        assert engine.stats["state_snapshot_evictions"] == 1
+        assert engine.kv.stats["state_snapshot_evictions"] == 1
         for p, hit in ((prompts[2], 16), (prompts[0], 0)):
-            before = dict(engine.stats)
+            before = _stats(engine)
             toks, lps = await _serve(engine, p, 4)
             _check(forward, params, hf, p, toks, lps)
             got = _delta(engine, before)
@@ -197,9 +203,9 @@ def test_a_small_state_gets_a_row_a_block_and_the_same_rule(forward, tiny):
 
     async def main():
         engine = _engine(cfg, params)
-        assert engine.snapshots.rows == 64 and not engine.snapshots.dense
+        assert engine.kv.snapshots.rows == 64 and not engine.kv.snapshots.dense
         for hit in (0, 20):
-            before = dict(engine.stats)
+            before = _stats(engine)
             toks, lps = await _serve(engine, prompt, 3)
             _check(forward, params, hf, prompt, toks, lps)
             assert _delta(engine, before)["prefix_cache_hits_tokens"] == hit
@@ -249,7 +255,7 @@ def test_no_state_on_the_wire(tiny):
     _hf, cfg, params = tiny
     engine = _engine(cfg, params)
     with pytest.raises(ValueError, match="per-sequence state"):
-        engine._no_state_transfer("prefill_extract (disaggregation)")
+        engine.kv.refuse_transfer("prefill_extract (disaggregation)")
 
 
 # ---------------- the pool's map, alone ----------------

@@ -159,7 +159,7 @@ def test_kv_routed_serving(run):
             engine = make_worker_engine()
             comp = w.namespace("dyn").component("worker")
             pub = KvEventPublisher(w, comp, w.primary_lease_id)
-            pub.attach(engine.allocator)
+            pub.attach(engine.kv.allocator)
             await comp.endpoint("gen").serve(engine, stats_handler=engine.load_metrics)
             workers.append(w)
             engines.append(engine)

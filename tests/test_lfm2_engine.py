@@ -11,6 +11,7 @@ import pytest
 
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.engine import MOE_COUNTERS, STATE_COUNTERS
+from dynamo_tpu.engine.kv_manager import SNAPSHOT_COUNTERS
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.parallel.mesh import MeshConfig
 from dynamo_tpu.protocols.common import (
@@ -71,11 +72,13 @@ def test_engine_prefix_hit_equals_the_cold_run(forward, tiny):
     async def main():
         engine = _engine(cfg, params)
         for p, hit in ((prompt, 0), (prompt, 36), (fork, 20)):
-            before = dict(engine.stats)
+            before = {**engine.stats, **engine.kv.stats}
             toks, lps = await _serve(engine, p, 6)
             _check(forward, params, hf, p, toks, lps)
-            got = {k: engine.stats[k] - before[k] for k in (
-                "prefix_cache_hits_tokens", *STATE_COUNTERS)}
+            now = {**engine.stats, **engine.kv.stats}
+            got = {k: now[k] - before[k] for k in (
+                "prefix_cache_hits_tokens", *STATE_COUNTERS,
+                *SNAPSHOT_COUNTERS)}
             assert got["prefix_cache_hits_tokens"] == hit
             assert got["prefix_matched_tokens"] == hit
             assert got["state_restores"] == (hit > 0)

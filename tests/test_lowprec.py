@@ -171,8 +171,8 @@ def test_every_fresh_allocation_queues_a_scale_reset(run):
         eng = JaxEngine(engine_cfg(kv_cache_dtype="int8"), params=PARAMS)
         try:
             seen = []
-            inner = eng.allocator.on_allocated
-            eng.allocator.on_allocated = lambda i: (seen.append(i),
+            inner = eng.kv.allocator.on_allocated
+            eng.kv.allocator.on_allocated = lambda i: (seen.append(i),
                                                     inner(i))
             await serve_tokens(eng, range(10, 30), max_tokens=4)
             assert seen, "fresh allocations must queue scale resets"

@@ -15,7 +15,7 @@ import pytest
 
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.allocator import sequence_block_hashes
-from dynamo_tpu.engine.engine import WINDOW_COUNTERS
+from dynamo_tpu.engine.kv_manager import WINDOW_COUNTERS
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.protocols.common import (
     PreprocessedRequest, SamplingOptions, StopConditions,
@@ -64,8 +64,14 @@ def _prompt(seed, n):
     return [int(t) for t in np.random.default_rng(seed).integers(16, 512, n)]
 
 
+def _stats(engine):
+    """The scheduler's counters and the KV manager's, as one mapping."""
+    return {**engine.stats, **engine.kv.stats}
+
+
 def _used(engine):
-    return engine.allocator.used_count, engine.wpool.allocator.used_count
+    return (engine.kv.allocator.used_count,
+            engine.kv.window.allocator.used_count)
 
 
 def _poison_released(engine):
@@ -74,7 +80,7 @@ def _poison_released(engine):
     the pool hands it out again (then it is zeroed: the XLA attention of
     these CPU tests multiplies a masked key's zero weight with what the
     block holds, and a fresh block's unwritten rows are masked keys)."""
-    pool = engine.wpool
+    pool = engine.kv.window
     release = pool.release_behind
 
     def fill(blocks, value):
@@ -105,7 +111,7 @@ def test_chunked_prefill_and_decode_hold_a_window_not_a_history(forward, tiny):
     its full-pool blocks grow with the context; both pools end empty."""
     hf, cfg, params = tiny
     engine = _engine(cfg, params)
-    assert engine.wpool.window == W
+    assert engine.kv.window.window == W
     _poison_released(engine)
     peak = [0, 0]
     step_done = engine._step_done
@@ -129,7 +135,7 @@ def test_chunked_prefill_and_decode_hold_a_window_not_a_history(forward, tiny):
     assert peak[1] <= -(-(W + CHUNK) // BS) + 1
     assert peak[0] >= (len(prompt) + len(toks)) // BS
     assert _used(engine) == (0, 0)
-    st = engine.stats
+    st = engine.kv.stats
     assert 0 < st["kv_window_resident_tokens"] < st["kv_window_context_tokens"] / 3
     assert 0 < st["attn_window_pages"] < st["attn_window_context_pages"] / 3
     # every gauge and counter of the window pool is exported
@@ -155,7 +161,7 @@ def test_mixed_steps_and_many_rows_stay_under_the_bound(forward, tiny):
     step_done = engine._step_done
 
     def watching(*info):
-        peak[0] = max(peak[0], engine.wpool.allocator.used_count)
+        peak[0] = max(peak[0], engine.kv.window.allocator.used_count)
         step_done(*info)
 
     engine._step_done = watching
@@ -203,7 +209,7 @@ def test_preemption_and_resume(forward, tiny):
 def _forget(engine, blocks):
     """The window pool reuses ``blocks`` (indices along the first
     prompt's chain): they hold other content now."""
-    alloc = engine.wpool.allocator
+    alloc = engine.kv.window.allocator
     for h in blocks:
         b = alloc.claim(h)
         del alloc._by_hash[h]
@@ -231,14 +237,14 @@ def test_the_prefix_rule(forward, tiny, lost, hit):
         await _serve(engine, first, 4)
         chain = [h for _l, h in sequence_block_hashes(first, BS)]
         _forget(engine, [chain[i] for i in lost])
-        before = dict(engine.stats)
+        before = _stats(engine)
         toks, lps = await _serve(engine, second, 6)
         await engine.close()
         return toks, lps, before
 
     toks, lps, before = asyncio.run(run())
     _check(forward, params, hf, second, toks, lps)
-    delta = {k: engine.stats[k] - before[k] for k in (
+    delta = {k: _stats(engine)[k] - before[k] for k in (
         "prefix_cache_hits_tokens", "prefix_matched_tokens",
         "prefix_window_missed_tokens")}
     assert delta == {"prefix_cache_hits_tokens": hit,
@@ -276,7 +282,8 @@ def test_a_model_with_one_kind_of_layer_keeps_one_pool(tiny):
         engine = JaxEngine(EngineConfig(
             model=cfg, num_blocks=16, block_size=BS, max_batch_size=2,
             max_context=64))
-        assert engine.wpool is None and not isinstance(engine.k_cache, tuple)
+        assert engine.kv.window is None and not isinstance(
+            engine.k_cache, tuple)
         assert not any(
             word in k for k in engine.device_path_stats()
             for word in ("kv_window", "attn_window", "window_missed",
@@ -289,7 +296,7 @@ def test_the_window_pools_size_is_derived_or_asked(tiny):
     and chunk and a lone prefill's chunk (the floor), and a window a slot
     of cached tails; ``window_blocks`` replaces it and may not lie under
     the floor. A model with one pool has none."""
-    from dynamo_tpu.engine.engine import window_pool_blocks
+    from dynamo_tpu.engine.kv_manager import window_pool_blocks
 
     hf, cfg, params = tiny
     served = ModelConfig.from_local_path(
@@ -301,7 +308,7 @@ def test_the_window_pools_size_is_derived_or_asked(tiny):
         window_pool_blocks(served, 32, 16, 512, 2048, 3000)
     assert window_pool_blocks(ModelConfig.tiny(), 32, 16, 512, 2048) == 0
     engine = _engine(cfg, params, window_blocks=80)
-    assert engine.wpool.allocator.num_blocks == 80
+    assert engine.kv.window.allocator.num_blocks == 80
     assert engine.k_cache[1].shape[2] == engine.v_cache[1].shape[2] == 80
     with pytest.raises(ValueError, match="window_blocks=20"):
         _engine(cfg, params, window_blocks=20)
@@ -326,7 +333,7 @@ def test_a_long_prompts_inside_does_not_push_out_other_contexts_tails(
         for p in short:
             await _serve(engine, p, 2)
         await _serve(engine, long, 2)
-        before = dict(engine.stats)
+        before = _stats(engine)
         out = [await _serve(engine, p + _prompt(53, 6), 4) for p in short]
         # and the long one's own tail is there too
         out.append(await _serve(engine, long + _prompt(54, 5), 4))
@@ -337,7 +344,7 @@ def test_a_long_prompts_inside_does_not_push_out_other_contexts_tails(
     for prompt, extra, (toks, lps) in zip(
             short + [long], (_prompt(53, 6),) * 2 + (_prompt(54, 5),), out):
         _check(forward, params, hf, prompt + extra, toks, lps)
-    assert engine.stats["prefix_window_missed_tokens"] == before[
+    assert engine.kv.stats["prefix_window_missed_tokens"] == before[
         "prefix_window_missed_tokens"] == 0
     hit = engine.stats["prefix_cache_hits_tokens"] - before[
         "prefix_cache_hits_tokens"]

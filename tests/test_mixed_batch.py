@@ -324,9 +324,9 @@ def test_multi_prefill_one_prompt_cancelled_mid_mixture(run):
         # residents are LRU-claimable, a leaked refcount is not)
         assert not engine._prefill_states
         assert engine._n_active == 0
-        fresh = engine.allocator.allocate(engine.allocator.num_blocks - 1)
+        fresh = engine.kv.allocator.allocate(engine.kv.allocator.num_blocks - 1)
         assert fresh is not None, "cancelled prompt leaked block refs"
-        engine.allocator.free(fresh)
+        engine.kv.allocator.free(fresh)
         await engine.close()
         return dec_out, outs
 

@@ -278,7 +278,7 @@ def test_cancel_mid_upload_rolls_back(run, monkeypatch):
     async def main():
         toks_ref = await _park_in_host_tier(engine, prompt_a)
         resident_before = len(engine.offload.pool)
-        free_before = engine.allocator.free_count
+        free_before = engine.kv.allocator.free_count
         ctx = Context(_req(prompt_a, 2))
         seq = _Sequence(
             request=ctx.data, context=ctx.context,
@@ -300,7 +300,7 @@ def test_cancel_mid_upload_rolls_back(run, monkeypatch):
         # reservation rolled back: pool regained the chain, device
         # blocks freed, the abandonment is counted
         assert len(engine.offload.pool) == resident_before
-        assert engine.allocator.free_count == free_before
+        assert engine.kv.allocator.free_count == free_before
         assert engine.offload.h2d_uploads_cancelled == 1
 
         # and the chain still restores, bit-exact

@@ -73,7 +73,7 @@ def test_reshard_soak_morphs_mid_wave(run):
             engine = make_engine()
             comp = w.namespace("soak").component("worker")
             KvEventPublisher(w, comp, w.primary_lease_id).attach(
-                engine.allocator
+                engine.kv.allocator
             )
             listener = await ReshardListener(
                 w, comp, w.primary_lease_id, engine

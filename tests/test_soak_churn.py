@@ -55,7 +55,7 @@ async def spawn_worker(hub_addr):
     engine = make_engine()
     comp = drt.namespace("soak").component("worker")
     pub = KvEventPublisher(drt, comp, drt.primary_lease_id)
-    pub.attach(engine.allocator)
+    pub.attach(engine.kv.allocator)
     await comp.endpoint("gen").serve(
         engine, stats_handler=engine.load_metrics)
     return drt, conn, engine

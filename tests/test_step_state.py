@@ -246,7 +246,7 @@ def test_streams_equal_the_host_fed_engines(name, monkeypatch):
         assert st["preemptions"] > 0
         assert evicted_under and all(w is None for w in evicted_under)
     if name == "mellum2-window-pool":
-        assert engines[0].wpool.released > 0, "no page left a window"
+        assert engines[0].kv.window.released > 0, "no page left a window"
 
 
 def test_pipelined_windows_chain_in_the_resident_state():
