@@ -2379,12 +2379,18 @@ class JaxEngine(AsyncEngine):
             self.state, jnp.int32(st.seq.state_slot),
             jnp.int32(self.kv.restore_from(st.seq.hold)))
 
-    def _note_state(self, segments: int, decode_steps: int = 0) -> None:
+    def _note_state(self, segments: int, decode_steps: int = 0,
+                    live_rows: int = 0) -> None:
         """Bytes of recurrent matrices a dispatch reads and writes: a
-        prefill segment its sequence's, a decode step every slot's (the
-        program carries dead slots' through unchanged)."""
+        prefill segment its sequence's, a decode step its ``live_rows``'
+        (the kernel walks the live rows and leaves a dead slot's where
+        they lie: ops/gated_delta_pallas). With kernels off the plain
+        step carries every slot's through, and every slot counts: the
+        counter says what the program moves."""
+        if not llama.linear_step_walks_live(self.cfg.model, self.use_pallas):
+            live_rows = self.cfg.max_batch_size
         self.stats["linear_state_bytes"] += 2 * self._rec_row_bytes * (
-            segments + decode_steps * self.cfg.max_batch_size)
+            segments + decode_steps * live_rows)
 
     def _state_kw(self, seq: Optional[_Sequence] = None,
                   end: int = 0) -> dict:
@@ -3885,6 +3891,7 @@ class JaxEngine(AsyncEngine):
                     p_ids[i] = st.seq.adapter_id
                 kwargs.update(lora=self.adapters.device_stack())
                 segs["p_adapter_ids"] = p_ids
+            live_rows = int((self._rows.seq_lens > 0).sum())
             if self.state is not None:
                 # a dead segment names a row past the state: dropped
                 slots_p = np.full(MP, cfg.max_batch_size, np.int32)
@@ -3897,9 +3904,8 @@ class JaxEngine(AsyncEngine):
                     [(st.seq.hold, st.pos + take) for st, take in packed], MP)
                 if snaps_p is not None:
                     segs["p_snaps"] = snaps_p
-                self._state_rows += len(packed) + int(
-                    (self._rows.seq_lens > 0).sum())
-                self._note_state(len(packed), 1)
+                self._state_rows += len(packed) + live_rows
+                self._note_state(len(packed), 1, live_rows)
             kwargs.update(jax.device_put(segs, self._rows.sharding))
             self._handed("mixed", 1)
             self._note_prefill_work(MP * T, int(valids_p.sum()))
@@ -3911,7 +3917,6 @@ class JaxEngine(AsyncEngine):
             ))
             self.kv.note_work(1, self._live_holds(seq_lens), [
                 (st.pos, take) for st, take in packed])
-            live_rows = int((self._rows.seq_lens > 0).sum())
             out = self._timed_dispatch(lambda: llama.mixed_step(
                 self.params,
                 cfg.model,
@@ -4354,7 +4359,7 @@ class JaxEngine(AsyncEngine):
         if self.state is not None:
             self.state = rest.pop(0)
             self._state_rows += live_rows
-            self._note_state(0, n)
+            self._note_state(0, n, live_rows)
         if quantized:
             self.k_scales = rest.pop(0)
             self.v_scales = rest.pop(0)

@@ -1839,6 +1839,18 @@ def gated_delta(lp: dict, cfg: ModelConfig, h: jnp.ndarray, parts: list,
     return _mm(y, lp["lin_out"]), exts, recs
 
 
+def linear_step_walks_live(cfg: ModelConfig, use_pallas: bool,
+                           interpret: bool = False) -> bool:
+    """Whether a decode batch's recurrence runs in the Pallas kernel,
+    which moves the LIVE rows' matrices only (ops/gated_delta_pallas);
+    ``delta_rule_step`` carries every slot's through."""
+    from ..ops.gated_delta_pallas import kernel_serves
+
+    return bool(cfg.linear_layers) and use_pallas and (
+        interpret or kernel_serves(cfg.linear_value_heads,
+                                   cfg.linear_key_dim, cfg.linear_value_dim))
+
+
 class Segments(NamedTuple):
     """S segments of T rows each, as a step program hands them to the
     state layers: a decode batch (T = 1) or prefill chunks."""
@@ -1928,19 +1940,22 @@ class StateTrack:
         move in place in ``rec`` (and into ``snap_rec`` where a segment
         names a snapshot row). With kernels on, a decode batch (every
         slot a segment of one row) takes its step in the Pallas kernel,
-        which reads and writes the layer's matrices once, where they
-        lie (ops/gated_delta_pallas)."""
+        which reads and writes the LIVE rows' matrices of the layer once,
+        where they lie, and leaves a dead slot's alone
+        (ops/gated_delta_pallas)."""
         from ..ops import gated_delta_pallas as gdp
 
         cfg = self.cfg
-        kernel = use_pallas and (interpret or gdp.kernel_serves(
-            cfg.linear_value_heads, cfg.linear_key_dim, cfg.linear_value_dim))
+        kernel = linear_step_walks_live(cfg, use_pallas, interpret)
 
-        def in_place(q, k, v, g, beta, _rec_in):
-            o, self.rec = gdp.linear_attn_recurrent_step(
-                q, k, v, g, beta, self.rec, jnp.int32(ci),
-                interpret=interpret)
-            return o, None
+        def in_place(n):
+            def step(q, k, v, g, beta, _rec_in):
+                o, self.rec = gdp.linear_attn_recurrent_step(
+                    q, k, v, g, beta, self.rec, jnp.int32(ci), n,
+                    interpret=interpret)
+                return o, None
+
+            return step
 
         parts, steps = [], []
         for g, st in zip(self.groups, self.start):
@@ -1948,7 +1963,7 @@ class StateTrack:
             rec_in = (None if whole else self.rec[ci] if g.rows is None
                       else self.rec[ci, g.rows])
             parts.append((st.shape[0], g.T, g.n, st[:, ci], rec_in))
-            steps.append(in_place if whole else None)
+            steps.append(in_place(g.n) if whole else None)
         y, exts, recs = gated_delta(lp, cfg, h, parts, steps)
         self.add(exts)
         for g, rec in zip(self.groups, recs):

@@ -512,37 +512,126 @@ def test_tally_counts_the_held_assignments(tiny):
 # ---------------- the decode step's kernel ----------------
 
 
-def test_recurrent_step_kernel_matches_the_plain_step():
-    """The Pallas kernel (interpret mode) against ``delta_rule_step``:
-    one layer of a layer-major state moved one token on, in place, the
-    other layers and a dead row (g = 0, beta = 0) bit for bit as they
-    were. Both are float32 multiply-adds of the same terms; only the
-    order of a 16-term sum differs."""
-    from chipbench import kernel_work
-    from dynamo_tpu.ops.gated_delta_pallas import linear_attn_recurrent_step
-
-    Ll, B, Hv, Dk, Dv = 3, 4, 8, 16, 128
+def _step_operands(B=6, Hv=8, Dk=16):
+    Ll, Dv = 3, 128
     ks = jax.random.split(jax.random.key(12), 6)
     q = jax.random.normal(ks[0], (B, Hv, Dk)) * 0.3
     k = jax.random.normal(ks[1], (B, Hv, Dk)) * 0.3
     v = jax.random.normal(ks[2], (B, Hv, Dv))
     g = -jax.random.uniform(ks[3], (B, Hv), minval=0.01, maxval=0.5)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, Hv)))
-    g, beta = g.at[2].set(0.0), beta.at[2].set(0.0)  # a dead slot
     rec = jax.random.normal(ks[5], (Ll, B, Hv, Dk, Dv))
+    return q, k, v, g, beta, rec
+
+
+@pytest.mark.parametrize("alive,heads", [
+    pytest.param([1, 1, 1, 1, 1, 1], 8, id="all-live"),
+    pytest.param([0, 0, 0, 0, 0, 0], 8, id="none-live"),
+    pytest.param([1, 0, 0, 0, 0, 0], 8, id="only-slot-0"),
+    pytest.param([0, 0, 0, 0, 0, 1], 8, id="only-the-last-slot"),
+    pytest.param([0, 1, 0, 0, 1, 1], 8, id="a-scattered-half"),
+    pytest.param([1, 1, 0, 1, 1, 1], 8, id="one-dead-between-live"),
+    # the published head count: two grid steps of 32 heads a live row,
+    # each a loop over four unrolled groups (8 heads are one group)
+    pytest.param([1, 0, 1], 64, id="64-heads"),
+])
+def test_recurrent_step_kernel_matches_the_plain_step(alive, heads):
+    """The Pallas kernel (interpret mode) against ``delta_rule_step``:
+    one layer of a layer-major state moved one token on, in place, for
+    the LIVE rows (``n > 0``); the other layers and the dead rows'
+    matrices bit for bit as they were, a dead row's ``o`` zero. Both are
+    float32 multiply-adds of the same terms; only the order of a 16-term
+    sum differs."""
+    from chipbench import kernel_work
+    from dynamo_tpu.ops.gated_delta_pallas import (
+        HEADS_PER_STEP, HEADS_UNROLLED, linear_attn_recurrent_step,
+    )
+
+    assert (HEADS_PER_STEP, HEADS_UNROLLED) == (32, 8)
+    q, k, v, g, beta, rec = _step_operands(len(alive), heads)
+    a = np.asarray(alive, bool)
+    n = jnp.asarray(a.astype(np.int32) * 3)  # any count of real tokens
     want_o, want_S = llama.delta_rule_step(q, k, v, g, beta, rec[1])
     o, out = linear_attn_recurrent_step(
-        q, k, v, g, beta, jnp.array(rec), jnp.int32(1), interpret=True)
-    np.testing.assert_allclose(o, want_o, atol=1e-5)
-    np.testing.assert_allclose(out[1], want_S, atol=1e-5)
+        q, k, v, g, beta, jnp.array(rec), jnp.int32(1), n, interpret=True)
+    o, out = np.asarray(o), np.asarray(out)
+    np.testing.assert_allclose(o[a], np.asarray(want_o)[a], atol=1e-5)
+    np.testing.assert_allclose(out[1][a], np.asarray(want_S)[a], atol=1e-5)
+    np.testing.assert_array_equal(out[1][~a], np.asarray(rec[1])[~a])
+    np.testing.assert_array_equal(o[~a], 0.0)
     np.testing.assert_array_equal(out[0], rec[0])
     np.testing.assert_array_equal(out[2], rec[2])
-    np.testing.assert_array_equal(out[1, 2], rec[1, 2])
     # what a call moves at the published widths: 32 rows x 64 heads
     assert kernel_work.linear_attn_recurrent_step_bytes(
         32, 64, 128, 128) == 2 * (128 << 20) + 6 * (1 << 20)
     assert kernel_work.linear_attn_recurrent_step_flops(
         32, 64, 128, 128) == 7 * (32 << 20)
+
+
+def test_recurrent_step_kernel_does_not_read_a_dead_row():
+    """Everything a dead slot holds, poisoned: its ``q, k, v, g, beta``
+    AND its matrices are NaN. The live rows' results are those of the
+    clean call, bit for bit, and the dead rows' matrices come back as
+    they went in (NaN where NaN was: moved by nothing)."""
+    from dynamo_tpu.ops.gated_delta_pallas import linear_attn_recurrent_step
+
+    q, k, v, g, beta, rec = _step_operands()
+    a = np.asarray([1, 0, 1, 0, 0, 1], bool)
+    n = jnp.asarray(a.astype(np.int32))
+    clean_o, clean = linear_attn_recurrent_step(
+        q, k, v, g, beta, jnp.array(rec), jnp.int32(1), n, interpret=True)
+    dead = jnp.asarray(~a)
+    nan = lambda x: jnp.where(  # noqa: E731
+        dead.reshape((-1,) + (1,) * (x.ndim - 1)), jnp.nan, x)
+    bad = rec.at[1].set(nan(rec[1]))
+    o, out = linear_attn_recurrent_step(
+        nan(q), nan(k), nan(v), nan(g), nan(beta), jnp.array(bad),
+        jnp.int32(1), n, interpret=True)
+    np.testing.assert_array_equal(np.asarray(o)[a], np.asarray(clean_o)[a])
+    np.testing.assert_array_equal(np.asarray(o)[~a], 0.0)
+    np.testing.assert_array_equal(np.asarray(out[1])[a],
+                                  np.asarray(clean[1])[a])
+    assert np.isnan(np.asarray(out[1])[~a]).all()
+    np.testing.assert_array_equal(out[0], rec[0])
+    np.testing.assert_array_equal(out[2], rec[2])
+
+
+def test_decode_program_hands_the_kernel_the_whole_state(tiny):
+    """A kernels-on decode program of the tiny model: ONE ``pallas_call``
+    named ``linear_attn_recurrent_step`` a linear layer, with three
+    scalar-prefetch operands (the layer, the live slots, their count:
+    the kernel walks the live rows), and its state operand the whole
+    ``[Ll, B, Hv, Dk, Dv]`` array: no equation cuts a layer's matrices
+    out of it (a slice in front of a custom call is a copy, 128 MiB a
+    layer at the published widths)."""
+    from conftest import jaxpr_eqns
+
+    _hf, cfg, params = tiny
+    B, M, N = 4, 8, 24
+    kc, vc = llama.init_kv_cache(cfg, N, BS)
+    state = llama.init_state(cfg, B, N, 4)
+    zi, zf = jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, kc, vc, state: llama.decode_window(
+            p, cfg, zi, zi, jnp.zeros((B, M), jnp.int32), zi + 1, zi, zi, zf,
+            zi, zf + 1, kc, vc, n_steps=1, state=state, use_pallas=True,
+            interpret=True))(params, kc, vc, state)
+    rec_shape = state["rec"].shape
+    assert rec_shape[:2] == (cfg.linear_layers, B)
+    calls = 0
+    for eqn in jaxpr_eqns(jaxpr.jaxpr):
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            assert shape not in (rec_shape[1:], (1, *rec_shape[1:])), (
+                f"{eqn.primitive.name} produces a layer's matrices {shape}")
+        if eqn.primitive.name != "pallas_call" or (
+                eqn.params["name"] != "linear_attn_recurrent_step"):
+            continue
+        calls += 1
+        assert eqn.params["grid_mapping"].num_index_operands == 3
+        assert [v.aval.shape for v in eqn.invars].count(rec_shape) == 1
+        assert eqn.outvars[1].aval.shape == rec_shape
+    assert calls == cfg.linear_layers == 4
 
 
 def test_decode_through_the_kernels_matches_the_reference(forward, tiny):

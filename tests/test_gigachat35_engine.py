@@ -8,6 +8,7 @@ of ``tests/test_gigachat35.py``, whose fixtures these are."""
 
 import asyncio
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -232,6 +233,85 @@ def test_counts_held_assignments_and_state_bytes(tiny, sparse):
         assert s["linear_state_bytes"] % (2 * row) == 0
         assert m["engine_state_bytes"] == sum(
             a.size * a.dtype.itemsize for a in engine.state.values())
+        await engine.close()
+
+    asyncio.run(main())
+
+
+@pytest.fixture()
+def kernels_on(monkeypatch):
+    """The engine as the chip runs it, where the CPU can: decode windows
+    and mixed steps with the kernels on in interpret mode (``use_pallas``
+    follows the backend, so a test sets it on the engine it has built);
+    prefill chunks (alone or in a mixed step) stay on the XLA path."""
+    prefill, walks = llama.prefill, llama.linear_step_walks_live
+    monkeypatch.setattr(
+        llama, "linear_step_walks_live",
+        lambda cfg, use_pallas, interpret=False: walks(cfg, use_pallas, True))
+    for name in ("decode_window", "mixed_step"):
+        monkeypatch.setattr(llama, name, functools.partial(
+            getattr(llama, name), interpret=True))
+
+    def xla(fn):
+        return lambda *a, **kw: fn(*a, **dict(kw, use_pallas=False))
+
+    alone = xla(prefill)
+    alone.__wrapped__ = xla(prefill.__wrapped__)  # a mixed step's chunks
+    monkeypatch.setattr(llama, "prefill", alone)
+
+
+def test_a_decode_step_counts_and_moves_its_live_rows(
+        forward, tiny, sparse, kernels_on):
+    """Two sequences decode in an engine of four slots while a third is
+    admitted in chunks beside them: a decode step adds the TWO live rows'
+    matrices to ``linear_state_bytes``, read and written, not the four
+    slots' (the kernel walks the live rows; with kernels off every slot
+    counts, as the plain step carries every slot). The slot the third
+    sequence owns while it is still prefilling is dead to the decode
+    group of every mixed step that carries its chunks, in the SAME
+    program and after the chunk has written it: the kernel leaves its
+    matrices where they lie, or the third stream's logits would not be
+    the reference's. Every stream's are."""
+    hf, cfg, params = tiny
+    rng = np.random.default_rng(27)
+    short = [[int(t) for t in rng.integers(16, 512, n)] for n in (9, 13)]
+    long = [int(t) for t in rng.integers(16, 512, 40)]
+    row = 4 * 4 * 16 * 16 * 4  # 4 layers x 4 heads x [16, 16] float32
+
+    async def main():
+        engine = _engine(cfg, params, mixed_step_budget=16,
+                         mixed_max_prefills=1)
+        assert engine._rec_row_bytes == row and not engine.use_pallas
+        engine._note_state(0, 1, 2)
+        assert engine.stats["linear_state_bytes"] == 2 * row * 4
+        engine.use_pallas = True
+        engine.stats["linear_state_bytes"] = 0
+        seen = []  # (segments, decode steps, live rows, bytes added)
+        note = engine._note_state
+
+        def spy(segments, decode_steps=0, live_rows=0):
+            before = engine.stats["linear_state_bytes"]
+            note(segments, decode_steps, live_rows)
+            seen.append((segments, decode_steps, live_rows,
+                         engine.stats["linear_state_bytes"] - before))
+
+        engine._note_state = spy
+        first = [asyncio.ensure_future(_serve(engine, p, 10)) for p in short]
+        while engine.stats["decode_steps"] < 2:
+            await asyncio.sleep(0.01)
+        third = asyncio.ensure_future(_serve(engine, long, 3))
+        outs = [await f for f in first] + [await third]
+        for p, (toks, lps) in zip(short + [long], outs):
+            _check(forward, params, hf, p, toks, lps)
+        assert engine.stats["mixed_steps"] >= 3
+        for segments, steps, live, added in seen:
+            assert added == 2 * row * (segments + steps * live)
+        # decode windows of both short streams alone: two rows a step
+        assert any(seg == 0 and live == 2 for seg, _s, live, _a in seen)
+        # the long prompt's chunks beside them: its own segment and two rows
+        assert any(seg == 1 and live == 2 for seg, _s, live, _a in seen)
+        assert all(live <= 3 for _seg, _s, live, _a in seen)
+        assert engine.stats["linear_state_bytes"] % (2 * row) == 0
         await engine.close()
 
     asyncio.run(main())

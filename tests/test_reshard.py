@@ -555,18 +555,20 @@ def test_reshard_listener_applies_and_filters(run):
             MorphDecision(worker_id=0, tp=2, pool="prefill"),
             lambda: eng.cfg.mesh is not None, n=25,
         )
-        # pool-wide grow applies
+        # pool-wide grow applies (the listener counts a morph AFTER the
+        # engine's reshard has returned: wait for the count, the mesh is
+        # set a task switch earlier)
         assert await publish_and_wait(
             MorphDecision(worker_id=0, tp=2, reason="grow_tp"),
-            lambda: eng.cfg.mesh is not None and eng.cfg.mesh.tp == 2,
+            lambda: listener.morphs_applied == 1,
         )
-        assert listener.morphs_applied == 1
+        assert eng.cfg.mesh is not None and eng.cfg.mesh.tp == 2
         # shrink normalizes the all-ones mesh back to unsharded
         assert await publish_and_wait(
             MorphDecision(worker_id=7, tp=1, reason="shrink_tp"),
-            lambda: eng.cfg.mesh is None,
+            lambda: listener.morphs_applied == 2,
         )
-        assert listener.morphs_applied == 2
+        assert eng.cfg.mesh is None
         # same degree again: noop, not an error
         assert await publish_and_wait(
             MorphDecision(worker_id=0, tp=1),

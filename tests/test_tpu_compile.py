@@ -693,7 +693,9 @@ def test_linear_attn_recurrent_step_at_gigachat35_widths(chip):
     of [128, 128] float32, in place in layer 2 of a 4-layer state of 512
     MiB: the whole state is aliased to the output, nothing of it is a
     temporary (a ``rec[li]`` operand would be a copy of a layer's 128
-    MiB)."""
+    MiB). Which slots are live is an operand (``n``: 13 of the 32 in the
+    benchmark's cell), so one program serves every load and the index
+    maps that walk the live rows are in it."""
     from dynamo_tpu.ops.gated_delta_pallas import (
         kernel_serves, linear_attn_recurrent_step,
     )
@@ -702,14 +704,17 @@ def test_linear_attn_recurrent_step_at_gigachat35_widths(chip):
     assert kernel_serves(hv, dk, dv) and not kernel_serves(4, 16, 16)
     f = jnp.float32
 
-    def step(q, k, v, g, beta, rec):
-        return linear_attn_recurrent_step(q, k, v, g, beta, rec, jnp.int32(2))
+    def step(q, k, v, g, beta, rec, n):
+        return linear_attn_recurrent_step(
+            q, k, v, g, beta, rec, jnp.int32(2), n)
 
     compiled = jax.jit(step, donate_argnums=(5,)).lower(
         chip((b, hv, dk), f), chip((b, hv, dk), f), chip((b, hv, dv), f),
         chip((b, hv), f), chip((b, hv), f), chip((ll, b, hv, dk, dv), f),
+        chip((b,), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") and "linear_attn_recurrent_step" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == ll * b * hv * dk * dv * 4
     assert mem.temp_size_in_bytes < 16 << 20
