@@ -945,6 +945,11 @@ class JaxEngine(AsyncEngine):
             # all-gather back, so pp meshes keep the XLA absorbed path.
             if m.kv_lora_rank % 128:
                 return f"kv_lora_rank {m.kv_lora_rank} is not 128-aligned"
+            # the decode kernel cuts its pages out of the pools itself:
+            # whole 128-lane rows (``llama.rope_lanes`` seats 64 in 128)
+            if llama.rope_lanes(m) % 128:
+                return (f"qk_rope_head_dim {m.qk_rope_head_dim} does not "
+                        "fill 128-lane rows")
             if mesh is not None and mesh.shape.get("pp", 1) != 1:
                 return "pp shards the latent cache's layer axis"
             # the sharded latent kernels shard_map the QUERY-head axis

@@ -484,8 +484,28 @@ def kv_lanes(cfg: ModelConfig) -> int:
     and outputs are those of the unpadded head. Heads the kernels never
     take (not a multiple of 64: the tests' tiny models) stay as they
     are."""
-    D = cfg.head_dim
-    return -(-D // 128) * 128 if D % 64 == 0 else D
+    return _in_lanes(cfg.head_dim)
+
+
+def _in_lanes(width: int) -> int:
+    """``width`` in whole 128-lane rows if the kernels take it (a multiple
+    of 64), else as it is (the tests' tiny models)."""
+    return -(-width // 128) * 128 if width % 64 == 0 else width
+
+
+def rope_lanes(cfg: ModelConfig) -> int:
+    """Width of a token's row in a latent model's rope pool, and of
+    ``q_pe`` / ``k_pe`` wherever attention sees them: ``kv_lanes``' rule
+    for ``qk_rope_head_dim`` (64 rides in 128 lanes for every published
+    latent model, upper lanes zero: they add nothing to ``q_pe . k_pe``).
+    The latent decode kernel cuts its pages out of the pool itself, and
+    a pool of 64 lanes is not even row-major on the chip: its default
+    layout is pages-minor, which every program that takes the pool
+    re-laid at entry and exit (``ops/mla_attention_pallas.py``; PERF.md
+    section 6, PR 53). A token of latent cache is ``kv_lora_rank +
+    rope_lanes`` values a layer, 1,280 B where the model's own widths
+    make 1,152."""
+    return _in_lanes(cfg.qk_rope_head_dim)
 
 
 def _from_lanes(cfg: ModelConfig, o_flat: jnp.ndarray) -> jnp.ndarray:
@@ -503,8 +523,9 @@ def kv_cache_shapes(
 ) -> tuple[tuple, tuple]:
     """(k_shape, v_shape). MLA stores the compressed latent instead of
     per-head K/V: c_kv rides the k slot, the head-shared rotated k_pe the
-    v slot — both single-"head" paged arrays, so every block-table /
-    allocator / offload / transfer path works unchanged (models/mla.py).
+    v slot (in ``rope_lanes`` lanes) — both single-"head" paged arrays,
+    so every block-table / allocator / offload / transfer path works
+    unchanged (models/mla.py).
     Every other family stores a head in ``kv_lanes`` lanes. The layer
     axis counts the layers that hold keys and values (an LFM2 stack's
     attention layers: ``cfg.op_index`` is a layer's index here)."""
@@ -512,7 +533,7 @@ def kv_cache_shapes(
     if cfg.is_mla:
         return (
             (L, 1, num_blocks, block_size, cfg.kv_lora_rank),
-            (L, 1, num_blocks, block_size, cfg.qk_rope_head_dim),
+            (L, 1, num_blocks, block_size, rope_lanes(cfg)),
         )
     s = (L, cfg.num_kv_heads, num_blocks, block_size, kv_lanes(cfg))
     return s, s
@@ -2439,12 +2460,12 @@ def _decode_body(
             pe_news.append(k_pe)
             if mesh is None:
                 o_lat = _mla_ops.mla_decode_attention_merged(
-                    q_eff, q_pe, c_kv, k_pe, k_cache[a], v_cache[a],
+                    q_eff, q_pe, c_kv, k_pe, k_cache, v_cache, a,
                     block_tables, hist_lens, scale, interpret=interpret,
                 )
             else:
                 o_lat = _mla_ops.mla_decode_attention_merged_sharded(
-                    q_eff, q_pe, c_kv, k_pe, k_cache[a], v_cache[a],
+                    q_eff, q_pe, c_kv, k_pe, k_cache, v_cache, a,
                     block_tables, hist_lens, scale, mesh,
                     interpret=interpret,
                 )
@@ -3309,7 +3330,7 @@ def _verify_forward(
                 c_news.append(c_kv)
                 pe_news.append(k_pe)
                 o = _mla_ops.mla_verify_attention(
-                    q_eff, q_pe, c_kv, k_pe, k_cache[l], v_cache[l],
+                    q_eff, q_pe, c_kv, k_pe, k_cache, v_cache, l,
                     block_tables, hist_lens, scale,
                     use_pallas=use_pallas and mesh is None,
                     interpret=interpret,

@@ -6,17 +6,20 @@ Here MLA is TPU-native and built around the COMPRESSED cache from the
 start:
 
   * per token the cache stores the kv_lora_rank latent ``c_kv`` (k-cache
-    slot) and the head-shared rotated ``k_pe`` (v-cache slot) — a
-    single-"head" paged layout ``[L, 1, N, bs, D]`` that rides the
-    existing block tables / allocator / offload / transfer machinery
-    unchanged (the two caches just have different trailing dims);
+    slot) and the head-shared rotated ``k_pe`` (v-cache slot, in whole
+    128-lane rows: ``llama.rope_lanes``, 64 values in 128 lanes, upper
+    lanes zero, so that the decode kernel can cut its pages out of the
+    pool itself) — a single-"head" paged layout ``[L, 1, N, bs, D]``
+    that rides the existing block tables / allocator / offload /
+    transfer machinery unchanged (the two caches just have different
+    trailing dims);
   * attention runs ABSORBED: q_nope is folded through the kv_b
     up-projection once per layer (``q_eff = q_nope @ w_kc``), scores are
     ``q_eff . c_kv + q_pe . k_pe`` against raw latents, and the output
     latent folds back through ``w_vc`` — no per-token reconstruction of
     full K/V, so HBM reads per step stay at
-    ``kv_lora_rank + qk_rope_head_dim`` bytes/token (the entire point of
-    MLA; 576 vs 2*128*Hkv for V3);
+    ``kv_lora_rank + rope_lanes`` values/token (the entire point of
+    MLA; 640 vs 2*128*Hkv for V3);
   * the XLA paths here (dense einsums over gathered pages, MQA-shaped:
     one shared KV stream, H query heads) are the correctness baseline
     and serve CPU/meshes; single-host TPU decode runs the Pallas latent
@@ -112,11 +115,14 @@ def mla_q_and_latent(lp: dict, cfg: ModelConfig, x: jnp.ndarray,
     x: [..., E] with arbitrary leading batch dims; positions broadcasts
     against them (prefill [T]/[T,E], decode [B]/[B,E], verify [B,T]/
     [B,T,E]).
-    Returns (q_eff [..., H, C], q_pe [..., H, R], c_kv [..., C],
-    k_pe [..., R])
-    with C = kv_lora_rank, R = qk_rope_head_dim. q_eff is the ABSORBED
-    query (q_nope @ w_kc) scoring directly against cache latents."""
-    from .llama import _mm, model_norm
+    Returns (q_eff [..., H, C], q_pe [..., H, Rl], c_kv [..., C],
+    k_pe [..., Rl])
+    with C = kv_lora_rank and the rotated qk_rope_head_dim values in
+    Rl = llama.rope_lanes lanes, the rope pool's row (upper lanes zero:
+    they add nothing to q_pe . k_pe). q_eff is the ABSORBED query
+    (q_nope @ w_kc) scoring directly against cache latents."""
+    from ..ops.mla_attention_pallas import rope_to_lanes
+    from .llama import _mm, model_norm, rope_lanes
 
     H = cfg.num_heads
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -134,6 +140,8 @@ def mla_q_and_latent(lp: dict, cfg: ModelConfig, x: jnp.ndarray,
     k_pe = kv[..., cfg.kv_lora_rank:]
     k_pe = rope_rotate(k_pe[..., None, :], positions, inv_freq,
                        mscale)[..., 0, :]
+    q_pe = rope_to_lanes(q_pe, rope_lanes(cfg))
+    k_pe = rope_to_lanes(k_pe, rope_lanes(cfg))
 
     w_kc, _ = _wkv_b_parts(lp, cfg)
     # fold q_nope through the k up-projection: [T, H, dn] x [C, H, dn]

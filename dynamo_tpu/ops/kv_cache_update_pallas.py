@@ -52,6 +52,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 
+def _in_hbm(cache):
+    """``out_shape`` of an aliased cache, pinned to HBM (and with it the
+    input it aliases). The kernels touch a page tile a grid step; the
+    TPU compiler, which takes a custom call to read its operands whole,
+    otherwise stages a pool that fits its fast memory around the call,
+    in and out again: a latent model's 50 MB rope pool three times a
+    decode step (PERF.md section 6, PR 53). Pools of GiBs never were.
+    The price: a program that donates the caches and whose ROOT is the
+    call itself (an append jitted on its own) does not compile, the
+    pinned result and the unpinned parameter fail the compiler's alias
+    check. Behind anything at all it does (every step program;
+    ``scripts/validate_tpu_kernels.py`` puts a barrier there)."""
+    return pltpu.HBM(cache.shape, cache.dtype)
+
+
 def _land_row(tile, new_row, row):
     """``tile`` [Hkv, bs, D] f32 with sublane ``row`` replaced by
     ``new_row`` [Hkv, 1, D] f32 — a full-tile select on an iota mask.
@@ -468,10 +483,7 @@ def kv_cache_append_tokens(
         k_cache, v_cache = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-            ],
+            out_shape=[_in_hbm(k_cache), _in_hbm(v_cache)],
             input_output_aliases={4: 0, 5: 1},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
@@ -549,10 +561,7 @@ def _append_call(k_new, v_new, k_cache, v_cache, blk, off, interpret=False):
     return pl.pallas_call(
         _append_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-        ],
+        out_shape=[_in_hbm(k_cache), _in_hbm(v_cache)],
         # +2 for the scalar-prefetch args: pallas numbers aliases over the
         # FULL operand list including prefetch scalars
         input_output_aliases={4: 0, 5: 1},

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: paged LATENT attention for MLA decode.
+"""Pallas TPU kernels: paged LATENT attention for MLA decode and prefill.
 
 The reference serves DeepSeek through vLLM, whose GPU MLA path pairs a
 fused latent decode kernel with reshape_and_cache (README workloads;
@@ -10,22 +10,53 @@ are the two-part absorbed dot ``q_eff . c_kv + q_pe . k_pe``, and the
 VALUES are the ``c_kv`` latents themselves (the caller folds the output
 latent through w_vc).
 
-Design mirrors ops/paged_attention_pallas (the decode make-or-break,
-SURVEY §7): grid = (batch, superblocks of P logical pages), the block
-table scalar-prefetched so per-page ``index_map``s DMA exactly the
-needed physical [bs, C] / [bs, R] tiles (pages past a sequence's length
-re-map to its last valid page — consecutive identical indices skip the
-re-fetch), fp32 online softmax in VMEM scratch, output written once.
-The kv-head grid axis is gone (Hkv == 1 by construction) and the H
-query heads pack the row dimension — H is 16..128 for real DeepSeek
-configs, so the score matrix [H, P*bs] is MXU-shaped without the
-query-group packing the GQA kernel needs.
+Decode (``mla_paged_decode_attention``) has the form of
+``ops/paged_attention_pallas.paged_decode_attention`` (PERF.md section
+6, PRs 29, 31 and 53):
+
+  * the operands are the WHOLE caches, ``c_cache [L, 1, N, bs, C]`` and
+    ``pe_cache [L, 1, N, bs, Rl]``, once each, left in HBM
+    (``memory_space=pl.ANY``), and the layer as a value (scalar
+    prefetch): a program's layer-calls share one Mosaic kernel, a traced
+    index may be passed, and a caller that holds one layer's slab passes
+    ``slab[None]`` and layer 0 (a bitcast). A ``c_cache[l]`` operand of a
+    custom call is a copy of a slab a layer-call.
+  * grid = ``(B,)``: the table's width is not in the grid. Inside a grid
+    step the kernel walks the row's OWN superblocks, ``cdiv(seq_len,
+    P * bs)`` of them, with the GQA kernel's page pipeline
+    (``_page_walk``): one DMA a page and operand (``c_cache.at[layer,
+    0, page]`` -> ``[bs, C]``, ``pe_cache.at[layer, 0, page]`` ->
+    ``[bs, Rl]``) into a two-slot VMEM scratch, superblock ``i + 1`` in
+    flight while ``i`` is scored; a page past the row's last is not
+    fetched and its latents in the slot are blanked (they ARE the
+    values); a row of length <= 0 runs no trip. A grid over ``M // P``
+    superblocks ran 1,024 steps a call of which 120-130 scored anything
+    in ``gigachat35.reason`` (``attn_table_live_share`` 11.5 %).
+  * Mosaic cuts a page out of an HBM ref only along whole 128-lane
+    tiles, so the rope pool holds ``k_pe`` in ``Rl`` lanes, ``R`` rounded
+    up to 128 (``models/llama.py`` ``rope_lanes``; upper lanes zero, which
+    add nothing to ``q_pe . k_pe``). That is also the form the chip's
+    default layout keeps row-major: a pool of 64 lanes was laid pages-minor
+    and every program re-laid it at entry and exit and staged it around
+    each custom call.
+  * the kv-head grid axis is gone (Hkv == 1 by construction) and the H
+    query heads pack the row dimension: H is 16..128 for real DeepSeek
+    configs, so the score matrix ``[H, P*bs]`` is MXU-shaped without the
+    query-group packing the GQA kernel needs. fp32 online softmax over
+    the row's superblocks in their order, output written once.
 
 The stats-emitting variant (m, l) powers the MERGED one-write decode:
 attention handles the current token out-of-cache (flash merge), so the
 step batches all layers' latent writes into one in-place append
 (ops/kv_cache_update_pallas) instead of 2L XLA scatters that each copy
 the cache.
+
+Prefill (``mla_paged_prefill_attention``) keeps the grid (q tiles,
+superblocks of the table) and one layer's slab as ``P`` BlockSpec
+streams an operand: the lone prefill's slab is every family's
+(ROADMAP A5), one chunk's queries score against most of their table,
+and no decode stream waits on it. It reads the same ``Rl``-lane rope
+pool.
 """
 
 from __future__ import annotations
@@ -38,18 +69,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-# one superblock-sizing policy for every paged kernel (GQA and MLA pick
-# the same page pipeline for the same block table)
-from .paged_attention_pallas import _pick_pages_per_step
+# one superblock-sizing policy and one page pipeline for every paged
+# decode kernel (GQA and MLA walk the same block table the same way)
+from .paged_attention_pallas import _page_walk, _pick_pages_per_step
 
 _NEG_INF = -1e30
+
+
+def rope_to_lanes(pe: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    """``q_pe`` / ``k_pe [..., R]`` in the rope pool's ``lanes``, zeros
+    above ``R`` (they add nothing to ``q_pe . k_pe``)."""
+    pad = lanes - pe.shape[-1]
+    if pad == 0:
+        return pe
+    return jnp.pad(pe, [(0, 0)] * (pe.ndim - 1) + [(0, pad)])
 
 
 def _mla_decode_kernel(
     # scalar prefetch
     block_tables_ref,  # [B, M] int32 (SMEM)
     seq_lens_ref,  # [B] int32 (SMEM)
-    # inputs: q_eff, q_pe, then P c-page refs then P pe-page refs
+    layer_ref,  # [1] int32 (SMEM): the layer every page fetch reads
+    # inputs: q_eff, q_pe (VMEM blocks), the whole caches (HBM)
+    qc_ref,  # [1, Hp, C]
+    qp_ref,  # [1, Hp, Rl]
+    c_hbm,  # [L, 1, N, bs, C]
+    pe_hbm,  # [L, 1, N, bs, Rl]
+    # outputs (o [1, Hp, C] [+ m, l [1, Hp, 128]]), then the scratch
     *refs,
     scale: float,
     block_size: int,
@@ -57,42 +103,53 @@ def _mla_decode_kernel(
     return_stats: bool,
 ):
     P = pages_per_step
-    qc_ref = refs[0]  # [1, Hp, C]
-    qp_ref = refs[1]  # [1, Hp, R]
-    c_refs = refs[2 : 2 + P]  # each [1, 1, bs, C]
-    pe_refs = refs[2 + P : 2 + 2 * P]  # each [1, 1, bs, R]
-    if return_stats:
-        o_ref, mo_ref, lo_ref = refs[2 + 2 * P : 5 + 2 * P]
-        m_scr, l_scr, acc_scr = refs[5 + 2 * P :]
-    else:
-        o_ref = refs[2 + 2 * P]  # [1, Hp, C]
-        m_scr, l_scr, acc_scr = refs[3 + 2 * P :]
+    n_out = 3 if return_stats else 1
+    o_ref, *stat_refs = refs[:n_out]
+    # c_buf [2, P, bs, C] / pe_buf [2, P, bs, Rl]: two slots of one
+    # superblock's pages; sems [2, 2] DMA semaphores (operand, slot)
+    c_buf, pe_buf, sems, m_scr, l_scr, acc_scr = refs[n_out:]
 
     b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
     seq_len = seq_lens_ref[b]
-    start = i * (P * block_size)
+    layer = layer_ref[0]
+    span = P * block_size
+    last_page = (seq_len - 1) // block_size
+    # the row's own superblocks: none for a row of length <= 0
+    last = (seq_len + span - 1) // span
 
-    @pl.when(start < seq_len)
-    def _superblock():
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    pages_of, fetch, arrive = _page_walk(
+        block_tables_ref, b, last_page, P,
+        [(lambda page, hbm=hbm: hbm.at[layer, 0, page], buf)
+         for hbm, buf in ((c_hbm, c_buf), (pe_hbm, pe_buf))],
+        sems, c_buf,  # the latents ARE the values
+    )
+
+    @pl.when(0 < last)
+    def _warm_up():
+        fetch(0, 0)
+
+    def superblock(i, slot):
+        # superblock i + 1 is in flight while i is computed
+        @pl.when(i + 1 < last)
+        def _prefetch():
+            fetch(i + 1, 1 - slot)
+
+        arrive(pages_of(i), slot)
+        start = i * span
         qc = qc_ref[0].astype(jnp.float32) * scale  # [Hp, C]
-        qp = qp_ref[0].astype(jnp.float32) * scale  # [Hp, R]
+        qp = qp_ref[0].astype(jnp.float32) * scale  # [Hp, Rl]
         c = jnp.concatenate(
-            [r[0, 0] for r in c_refs], axis=0
+            [c_buf[slot, p] for p in range(P)], axis=0
         ).astype(jnp.float32)  # [P*bs, C]
-        pe = jnp.concatenate([r[0, 0] for r in pe_refs], axis=0).astype(
-            jnp.float32
-        )  # [P*bs, R]
+        pe = jnp.concatenate(
+            [pe_buf[slot, p] for p in range(P)], axis=0
+        ).astype(jnp.float32)  # [P*bs, Rl]
         # two-part absorbed score; separate dots keep each contracted dim
-        # at its natural width (C and R) instead of a concat at C+R,
-        # which is rarely lane-aligned (576 for V2/V3)
+        # at its own whole-tile width (C and Rl) instead of a concat
         s = jax.lax.dot_general(
             qc, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) + jax.lax.dot_general(
@@ -113,14 +170,16 @@ def _mla_decode_kernel(
         )  # values ARE the latents
         m_scr[...] = jnp.broadcast_to(m_cur, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_cur, l_scr.shape)
+        return 1 - slot
 
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _emit():
-        l = jnp.maximum(l_scr[:, 0:1], 1e-20)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        if return_stats:
-            mo_ref[0] = m_scr[...]
-            lo_ref[0] = l_scr[...]
+    jax.lax.fori_loop(0, last, superblock, 0)
+
+    l = jnp.maximum(l_scr[:, 0:1], 1e-20)
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    if return_stats:
+        mo_ref, lo_ref = stat_refs
+        mo_ref[0] = m_scr[...]
+        lo_ref[0] = l_scr[...]
 
 
 @functools.partial(
@@ -129,9 +188,10 @@ def _mla_decode_kernel(
 )
 def mla_paged_decode_attention(
     q_eff: jnp.ndarray,  # [B, H, C] absorbed queries
-    q_pe: jnp.ndarray,  # [B, H, R]
-    c_cache_layer: jnp.ndarray,  # [1, N, bs, C]
-    pe_cache_layer: jnp.ndarray,  # [1, N, bs, R]
+    q_pe: jnp.ndarray,  # [B, H, R] (R <= Rl)
+    c_cache: jnp.ndarray,  # [L, 1, N, bs, C]: the whole cache
+    pe_cache: jnp.ndarray,  # [L, 1, N, bs, Rl]
+    layer,  # int or int32 scalar (may be traced): the layer to read
     block_tables: jnp.ndarray,  # [B, M] int32
     seq_lens: jnp.ndarray,  # [B] int32
     scale: float,
@@ -140,48 +200,49 @@ def mla_paged_decode_attention(
     interpret: bool = False,
 ):  # [B, H, C] f-out, or (out, m [B, H], l [B, H]) when return_stats
     B, H, C = q_eff.shape
-    _, N, bs, R = pe_cache_layer.shape
+    _, _, N, bs, Rl = pe_cache.shape
     M = block_tables.shape[1]
     P = pages_per_step or _pick_pages_per_step(M)
     if M % P:
         raise ValueError(
             f"pages_per_step={P} must divide table width M={M} "
-            "(a truncated grid would silently drop tail pages)"
+            "(the last superblock would reach past the table)"
         )
     Hp = max(8, -(-H // 8) * 8)  # fp32 sublane quantum
     qc = q_eff.astype(jnp.float32)
-    qp = q_pe.astype(jnp.float32)
+    qp = rope_to_lanes(q_pe, Rl).astype(jnp.float32)
     if Hp != H:
         qc = jnp.pad(qc, ((0, 0), (0, Hp - H), (0, 0)))
         qp = jnp.pad(qp, ((0, 0), (0, Hp - H), (0, 0)))
 
-    def page_index(j):
-        def index(b, i, bt, sl):
-            last = jnp.maximum(sl[b] - 1, 0) // bs
-            return (0, bt[b, jnp.minimum(i * P + j, last)], 0, 0)
+    # index maps see every scalar-prefetch ref; ``*_`` absorbs them
+    def row_index(b, *_):
+        return (b, 0, 0)
 
-        return index
-
-    c_specs = [pl.BlockSpec((1, 1, bs, C), page_index(j)) for j in range(P)]
-    pe_specs = [pl.BlockSpec((1, 1, bs, R), page_index(j)) for j in range(P)]
-    o_spec = pl.BlockSpec((1, Hp, C), lambda b, i, bt, sl: (b, 0, 0))
-    stat_spec = pl.BlockSpec((1, Hp, 128), lambda b, i, bt, sl: (b, 0, 0))
+    o_spec = pl.BlockSpec((1, Hp, C), row_index)
+    stat_spec = pl.BlockSpec((1, Hp, 128), row_index)
     out_specs = [o_spec, stat_spec, stat_spec] if return_stats else o_spec
     out_shape = jax.ShapeDtypeStruct((B, Hp, C), q_eff.dtype)
     if return_stats:
         stat_shape = jax.ShapeDtypeStruct((B, Hp, 128), jnp.float32)
         out_shape = [out_shape, stat_shape, stat_shape]
+    # the caches stay in HBM, whole: the kernel fetches the pages a row
+    # holds itself, so no grid dimension has the table's width
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, M // P),
+        num_scalar_prefetch=3,
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, Hp, C), lambda b, i, bt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, Hp, R), lambda b, i, bt, sl: (b, 0, 0)),
-            *c_specs,
-            *pe_specs,
+            pl.BlockSpec((1, Hp, C), row_index),
+            pl.BlockSpec((1, Hp, Rl), row_index),
+            in_hbm,
+            in_hbm,
         ],
         out_specs=out_specs,
         scratch_shapes=[
+            pltpu.VMEM((2, P, bs, C), c_cache.dtype),
+            pltpu.VMEM((2, P, bs, Rl), pe_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((Hp, 128), jnp.float32),
             pltpu.VMEM((Hp, 128), jnp.float32),
             pltpu.VMEM((Hp, C), jnp.float32),
@@ -191,24 +252,31 @@ def mla_paged_decode_attention(
         _mla_decode_kernel, scale=scale, block_size=bs, pages_per_step=P,
         return_stats=return_stats,
     )
+    caches = (c_cache, pe_cache)
+    if not interpret:
+        # pinned: left to choose (``pl.ANY``), the compiler stages a pool
+        # that fits its fast memory around the call, whole, for a kernel
+        # that reads a few pages of it (the interpreter knows no spaces)
+        caches = [pltpu.with_memory_space_constraint(c, pltpu.HBM)
+                  for c in caches]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
+        # an upper bound, what a batch of full rows costs: the walk
+        # follows seq_lens, which no shape tells the scheduler
         cost_estimate=pl.CostEstimate(
-            flops=2 * B * H * M * bs * (C + R + C),
-            bytes_accessed=(
-                M * bs * (C + R) * c_cache_layer.dtype.itemsize * B
-            ),
+            flops=2 * B * H * M * bs * (C + Rl + C),
+            bytes_accessed=M * bs * (C + Rl) * c_cache.dtype.itemsize * B,
             transcendentals=B * H * M * bs,
         ),
         interpret=interpret,
     )(
-        block_tables, seq_lens, qc, qp,
-        *([c_cache_layer] * P), *([pe_cache_layer] * P),
+        block_tables, seq_lens, jnp.asarray(layer, jnp.int32).reshape(1),
+        qc, qp, *caches,
     )
     if return_stats:
         o, m, l = out
@@ -221,8 +289,9 @@ def mla_decode_attention_merged(
     q_pe: jnp.ndarray,  # [B, H, R]
     c_new: jnp.ndarray,  # [B, C] current token's latent (NOT in cache)
     pe_new: jnp.ndarray,  # [B, R] current token's rotated k_pe
-    c_cache_layer: jnp.ndarray,  # [1, N, bs, C] history only
-    pe_cache_layer: jnp.ndarray,  # [1, N, bs, R]
+    c_cache: jnp.ndarray,  # [L, 1, N, bs, C] history only: the whole cache
+    pe_cache: jnp.ndarray,  # [L, 1, N, bs, Rl]
+    layer,  # int or int32 scalar (may be traced): the layer to read
     block_tables: jnp.ndarray,  # [B, M]
     hist_lens: jnp.ndarray,  # [B] tokens in cache (EXCLUDES current)
     scale: float,
@@ -236,7 +305,7 @@ def mla_decode_attention_merged(
     all layers' latent writes batch into one in-place append.
     hist_lens == 0 rows degenerate cleanly to out = c_new."""
     o_h, m_h, l_h = mla_paged_decode_attention(
-        q_eff, q_pe, c_cache_layer, pe_cache_layer, block_tables, hist_lens,
+        q_eff, q_pe, c_cache, pe_cache, layer, block_tables, hist_lens,
         scale, return_stats=True, interpret=interpret,
     )
     o_h = o_h.astype(jnp.float32)
@@ -339,7 +408,7 @@ def mla_paged_prefill_attention(
     q_eff: jnp.ndarray,  # [T, H, C] chunk's absorbed queries
     q_pe: jnp.ndarray,  # [T, H, R]
     c_cache_layer: jnp.ndarray,  # [1, N, bs, C] — chunk ALREADY written
-    pe_cache_layer: jnp.ndarray,  # [1, N, bs, R]
+    pe_cache_layer: jnp.ndarray,  # [1, N, bs, Rl]
     block_table: jnp.ndarray,  # [M] int32, covers history + padded chunk
     history_len: jnp.ndarray,  # scalar int32
     scale: float,
@@ -379,7 +448,7 @@ def mla_paged_prefill_attention(
         return q.reshape(1, Tpad * Hp, D)
 
     qc = pack(q_eff, C)
-    qp = pack(q_pe, R)
+    qp = pack(rope_to_lanes(q_pe, R), R)
 
     def page_index(p):
         def index(j, i, bt, hist):
@@ -476,8 +545,9 @@ def mla_verify_attention(
     q_pe: jnp.ndarray,  # [B, T, H, R]
     c_win: jnp.ndarray,  # [B, T, C] their latents (NOT in cache)
     pe_win: jnp.ndarray,  # [B, T, R]
-    c_cache_layer: jnp.ndarray,  # [1, N, bs, C] history only
-    pe_cache_layer: jnp.ndarray,  # [1, N, bs, R]
+    c_cache: jnp.ndarray,  # [L, 1, N, bs, C] history only: the whole cache
+    pe_cache: jnp.ndarray,  # [L, 1, N, bs, Rl]
+    layer,  # int or int32 scalar: the layer to read
     block_tables: jnp.ndarray,  # [B, M]
     hist_lens: jnp.ndarray,  # [B] tokens in cache (before the window)
     scale: float,
@@ -498,7 +568,7 @@ def mla_verify_attention(
     if use_pallas:
         o_h, m_h, l_h = mla_paged_decode_attention(
             q_eff.reshape(B, T * H, C), q_pe.reshape(B, T * H, R),
-            c_cache_layer, pe_cache_layer, block_tables, hist_lens, scale,
+            c_cache, pe_cache, layer, block_tables, hist_lens, scale,
             return_stats=True, interpret=interpret,
         )
         o_h = o_h.reshape(B, T, H, C).astype(jnp.float32)
@@ -506,17 +576,19 @@ def mla_verify_attention(
         l_h = l_h.reshape(B, T, H)
     else:
         M = block_tables.shape[1]
-        bs = c_cache_layer.shape[2]
-        ck = jnp.take(c_cache_layer[0], block_tables, axis=0).reshape(
+        bs = c_cache.shape[3]
+        # the XLA twin cuts its own layer (a gather reads it in place)
+        ck = jnp.take(c_cache[layer, 0], block_tables, axis=0).reshape(
             B, M * bs, C
         )
-        kp = jnp.take(pe_cache_layer[0], block_tables, axis=0).reshape(
+        kp = jnp.take(pe_cache[layer, 0], block_tables, axis=0).reshape(
             B, M * bs, -1
         )
+        q_pe_l = rope_to_lanes(q_pe, kp.shape[-1])
         s = (
             jnp.einsum("bthc,bsc->bths", q_eff.astype(jnp.float32) * scale,
                        ck.astype(jnp.float32))
-            + jnp.einsum("bthr,bsr->bths", q_pe.astype(jnp.float32) * scale,
+            + jnp.einsum("bthr,bsr->bths", q_pe_l.astype(jnp.float32) * scale,
                          kp.astype(jnp.float32))
         )
         valid = jnp.arange(M * bs)[None, :] < hist_lens[:, None]  # [B, S]
@@ -552,8 +624,9 @@ def mla_decode_attention_merged_sharded(
     q_pe: jnp.ndarray,  # [B, H, R], H sharded over tp
     c_new: jnp.ndarray,  # [B, C] replicated
     pe_new: jnp.ndarray,  # [B, R] replicated
-    c_cache_layer: jnp.ndarray,  # [1, N, bs, C] replicated
-    pe_cache_layer: jnp.ndarray,  # [1, N, bs, R] replicated
+    c_cache: jnp.ndarray,  # [L, 1, N, bs, C] replicated: the whole cache
+    pe_cache: jnp.ndarray,  # [L, 1, N, bs, Rl] replicated
+    layer,  # int or int32 scalar: the layer to read
     block_tables: jnp.ndarray,  # [B, M] replicated
     hist_lens: jnp.ndarray,  # [B] replicated
     scale: float,
@@ -582,10 +655,11 @@ def mla_decode_attention_merged_sharded(
             P(),  # pe_new
             P(),  # c cache
             P(),  # pe cache
+            P(),  # layer
             P(),  # tables
             P(),  # hist_lens
         ),
         out_specs=P(None, "tp", None),
         check_vma=False,
-    )(q_eff, q_pe, c_new, pe_new, c_cache_layer, pe_cache_layer,
-      block_tables, hist_lens)
+    )(q_eff, q_pe, c_new, pe_new, c_cache, pe_cache,
+      jnp.asarray(layer, jnp.int32), block_tables, hist_lens)

@@ -122,6 +122,60 @@ def _pick_heads_per_step(Hkv, Gp, D, bs, P, itemsize) -> int:
     return 1
 
 
+def _page_walk(block_tables_ref, b, last_page, P, streams, sems, values):
+    """A decode grid step's page pipeline over row ``b`` of the table:
+    ``(pages_of, fetch, arrive)``, shared by the GQA kernel here and the
+    latent kernel (``ops/mla_attention_pallas.py``). ``streams`` is one
+    ``(source, buf)`` an operand: ``source(page)`` the HBM view of one
+    physical page, ``buf`` ``[2, P, ...]`` its two slots of a
+    superblock's pages; ``sems`` ``[operands, 2]`` DMA semaphores;
+    ``values`` the buffer whose rows reach the accumulator."""
+
+    def pages_of(i):
+        """(held, physical page) of superblock ``i``'s ``P`` pages, the
+        one rule of the fetch, its wait and the int8 lane's scale lookup:
+        a page past the row's last is not ``held`` and never fetched, and
+        names the last one (no table entry that no sequence holds is
+        read)."""
+        return [
+            (i * P + p <= last_page,
+             block_tables_ref[b, jnp.minimum(i * P + p, last_page)])
+            for p in range(P)
+        ]
+
+    def page_copies(page, slot, p):
+        """The copies of one page into ``slot``, one DMA an operand (a
+        GQA page's ``Hh`` heads are one strided DMA)."""
+        return [
+            pltpu.make_async_copy(
+                source(page), buf.at[slot, p], sems.at[o, slot])
+            for o, (source, buf) in enumerate(streams)
+        ]
+
+    def fetch(i, slot):
+        for p, (held, page) in enumerate(pages_of(i)):
+            @pl.when(held)
+            def _start():
+                for copy in page_copies(page, slot, p):
+                    copy.start()
+
+    def arrive(pages, slot):
+        for p, (held, page) in enumerate(pages):
+            @pl.when(held)
+            def _wait():
+                for copy in page_copies(page, slot, p):
+                    copy.wait()
+
+            # what the slot holds of an unfetched page is whatever was
+            # there: its scores are masked below, but 0 x NaN of a stale
+            # value row would still reach the accumulator
+            @pl.when(jnp.logical_not(held))
+            def _blank():
+                values[slot, p] = jnp.zeros(values.shape[2:], values.dtype)
+
+    return pages_of, fetch, arrive
+
+
 def _decode_kernel(
     # scalar prefetch [+ k_scales, v_scales [N] f32 when has_scales]
     block_tables_ref,  # [B, M] int32 (SMEM)
@@ -179,50 +233,12 @@ def _decode_kernel(
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def pages_of(i):
-        """(held, physical page) of superblock ``i``'s ``P`` pages, the
-        one rule of the fetch, its wait and the int8 lane's scale lookup:
-        a page past the row's last is not ``held`` and never fetched, and
-        names the last one (no table entry that no sequence holds is
-        read)."""
-        return [
-            (i * P + p <= last_page,
-             block_tables_ref[b, jnp.minimum(i * P + p, last_page)])
-            for p in range(P)
-        ]
-
-    def page_copies(page, slot, p):
-        """The K and the V copy of one page into ``slot``: one strided
-        DMA of ``Hh`` heads each."""
-        return [
-            pltpu.make_async_copy(
-                hbm.at[layer, pl.ds(h * Hh, Hh), page],
-                buf.at[slot, p],
-                sems.at[o, slot],
-            )
-            for o, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))
-        ]
-
-    def fetch(i, slot):
-        for p, (held, page) in enumerate(pages_of(i)):
-            @pl.when(held)
-            def _start():
-                for copy in page_copies(page, slot, p):
-                    copy.start()
-
-    def arrive(pages, slot):
-        for p, (held, page) in enumerate(pages):
-            @pl.when(held)
-            def _wait():
-                for copy in page_copies(page, slot, p):
-                    copy.wait()
-
-            # what the slot holds of an unfetched page is whatever was
-            # there: its scores are masked below, but 0 x NaN of a stale
-            # V row would still reach the accumulator
-            @pl.when(jnp.logical_not(held))
-            def _blank():
-                v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+    pages_of, fetch, arrive = _page_walk(
+        block_tables_ref, b, last_page, P,
+        [(lambda page, hbm=hbm: hbm.at[layer, pl.ds(h * Hh, Hh), page], buf)
+         for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf))],
+        sems, v_buf,
+    )
 
     @pl.when(first < last)
     def _warm_up():

@@ -185,7 +185,7 @@ def kv_row_bytes(cfg: ModelConfig, kv_dtype: str = "model") -> float:
     if b is None:
         b = _DTYPE_BYTES.get(cfg.dtype, 2)
     if cfg.is_mla:
-        per_layer = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        per_layer = cfg.kv_lora_rank + llama.rope_lanes(cfg)
     else:
         per_layer = 2 * cfg.num_kv_heads * llama.kv_lanes(cfg)
     row = float(per_layer * b * cfg.num_layers)

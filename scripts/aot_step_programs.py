@@ -53,32 +53,40 @@ def _flag(flags: list, name: str, default=None):
     return flags[flags.index(name) + 1] if name in flags else default
 
 
-def _pool_sized(text: str, pool: tuple) -> dict[str, int]:
+def _pool_sized(text: str, *pools: tuple) -> dict[str, int]:
     """opcode -> how many instructions give a result with as many
-    elements as the pool or as one layer's slab, in whatever shape (the
-    compiler scatters into the pool as ``[L*Hkv*N*bs, D]``). Parameters,
-    bitcasts and tuple plumbing make no array. What may stand here: the
-    in-place writers (``scatter`` and the fusion around it, the append
-    kernels' ``custom-call``). A ``copy``, ``slice``, ``dynamic-slice``,
+    elements as one of ``pools`` (a latent cache's second pool, the
+    rotary keys, is narrower than its first) or as one layer's slab of
+    it, in whatever shape of rows as wide as the pool's (the compiler
+    scatters into the pool as ``[L*Hkv*N*bs, D]``). Parameters, bitcasts and tuple plumbing make no
+    array. What may stand here: the in-place writers (``scatter`` and the
+    fusion around it, the append kernels' ``custom-call``). A ``copy``,
+    ``copy-start`` (a staging into another memory space: its result is a
+    tuple, whose first shape is counted), ``slice``, ``dynamic-slice``,
     ``dynamic-update-slice`` or ``transpose`` of that size is a copy of
     the pool or of a slab."""
-    slab = 1
-    for d in pool[1:]:
-        slab *= d
-    sizes = {slab, slab * pool[0]}
+    sizes = set()  # (elements, the row's width)
+    for pool in pools:
+        slab = 1
+        for d in pool[1:]:
+            slab *= d
+        sizes |= {(slab, pool[-1]), (slab * pool[0], pool[-1])}
     found: dict[str, int] = {}
     for line in text.splitlines():
-        m = re.match(
-            r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
-        if not m:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]+)\]", line)
+        # the opcode stands behind the result's type: ``...} copy(`` or,
+        # behind a tuple's, ``...) copy-start(``
+        op = m and re.search(r"[\]})] ([a-z][\w-]*)\(", line[m.end(1):])
+        if not op:
             continue
+        dims = [int(d) for d in m.group(1).split(",")]
         elems = 1
-        for d in m.group(1).split(","):
-            elems *= int(d)
-        op = m.group(2)
-        if elems in sizes and op not in (
-                "parameter", "bitcast", "get-tuple-element"):
-            found[op] = found.get(op, 0) + 1
+        for d in dims:
+            elems *= d
+        if (elems, dims[-1]) in sizes and op.group(1) not in (
+                "parameter", "bitcast", "get-tuple-element", "tuple",
+                "while", "copy-done"):
+            found[op.group(1)] = found.get(op.group(1), 0) + 1
     return found
 
 
@@ -229,7 +237,7 @@ def main() -> int:
         compiled = lowered[name](t).compile()
         text = compiled.as_text()
         mem = compiled.memory_analysis()
-        hits = _pool_sized(text, pool)
+        hits = _pool_sized(text, pool, pool_v)
         print(f"{name} ({t if name != 'decode' else args.window}): "
               f"arguments {mem.argument_size_in_bytes / GIB:.3f} GiB, "
               f"temporaries {mem.temp_size_in_bytes / GIB:.4f} GiB "

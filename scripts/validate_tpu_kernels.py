@@ -99,6 +99,16 @@ def chip(*xs):
     return out if len(out) > 1 else out[0]
 
 
+def on_its_own(append):
+    """An append kernel as a program of its own. Its aliased outputs are
+    pinned to HBM (``kv_cache_update_pallas._in_hbm``); as the ROOT of a
+    program that donates the caches the pinned result meets an unpinned
+    parameter and the compiler's alias check refuses the pair. Every step
+    program has something behind the call; here a barrier stands in."""
+    return jax.jit(lambda *args: lax.optimization_barrier(append(*args)),
+                   donate_argnums=(2, 3))
+
+
 class Checker:
     def __init__(self):
         self.failed: list[str] = []
@@ -149,7 +159,8 @@ def check_kernels(check: Checker) -> None:
     for l in range(L):
         ref_k = ref_k.at[l, :, blk, off].set(k_new[l])
         ref_v = ref_v.at[l, :, blk, off].set(v_new[l])
-    got_k, got_v = kv_cache_append(*chip(k_new, v_new, kc, vc, blk, off))
+    got_k, got_v = on_its_own(kv_cache_append)(
+        *chip(k_new, v_new, kc, vc, blk, off))
     check("kv_cache_append k", got_k, ref_k, rtol=0, atol=0)
     check("kv_cache_append v", got_v, ref_v, rtol=0, atol=0)
 
@@ -184,7 +195,7 @@ def check_kernels(check: Checker) -> None:
     kt = jax.random.normal(ks[5], (L, B, Tv, HKV, D), jnp.bfloat16)
     vt = jax.random.normal(ks[6], (L, B, Tv, HKV, D), jnp.bfloat16)
     ref_k, ref_v = kv_cache_append_tokens_xla(kt, vt, kc, vc, blk_t, off_t)
-    got_k, got_v = kv_cache_append_tokens(
+    got_k, got_v = on_its_own(kv_cache_append_tokens)(
         *chip(kt, vt, kc, vc, blk_t, off_t)
     )
     check("kv_cache_append_tokens k", got_k[:, :, 1:], ref_k[:, :, 1:],
@@ -550,33 +561,48 @@ def check_other_families(check: Checker) -> None:
               np.asarray(got[..., D64:], np.float32),
               np.zeros((B, H, lanes - D64), np.float32), rtol=0, atol=0)
 
-    # MLA latent kernels at DeepSeek widths
+    # MLA latent kernels at DeepSeek widths: the whole caches and layer 1
+    # of 2 by its index, the rope row of 64 in the pool's 128 lanes
+    # (llama.rope_lanes); the reference reads layer 1's slab at 64 lanes.
+    # Then a ragged batch with dead slots, whose table entries past a
+    # row's last page are out of range: the walk reads none of them
     C, R, Hm = 512, 64, 16
     ks = jax.random.split(jax.random.key(5), 6)
     q_eff = jax.random.normal(ks[0], (B, Hm, C), jnp.bfloat16)
     q_pe = jax.random.normal(ks[1], (B, Hm, R), jnp.bfloat16)
-    cc = jax.random.normal(ks[2], (1, N, BS, C), jnp.bfloat16)
-    pc = jax.random.normal(ks[3], (1, N, BS, R), jnp.bfloat16)
+    cc = jax.random.normal(ks[2], (2, 1, N, BS, C), jnp.bfloat16)
+    pc = jax.random.normal(ks[3], (2, 1, N, BS, R), jnp.bfloat16)
+    pc_lanes = jnp.pad(pc, [(0, 0)] * 4 + [(0, 128 - R)])
     mscale = (C + R) ** -0.5
-    ref = _mla.mla_decode_attention_xla(
-        q_eff, q_pe, cc, pc, tables, seq_lens, mscale
-    )
-    got = mla_paged_decode_attention(
-        *chip(q_eff, q_pe, cc, pc, tables, seq_lens), mscale
-    )
-    check("mla_paged_decode_attention", got, ref)
     c_new = jax.random.normal(ks[4], (B, C), jnp.bfloat16)
     pe_new = jax.random.normal(ks[5], (B, R), jnp.bfloat16)
-    hist = seq_lens - 1
-    blk, off = att.decode_slot_indices(tables, hist, BS)
-    ref = _mla.mla_decode_attention_xla(
-        q_eff, q_pe, cc.at[0, blk, off].set(c_new),
-        pc.at[0, blk, off].set(pe_new), tables, hist + 1, mscale,
-    )
-    got = mla_decode_attention_merged(
-        *chip(q_eff, q_pe, c_new, pe_new, cc, pc, tables, hist), mscale
-    )
-    check("mla_decode_attention_merged", got, ref)
+    ragged = jnp.asarray(
+        [0, 1, BS, 0, 8 * BS, 8 * BS + 1, 0, M * BS - 1], jnp.int32)
+    for name, lens in (("", seq_lens), (" ragged, dead slots", ragged)):
+        live = np.asarray(lens) > 0
+        held = np.arange(M)[None, :] * BS < np.asarray(lens)[:, None]
+        tbl = jnp.where(held, tables, 2**30) if name else tables
+        ref = _mla.mla_decode_attention_xla(
+            q_eff, q_pe, cc[1], pc[1], tables, lens, mscale
+        )
+        got = mla_paged_decode_attention(
+            *chip(q_eff, q_pe, cc, pc_lanes), 1, *chip(tbl, lens), mscale
+        )
+        check(f"mla_paged_decode_attention{name}", got[live], ref[live])
+        check(f"mla_paged_decode_attention{name}: a dead slot reads 0",
+              got[~live], np.zeros_like(ref[~live]), rtol=0, atol=0)
+        hist = jnp.maximum(lens - 1, 0)
+        blk, off = att.decode_slot_indices(tables, hist, BS)
+        ref = _mla.mla_decode_attention_xla(
+            q_eff, q_pe, cc[1].at[0, blk, off].set(c_new),
+            pc[1].at[0, blk, off].set(pe_new), tables, hist + 1, mscale,
+        )
+        got = mla_decode_attention_merged(
+            *chip(q_eff, q_pe, c_new, pe_new, cc, pc_lanes), 1,
+            *chip(tbl, lens - 1), mscale
+        )
+        # (a slot with no history attends its own token alone, in both)
+        check(f"mla_decode_attention_merged{name}", got[live], ref[live])
 
     # the grouped matmul (MoE experts), the LAST layer of a 2-layer
     # stack by its index: int8 stacks with ragged groups incl. empty

@@ -661,21 +661,31 @@ def test_whole_cache_operand_equals_slab_bitwise(case):
 
 
 def _step_program_jaxpr(program, B=4, M=8, N=40, layers=3):
-    """(jaxpr, cfg, the cache's shape) of a decode program on the
-    kernels' path, traced at a tiny width."""
+    """(jaxpr, cfg, the caches' shapes) of a decode program on the
+    kernels' path, traced at a tiny width. ``<program>-mla``: a latent
+    model, whose second cache (the rotary keys) is narrower."""
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
 
     moe = dict(num_experts=4, num_experts_per_tok=2,
                moe_intermediate_size=32)
-    cfg = ModelConfig.tiny(
-        num_layers=layers, head_dim=128,
-        **(moe if program.endswith("experts") else {}),
-    )
     bs = 16
+    if program.endswith("-mla"):
+        program = program[:-len("-mla")]
+        cfg = ModelConfig.tiny_mla(num_layers=layers, kv_lora_rank=128)
+    else:
+        cfg = ModelConfig.tiny(
+            num_layers=layers, head_dim=128,
+            **(moe if program.endswith("experts") else {}),
+        )
     params = llama.init_params(cfg, jax.random.key(0))
     kc, vc = llama.init_kv_cache(cfg, N, bs)
-    assert kc.shape == (layers, cfg.num_kv_heads, N, bs, 128)
+    if cfg.is_mla:
+        assert kc.shape == (layers, 1, N, bs, 128)
+        assert vc.shape == (layers, 1, N, bs, 8)
+    else:
+        assert kc.shape == vc.shape == (
+            layers, cfg.num_kv_heads, N, bs, 128)
     ints = jnp.ones((B,), jnp.int32)
     floats = jnp.ones((B,), jnp.float32)
     tables = jnp.ones((B, M), jnp.int32)
@@ -702,42 +712,48 @@ def _step_program_jaxpr(program, B=4, M=8, N=40, layers=3):
                 floats, ints, floats, k, v, n_spec=2, **kw,
             )
         )(params, kc, vc)
-    return jaxpr, cfg, kc.shape
+    return jaxpr, cfg, {kc.shape, vc.shape}
 
 
 @pytest.mark.parametrize(
     "program", ["decode_window-dense", "decode_window-experts",
-                "decode_step", "verify_window"]
+                "decode_step", "verify_window", "decode_window-mla",
+                "decode_step-mla", "verify_window-mla"]
 )
 def test_step_programs_hand_the_kernel_the_whole_cache(program):
     """The slab must not come back: in the decode programs every cache
     operand of every ``pallas_call`` is the cache itself, 5-D, and no
     equation cuts an ``[Hkv, N, bs, D]`` layer out of it (the TPU
     compiler materialises a slice that feeds a custom call: a copy of
-    the whole pool a step, PERF.md section 6, PR 29)."""
-    jaxpr, cfg, cache_shape = _step_program_jaxpr(program)
+    the whole pool a step, PERF.md section 6, PR 29). A latent model
+    (PR 53) with more than one latent layer, so that a cut is no
+    bitcast."""
+    jaxpr, cfg, cache_shapes = _step_program_jaxpr(program)
     kernels = 0
     for eqn in _eqns(jaxpr.jaxpr):
-        if eqn.primitive.name == "pallas_call":
-            kernels += 1
-            for var in eqn.invars:
-                shape = var.aval.shape
-                if len(shape) >= 4 and shape[-3:] == cache_shape[-3:]:
-                    assert shape == cache_shape, (
-                        f"a pallas_call takes a {shape} cut of the "
-                        f"{cache_shape} cache"
-                    )
-        for var in eqn.outvars:
-            shape = getattr(var.aval, "shape", ())
-            assert shape not in (cache_shape[1:], (1, *cache_shape[1:])), (
-                f"{eqn.primitive.name} produces a layer slab {shape}"
-            )
+        for cache_shape in cache_shapes:
+            if eqn.primitive.name == "pallas_call":
+                for var in eqn.invars:
+                    shape = var.aval.shape
+                    if len(shape) >= 4 and shape[-3:] == cache_shape[-3:]:
+                        assert shape == cache_shape, (
+                            f"a pallas_call takes a {shape} cut of the "
+                            f"{cache_shape} cache"
+                        )
+            for var in eqn.outvars:
+                shape = getattr(var.aval, "shape", ())
+                assert shape not in (
+                    cache_shape[1:], (1, *cache_shape[1:])), (
+                    f"{eqn.primitive.name} produces a layer slab {shape}"
+                )
+        kernels += eqn.primitive.name == "pallas_call"
     # attention of every layer (+ the merged paths' one append)
     assert kernels >= cfg.num_layers
 
 
 @pytest.mark.parametrize(
-    "program", ["decode_window-dense", "decode_step", "verify_window"]
+    "program", ["decode_window-dense", "decode_step", "verify_window",
+                "decode_window-mla", "decode_step-mla", "verify_window-mla"]
 )
 def test_step_programs_walk_no_grid_over_the_table(program):
     """The table's width must not come back into a grid: in the decode
@@ -751,7 +767,7 @@ def test_step_programs_walk_no_grid_over_the_table(program):
     B, M, layers = 3, 56, 2
     P = _pick_pages_per_step(M)
     assert (P, M // P) == (8, 7)
-    jaxpr, cfg, cache_shape = _step_program_jaxpr(
+    jaxpr, cfg, cache_shapes = _step_program_jaxpr(
         program, B=B, M=M, N=B * M + 1, layers=layers
     )
     attention = 0
@@ -762,16 +778,17 @@ def test_step_programs_walk_no_grid_over_the_table(program):
         assert M // P not in grid and M not in grid, (
             f"a kernel's grid {grid} has the table's width in it"
         )
-        caches = [v for v in eqn.invars if v.aval.shape == cache_shape]
+        caches = [v for v in eqn.invars if v.aval.shape in cache_shapes]
         assert len(caches) <= 2 and len(set(map(id, caches))) == len(caches), (
             f"a kernel takes {len(caches)} cache operands"
         )
         tables = [v for v in eqn.invars if v.aval.shape == (B, M)]
         if tables and not any(
-            v.aval.shape == cache_shape for v in eqn.outvars
+            v.aval.shape in cache_shapes for v in eqn.outvars
         ):
             attention += 1
-            assert grid == (B, 1)  # rows x head tiles
+            # rows x head tiles; a latent cache has one "head": rows
+            assert grid == ((B,) if cfg.is_mla else (B, 1))
     assert attention == layers
 
 

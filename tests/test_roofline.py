@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 from jax import lax
 
+from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.perf import roofline as R
 
@@ -142,7 +143,9 @@ def test_param_bytes_8b_quant_halves_projections():
 def test_kv_row_bytes_mla_is_latent_sized():
     cfg = ModelConfig.deepseek_r1()
     row = R.kv_row_bytes(cfg, "model")
-    assert row == (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * 2 * cfg.num_layers
+    # (the rope row of 64 rides in 128 lanes of the pool: llama.rope_lanes)
+    assert llama.rope_lanes(cfg) == 128 > cfg.qk_rope_head_dim
+    assert row == (cfg.kv_lora_rank + 128) * 2 * cfg.num_layers
     # the latent cache is tiny next to a dense-head equivalent
     dense_row = 2 * cfg.num_kv_heads * (128 + 64) * 2 * cfg.num_layers
     assert row < dense_row / 50

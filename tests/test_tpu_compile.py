@@ -30,6 +30,7 @@ from jax.sharding import SingleDeviceSharding
 B, H, HKV, D, N, BS, M, L = 16, 32, 8, 128, 2048, 16, 128, 16
 T_CHUNK, MP = 512, 2  # prefill chunk rows, mixed-step prefill segments
 C_MLA, R_MLA = 512, 64  # DeepSeek latent / rope widths
+RL_MLA = 128  # a rope row in the pool: 64 in 128 lanes (llama.rope_lanes)
 SCALE = D**-0.5
 
 
@@ -96,17 +97,17 @@ def test_kv_cache_append(chip, dtype):
 
 
 def test_kv_cache_append_mla_latent(chip):
-    """MLA stores c_kv [C] in the k slot and k_pe [R] in the v slot:
-    one kv "head", two different trailing dims, R below a lane tile."""
+    """MLA stores c_kv [C] in the k slot and k_pe in the v slot: one kv
+    "head", two different trailing dims (the rope row in whole lanes)."""
     from dynamo_tpu.ops.kv_cache_update_pallas import kv_cache_append
 
     idx = chip((B,), jnp.int32)
     _compile(
         kv_cache_append,
         chip((L, B, 1, C_MLA), jnp.bfloat16),
-        chip((L, B, 1, R_MLA), jnp.bfloat16),
+        chip((L, B, 1, RL_MLA), jnp.bfloat16),
         _cache(chip, jnp.bfloat16, d=C_MLA, hkv=1),
-        _cache(chip, jnp.bfloat16, d=R_MLA, hkv=1),
+        _cache(chip, jnp.bfloat16, d=RL_MLA, hkv=1),
         idx, idx,
     )
 
@@ -197,8 +198,24 @@ def test_paged_decode_attention(chip, d, dtype, scales, stats, shape):
              chip((b,), jnp.int32), *_scale_planes(chip, scales))
 
 
-@pytest.mark.parametrize("experts,head", [(0, 128), (8, 128), (0, 64)],
-                         ids=["dense", "experts", "head64-sinks-windows"])
+def _pool_sized(text, *pools):
+    """``scripts/aot_step_programs.py``'s reader of an optimized HLO
+    text: opcode -> instructions whose result is as large as a pool or a
+    layer's slab of it."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "aot_step_programs", os.path.join(
+            os.path.dirname(__file__), "..", "scripts",
+            "aot_step_programs.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod._pool_sized(text, *pools)
+
+
+@pytest.mark.parametrize(
+    "experts,head", [(0, 128), (8, 128), (0, 64), (0, 0)],
+    ids=["dense", "experts", "head64-sinks-windows", "mla"])
 def test_decode_window_keeps_no_copy_of_the_pool(chip, experts, head):
     """An unrolled ``decode_window`` on the Pallas path reads its KV where
     it lies: the program's temporaries stay far under its pool. With a
@@ -207,36 +224,65 @@ def test_decode_window_keeps_no_copy_of_the_pool(chip, experts, head):
     temporaries were the pool and more (PERF.md section 6, PR 29). A
     head of 64 with gpt-oss's sinks and alternating windows compiles the
     same kernels: its cache and its q, k and v come 128 lanes wide
-    (``llama.kv_lanes``), the form the kernel's own DMAs can slice."""
+    (``llama.kv_lanes``), the form the kernel's own DMAs can slice.
+    ``mla``: two latent layers (a cut is no bitcast) at DeepSeek's
+    widths. Both pools are parameters in the custom calls' row-major
+    form (a rope pool of 64 lanes came pages-minor and was re-laid at
+    entry and exit), and nothing copies or stages either: the pools are
+    small enough here for the compiler's fast memory, which is where it
+    staged the rope pool three times a step (PERF.md section 6, PR 53)."""
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
 
-    cfg = ModelConfig(
-        vocab_size=2048, hidden_size=512, intermediate_size=1024,
-        num_layers=4, num_heads=4, num_kv_heads=4, head_dim=head,
-        num_experts=experts, num_experts_per_tok=2,
-        moe_intermediate_size=256 if experts else 0,
-        attn_sinks=head == 64,
-        layer_windows=(128, 0, 128, 0) if head == 64 else (),
-    )
+    if head:
+        cfg = ModelConfig(
+            vocab_size=2048, hidden_size=512, intermediate_size=1024,
+            num_layers=4, num_heads=4, num_kv_heads=4, head_dim=head,
+            num_experts=experts, num_experts_per_tok=2,
+            moe_intermediate_size=256 if experts else 0,
+            attn_sinks=head == 64,
+            layer_windows=(128, 0, 128, 0) if head == 64 else (),
+        )
+    else:
+        cfg = ModelConfig(
+            vocab_size=2048, hidden_size=512, intermediate_size=1024,
+            num_layers=2, num_heads=16, num_kv_heads=16, q_lora_rank=256,
+            kv_lora_rank=C_MLA, qk_nope_head_dim=128,
+            qk_rope_head_dim=R_MLA, v_head_dim=128,
+        )
     b, m, n = 8, 32, 1024
     params = jax.tree.map(
         lambda a: chip(a.shape, a.dtype),
         jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
     )
-    cache = chip(llama.kv_cache_shapes(cfg, n, BS)[0], jnp.bfloat16)
+    pools = llama.kv_cache_shapes(cfg, n, BS)
+    caches = [chip(pool, jnp.bfloat16) for pool in pools]
     ints, floats = chip((b,), jnp.int32), chip((b,), jnp.float32)
     compiled = llama.decode_window.lower(
         params, cfg, ints, ints, chip((b, m), jnp.int32), ints, ints, ints,
-        floats, ints, floats, cache, cache, n_steps=2, use_pallas=True,
+        floats, ints, floats, *caches, n_steps=2, use_pallas=True,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     # under a quarter of the pool, and sharper: under half of ONE layer's
     # K slab, since a compiler that reuses one buffer for the slabs of
     # this small program would still pass the quarter (the sliced form
     # measured 30.0 MB here, this form 2.5-3.0 MB, a slab is 16.8 MB)
-    slab = cfg.num_kv_heads * n * BS * llama.kv_lanes(cfg) * 2
+    slab = pools[0][1] * n * BS * pools[0][-1] * 2
     assert compiled.memory_analysis().temp_size_in_bytes < slab // 2
+    if not cfg.is_mla:
+        return
+    assert pools[1][-1] == RL_MLA
+    copied = _pool_sized(text, *pools).keys() & {
+        "copy", "copy-start", "transpose", "slice", "dynamic-slice",
+        "dynamic-update-slice"}
+    assert not copied, f"pool- or slab-sized {sorted(copied)}"
+    for name, pool in zip(("k_cache", "v_cache"), pools):
+        dims = ",".join(map(str, pool))
+        laid = re.findall(
+            rf"%{name}\S* = bf16\[{dims}\]\{{([\d,]+)[:}}][^\n]* parameter\(",
+            text)
+        assert laid == ["4,3,2,1,0"], f"{name} is laid out as {laid}"
 
 
 @pytest.mark.parametrize("tp", [0, 4], ids=["one-chip", "tp4-shard"])
@@ -463,20 +509,29 @@ def test_ragged_mixed_attention(chip, dtype, scales, shape):
 # ---------------- MLA latent kernels ----------------
 
 
-def test_mla_paged_decode_attention(chip):
+@pytest.mark.parametrize(
+    "b,h,m,layers,stats",
+    [(B, H, M, L, True), (32, 64, 256, 1, True), (32, 64, 256, 1, False)],
+    ids=["deepseek-16-layers", "gigachat35-cell", "gigachat35-cell-plain"])
+def test_mla_paged_decode_attention(chip, b, h, m, layers, stats):
+    """The whole caches and a traced layer, the kernel's own page DMAs
+    out of HBM (a rope row of 64 in 128 lanes: Mosaic cuts whole lane
+    tiles only); ``gigachat35-cell``: the benchmark cell's widths, 32
+    slots of 64 heads over a table of 256."""
     from dynamo_tpu.ops.mla_attention_pallas import mla_paged_decode_attention
 
-    def fn(qe, qp, cc, pc, bt, sl):
+    def fn(qe, qp, cc, pc, layer, bt, sl):
         return mla_paged_decode_attention(
-            qe, qp, cc, pc, bt, sl, (C_MLA + R_MLA) ** -0.5,
-            return_stats=True,
+            qe, qp, cc, pc, layer, bt, sl, (C_MLA + R_MLA) ** -0.5,
+            return_stats=stats,
         )
 
-    _compile(fn, chip((B, H, C_MLA), jnp.bfloat16),
-             chip((B, H, R_MLA), jnp.bfloat16),
-             chip((1, N, BS, C_MLA), jnp.bfloat16),
-             chip((1, N, BS, R_MLA), jnp.bfloat16),
-             chip((B, M), jnp.int32), chip((B,), jnp.int32))
+    _compile(fn, chip((b, h, C_MLA), jnp.bfloat16),
+             chip((b, h, R_MLA), jnp.bfloat16),
+             _cache(chip, jnp.bfloat16, d=C_MLA, hkv=1, layers=layers),
+             _cache(chip, jnp.bfloat16, d=RL_MLA, hkv=1, layers=layers),
+             chip((), jnp.int32), chip((b, m), jnp.int32),
+             chip((b,), jnp.int32))
 
 
 def test_mla_paged_prefill_attention(chip):
@@ -492,7 +547,7 @@ def test_mla_paged_prefill_attention(chip):
     _compile(fn, chip((T_CHUNK, H, C_MLA), jnp.bfloat16),
              chip((T_CHUNK, H, R_MLA), jnp.bfloat16),
              chip((1, N, BS, C_MLA), jnp.bfloat16),
-             chip((1, N, BS, R_MLA), jnp.bfloat16),
+             chip((1, N, BS, RL_MLA), jnp.bfloat16),
              chip((M,), jnp.int32), chip((), jnp.int32))
 
 
